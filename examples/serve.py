@@ -8,9 +8,9 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from faabric_tpu.util.device_env import force_cpu_if_requested
+from faabric_tpu.util.device_env import configure_compile_cache
 
-force_cpu_if_requested()
+configure_compile_cache()
 
 import jax
 import jax.numpy as jnp

@@ -4,9 +4,9 @@ The fourth rung of the MPI dispatch ladder (shm → tcp → device): when a
 world's ranks all resolved onto devices of one JAX mesh (registry.py),
 allreduce / allgather / reduce_scatter run as ONE compiled XLA program
 over that mesh instead of chunk-pipelined host rings — on TPU the
-collective rides ICI scheduled by XLA; on this container's CPU backend
-the same code runs over virtual devices (cross-process via the gloo
-collectives layer), which is what the tests and bench drive today.
+collective rides ICI scheduled by XLA; on the CPU backend the same code
+runs over virtual devices (cross-process via the gloo collectives
+layer), which is what the tests drive.
 
 Execution model (multi-controller SPMD): rank threads of one process
 rendezvous per collective — each deposits its buffer, the LAST arriver
@@ -478,10 +478,8 @@ class DevicePlane:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from faabric_tpu.parallel.collectives import shard_map_compat
-
         axis = self.axis
-        check_vma = None
+        check_vma = True
         if kind == "allreduce":
             op = MpiOp(op_code)
             prim = {MpiOp.SUM: jax.lax.psum, MpiOp.MAX: jax.lax.pmax,
@@ -505,8 +503,7 @@ class DevicePlane:
             def f(shard):  # (1, k) → (n·k,) replicated
                 return jax.lax.all_gather(shard[0], axis, tiled=True)
             out_spec = P()
-            # Replicated output the static check cannot infer — the
-            # same version-portable disable parallel/collectives.py uses
+            # Replicated output the static check cannot infer
             check_vma = False
         elif kind == "ring_permute":
             from faabric_tpu.device_plane.pallas_ring import permute_body
@@ -515,12 +512,13 @@ class DevicePlane:
             # remote-copy kernel on TPU, lax.ppermute elsewhere
             f = permute_body(self.mesh, axis, op_code)
             out_spec = P(axis, None)
+            # pallas_call's out_shape carries no varying-axes annotation
+            check_vma = False
         else:
             raise RuntimeError(f"unknown device collective {kind}")
 
-        fn = shard_map_compat(f, mesh=self.mesh,
-                              in_specs=P(axis, None),
-                              out_specs=out_spec, check_vma=check_vma)
+        fn = jax.shard_map(f, mesh=self.mesh, in_specs=P(axis, None),
+                           out_specs=out_spec, check_vma=check_vma)
         return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
     def _distribute(self, kind: str, y, resident: bool) -> dict:

@@ -60,10 +60,11 @@ def registration_row(rank: int, device) -> np.ndarray:
 
 def resolve_local_device(world, rank: int):
     """Default registration: the planner-assigned chip of ``rank``
-    (decision ``device_ids`` riding the PTP mappings), mapped onto this
-    process's jax devices the same way local_devices_for_ids does —
-    per-host indexes wrap modulo the local device count. None when the
-    placement carries no device or the backend has none."""
+    (decision ``device_ids`` riding the PTP mappings) as an index into
+    this process's jax devices, the same way local_devices_for_ids maps
+    it. None when the placement carries no device or names a chip this
+    process does not have — the handshake then refuses the plane
+    instead of folding two ranks onto one chip."""
     import jax
 
     try:
@@ -73,9 +74,9 @@ def resolve_local_device(world, rank: int):
     if dev_id is None or dev_id < 0:
         return None
     local = jax.local_devices()
-    if not local:
+    if dev_id >= len(local):
         return None
-    return local[dev_id % len(local)]
+    return local[dev_id]
 
 
 def resolve_mesh(rows: np.ndarray, size: int, local_ranks,

@@ -88,14 +88,18 @@ class GuestContext:
 
     @property
     def device(self):
-        """The local jax device for this rank (falls back to device 0)."""
-        import jax
-
+        """The local jax device the planner pinned this rank to. Raises
+        when it pinned none (the host registered no chips): defaulting
+        to the first device would run every rank on one chip and still
+        return the right answer."""
         from faabric_tpu.parallel.collectives import local_devices_for_ids
 
         did = self.device_id
         if did < 0:
-            return jax.local_devices()[0]
+            raise RuntimeError(
+                f"no chip pinned for {self.message.user}/"
+                f"{self.message.function} idx {self.message.group_idx}: "
+                "the worker must register its chips (n_devices)")
         return local_devices_for_ids([did])[0]
 
     # -- messaging ------------------------------------------------------

@@ -183,15 +183,13 @@ def _sharded_flash(q, k, v, mesh: Mesh):
     embarrassingly parallel for attention, so each shard runs the Pallas
     kernel on its local (B/dp, S, H/tp, D) slab — no collectives."""
     from faabric_tpu.ops.flash_attention import flash_attention
-    from faabric_tpu.parallel.collectives import shard_map_compat
 
     spec = P("dp", None, "tp", None)
-    # check off (check_vma / check_rep by JAX version): pallas_call's
-    # out_shape carries no varying-mesh-axes annotation, and this
-    # wrapper is trivially per-shard anyway
-    return shard_map_compat(lambda q, k, v: flash_attention(q, k, v, True),
-                            mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec, check_vma=False)(q, k, v)
+    # check off: pallas_call's out_shape carries no varying-mesh-axes
+    # annotation, and this wrapper is trivially per-shard anyway
+    return jax.shard_map(lambda q, k, v: flash_attention(q, k, v, True),
+                         mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,

@@ -9,6 +9,7 @@ in-process multi-host tests can run one registry per logical host.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Optional
 
 from faabric_tpu.mpi.world import MpiWorld
@@ -16,6 +17,10 @@ from faabric_tpu.proto import BatchExecuteRequest, Message, batch_exec_factory
 from faabric_tpu.util.logging import get_logger
 
 logger = get_logger(__name__)
+
+# How long a joining rank waits for this host's in-progress create_world
+# (one planner round-trip to chain the ranks) before giving up
+RESERVATION_WAIT_S = 60.0
 
 
 class MpiWorldRegistry:
@@ -29,6 +34,9 @@ class MpiWorldRegistry:
         self.broker = broker
         self.planner_client = planner_client
         self._lock = threading.Lock()
+        # Signalled when a create_world reservation resolves (published
+        # or withdrawn): local joiners wait on it
+        self._resolved = threading.Condition(self._lock)
         self._worlds: dict[int, MpiWorld] = {}
 
     # ------------------------------------------------------------------
@@ -76,6 +84,7 @@ class MpiWorldRegistry:
             with self._lock:
                 if self._worlds.get(world_id) is None:
                     self._worlds.pop(world_id, None)
+                self._resolved.notify_all()
             raise
         with self._lock:
             if world_id not in self._worlds:
@@ -86,6 +95,7 @@ class MpiWorldRegistry:
                 raise RuntimeError(
                     f"Registry cleared while creating world {world_id}")
             self._worlds[world_id] = world
+            self._resolved.notify_all()
         logger.debug("Created MPI world %d (size=%d group=%d)", world_id,
                      size, group_id)
         return world
@@ -93,20 +103,31 @@ class MpiWorldRegistry:
     def get_or_initialise_world(self, msg: Message) -> MpiWorld:
         """Non-zero ranks join from their dispatched message (reference
         getOrInitialiseWorld :54-75 — idempotent per host)."""
+        world_id = msg.mpi_world_id
         with self._lock:
-            world = self._worlds.get(msg.mpi_world_id)
             # A None entry is a reservation by an in-progress create_world
-            # on this host; joining ranks build their own view
+            # on this host (the chained ranks are dispatched before the
+            # creator has its world in hand). Wait for it: the ranks of one
+            # process must share ONE world object — the device plane's
+            # rendezvous is in-process state, and a rank with a private
+            # view would wait there alone until the timeout sends its
+            # collective down the host ladder
+            deadline = time.monotonic() + RESERVATION_WAIT_S
+            while (world_id in self._worlds
+                   and self._worlds[world_id] is None):
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    raise RuntimeError(
+                        f"World {world_id} is still being created on this "
+                        f"host after {RESERVATION_WAIT_S:.0f}s")
+                self._resolved.wait(left)
+            world = self._worlds.get(world_id)
             if world is None:
-                world = MpiWorld(self.broker, msg.mpi_world_id,
+                world = MpiWorld(self.broker, world_id,
                                  msg.mpi_world_size, msg.group_id,
                                  user=msg.user, function=msg.function)
                 world.record_exec_graph = msg.record_exec_graph
-                if self._worlds.get(msg.mpi_world_id) is None \
-                        and msg.mpi_world_id in self._worlds:
-                    # keep the creator's reservation authoritative
-                    return world
-                self._worlds[msg.mpi_world_id] = world
+                self._worlds[world_id] = world
             return world
 
     def get_world(self, world_id: int) -> MpiWorld:
@@ -127,6 +148,7 @@ class MpiWorldRegistry:
     def clear(self) -> None:
         with self._lock:
             worlds, self._worlds = dict(self._worlds), {}
+            self._resolved.notify_all()
         for w in worlds.values():
             if w is not None:  # None = create_world's in-flight reservation
                 w.close()

@@ -356,14 +356,24 @@ class MpiWorld:
         # Stub brokers in unit tests may not expose device mappings
         get_dev = getattr(self.broker, "get_device_for_idx", None)
         with self._lock:
-            self._rank_hosts = {
+            rank_hosts = {
                 idx: self.broker.get_host_for_receiver(self.group_id, idx)
                 for idx in range(self.size)
             }
-            self._rank_devices = (
+            rank_devices = (
                 {idx: get_dev(self.group_id, idx)
                  for idx in range(self.size)}
                 if get_dev is not None else {})
+            # Every local rank refreshes on joining (GuestContext.
+            # mpi_world): only a placement that CHANGED is a new
+            # generation. Bumping on an identical re-read let a late
+            # joiner invalidate the device-plane handshake its siblings
+            # were already in — they refused the plane, it did not
+            if (rank_hosts, rank_devices) == (self._rank_hosts,
+                                              self._rank_devices):
+                return
+            self._rank_hosts = rank_hosts
+            self._rank_devices = rank_devices
             self._topology_cache = None
             self._same_machine_cache = None
             self._topology_gen += 1

@@ -10,8 +10,18 @@ Training runs the standard two-pass flash backward: the forward kernel
 additionally emits the per-row log-sum-exp, and two backward kernels
 recompute probabilities in-block from (Q, K, LSE) — one gridded over
 q-blocks producing dQ, one over k-blocks producing dK/dV. Peak memory
-stays O(S·D) in both directions (VERDICT r2 §weak-3: the old backward
-recomputed through plain jnp attention, materialising (S, S) scores).
+stays O(S·D) in both directions.
+
+Which path runs is a pure function of the shapes and the backend:
+:func:`uses_kernel` answers it, so a caller can assert the kernel was
+taken. Shapes the tiling cannot take (ragged, sub-tile, causal with
+s_q > s_k) run the jnp reference; every other shape runs the kernels,
+and a shape the Mosaic compiler refuses is an error, never the
+reference. The kernels hold whole-sequence blocks in VMEM (K/V in the
+forward and dQ passes; Q, dO and the lane-broadcast statistics in the
+dK/dV pass), so libtpu rejects long sequences with RESOURCE_EXHAUSTED
+at compile time: on a v5e the backward compiles up to S = 8192 and the
+forward up to S = 12288 at head_dim 128.
 
 On CPU (tests) the kernels run in interpreter mode automatically.
 """
@@ -231,7 +241,12 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _uses_kernel(q_shape, k_shape, causal, block_q, block_k) -> bool:
+def uses_kernel(q_shape, k_shape, causal: bool = True,
+                block_q: int = DEFAULT_BLOCK_Q,
+                block_k: int = DEFAULT_BLOCK_K) -> bool:
+    """True when :func:`flash_attention` on (B, S, H, D) shapes runs the
+    Pallas kernels on the current backend, False when it runs the jnp
+    reference."""
     s_q, s_k = q_shape[1], k_shape[1]
     d = q_shape[-1]
     # On real TPU hardware, sub-tile shapes (short sequences / narrow
@@ -273,7 +288,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k):
     back into the kernels without re-materializing the broadcast)."""
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    if not _uses_kernel(q.shape, k.shape, causal, block_q, block_k):
+    if not uses_kernel(q.shape, k.shape, causal, block_q, block_k):
         return _reference_attention(q, k, v, causal), None
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
@@ -301,6 +316,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k):
             jax.ShapeDtypeStruct((b * h, s_q, LANE), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, s_q, d).transpose(0, 2, 1, 3), lse
 
@@ -350,6 +366,7 @@ def _run_bwd_kernels(q, k, v, g_out, out, lse_l, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, dof, lse_l, delta_l)
 
     dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
@@ -374,6 +391,7 @@ def _run_bwd_kernels(q, k, v, g_out, out, lse_l, causal, block_q, block_k,
             jax.ShapeDtypeStruct((b * h, s_k, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, dof, lse_l, delta_l)
 
     def unfold(x, s):

@@ -5,11 +5,17 @@ instead of the separate reductions + elementwise XLA would otherwise
 schedule through HBM for large rows. fp32 statistics regardless of input
 dtype (matches the model's _rms_norm semantics). Differentiable via
 recompute-through-reference VJP.
+
+Which path runs is a pure function of the shape and the backend:
+:func:`uses_kernel` answers it. Row counts the block does not divide,
+and sub-tile shapes on real hardware, run the jnp reference; a shape
+the Mosaic compiler refuses is an error, never the reference.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -36,21 +42,27 @@ def rms_norm(x, scale, eps: float = 1e-6):
     return _rms_forward(x, scale, eps)
 
 
-def _rms_forward(x, scale, eps):
-    import math
-
-    orig_shape = x.shape
-    d = orig_shape[-1]
-    rows = math.prod(orig_shape[:-1]) if len(orig_shape) > 1 else 1
-    flat = x.reshape(rows, d)
-
-    block = min(DEFAULT_BLOCK_ROWS, rows)
-    if rows % block:
-        return _reference_rms_norm(x, scale, eps)
+def uses_kernel(x_shape) -> bool:
+    """True when :func:`rms_norm` on an (..., D) input runs the Pallas
+    kernel on the current backend, False when it runs the jnp
+    reference."""
+    d = x_shape[-1]
+    rows = math.prod(x_shape[:-1])
+    if rows % min(DEFAULT_BLOCK_ROWS, rows):
+        return False
     # Sub-tile rows (vs the 128-lane register tiling) stay on the
     # reference path on real hardware; interpret mode has no tiling
-    if jax.default_backend() == "tpu" and (d < 128 or rows < 8):
+    return not (jax.default_backend() == "tpu" and (d < 128 or rows < 8))
+
+
+def _rms_forward(x, scale, eps):
+    if not uses_kernel(x.shape):
         return _reference_rms_norm(x, scale, eps)
+    orig_shape = x.shape
+    d = orig_shape[-1]
+    rows = math.prod(orig_shape[:-1])
+    flat = x.reshape(rows, d)
+    block = min(DEFAULT_BLOCK_ROWS, rows)
 
     interpret = jax.default_backend() == "cpu"
     out = pl.pallas_call(
@@ -63,6 +75,7 @@ def _rms_forward(x, scale, eps):
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret,
+        name="rms_norm",
     )(flat, scale)
     return out.reshape(orig_shape)
 

@@ -32,37 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # JAX >= 0.4.35 exports shard_map at the top level
-    from jax import shard_map  # type: ignore[attr-defined]
-
-    _SHARD_MAP_NO_CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover — older JAX
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-    _SHARD_MAP_NO_CHECK_KW = "check_rep"
-
-# Whether the installed JAX has the varying-mesh-axes (vma) machinery:
-# shard_map(check_vma=), lax.pcast/pvary. Without it (<= 0.4.x) the
-# older check_rep static-replication inference runs instead — it cannot
-# be helped along by _mark_varying (a no-op there) and is known not to
-# see through scan/vjp-heavy bodies like the pipeline schedules.
-SHARD_MAP_HAS_VMA = _SHARD_MAP_NO_CHECK_KW == "check_vma"
-
-
-def shard_map_compat(fn, mesh=None, in_specs=None, out_specs=None,
-                     check_vma=None):
-    """Version-portable ``shard_map``: ``check_vma`` maps onto whichever
-    check kwarg the installed JAX understands (``check_vma`` on current
-    releases, ``check_rep`` on 0.4.x). ``None`` keeps the library
-    default. Every shard_map in this codebase that passes a check kwarg
-    must go through here — JAX 0.4.37 raises TypeError on a literal
-    ``check_vma=`` (the seed test_ops failure)."""
-    kwargs = {}
-    if check_vma is not None:
-        kwargs[_SHARD_MAP_NO_CHECK_KW] = check_vma
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kwargs)
-
 from faabric_tpu.mpi.types import MpiOp
 
 _PRIMITIVE_REDUCERS = {
@@ -100,7 +69,9 @@ class DeviceCollectives:
         process must hold every rank's buffer (all devices addressable
         or the data replicated); on a multi-process plane use
         :meth:`shard_stacked_addressable`."""
-        stacked = jnp.stack([jnp.asarray(b) for b in per_rank])
+        # Stacked on the host so each rank's row goes straight to its own
+        # device instead of passing through the default one
+        stacked = np.stack([np.asarray(b) for b in per_rank])
         return jax.device_put(stacked, self.sharding())
 
     def shard_stacked_addressable(self, local_per_rank,
@@ -148,14 +119,11 @@ class DeviceCollectives:
             return fn
 
     def _shard_mapped(self, fn, in_spec, out_spec, replicated_out: bool = False):
-        kwargs = {}
-        if replicated_out:
-            # all_gather/broadcast outputs ARE replicated, but the static
-            # replication check cannot infer it (kwarg name differs by
-            # JAX version: check_vma on current, check_rep on older)
-            kwargs[_SHARD_MAP_NO_CHECK_KW] = False
-        return jax.jit(shard_map(fn, mesh=self.mesh, in_specs=in_spec,
-                                 out_specs=out_spec, **kwargs))
+        # all_gather/broadcast outputs ARE replicated, but the static
+        # varying-axes check cannot infer it
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_spec,
+                                     out_specs=out_spec,
+                                     check_vma=not replicated_out))
 
     # ------------------------------------------------------------------
     # Collectives
@@ -344,17 +312,18 @@ class DeviceCollectives:
 
 
 def local_devices_for_ids(device_ids: Sequence[int]) -> list:
-    """Resolve planner-assigned chip ids to jax devices on this host.
+    """Resolve planner-assigned chip indexes to jax devices on this host.
 
-    Ids that don't exist locally (e.g. a CPU test mesh whose jax ids
-    differ from the planner's numbering) wrap modulo the local device
-    count — but a mesh needs unique devices, so a wrap that collides
-    raises instead of silently aliasing two ranks onto one chip."""
+    The planner numbers a host's chips 0..n_devices-1 (the count the
+    worker registered), which indexes ``jax.local_devices()``. An index
+    the host does not have, or two ranks on one chip, raises: a mesh
+    silently folded onto fewer chips than the planner assigned would
+    still compute the right answer."""
     all_devs = jax.local_devices()
-    by_id = {d.id: d for d in all_devs}
-    out = [by_id.get(i, all_devs[i % len(all_devs)]) for i in device_ids]
-    if len({id(d) for d in out}) != len(out):
+    ids = [int(i) for i in device_ids]
+    bad = [i for i in ids if not 0 <= i < len(all_devs)]
+    if bad or len(set(ids)) != len(ids):
         raise ValueError(
-            f"Device ids {list(device_ids)} do not map onto distinct local "
-            f"devices ({len(all_devs)} available)")
-    return out
+            f"Device ids {ids} do not map onto distinct local devices "
+            f"({len(all_devs)} available)")
+    return [all_devs[i] for i in ids]

@@ -118,31 +118,17 @@ def join_device_plane(spec: DevicePlaneSpec,
         addr = spec.coordinator_address()
         logger.info("Joining device plane: %s as process %d/%d",
                     addr, spec.process_id, spec.num_processes)
-        # Cross-process collectives on the CPU backend need the gloo
-        # implementation opted in BEFORE the backend initialises; newer
-        # JAX defaults to it, 0.4.x raises "Multiprocess computations
-        # aren't implemented on the CPU backend" without it (the seed
-        # device-plane dist failure). Real TPU/GPU backends ignore it.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — unknown config on some versions
-            logger.debug("jax_cpu_collectives_implementation not settable",
-                         exc_info=True)
-        kwargs = {}
-        if local_device_ids is not None:
-            kwargs["local_device_ids"] = list(local_device_ids)
-        try:
-            jax.distributed.initialize(
-                coordinator_address=addr,
-                num_processes=spec.num_processes,
-                process_id=spec.process_id,
-                initialization_timeout=int(init_timeout_s),
-                **kwargs)
-        except TypeError:  # older jax without initialization_timeout
-            jax.distributed.initialize(
-                coordinator_address=addr,
-                num_processes=spec.num_processes,
-                process_id=spec.process_id, **kwargs)
+        # Cross-process collectives on the CPU backend ride gloo, which
+        # must be selected BEFORE the backend initialises. TPU backends
+        # ignore it.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+        jax.distributed.initialize(
+            coordinator_address=addr,
+            num_processes=spec.num_processes,
+            process_id=spec.process_id,
+            local_device_ids=(None if local_device_ids is None
+                              else list(local_device_ids)),
+            initialization_timeout=int(init_timeout_s))
         _joined_spec = spec
 
 
@@ -183,12 +169,9 @@ def force_cpu_virtual_devices(n: int) -> None:
     """Single-machine plane testing: give this process EXACTLY ``n``
     virtual CPU devices, replacing any inherited device-count flag (a
     test harness parent exports its own). Must run before any JAX
-    backend initialises; composes with the sitecustomize override the
-    same way util/device_env.py does."""
+    backend initialises. The flag only shapes the CPU backend; which
+    backend runs is the caller's ``JAX_PLATFORMS=cpu``."""
     flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
              if "xla_force_host_platform_device_count" not in f]
     flags.append(f"--xla_force_host_platform_device_count={n}")
     os.environ["XLA_FLAGS"] = " ".join(flags)
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")

@@ -42,21 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from faabric_tpu.parallel.collectives import (
-    SHARD_MAP_HAS_VMA,
-    shard_map_compat,
-)
-
-# Replication checking for the pipeline shard_maps. On current JAX the
-# vma machinery (pcast in _mark_varying) lets the default check pass and
-# catch real mistakes, so keep it on (None = library default, True). On
-# 0.4.x the older check_rep inference cannot see through the schedule
-# bodies (scan-carried accumulators, in-body vjp) and rejects the
-# statically-correct P() loss out-spec — run those shard_maps unchecked
-# there; the schedule tests pin the numerics against dense/autodiff
-# references, which is the stronger check anyway.
-_PP_CHECK = None if SHARD_MAP_HAS_VMA else False
-
 from faabric_tpu.models.transformer import (
     ModelConfig,
     _rms_norm,
@@ -376,19 +361,15 @@ def _pipeline_loss_local(pp_params, tokens_mb, targets_mb,
     # Loss head scanned one microbatch at a time so peak logits memory
     # stays (b, S, V) — not M× that. Real data only on the last stage;
     # other stages' buffers are garbage and get masked out below.
-    # The accumulator is shape (1,), NOT scalar: JAX 0.4.x shard_map
-    # partial-eval fails to promote a scalar scan-carry residual
-    # (rank-0 output vs the {0: all_names} residual spec — the seed
-    # test_pipeline _SpecError), and a singleton axis costs nothing.
     def loss_one(acc, y_t):
         y, targets_m = y_t
         return acc + _head_nll(y, pp_params["ln_f"], pp_params["lm_head"],
                                targets_m, cfg), None
 
     loss_sum, _ = jax.lax.scan(
-        loss_one, _mark_varying(jnp.zeros((1,), jnp.float32), ("dp", "pp")),
+        loss_one, _mark_varying(jnp.zeros((), jnp.float32), ("dp", "pp")),
         (outputs, targets_mb))
-    local_loss = loss_sum[0] / m_count
+    local_loss = loss_sum / m_count
 
     loss = jax.lax.psum(
         jnp.where(s_idx == n_stages - 1, local_loss, 0.0), "pp")
@@ -403,9 +384,9 @@ def make_pp_loss(cfg: ModelConfig, mesh: Mesh):
     param_specs, data_spec = _pp_specs(cfg, mesh)
 
     local = partial(_pipeline_loss_local, cfg=cfg, n_stages=n_stages)
-    return shard_map_compat(local, mesh=mesh,
-                            in_specs=(param_specs, data_spec, data_spec),
-                            out_specs=P(), check_vma=_PP_CHECK)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(param_specs, data_spec, data_spec),
+                         out_specs=P())
 
 
 # ---------------------------------------------------------------------------
@@ -426,8 +407,7 @@ def ring_slots(n_stages: int) -> int:
 
 
 def _pipeline_1f1b_local(pp_params, tokens_mb, targets_mb,
-                         cfg: ModelConfig, n_stages: int, dp_size: int,
-                         unmentioned=None, ad_overcount: float = 1.0):
+                         cfg: ModelConfig, n_stages: int, dp_size: int):
     """Per-device 1F1B body: a FORWARD-ONLY scan that carries gradient
     accumulators — no outer jax.grad, so XLA never materialises per-tick
     saved activations. Schedule (branch-free, both units every tick):
@@ -562,39 +542,11 @@ def _pipeline_1f1b_local(pp_params, tokens_mb, targets_mb,
     # Gradient normalization — two regimes:
     # - manually-accumulated g_embed (scatter of the dp-LOCAL dx): combine
     #   stages with psum('pp'), dp-average with pmean;
-    # - vjp-produced g_stacked / g_lnf / g_lmh: on vma-era JAX the
+    # - vjp-produced g_stacked / g_lnf / g_lmh: the
     #   in-body vjp already psum'd them over every axis their param is
     #   invariant on (dp; pp too for the head leaves) — they arrive as
     #   Σ over dp shards, so the dp MEAN is a static division, and
     #   another psum/pmean would double-count.
-    if unmentioned is not None:
-        # Old JAX (check_rep era): the in-body vjp inserts NO automatic
-        # collectives — every vjp-produced cotangent arrives as this
-        # member's PARTIAL (dp-local data shard; tp/sp/ep-local compute
-        # slice; heads zero off the last pp stage). Summing each leaf
-        # over its spec's unmentioned axes assembles the full gradient
-        # (vjp is linear in the cotangent, so partial dy hops through
-        # the ring sum correctly too), and the psum also registers the
-        # replication the out_specs check needs. g_embed's dp/pp/sp
-        # reductions happen explicitly below — only its remaining
-        # unmentioned axes (tp, and ep for MoE) are summed here.
-        # The raw-JAX psum transpose re-psums cotangents ("psum +
-        # pbroadcast"), so each explicit in-body collective axis the
-        # backward crosses (tp in the Megatron psums, sp in the head
-        # pmean, ep in the MoE psums) inflates every assembled leaf by
-        # that axis size, uniformly — divide it back out (ad_overcount
-        # = tp·sp·ep, computed by the factory from the mesh).
-        inv_over = 1.0 / ad_overcount
-
-        def _assemble(g, axes):
-            return (jax.lax.psum(g, axes) if axes else g) * inv_over
-
-        g_stacked = {k: _assemble(v, unmentioned["stacked"][k])
-                     for k, v in g_stacked.items()}
-        g_lnf = _assemble(g_lnf, unmentioned["ln_f"])
-        g_lmh = _assemble(g_lmh, unmentioned["lm_head"])
-        g_embed = _assemble(g_embed, tuple(
-            a for a in unmentioned["embed"] if a not in ("dp", "pp", "sp")))
     g_embed = jax.lax.pmean(
         jax.lax.psum(jax.lax.psum(g_embed * inv_m, "pp"), "sp"), "dp")
     scale = inv_m / dp_size
@@ -615,40 +567,11 @@ def make_pp_1f1b_value_and_grad(cfg: ModelConfig, mesh: Mesh):
     n_stages = _validate_pp_mesh(cfg, mesh)
     param_specs, data_spec = _pp_specs(cfg, mesh)
 
-    # 1F1B keeps the replication check ON on every JAX version. On old
-    # (check_rep) JAX the in-body vjp produces per-member PARTIAL
-    # cotangents, so the body must assemble each grad leaf with a psum
-    # over the axes its spec leaves unmentioned (which also proves the
-    # out_specs replication to the static tracker) — precompute those
-    # axis tuples here, where the mesh is in hand.
-    unmentioned = None
-    ad_overcount = 1.0
-    if not SHARD_MAP_HAS_VMA:
-        def _un(spec):
-            named = {n for part in spec if part is not None
-                     for n in (part if isinstance(part, tuple) else (part,))}
-            return tuple(a for a in mesh.axis_names if a not in named)
-
-        unmentioned = {
-            "embed": _un(param_specs["embed"]),
-            "ln_f": _un(param_specs["ln_f"]),
-            "lm_head": _un(param_specs["lm_head"]),
-            "stacked": {k: _un(s)
-                        for k, s in param_specs["stacked"].items()},
-        }
-        # Axes the in-body backward crosses through EXPLICIT collectives
-        # (see _pipeline_1f1b_local._assemble): tp (Megatron psums), sp
-        # (head pmean + gathered-KV attention), ep (MoE psums).
-        ad_overcount = float(mesh.shape.get("tp", 1)
-                             * mesh.shape.get("sp", 1)
-                             * mesh.shape.get("ep", 1))
-
     local = partial(_pipeline_1f1b_local, cfg=cfg, n_stages=n_stages,
-                    dp_size=mesh.shape["dp"], unmentioned=unmentioned,
-                    ad_overcount=ad_overcount)
-    return shard_map_compat(local, mesh=mesh,
-                            in_specs=(param_specs, data_spec, data_spec),
-                            out_specs=(P(), param_specs))
+                    dp_size=mesh.shape["dp"])
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(param_specs, data_spec, data_spec),
+                         out_specs=(P(), param_specs))
 
 
 def microbatch(tokens: jax.Array, n_microbatches: int) -> jax.Array:

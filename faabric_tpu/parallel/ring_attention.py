@@ -27,12 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# Single source for the shard_map version shim (check_vma vs check_rep)
-from faabric_tpu.parallel.collectives import (
-    _SHARD_MAP_NO_CHECK_KW as _NO_CHECK_KW,
-    shard_map,
-)
-
 NEG_INF = -1e30
 
 
@@ -40,17 +34,12 @@ def _mark_varying(x, axes: tuple[str, ...]):
     """Tag a locally-built array as device-varying over the given mesh
     axes (loop-carry / cond-branch types must match shard-derived
     values). Only the axes the value isn't already varying over are
-    added — pcast rejects re-marking. API moved pvary →
-    pcast(to='varying') across JAX versions."""
+    added — pcast rejects re-marking."""
     have = getattr(getattr(x, "aval", None), "vma", frozenset())
     missing = tuple(a for a in axes if a not in have)
     if not missing:
         return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, missing, to="varying")
-    if hasattr(jax.lax, "pvary"):  # pragma: no cover — older JAX
-        return jax.lax.pvary(x, missing)
-    return x  # pragma: no cover — oldest JAX has no varying check
+    return jax.lax.pcast(x, missing, to="varying")
 
 
 def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
@@ -149,10 +138,9 @@ def _compiled_ring(mesh: Mesh, axis: str, causal: bool,
     # Varying-check off: pallas_call's out_shape carries no varying-mesh-
     # axes annotation (same trade as the model's flash path,
     # models/transformer.py)
-    return jax.jit(shard_map(local_fn, mesh=mesh,
-                             in_specs=(spec, spec, spec),
-                             out_specs=spec,
-                             **{_NO_CHECK_KW: False}))
+    return jax.jit(jax.shard_map(local_fn, mesh=mesh,
+                                 in_specs=(spec, spec, spec),
+                                 out_specs=spec, check_vma=False))
 
 
 def shard_sequence(x, mesh: Mesh, axis: str = "sp"):

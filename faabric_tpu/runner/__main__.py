@@ -43,7 +43,9 @@ def main(argv: list[str] | None = None) -> int:
                           help="this worker's identity (default: primary IP)")
     p_worker.add_argument("--slots", type=int, default=None,
                           help="execution slots (default: one per usable core; 0 = observer host)")
-    p_worker.add_argument("--devices", type=int, default=0)
+    p_worker.add_argument("--devices", type=int, default=None,
+                          help="chips to register (default: every local "
+                               "jax device)")
     p_worker.add_argument("--planner-host", default=None)
 
     args = parser.parse_args(argv)
@@ -94,9 +96,19 @@ def main(argv: list[str] | None = None) -> int:
         srv.stop()
     else:
         from faabric_tpu.runner import WorkerRuntime
+        from faabric_tpu.util.device_env import configure_compile_cache
 
+        # The worker is the one process that owns this host's chips:
+        # guests compile through the placed cache, and the planner pins
+        # ranks over exactly the chips jax sees here
+        configure_compile_cache()
+        n_devices = args.devices
+        if n_devices is None:
+            import jax
+
+            n_devices = len(jax.local_devices())
         runtime = WorkerRuntime(host=args.host, slots=args.slots,
-                                n_devices=args.devices,
+                                n_devices=n_devices,
                                 planner_host=args.planner_host)
         runtime.start()
         logger.info("Worker %s up", runtime.host)

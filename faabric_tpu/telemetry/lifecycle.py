@@ -56,7 +56,7 @@ from faabric_tpu.telemetry.metrics import get_metrics, metrics_enabled
 from faabric_tpu.telemetry.perfprofile import DecayedStat
 from faabric_tpu.util.config import _env_float, _env_int
 
-# -- phase taxonomy -----------------------------------------------------
+# -- phase list ---------------------------------------------------------
 # Wire keys are short on purpose: the ledger rides EVERY dispatched and
 # result-pushed message's JSON header. Values are monotonic ns stamps.
 PHASE_ADMIT = "adm"            # admission granted / classic entry
@@ -192,7 +192,7 @@ def charge_state_time(ns: int) -> None:
 
 def ledger_durations(lc: dict) -> dict[str, float]:
     """Phase durations (seconds) from a stamp ledger: stamps sort by
-    TIME (not taxonomy order — a requeue reorders the tail) and each
+    TIME (not listed order — a requeue reorders the tail) and each
     gap is attributed to the label of the stamp that ends it. Negative
     gaps (cross-machine clock offset) clamp to 0. Unknown keys keep
     their raw name so a future phase never silently vanishes.

@@ -131,9 +131,6 @@ class SystemConfig:
     # Transport
     serialisation: str = "json"
 
-    # Device / mesh
-    mesh_device_kind: str = "auto"  # auto | tpu | cpu
-
     @classmethod
     def from_env(cls) -> "SystemConfig":
         """Build a config populated from the environment. A plain
@@ -211,7 +208,6 @@ class SystemConfig:
             "MPI_ABORT_CHECK_SECONDS", 2.0)
 
         self.serialisation = _env("SERIALISATION", "json")
-        self.mesh_device_kind = _env("MESH_DEVICE_KIND", "auto")
 
     def print(self) -> str:
         lines = ["--- System config ---"]

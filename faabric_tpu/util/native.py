@@ -66,10 +66,10 @@ def _build_and_load(name: str, src_file: str, so_file: str,
     if not os.path.exists(so) or (os.path.getmtime(so)
                                   < os.path.getmtime(src)):
         os.makedirs(os.path.dirname(so), exist_ok=True)
-        if san:
-            opt_args: tuple = _SAN_FLAGS[san]
-        else:
-            opt_args = ("-O3", "-march=native")
+        # No -march=native: native/build/ travels with a copied checkout
+        # (it is ignored by git, not by a disk copy), and a library built
+        # for one machine's CPU must still load on the next
+        opt_args: tuple = _SAN_FLAGS[san] if san else ("-O3",)
         cmd = ["g++", *opt_args, "-shared", "-fPIC",
                src, "-o", so, *extra_args]
         # Never compile under an inherited sanitizer preload: cc1plus/
@@ -231,6 +231,13 @@ def get_uffd_lib() -> Optional[ctypes.CDLL]:
                         "libuffdtracker.so", _declare_tracker("uffd"),
                         install=install, extra_args=("-lpthread",),
                         fail_note="uffd dirty mode unavailable")
+
+
+def loaded_helpers() -> dict[str, bool]:
+    """Which native helpers this process has tried, and whether each
+    built and loaded (False: the caller is on its pure-Python path)."""
+    with _lock:
+        return {name: lib is not None for name, lib in _cache.items()}
 
 
 def reset_for_tests() -> None:

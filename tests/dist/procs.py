@@ -1007,8 +1007,6 @@ class DistExecutor(Executor):
         before applying the update — every rank's params stay bit-identical
         without any parameter server."""
         import jax
-
-        jax.config.update("jax_platforms", "cpu")
         import jax.numpy as jnp
 
         from faabric_tpu.mpi import MpiOp, get_mpi_context

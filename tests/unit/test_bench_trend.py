@@ -1,7 +1,7 @@
 """tools/bench_trend.py (ISSUE 12 satellite): per-key trajectory math
-over the COMMITTED bench history plus synthetic direction/status
-pins."""
+over synthetic bench rounds plus direction/status pins."""
 
+import json
 import os
 import sys
 
@@ -15,21 +15,29 @@ def _tools():
     return importlib.import_module("bench_trend")
 
 
-def test_collect_reads_committed_history():
+def _write_round(tmp_path, name, value, summary):
+    # the driver's round record: bench.py's stdout line under "parsed"
+    (tmp_path / name).write_text(json.dumps({"parsed": {
+        "metric": "ptp_dispatch_p50_ms", "value": value, "unit": "ms",
+        "summary": summary}}))
+
+
+def test_collect_reads_round_history(tmp_path):
     bt = _tools()
-    repo = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    series = bt.collect(repo)
-    assert series, "no committed BENCH_r*.json rounds found"
-    # The headline latency rides as `value` in every committed round
-    assert "value" in series
-    rounds = [r for r, _v in series["value"]]
+    _write_round(tmp_path, "BENCH_r01.json", 0.05,
+                 {"host_sendrecv_gibs": 0.5, "step_ms": 40.0})
+    _write_round(tmp_path, "BENCH_r02.json", 0.04,
+                 {"host_sendrecv_gibs": 0.6, "step_ms": 30.0})
+    series = bt.collect(str(tmp_path))
+    # The headline latency rides as `value` in every round
+    assert series["value"] == [("r01", 0.05), ("r02", 0.04)]
+    rounds = [r for r, _v in series["host_sendrecv_gibs"]]
     assert rounds == sorted(rounds), "rounds must be oldest → newest"
     rows = bt.trend_rows(series)
     by_key = {r["key"]: r for r in rows}
     # The container-drift-exempt keys never report as regressions
     assert by_key["value"]["status"] == "exempt"
-    # Rendering never raises on real data and marks gated keys
+    # Rendering never raises and marks gated keys
     out = bt.render(rows)
     assert "status" in out and "*" in out
 

@@ -74,7 +74,7 @@ class TestLedger:
         # durations sum EXACTLY to the span by construction
         assert sum(d.values()) == pytest.approx(ledger_span_s(lc))
 
-    def test_requeue_reorders_by_time_not_taxonomy(self):
+    def test_requeue_reorders_by_time_not_listed_order(self):
         """A requeued message's SECOND dispatch stamp lands after the
         requeue stamp; time-sorting attributes the detection+backoff
         gap to 'requeue' and keeps every duration non-negative."""

@@ -1638,3 +1638,49 @@ def test_scan_emits_span_and_counter(mpi_cluster):
     assert delta.get('faabric_mpi_collectives_total{op="scan"}') == 6
     assert delta.get(
         'faabric_mpi_collective_bytes_total{op="scan"}') == 6 * 8000
+
+
+def test_registry_joiners_share_the_creators_world():
+    """The chained ranks of a gang are dispatched BEFORE rank 0 has its
+    world in hand. A rank that joins meanwhile must wait for that world,
+    not build a private one: the device plane's rendezvous is in-process
+    state, and a rank with its own view would wait there alone until the
+    timeout silently sends its collective down the host ladder."""
+    import threading
+    import time
+
+    from faabric_tpu.proto import batch_exec_factory
+
+    chaining = threading.Event()
+    release = threading.Event()
+
+    class SlowPlanner:
+        def call_functions(self, req):
+            chaining.set()
+            assert release.wait(10)
+            return SchedulingDecision(app_id=req.app_id, group_id=777)
+
+    registry = MpiWorldRegistry(PointToPointBroker("regA"), SlowPlanner())
+    msgs = batch_exec_factory("demo", "gang", 3).messages
+    for rank, m in enumerate(msgs):
+        m.mpi_world_id, m.mpi_world_size, m.mpi_rank = 5151, 3, rank
+        m.group_id = 777
+
+    got = {}
+    threads = [threading.Thread(
+        target=lambda: got.update(creator=registry.create_world(msgs[0])))]
+    threads[0].start()
+    assert chaining.wait(10)
+    for rank in (1, 2):
+        threads.append(threading.Thread(
+            target=lambda r=rank: got.update(
+                {r: registry.get_or_initialise_world(msgs[r])})))
+        threads[-1].start()
+    time.sleep(0.2)
+    assert not got, "a joiner did not wait for the world being created"
+    release.set()
+    for t in threads:
+        t.join(10)
+        assert not t.is_alive()
+    assert got[1] is got["creator"] and got[2] is got["creator"]
+    registry.clear()

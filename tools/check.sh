@@ -46,14 +46,11 @@ if ! JAX_PLATFORMS=cpu python -m faabric_tpu.runner.profile --selftest; then
     rc=1
 fi
 
-echo "== pallas ring selftest (device ring-permute p2p) =="
-# On this container it validates the XLA fallback permute and reports
-# the Pallas kernel as untested (no TPU granted) — fast, clean; with a
-# granted TPU the same hook exercises make_async_remote_copy for real.
-if ! JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
-        python -m faabric_tpu.device_plane.pallas_ring --selftest; then
-    rc=1
-fi
+# The Pallas ring selftest (python -m faabric_tpu.device_plane.pallas_ring
+# --selftest) and chip_smoke.py are chip checks: both exit non-zero
+# without a TPU, so they are not gates of this CPU-side script. The ring
+# kernel's body runs here in TPU interpret mode inside the tier-1 suite
+# (tests/unit/test_device_resident.py).
 
 for arg in "$@"; do
     if [ "$arg" = "--with-chaos" ]; then
