@@ -1,0 +1,20 @@
+"""The table of peaks, keyed by the ``device_kind`` JAX reports. A device
+that is not in the table is an error, never a default."""
+
+from __future__ import annotations
+
+import json
+import os
+
+_TABLE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                      "peaks.json")
+
+
+def peaks_for(device_kind: str) -> dict:
+    with open(_TABLE) as f:
+        table = json.load(f)
+    if device_kind not in table:
+        raise KeyError(
+            f"device kind {device_kind!r} is not in {_TABLE}: add its "
+            f"published peaks with their source (known: {sorted(table)})")
+    return table[device_kind]
