@@ -14,6 +14,7 @@ from __future__ import annotations
 import enum
 import json
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
@@ -29,11 +30,15 @@ from faabric_tpu.proto import (
     BatchExecuteRequest,
     is_batch_exec_request_valid,
 )
+from faabric_tpu.telemetry import get_lifecycle
+from faabric_tpu.telemetry.lifecycle import PHASE_HTTP_IN
 from faabric_tpu.util.config import get_system_config
 from faabric_tpu.util.exec_graph import build_exec_graph
 from faabric_tpu.util.logging import get_logger
 
 logger = get_logger(__name__)
+
+_LC = get_lifecycle()
 
 
 class HttpMessageType(enum.IntEnum):
@@ -308,6 +313,9 @@ class PlannerHttpEndpoint:
         """(status_code, response_json, extra_headers) for one
         HttpMessage. Handlers may return 2- or 3-tuples; the headers
         slot carries e.g. ``Retry-After`` on a 429 shed."""
+        # The lifecycle ledger's first stamp (hin): the body has been
+        # read and nothing of it parsed yet
+        received_ns = time.monotonic_ns()
         try:
             msg = json.loads(body or b"{}")
         except json.JSONDecodeError:
@@ -319,7 +327,7 @@ class PlannerHttpEndpoint:
         http_type = msg.get("http_type", int(HttpMessageType.NO_TYPE))
         payload = msg.get("payload", "")
         try:
-            out = self._dispatch(http_type, payload)
+            out = self._dispatch(http_type, payload, received_ns)
         except Exception as e:  # noqa: BLE001 — REST errors cross the wire
             logger.exception("HTTP handler error (type %s)", http_type)
             return 500, json.dumps({"error": str(e)}), {}
@@ -327,7 +335,8 @@ class PlannerHttpEndpoint:
             return out[0], out[1], {}
         return out
 
-    def _dispatch(self, http_type: int, payload: str) -> tuple[int, str]:
+    def _dispatch(self, http_type: int, payload: str,
+                  received_ns: int) -> tuple[int, str]:
         planner = self.planner
         t = HttpMessageType(http_type)
 
@@ -383,6 +392,7 @@ class PlannerHttpEndpoint:
             req = BatchExecuteRequest.from_dict(json.loads(payload))
             if not is_batch_exec_request_valid(req):
                 return 400, json.dumps({"error": "Bad BatchExecRequest"})
+            _LC.backdate(req.messages, PHASE_HTTP_IN, received_ns)
             # Through the invocation ingress (ISSUE 8): admission
             # control + batched scheduling ticks. Sources are tenants
             # (the request's user) — one runaway tenant sheds before it

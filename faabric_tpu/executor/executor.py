@@ -29,15 +29,10 @@ from faabric_tpu.proto import (
     ReturnValue,
     get_main_thread_snapshot_key,
 )
-from faabric_tpu.telemetry import (
-    NULL_SPAN,
-    get_lifecycle,
-    get_metrics,
-    span,
-    tracing_enabled,
-)
+from faabric_tpu.telemetry import get_lifecycle, get_metrics
 from faabric_tpu.telemetry.lifecycle import (
     PHASE_EXEC_QUEUE_EXIT,
+    PHASE_RUN_CPU,
     PHASE_RUN_END,
     PHASE_RUN_START,
 )
@@ -81,7 +76,7 @@ class ExecutorTask:
     def __init__(self, msg_idx: int, req: BatchExecuteRequest) -> None:
         self.msg_idx = msg_idx
         self.req = req
-        self.enqueue_ts = time.monotonic()
+        self.enqueue_ns = time.monotonic_ns()
 
 
 def _merge_dirty_flags(acc, new):
@@ -282,31 +277,31 @@ class Executor:
         msg = req.messages[task.msg_idx]
         is_threads = req.type == int(BatchExecuteType.THREADS)
         msg.executed_host = self.scheduler.host if self.scheduler else ""
-        # Lifecycle ledger (ISSUE 14): the pool thread has the task
-        _LC.stamp(msg, PHASE_EXEC_QUEUE_EXIT)
-        queue_wait = time.monotonic() - task.enqueue_ts
-        _QUEUE_WAIT_SECONDS.observe(queue_wait)
+        # One helper marks each boundary of the task (eqx, rns, rne): it
+        # stamps the lifecycle ledger, opens the profiler's span, and its
+        # two clock reads are what the histograms and the exec graph's
+        # queue_us / exec_us below are computed from
+        with _LC.phase_span(msg, PHASE_EXEC_QUEUE_EXIT, None,
+                            "run_prep") as prep:
+            # Thread-local dirty tracking brackets the task so each thread
+            # reports only its own writes (reference Executor.cpp:464-476)
+            tracker = self._batch_tracker
+            mem = self.get_memory_view() if tracker is not None else None
+            if tracker is not None and mem is not None:
+                tracker.start_thread_local_tracking(
+                    mem, region_hints=self._batch_hints)
+            ExecutorContext.set(self, req, task.msg_idx)
 
-        # Thread-local dirty tracking brackets the task so each thread
-        # reports only its own writes (reference Executor.cpp:464-476)
-        tracker = self._batch_tracker
-        mem = self.get_memory_view() if tracker is not None else None
-        if tracker is not None and mem is not None:
-            tracker.start_thread_local_tracking(
-                mem, region_hints=self._batch_hints)
-
-        ExecutorContext.set(self, req, task.msg_idx)
-        _LC.stamp(msg, PHASE_RUN_START)
-        run_t0 = time.monotonic()
+        run = _LC.phase_span(msg, PHASE_RUN_START, PHASE_RUN_END, "run",
+                             cpu_phase=PHASE_RUN_CPU)
         try:
-            if _FAULTS:
-                # delay rules make stragglers; raise rules fail the task
-                # (the generic handler below folds it into the result)
-                _FP_RUN.fire(function=f"{msg.user}/{msg.function}",
-                             msg_id=msg.id)
-            with span("executor", "execute_task", msg_id=msg.id,
-                      function=f"{msg.user}/{msg.function}") \
-                    if tracing_enabled() else NULL_SPAN:
+            with run:
+                if _FAULTS:
+                    # delay rules make stragglers; raise rules fail the
+                    # task (the generic handler below folds it into the
+                    # result)
+                    _FP_RUN.fire(function=f"{msg.user}/{msg.function}",
+                                 msg_id=msg.id)
                 ret = self.execute_task(pool_idx, task.msg_idx, req)
         except FunctionMigratedException:
             logger.debug("%s task %d migrated", self.id, msg.id)
@@ -338,19 +333,49 @@ class Executor:
         finally:
             ExecutorContext.unset()
 
-        _LC.stamp(msg, PHASE_RUN_END)
-        run_seconds = time.monotonic() - run_t0
-        _RUN_SECONDS.observe(run_seconds)
-        _TASKS_TOTAL.inc()
-        msg.return_value = ret
-        msg.finish_timestamp = time.time()
-        # Per-message timing rides the result into the planner, so
-        # ExecGraph.to_json() can report wall/queue/exec durations per
-        # node (util/exec_graph.py)
-        msg.int_exec_graph_details["queue_us"] = int(queue_wait * 1e6)
-        msg.int_exec_graph_details["exec_us"] = int(run_seconds * 1e6)
-        self.last_exec = time.monotonic()
+        # The worker's half of the result push: from the guest's return
+        # to the push RPC written (rsp is stamped inside, by the planner
+        # client, as the last thing before the wire)
+        with _LC.phase_span(msg, None, None, "result_push"):
+            queue_ns = prep.start_ns - task.enqueue_ns
+            run_ns = run.end_ns - run.start_ns
+            _QUEUE_WAIT_SECONDS.observe(queue_ns / 1e9)
+            _RUN_SECONDS.observe(run_ns / 1e9)
+            _TASKS_TOTAL.inc()
+            msg.return_value = ret
+            msg.finish_timestamp = time.time()
+            # Per-message timing rides the result into the planner, so
+            # ExecGraph.to_json() can report wall/queue/exec durations per
+            # node (util/exec_graph.py)
+            msg.int_exec_graph_details["queue_us"] = queue_ns // 1000
+            msg.int_exec_graph_details["exec_us"] = run_ns // 1000
+            self.last_exec = time.monotonic()
+            last_in_batch = self._report_result(msg, ret, is_threads,
+                                                tracker, mem)
 
+        # Last task of the batch returns the executor to the pool
+        # (reference Executor.cpp:520-570).
+        if last_in_batch:
+            if is_threads and self._batch_tracker is not None:
+                # Unprotect/retire the batch-level bracket now rather than
+                # at the next batch's reassignment: segv mode would
+                # otherwise leave untouched pages PROT_READ and charge
+                # later non-THREADS work a fault per page
+                if mem is None:
+                    mem = self.get_memory_view()
+                if mem is not None:
+                    self._batch_tracker.stop_tracking(mem)
+                self._batch_tracker = None
+            if not is_threads:
+                self.reset(self.bound_msg)
+            self.release_claim()
+            if self.scheduler is not None:
+                self.scheduler.notify_executor_idle(self)
+
+    def _report_result(self, msg: Message, ret: int, is_threads: bool,
+                       tracker, mem) -> bool:
+        """Fold this task's dirty pages into the batch, count it done and
+        report its result. True for the batch's last task."""
         # Each thread contributes its dirty pages BEFORE the outstanding
         # count drops: the decrement elects the last thread, and that
         # thread must see every earlier thread's pages when it computes the
@@ -392,24 +417,7 @@ class Executor:
             else:
                 self.scheduler.report_message_result(msg)
 
-        # Last task of the batch returns the executor to the pool
-        # (reference Executor.cpp:520-570).
-        if last_in_batch:
-            if is_threads and self._batch_tracker is not None:
-                # Unprotect/retire the batch-level bracket now rather than
-                # at the next batch's reassignment: segv mode would
-                # otherwise leave untouched pages PROT_READ and charge
-                # later non-THREADS work a fault per page
-                if mem is None:
-                    mem = self.get_memory_view()
-                if mem is not None:
-                    self._batch_tracker.stop_tracking(mem)
-                self._batch_tracker = None
-            if not is_threads:
-                self.reset(self.bound_msg)
-            self.release_claim()
-            if self.scheduler is not None:
-                self.scheduler.notify_executor_idle(self)
+        return last_in_batch
 
     # ------------------------------------------------------------------
     # Chained messages (reference Executor::getChainedMessage)

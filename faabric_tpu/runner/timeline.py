@@ -22,7 +22,12 @@ import argparse
 import json
 import sys
 
-from faabric_tpu.telemetry.lifecycle import PHASE_LABELS, ledger_durations
+from faabric_tpu.telemetry.lifecycle import (
+    PHASE_LABELS,
+    ledger_durations,
+    ledger_run_cpu_s,
+    ledger_stamps,
+)
 
 _BAR_WIDTH = 44
 
@@ -30,6 +35,7 @@ _BAR_WIDTH = 44
 # first letter 'r' (requeue/run_prep/run/result_push/record) — exactly
 # the phases this tool exists to tell apart
 _BAR_MARKS = {
+    "http_in": "h",
     "ingress_queue": "q",
     "schedule": "s",
     "journal": "j",
@@ -64,8 +70,7 @@ def _msg_rows(status: dict) -> list[dict]:
     rows = []
     for m in status.get("messageResults") or []:
         lc = m.get("lc") or {}
-        stamps = sorted((int(v), k) for k, v in lc.items()
-                        if isinstance(v, (int, float)))
+        stamps = ledger_stamps(lc)
         if not stamps:
             continue
         rows.append({
@@ -75,6 +80,7 @@ def _msg_rows(status: dict) -> list[dict]:
             "return_value": m.get("return_value", 0),
             "stamps": stamps,
             "durations": ledger_durations(lc),
+            "run_cpu_s": ledger_run_cpu_s(lc),
             "t0": stamps[0][0],
             "t1": stamps[-1][0],
         })
@@ -112,6 +118,9 @@ def render_text(app_id: int, rows: list[dict]) -> str:
         parts = [f"{label}={secs * 1e3:.3f}ms"
                  for label, secs in sorted(r["durations"].items(),
                                            key=lambda kv: -kv[1])]
+        if r["run_cpu_s"] is not None:
+            parts.append(f"(of run, on the CPU: "
+                         f"{r['run_cpu_s'] * 1e3:.3f}ms)")
         lines.append("    " + "  ".join(parts))
     legend = ", ".join(f"{mark}={label}"
                        for label, mark in _BAR_MARKS.items())

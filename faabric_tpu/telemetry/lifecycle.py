@@ -9,17 +9,24 @@ end to end, cluster-merged:
 
 - **Phase ledger**: every Message carries a compact ``lc`` dict of
   monotonic nanosecond stamps (short wire keys, see ``PHASE_LABELS``)
-  written at admit, ingress-queue exit, tick schedule, journal append,
-  dispatch send, executor-queue exit, run start/end, result push,
-  planner record and waiter wake — across processes, because the dict
-  rides the Message wire form (``to_wire_dict``) on dispatch and on the
-  result push. Recovery requeues stamp a ``requeue`` boundary, so a
-  message that died with its host carries a ledger spanning BOTH
+  written at REST arrival, admit, ingress-queue exit, tick schedule,
+  journal append, dispatch send, executor-queue exit, run start/end,
+  result push, planner record and waiter wake — across processes,
+  because the dict rides the Message wire form (``to_wire_dict``) on
+  dispatch and on the result push. Two keys are durations, not stamps:
+  in-run state time (``stx``) and the pool thread's CPU time across
+  the run (``rcu``). Recovery requeues stamp a ``requeue`` boundary, so
+  a message that died with its host carries a ledger spanning BOTH
   attempts. Stamps are ``time.monotonic_ns()``: on one machine (every
   process shares CLOCK_MONOTONIC) all stamps compare exactly; across
   real machines the two transit phases (``executor_queue``, ``record``)
   absorb the clock offset — the same honesty caveat as
   ``faabric_planner_result_roundtrip_seconds``.
+- **Bridge into the JAX profiler** (ISSUE 25): the worker's side of an
+  invocation is marked by :class:`PhaseSpan`, which stamps the ledger
+  and, in a process that has JAX loaded, opens a ``faabric:<label>``
+  ``TraceAnnotation`` carrying the ledger so far — so a profiler
+  session shows the runtime's phases on the device trace's clock.
 - **Fold**: when the planner records a result, the ledger folds into
   per-phase log-bucket streaming estimators (the perfprofile
   ``DecayedStat``) plus an end-to-end digest — served on ``/healthz``
@@ -49,16 +56,19 @@ window, default 20).
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 
 from faabric_tpu.telemetry.metrics import get_metrics, metrics_enabled
 from faabric_tpu.telemetry.perfprofile import DecayedStat
+from faabric_tpu.telemetry.tracer import span, tracing_enabled
 from faabric_tpu.util.config import _env_float, _env_int
 
 # -- phase list ---------------------------------------------------------
 # Wire keys are short on purpose: the ledger rides EVERY dispatched and
 # result-pushed message's JSON header. Values are monotonic ns stamps.
+PHASE_HTTP_IN = "hin"          # REST body read, not yet parsed
 PHASE_ADMIT = "adm"            # admission granted / classic entry
 PHASE_QUEUE_EXIT = "qex"       # left the ingress queue (tick pickup)
 PHASE_SCHED = "sch"            # scheduling decision made
@@ -80,11 +90,20 @@ PHASE_WAITER_WAKE = "wwk"      # waiting client woken with the result
 # "run"). A duration key must never enter the time-sorted stamp walk —
 # its value is an interval, not a point on the monotonic clock.
 PHASE_STATE_ACC = "stx"
+# NOT a stamp either: the pool thread's CPU nanoseconds
+# (``time.thread_time_ns``) across the run window. Wall − CPU is time
+# the guest spent blocked on the device or off the core. It overlays
+# ``run`` (nothing is carved out), so it is reported as ``run_cpu``
+# beside the phases and kept out of the dominant-phase ranking.
+PHASE_RUN_CPU = "rcu"
+DURATION_KEYS = frozenset((PHASE_STATE_ACC, PHASE_RUN_CPU))
+RUN_CPU_LABEL = "run_cpu"
 
 # Duration label for the gap ENDING at each stamp (time-sorted — a
 # requeued message's second-attempt dispatch stamp lands after its
 # requeue stamp, and the sort attributes the gaps truthfully).
 PHASE_LABELS = {
+    PHASE_ADMIT: "http_in",
     PHASE_QUEUE_EXIT: "ingress_queue",
     PHASE_SCHED: "schedule",
     PHASE_JOURNAL: "journal",
@@ -122,8 +141,89 @@ class _NullLifecycle:
     def stamp_many(self, msgs, phase: str) -> None:
         pass
 
+    def backdate(self, msgs, phase: str, ns: int) -> None:
+        pass
+
+    def phase_span(self, msg, start_phase, end_phase, label: str,
+                   cpu_phase=None) -> "PhaseSpan":
+        return PhaseSpan(msg, None, None, label, None, False)
+
 
 NULL_LIFECYCLE = _NullLifecycle()
+
+
+class PhaseSpan:
+    """One interval of an invocation, marked once at each end: a context
+    manager that reads ``monotonic_ns`` on entry and on exit and keeps
+    both (``start_ns``, ``end_ns``), so whatever else wants the same
+    boundary (the executor's histograms, the exec graph's ``queue_us`` /
+    ``exec_us``) derives from these reads and makes none of its own.
+
+    With the lifecycle plane on it stamps ``start_phase`` / ``end_phase``
+    into ``msg.lc`` (either may be None: a boundary the neighbouring span
+    stamps), writes the thread's CPU time across the interval under
+    ``cpu_phase``, and, only where ``sys.modules`` already holds
+    ``jax.profiler`` (a worker that has imported JAX; the planner process
+    stays JAX-free), wraps the interval in
+    ``jax.profiler.TraceAnnotation("faabric:<label>", msg_id="m<id>",
+    mono_ns=<start_ns>, **<the ledger so far>)``. That annotation is
+    inert while no profiler session runs, so the bridge is on exactly
+    when somebody traces. ``mono_ns`` ties CLOCK_MONOTONIC to the
+    profiler's clock (the event's ``start_ns`` is that same instant), so
+    every stamp of the ledger, the planner's too, can be laid on the
+    device trace's timeline. While the Chrome tracer records, the
+    interval is an ``executor/<label>`` span of it as well. With the
+    plane off only the two clock reads are left."""
+
+    __slots__ = ("start_ns", "end_ns", "_msg", "_start", "_end", "_label",
+                 "_cpu", "_cpu0", "_ledger", "_opened")
+
+    def __init__(self, msg, start_phase, end_phase, label: str,
+                 cpu_phase, ledger: bool) -> None:
+        self._msg = msg
+        self._start, self._end, self._cpu = start_phase, end_phase, cpu_phase
+        self._label = label
+        self._ledger = ledger
+        self.start_ns = self.end_ns = 0
+
+    def __enter__(self) -> "PhaseSpan":
+        msg = self._msg
+        opened = []
+        if tracing_enabled():
+            opened.append(span("executor", self._label, msg_id=msg.id,
+                               function=f"{msg.user}/{msg.function}"))
+        self.start_ns = now = time.monotonic_ns()
+        if self._ledger:
+            if self._start is not None:
+                msg.lc[self._start] = now
+            # getattr: another pool thread may be importing jax at this
+            # moment (a guest's first lazy import), and a module sits in
+            # sys.modules before its body has run
+            annotation = getattr(sys.modules.get("jax.profiler"),
+                                 "TraceAnnotation", None)
+            if annotation is not None:
+                # msg_id as "m<id>": a gid has up to 68 bits, and the
+                # profiler reads digits as a number and keeps one beyond
+                # 64 bits as a double
+                opened.append(annotation(
+                    f"faabric:{self._label}", msg_id=f"m{msg.id}",
+                    mono_ns=now, **msg.lc))
+        for cm in opened:
+            cm.__enter__()
+        self._opened = opened
+        if self._cpu is not None:
+            self._cpu0 = time.thread_time_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._cpu is not None:
+            self._msg.lc[self._cpu] = time.thread_time_ns() - self._cpu0
+        for cm in reversed(self._opened):
+            cm.__exit__(*exc)
+        self.end_ns = now = time.monotonic_ns()
+        if self._ledger and self._end is not None:
+            self._msg.lc[self._end] = now
+        return False
 
 
 class Lifecycle:
@@ -150,6 +250,19 @@ class Lifecycle:
         now = time.monotonic_ns()
         for m in msgs:
             m.lc[phase] = now
+
+    @staticmethod
+    def backdate(msgs, phase: str, ns: int) -> None:
+        """First-write stamp of an instant read before the messages
+        existed (``hin``: the REST body was read, then parsed)."""
+        for m in msgs:
+            m.lc.setdefault(phase, ns)
+
+    @staticmethod
+    def phase_span(msg, start_phase, end_phase, label: str,
+                   cpu_phase=None) -> "PhaseSpan":
+        return PhaseSpan(msg, start_phase, end_phase, label, cpu_phase,
+                         True)
 
 
 _lifecycle: Lifecycle | _NullLifecycle | None = None
@@ -190,6 +303,14 @@ def charge_state_time(ns: int) -> None:
 # Pure ledger analysis
 # ---------------------------------------------------------------------------
 
+def ledger_stamps(lc: dict) -> list[tuple[int, str]]:
+    """The ledger's stamps as time-sorted ``(ns, key)``. The duration
+    keys (``stx``, ``rcu``) are intervals, not points on the monotonic
+    clock, and never enter the walk."""
+    return sorted((int(v), k) for k, v in (lc or {}).items()
+                  if isinstance(v, (int, float)) and k not in DURATION_KEYS)
+
+
 def ledger_durations(lc: dict) -> dict[str, float]:
     """Phase durations (seconds) from a stamp ledger: stamps sort by
     TIME (not listed order — a requeue reorders the tail) and each
@@ -200,11 +321,11 @@ def ledger_durations(lc: dict) -> dict[str, float]:
     ``stx`` (ISSUE 16) is a DURATION, not a stamp: accumulated in-run
     state pull/push ns. It is excluded from the stamp walk and carved
     OUT of the run window (``state`` + ``run`` still sum to the old
-    ``run``, so the fold's clock-coherence guard is unaffected)."""
+    ``run``, so the fold's clock-coherence guard is unaffected).
+    ``rcu`` is one too, but overlays the run window and is no part of
+    this partition of the span: :func:`ledger_run_cpu_s` reads it."""
     lc = lc or {}
-    stamps = sorted(((int(v), k) for k, v in lc.items()
-                     if isinstance(v, (int, float))
-                     and k != PHASE_STATE_ACC))
+    stamps = ledger_stamps(lc)
     out: dict[str, float] = {}
     for i in range(1, len(stamps)):
         t, key = stamps[i]
@@ -220,13 +341,20 @@ def ledger_durations(lc: dict) -> dict[str, float]:
     return out
 
 
+def ledger_run_cpu_s(lc: dict) -> float | None:
+    """Host CPU seconds the pool thread used inside ``run`` (None where
+    the ledger has no ``rcu``). ``run`` less this is time blocked on the
+    device or off the core."""
+    cpu = (lc or {}).get(PHASE_RUN_CPU)
+    return int(cpu) / 1e9 if isinstance(cpu, (int, float)) else None
+
+
 def ledger_span_s(lc: dict) -> float:
     """Last stamp − first stamp, seconds (0 with <2 stamps)."""
-    vals = [int(v) for v in (lc or {}).values()
-            if isinstance(v, (int, float))]
-    if len(vals) < 2:
+    stamps = ledger_stamps(lc)
+    if len(stamps) < 2:
         return 0.0
-    return max(0.0, (max(vals) - min(vals)) / 1e9)
+    return max(0.0, (stamps[-1][0] - stamps[0][0]) / 1e9)
 
 
 def ledger_e2e_s(lc: dict) -> float | None:
@@ -325,8 +453,7 @@ class LifecycleStats:
             # differs can blow the time-sorted span far past it, and
             # folding that would crown a phantom dominant phase. Such
             # ledgers contribute their (valid) e2e + SLO only.
-            if e2e is not None and sum(durations.values()) > \
-                    2.0 * e2e + 1.0:
+            if e2e is not None and ledger_span_s(lc) > 2.0 * e2e + 1.0:
                 self._incoherent.inc()
                 with self._lock:
                     self._count += 1
@@ -335,6 +462,9 @@ class LifecycleStats:
                     self._e2e.observe(e2e)
                 self._h_e2e.observe(e2e)
                 continue
+            cpu = ledger_run_cpu_s(lc)
+            if cpu is not None:
+                durations[RUN_CPU_LABEL] = cpu
             now = time.monotonic()
             with self._lock:
                 self._count += 1
@@ -376,7 +506,10 @@ class LifecycleStats:
             rows = {label: self._stat_row(s)
                     for label, s in self._phases.items()}
         e2e_p99 = (e2e_row or {}).get("p99_ms") or 0.0
-        dominant = sorted(rows.items(), key=lambda kv: -kv[1]["p99_ms"])
+        # run_cpu overlays run: a row of its own, never a dominant phase
+        dominant = sorted((kv for kv in rows.items()
+                           if kv[0] != RUN_CPU_LABEL),
+                          key=lambda kv: -kv[1]["p99_ms"])
         return {
             "count": count,
             "failed": failed,
