@@ -1,7 +1,13 @@
 """Autoregressive decoding with a KV cache.
 
-Static shapes end-to-end: the cache is pre-allocated at ``max_seq`` and
-filled with ``lax.dynamic_update_slice``; attention masks by position, so
+Static shapes end-to-end: the cache is pre-allocated with as many slots
+as the call can fill (prompt length + new tokens, both static, rounded up
+to ``_SLOT_MULTIPLE`` and never above ``max_seq``), so a decode step
+attends over the call's own reach and not over ``max_seq``. It is laid
+head-major, ``(batch, heads, slots, head_dim)``, and pinned so in
+memory: one head's keys are one contiguous ``(slots, head_dim)`` tile
+array. It is filled with ``lax.dynamic_update_slice``; attention masks by
+position, so
 prefill and every decode step compile once each. The whole greedy loop is
 one ``lax.scan`` under jit — no host round-trips between tokens, which is
 what keeps a TPU busy at small batch.
@@ -15,6 +21,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from faabric_tpu.models.transformer import (
     ModelConfig,
@@ -23,29 +30,50 @@ from faabric_tpu.models.transformer import (
 )
 
 
-def init_kv_cache(cfg: ModelConfig, batch: int) -> list[dict]:
-    return [{
-        "k": jnp.zeros((batch, cfg.max_seq, cfg.n_heads, cfg.head_dim),
-                       cfg.compute_dtype),
-        "v": jnp.zeros((batch, cfg.max_seq, cfg.n_heads, cfg.head_dim),
-                       cfg.compute_dtype),
-    } for _ in range(cfg.n_layers)]
+# Cache lengths are rounded up to this many slots: the TPU's lane width,
+# so the scores' last dimension fills whole tiles.
+_SLOT_MULTIPLE = 128
+
+
+def _head_major(cache: jax.Array) -> jax.Array:
+    """Pin a (batch, heads, slots, head_dim) cache row-major in memory.
+    Left to itself XLA:TPU lays the scan's carried cache heads-minor
+    whatever the logical order, the heads padded to a tile's 128 lanes:
+    with 16 heads, eight times the bytes at every step."""
+    return with_layout_constraint(cache, Layout((0, 1, 2, 3)))
+
+
+def _cache_slots(cfg: ModelConfig, reach: int) -> int:
+    """Slots for a call whose last write is position ``reach - 1``."""
+    rounded = -(-reach // _SLOT_MULTIPLE) * _SLOT_MULTIPLE
+    return min(rounded, cfg.max_seq)
+
+
+def init_kv_cache(cfg: ModelConfig, batch: int,
+                  slots: int | None = None) -> list[dict]:
+    """Zeroed per-layer caches, head-major: (batch, heads, slots,
+    head_dim). ``slots`` defaults to ``cfg.max_seq``."""
+    shape = (batch, cfg.n_heads, cfg.max_seq if slots is None else slots,
+             cfg.head_dim)
+    return [{"k": jnp.zeros(shape, cfg.compute_dtype),
+             "v": jnp.zeros(shape, cfg.compute_dtype)}
+            for _ in range(cfg.n_layers)]
 
 
 def _cached_attention(q, cache_k, cache_v, length):
-    """q (B, S_q, H, D) against the cache's first ``length`` positions
-    (q's last position is length-1)."""
+    """q (B, S_q, H, D) against the first ``length`` positions of a
+    head-major cache (B, H, slots, D); q's last position is length-1."""
     scale = 1.0 / np.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, cache_k
+    logits = jnp.einsum("bqhd,bhkd->bhqk", q, cache_k
                         ).astype(jnp.float32) * scale
     s_q = q.shape[1]
-    max_seq = cache_k.shape[1]
+    slots = cache_k.shape[2]
     q_pos = (length - s_q) + jnp.arange(s_q)
-    k_pos = jnp.arange(max_seq)
+    k_pos = jnp.arange(slots)
     mask = q_pos[:, None] >= k_pos[None, :]
     logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, cache_v)
+    return jnp.einsum("bhqk,bhkd->bqhd", probs, cache_v)
 
 
 def _block_with_cache(x, blk, cache, start, length, cfg: ModelConfig):
@@ -61,8 +89,10 @@ def _block_with_cache(x, blk, cache, start, length, cfg: ModelConfig):
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
 
-    cache_k = jax.lax.dynamic_update_slice(cache["k"], k, (0, start, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache["v"], v, (0, start, 0, 0))
+    cache_k = _head_major(jax.lax.dynamic_update_slice(
+        cache["k"], k.transpose(0, 2, 1, 3), (0, 0, start, 0)))
+    cache_v = _head_major(jax.lax.dynamic_update_slice(
+        cache["v"], v.transpose(0, 2, 1, 3), (0, 0, start, 0)))
 
     attn = _cached_attention(q, cache_k, cache_v, length)
     x = x + jnp.einsum("bshe,hed->bsd", attn,
@@ -124,15 +154,15 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     b, s_p = prompt.shape
-    cache = init_kv_cache(cfg, b)
+    cache = init_kv_cache(cfg, b, _cache_slots(cfg, s_p + n_tokens))
     if mesh is not None:
-        kv_sharding = NamedSharding(mesh, P("dp", None, "tp", None))
+        kv_sharding = NamedSharding(mesh, P("dp", "tp", None, None))
         cache = [{k: jax.lax.with_sharding_constraint(v, kv_sharding)
                   for k, v in layer.items()} for layer in cache]
 
     if prefill_chunk and prefill_chunk < s_p:
         # Chunked prefill: attention during prefill peaks at
-        # (chunk × max_seq) scores instead of (S_p × max_seq) — the
+        # (chunk × slots) scores instead of (S_p × slots) — the
         # long-prompt memory bound. Chunk boundaries are static.
         pos = 0
         logits = None
@@ -169,12 +199,21 @@ def generate(params, prompt, cfg: ModelConfig, n_tokens: int,
     scanned single-token decode loop, all one program. Default is greedy
     (temperature 0); pass a PRNG ``key`` with ``temperature``/``top_k``/
     ``top_p`` for sampling (varying temperature/top_p does NOT
-    recompile; varying top_k does — it's a shape). With ``mesh``, the KV
-    cache shards batch over ``dp`` and heads over ``tp`` (matching
-    tp-sharded params), so decode runs tensor-parallel with XLA
-    inserting the activation collectives. ``prefill_chunk`` processes
-    long prompts in fixed-size chunks, bounding prefill attention
-    memory."""
+    recompile; varying top_k does — it's a shape). The KV cache holds
+    the ``S_p + n_tokens`` positions this call can write (rounded up to
+    a multiple of 128), so each (prompt length, ``n_tokens``) pair is
+    its own program and a step attends over no more than that;
+    ``S_p + n_tokens`` above ``cfg.max_seq`` raises ``ValueError``. With
+    ``mesh``, the KV cache shards batch over ``dp`` and heads over ``tp``
+    (matching tp-sharded params), so decode runs tensor-parallel with
+    XLA inserting the activation collectives. ``prefill_chunk``
+    processes long prompts in fixed-size chunks, bounding prefill
+    attention memory."""
+    reach = prompt.shape[1] + n_tokens
+    if reach > cfg.max_seq:
+        raise ValueError(
+            f"prompt of {prompt.shape[1]} tokens + {n_tokens} new needs "
+            f"{reach} cache slots; cfg.max_seq is {cfg.max_seq}")
     greedy = temperature == 0.0
     if key is None:
         key = jax.random.PRNGKey(0)

@@ -172,6 +172,107 @@ def test_kv_cache_generation_matches_full_forward():
     np.testing.assert_array_equal(got, expect)
 
 
+def _greedy_by_full_forward(params, prompt, cfg, served):
+    """What the full forward picks after the prompt and after each served
+    token but the last: causal, so one pass over the whole sequence is
+    the growing sequence's every step."""
+    seq = jnp.concatenate([prompt, jnp.asarray(served)[:, :-1]], axis=1)
+    logits = forward(params, seq, cfg)[:, prompt.shape[1] - 1:]
+    return np.asarray(jnp.argmax(logits, axis=-1), dtype=np.int32)
+
+
+@pytest.mark.parametrize("max_seq,s_p,n_new,chunk,slots", [
+    (512, 8, 6, 0, 128),      # the call's reach below one rounding unit
+    (512, 120, 20, 0, 256),   # not a multiple of the unit: rounded up
+    (256, 250, 6, 0, 256),    # the reach is exactly max_seq
+    (200, 150, 50, 0, 200),   # rounding may not pass max_seq
+    (512, 130, 10, 48, 256),  # chunked prefill with a ragged last chunk
+], ids=["below_unit", "rounded_up", "exactly_max_seq", "capped_at_max_seq",
+        "prefill_chunk"])
+def test_generate_with_bounded_cache_matches_full_forward(
+        max_seq, s_p, n_new, chunk, slots):
+    """The cache holds what the call can fill (prompt + new tokens, in
+    128s, at most max_seq); decoding over it equals the full forward on
+    the growing sequence."""
+    from faabric_tpu.models.generate import _cache_slots, generate
+
+    cfg = ModelConfig(vocab_size=128, d_model=32, n_layers=2, n_heads=4,
+                      d_ff=64, max_seq=max_seq, compute_dtype=jnp.float32)
+    assert _cache_slots(cfg, s_p + n_new) == slots
+    params = init_params(jax.random.PRNGKey(7), cfg)
+    prompt = jnp.asarray(
+        np.random.RandomState(s_p).randint(0, cfg.vocab_size, (2, s_p)),
+        dtype=jnp.int32)
+    got = np.asarray(generate(params, prompt, cfg, n_new,
+                              prefill_chunk=chunk))
+    np.testing.assert_array_equal(
+        got, _greedy_by_full_forward(params, prompt, cfg, got))
+
+
+def test_generate_beyond_max_seq_raises():
+    """A call whose last write would lie past max_seq is refused (it used
+    to overwrite the last slot in silence)."""
+    from faabric_tpu.models.generate import generate
+
+    params = init_params(jax.random.PRNGKey(0), CFG)
+    prompt = jnp.zeros((1, CFG.max_seq - 4), jnp.int32)
+    generate(params, prompt, CFG, 4)
+    with pytest.raises(ValueError, match="max_seq"):
+        generate(params, prompt, CFG, 5)
+
+
+def test_init_kv_cache_is_head_major_and_defaults_to_max_seq():
+    from faabric_tpu.models import init_kv_cache
+
+    full = init_kv_cache(CFG, 3)
+    assert len(full) == CFG.n_layers
+    assert full[0]["k"].shape == (3, CFG.n_heads, CFG.max_seq, CFG.head_dim)
+    short = init_kv_cache(CFG, 3, 8)
+    assert short[1]["v"].shape == (3, CFG.n_heads, 8, CFG.head_dim)
+    assert short[1]["v"].dtype == CFG.compute_dtype
+
+
+def _walk_jaxpr(jaxpr, inside_loop=False):
+    """Every (equation, is it inside a loop's body) of a jaxpr, nested
+    jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside_loop
+        inner = inside_loop or eqn.primitive.name in ("scan", "while")
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_jaxpr(sub, inner)
+
+
+def test_generate_is_one_loop_over_the_calls_own_slots():
+    """The serve cell's shapes at toy widths, traced and not compiled:
+    the program holds exactly one loop (``decode_hbm_share.serve`` divides
+    the one ``while``'s span by its steps), and nothing inside it is as
+    long as max_seq: a 128-token prompt with 64 new tokens attends 256
+    slots."""
+    from faabric_tpu.models.generate import generate
+
+    cfg = ModelConfig(vocab_size=320, d_model=64, n_layers=2, n_heads=4,
+                      d_ff=96, max_seq=2048, compute_dtype=jnp.float32)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    prompt = jnp.zeros((1, 128), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p, t: generate(p, t, cfg, 64))(
+        params, prompt)
+
+    walked = list(_walk_jaxpr(jaxpr.jaxpr))
+    loops = [e.primitive.name for e, _ in walked
+             if e.primitive.name in ("scan", "while")]
+    assert loops == ["scan"]
+    in_loop = [v.aval.shape for e, inside in walked if inside
+               for v in list(e.invars) + list(e.outvars)
+               if hasattr(v.aval, "shape")]
+    assert in_loop, "the walk found nothing inside the scan"
+    assert not [s for s in in_loop if cfg.max_seq in s]
+    assert (1, cfg.n_heads, 256, cfg.head_dim) in in_loop
+
+
 def test_generate_sampling_modes():
     """Greedy default unchanged; temperature/top-k/top-p sampling produce
     valid tokens, are deterministic per key, and vary across keys."""
