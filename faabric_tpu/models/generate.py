@@ -3,30 +3,29 @@
 Static shapes end-to-end: the cache is pre-allocated with as many slots
 as the call can fill (prompt length + new tokens, both static, rounded up
 to ``_SLOT_MULTIPLE`` and never above ``max_seq``), so a decode step
-attends over the call's own reach and not over ``max_seq``. It is laid
-head-major, ``(batch, heads, slots, head_dim)``, and pinned so in
-memory: one head's keys are one contiguous ``(slots, head_dim)`` tile
-array. It is filled with ``lax.dynamic_update_slice``; attention masks by
-position, so
-prefill and every decode step compile once each. The whole greedy loop is
-one ``lax.scan`` under jit — no host round-trips between tokens, which is
-what keeps a TPU busy at small batch.
+attends over the call's own reach and not over ``max_seq``. Each layer
+has one cache a pass of the stack, ``(passes, batch, heads, slots,
+head_dim)``, head-major and pinned so in memory: one head's keys are one
+contiguous ``(slots, head_dim)`` tile array. It is filled with
+``lax.dynamic_update_slice``; attention masks by position, so prefill
+and every decode step compile once each. The whole greedy loop is one
+``lax.scan`` under jit — no host round-trips between tokens, which is
+what keeps a TPU busy at small batch; a looped stack's passes are a
+rolled loop inside it, over the same weights.
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.experimental.layout import Layout, with_layout_constraint
 
 from faabric_tpu.models.transformer import (
     ModelConfig,
-    _norm,
-    _rope,
+    _block,
+    resolve_impls,
+    run_passes,
 )
 
 
@@ -35,92 +34,65 @@ from faabric_tpu.models.transformer import (
 _SLOT_MULTIPLE = 128
 
 
-def _head_major(cache: jax.Array) -> jax.Array:
-    """Pin a (batch, heads, slots, head_dim) cache row-major in memory.
-    Left to itself XLA:TPU lays the scan's carried cache heads-minor
-    whatever the logical order, the heads padded to a tile's 128 lanes:
-    with 16 heads, eight times the bytes at every step."""
-    return with_layout_constraint(cache, Layout((0, 1, 2, 3)))
-
-
 def _cache_slots(cfg: ModelConfig, reach: int) -> int:
     """Slots for a call whose last write is position ``reach - 1``."""
     rounded = -(-reach // _SLOT_MULTIPLE) * _SLOT_MULTIPLE
     return min(rounded, cfg.max_seq)
 
 
-def init_kv_cache(cfg: ModelConfig, batch: int,
-                  slots: int | None = None) -> list[dict]:
-    """Zeroed per-layer caches, head-major: (batch, heads, slots,
-    head_dim). ``slots`` defaults to ``cfg.max_seq``."""
-    shape = (batch, cfg.n_heads, cfg.max_seq if slots is None else slots,
-             cfg.head_dim)
+def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
+               n_tokens: int) -> dict:
+    """What one ``generate`` call of these static shapes allocates and
+    runs: ``cache_slots`` a cache, ``cache_bytes`` of all the caches (keys
+    and values, every layer, every pass), ``ut_passes`` of the stack
+    (prefill and each decode step pass it ``cfg.n_passes`` times).
+    ``generate`` sizes its cache from this; a server reports it beside
+    its answers."""
+    slots = _cache_slots(cfg, prompt_len + n_tokens)
+    itemsize = jnp.dtype(cfg.compute_dtype).itemsize
+    return {
+        "cache_slots": slots,
+        "cache_bytes": (2 * cfg.n_passes * cfg.n_layers * batch
+                        * cfg.n_heads * slots * cfg.head_dim * itemsize),
+        "ut_passes": cfg.n_passes * (1 + n_tokens),
+    }
+
+
+def init_kv_cache(cfg: ModelConfig, batch: int, slots: int) -> list[dict]:
+    """Zeroed per-layer caches, one a pass, head-major: (passes, batch,
+    heads, slots, head_dim)."""
+    shape = (cfg.n_passes, batch, cfg.n_heads, slots, cfg.head_dim)
     return [{"k": jnp.zeros(shape, cfg.compute_dtype),
              "v": jnp.zeros(shape, cfg.compute_dtype)}
             for _ in range(cfg.n_layers)]
 
 
-def _cached_attention(q, cache_k, cache_v, length):
-    """q (B, S_q, H, D) against the first ``length`` positions of a
-    head-major cache (B, H, slots, D); q's last position is length-1."""
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqhd,bhkd->bhqk", q, cache_k
-                        ).astype(jnp.float32) * scale
-    s_q = q.shape[1]
-    slots = cache_k.shape[2]
-    q_pos = (length - s_q) + jnp.arange(s_q)
-    k_pos = jnp.arange(slots)
-    mask = q_pos[:, None] >= k_pos[None, :]
-    logits = jnp.where(mask[None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bqhd", probs, cache_v)
-
-
-def _block_with_cache(x, blk, cache, start, length, cfg: ModelConfig):
-    """One transformer block over tokens at positions [start, start+S);
-    updates the cache in place (functionally) and attends over
-    [0, length)."""
-    b, s, _ = x.shape
-    h = _norm(x, blk["ln1"], cfg)
-    qkv = jnp.einsum("bsd,dthe->tbshe", h,
-                     blk["wqkv"].astype(cfg.compute_dtype))
-    q, k, v = qkv[0], qkv[1], qkv[2]
-    positions = jnp.broadcast_to(start + jnp.arange(s)[None], (b, s))
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-
-    cache_k = _head_major(jax.lax.dynamic_update_slice(
-        cache["k"], k.transpose(0, 2, 1, 3), (0, 0, start, 0)))
-    cache_v = _head_major(jax.lax.dynamic_update_slice(
-        cache["v"], v.transpose(0, 2, 1, 3), (0, 0, start, 0)))
-
-    attn = _cached_attention(q, cache_k, cache_v, length)
-    x = x + jnp.einsum("bshe,hed->bsd", attn,
-                       blk["wo"].astype(cfg.compute_dtype))
-    h = _norm(x, blk["ln2"], cfg)
-    ff = jax.nn.gelu(h @ blk["w1"].astype(cfg.compute_dtype))
-    x = x + ff @ blk["w2"].astype(cfg.compute_dtype)
-    return x, {"k": cache_k, "v": cache_v}
-
-
 def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig):
     """tokens (B, S) entering at position ``start`` → (logits (B, S, V),
-    new cache). length = start + S."""
-    from faabric_tpu.models.transformer import resolve_impls
-
+    new cache). Pass ``t`` of the stack writes and attends ``cache[...][t]``
+    alone. A step whose depth depends on the data (an exit threshold
+    below 1.0) has no cached path."""
+    if cfg.exit_threshold < 1.0:
+        raise ValueError(
+            f"exit_threshold {cfg.exit_threshold} is below 1.0: cached "
+            "decoding runs every pass and serves the last")
     cfg = resolve_impls(cfg)
     b, s = tokens.shape
-    length = start + s
+    positions = jnp.broadcast_to(start + jnp.arange(s)[None], (b, s))
     x = params["embed"].astype(cfg.compute_dtype)[tokens]
-    new_cache = []
-    for blk, layer_cache in zip(params["blocks"], cache):
-        x, updated = _block_with_cache(x, blk, layer_cache, start, length,
-                                       cfg)
-        new_cache.append(updated)
-    x = _norm(x, params["ln_f"], cfg)
+
+    def stack(x, cache, t):
+        new_cache = []
+        for blk, layer_cache in zip(params["blocks"], cache):
+            x, updated = _block(x, blk, positions, cfg, cache=layer_cache,
+                                slot=(t, start))
+            new_cache.append(updated)
+        return x, new_cache
+
+    x, cache = run_passes(x, cache, params, cfg, stack)
     logits = (x @ params["lm_head"].astype(cfg.compute_dtype)
               ).astype(jnp.float32)
-    return logits, new_cache
+    return logits, cache
 
 
 def _pick_token(logits, key, greedy: bool, temperature, top_k: int,
@@ -154,33 +126,30 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     b, s_p = prompt.shape
-    cache = init_kv_cache(cfg, b, _cache_slots(cfg, s_p + n_tokens))
+    cache = init_kv_cache(
+        cfg, b, call_sizes(cfg, b, s_p, n_tokens)["cache_slots"])
     if mesh is not None:
-        kv_sharding = NamedSharding(mesh, P("dp", "tp", None, None))
+        kv_sharding = NamedSharding(mesh, P(None, "dp", "tp", None, None))
         cache = [{k: jax.lax.with_sharding_constraint(v, kv_sharding)
                   for k, v in layer.items()} for layer in cache]
 
-    if prefill_chunk and prefill_chunk < s_p:
-        # Chunked prefill: attention during prefill peaks at
-        # (chunk × slots) scores instead of (S_p × slots) — the
-        # long-prompt memory bound. Chunk boundaries are static.
-        pos = 0
-        logits = None
-        while pos < s_p:
-            hi = min(pos + prefill_chunk, s_p)
+    # Chunked prefill: attention during prefill peaks at (chunk × slots)
+    # scores instead of (S_p × slots) — the long-prompt memory bound.
+    # Chunk boundaries are static.
+    chunk = prefill_chunk if 0 < prefill_chunk < s_p else s_p
+    with jax.named_scope("prefill"):
+        for pos in range(0, s_p, chunk):
             logits, cache = forward_with_cache(
-                params, prompt[:, pos:hi], cache, pos, cfg)
-            pos = hi
-    else:
-        logits, cache = forward_with_cache(params, prompt, cache, 0, cfg)
+                params, prompt[:, pos:pos + chunk], cache, pos, cfg)
     key, sub = jax.random.split(key)
     next_tok = _pick_token(logits[:, -1], sub, greedy, temperature,
                            top_k, use_top_p, top_p)
 
     def step(carry, _):
         tok, pos, cache, key = carry
-        logits, cache = forward_with_cache(params, tok[:, None], cache,
-                                           pos, cfg)
+        with jax.named_scope("decode_step"):
+            logits, cache = forward_with_cache(params, tok[:, None], cache,
+                                               pos, cfg)
         key, sub = jax.random.split(key)
         nxt = _pick_token(logits[:, -1], sub, greedy, temperature,
                           top_k, use_top_p, top_p)
@@ -208,7 +177,9 @@ def generate(params, prompt, cfg: ModelConfig, n_tokens: int,
     (matching tp-sharded params), so decode runs tensor-parallel with
     XLA inserting the activation collectives. ``prefill_chunk``
     processes long prompts in fixed-size chunks, bounding prefill
-    attention memory."""
+    attention memory. A looped stack (``cfg.n_passes`` above 1) keeps
+    one cache a pass a layer; :func:`call_sizes` says what a call of
+    these shapes allocates."""
     reach = prompt.shape[1] + n_tokens
     if reach > cfg.max_seq:
         raise ValueError(

@@ -196,7 +196,7 @@ def moe_forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
 
     aux_total = jnp.zeros((), jnp.float32)
     for blk in params["blocks"]:
-        x = attention_sublayer(x, blk, positions, cfg, mesh)
+        x, _ = attention_sublayer(x, blk, positions, cfg, mesh)
         h = _rms_norm(x, blk["ln2"])
         moe_out, aux = _moe_layer(h, blk, cfg, mesh)
         aux_total = aux_total + aux

@@ -221,15 +221,22 @@ def test_generate_beyond_max_seq_raises():
         generate(params, prompt, CFG, 5)
 
 
-def test_init_kv_cache_is_head_major_and_defaults_to_max_seq():
+def test_init_kv_cache_is_head_major_one_a_pass_and_takes_its_slots():
+    """One cache a pass a layer, (passes, batch, heads, slots, head_dim);
+    the slot count is the caller's to give (a default of max_seq would be
+    100 GB for a looped model of 65,536 positions)."""
+    import dataclasses
+
     from faabric_tpu.models import init_kv_cache
 
-    full = init_kv_cache(CFG, 3)
-    assert len(full) == CFG.n_layers
-    assert full[0]["k"].shape == (3, CFG.n_heads, CFG.max_seq, CFG.head_dim)
     short = init_kv_cache(CFG, 3, 8)
-    assert short[1]["v"].shape == (3, CFG.n_heads, 8, CFG.head_dim)
+    assert len(short) == CFG.n_layers
+    assert short[1]["v"].shape == (1, 3, CFG.n_heads, 8, CFG.head_dim)
     assert short[1]["v"].dtype == CFG.compute_dtype
+    looped = init_kv_cache(dataclasses.replace(CFG, n_passes=4), 3, 8)
+    assert looped[0]["k"].shape == (4, 3, CFG.n_heads, 8, CFG.head_dim)
+    with pytest.raises(TypeError):
+        init_kv_cache(CFG, 3)
 
 
 def _walk_jaxpr(jaxpr, inside_loop=False):
