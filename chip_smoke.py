@@ -16,7 +16,9 @@ from ``messageResults[*].output_data``.
 Guests (user ``smoke``), all on the chips the planner pinned:
 
 - ``kernels`` — Pallas flash attention fwd and fwd+bwd, and the fused RMS
-  norm, against float32 ``jnp`` references at the shapes the model runs;
+  norm, against float32 ``jnp`` references at the shapes the model runs
+  and, for flash, the benchmark's (4 × 2048 and 1 × 8192 at 16 heads of
+  128), and a compile of both directions at S = 16384;
   plus the whole forward (one layer, full width) with the kernels against
   the same forward with the ``jnp`` impls.
 - ``train``   — a gang of one rank per chip; the leader lays the mesh over
@@ -187,34 +189,72 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
         out = {"device": _device_report(dev), "on_kernel_path": {}}
         rng = np.random.RandomState(0)
         b, h, d = run["batch_per_chip"], cfg.n_heads, cfg.head_dim
-        with jax.default_device(dev):
-            q, k, v, g = (jnp.asarray(rng.randn(b, seq, h, d), jnp.bfloat16)
+
+        def ref_attn(q, k, v):
+            with jax.default_matmul_precision("highest"):
+                return _reference_attention(
+                    *(t.astype(jnp.float32) for t in (q, k, v)), True)
+
+        def loss_of(attn):
+            return lambda q, k, v, g: jnp.sum(
+                attn(q, k, v).astype(jnp.float32) * g.astype(jnp.float32))
+
+        def causal_flash(q, k, v):
+            return flash_attention(q, k, v, True)
+
+        flash = jax.jit(causal_flash)
+        flash_grads = jax.jit(jax.grad(loss_of(causal_flash),
+                                       argnums=(0, 1, 2)))
+        ref = jax.jit(ref_attn)
+        ref_grads = jax.jit(jax.grad(loss_of(ref_attn), argnums=(0, 1, 2)))
+
+        def flash_parity(shape) -> dict:
+            """Forward and gradients at ``shape`` against the float32
+            reference, which goes two heads at a time (heads do not mix,
+            and its (S, S) logits of all heads at once would not fit at
+            S = 8192)."""
+            q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
                           for _ in range(4))
             if on_chip:
                 _require(flash_uses_kernel(q.shape, k.shape),
                          f"flash takes the reference at {q.shape}")
+            got = (flash(q, k, v), *flash_grads(q, k, v, g))
+            gap = np.zeros(4)
+            norm = np.zeros(4)
+            for h0 in range(0, shape[2], 2):
+                some = [t[:, :, h0:h0 + 2] for t in (q, k, v, g)]
+                want = (ref(*some[:3]), *ref_grads(*some))
+                for i, (a, r) in enumerate(zip(got, want)):
+                    a = np.asarray(a[:, :, h0:h0 + 2], np.float32)
+                    r = np.asarray(r, np.float32)
+                    gap[i] += np.sum((a - r) ** 2)
+                    norm[i] += np.sum(r ** 2)
+            err = np.sqrt(gap / np.maximum(norm, 1e-30))
+            return {"fwd": float(err[0]), "bwd": float(max(err[1:]))}
 
-            def ref_attn(q, k, v):
-                with jax.default_matmul_precision("highest"):
-                    return _reference_attention(
-                        *(t.astype(jnp.float32) for t in (q, k, v)), True)
-
-            def loss_of(attn):
-                return lambda q, k, v: jnp.sum(
-                    attn(q, k, v).astype(jnp.float32)
-                    * g.astype(jnp.float32))
-
-            flash = jax.jit(lambda q, k, v: flash_attention(q, k, v, True))
-            out["flash_fwd_rel_err"] = _rel_err(flash(q, k, v),
-                                                jax.jit(ref_attn)(q, k, v))
-            grads = jax.jit(jax.grad(loss_of(
-                lambda q, k, v: flash_attention(q, k, v, True)),
-                argnums=(0, 1, 2)))(q, k, v)
-            ref_grads = jax.jit(jax.grad(loss_of(ref_attn),
-                                         argnums=(0, 1, 2)))(q, k, v)
+        with jax.default_device(dev):
+            # the model's own call, then the benchmark's: train_2k_1chip's
+            # and a long one (tests: toy sizes of as many blocks)
+            shapes = [(b, seq, h, d)] + (
+                [(4, 2048, 16, 128), (1, 8192, 16, 128)] if on_chip
+                else [(1, 4 * seq, h, d)])
+            out["flash_shapes"] = {
+                "x".join(map(str, shape)): flash_parity(shape)
+                for shape in shapes}
+            out["flash_fwd_rel_err"] = max(
+                e["fwd"] for e in out["flash_shapes"].values())
             out["flash_bwd_rel_err"] = max(
-                _rel_err(a, r) for a, r in zip(grads, ref_grads))
-            out["on_kernel_path"]["flash"] = flash_uses_kernel(q.shape, k.shape)
+                e["bwd"] for e in out["flash_shapes"].values())
+            out["on_kernel_path"]["flash"] = flash_uses_kernel(
+                (b, seq, h, d), (b, seq, h, d))
+            # No block of the kernels is as long as the sequence, so a
+            # long one compiles (forward and backward; nothing runs)
+            long = jax.ShapeDtypeStruct(
+                (1, 16384, 16, 128) if on_chip else (1, 16 * seq, h, d),
+                jnp.bfloat16)
+            flash.lower(long, long, long).compile()
+            flash_grads.lower(long, long, long, long).compile()
+            out["flash_long_compiled"] = list(long.shape)
 
             scale = jnp.asarray(1.0 + 0.1 * rng.randn(cfg.d_model),
                                 jnp.float32)

@@ -1,0 +1,82 @@
+"""The flash kernels compiled by the TPU's own compiler for a described
+v5e, at real widths, without a chip: what the interpreter cannot refuse
+(a block Mosaic cannot tile, more VMEM than a kernel may take) fails here
+and costs no chip time. Nothing runs, so nothing is said about results
+or speed. All of these stay in this one file: the worker that runs it is
+the one that loads libtpu."""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from faabric_tpu.ops import flash_attention
+from faabric_tpu.ops.flash_attention import KERNELS, block_plan
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """The ops ask the backend which branch to take; the compile is for
+    the described chip, so they are told "tpu" for the test's length. The
+    persistent cache is off: an executable for a chip that is not there
+    can be written to it and never read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+# (q shape, k length, dtype, causal): train_2k_1chip's call and its
+# four-chip twin's, the long sequences the whole-sequence blocks refused,
+# a block of 384 (S = 128 · 9), float32 operands, a narrow head, a
+# decode-like cross length, no mask
+CALLS = {
+    "train_2k_1chip": ((4, 2048, 16, 128), 2048, jnp.bfloat16, True),
+    "train_2k_4chip_local": ((4, 2048, 8, 128), 2048, jnp.bfloat16, True),
+    "seq_8192": ((1, 8192, 16, 128), 8192, jnp.bfloat16, True),
+    "seq_16384": ((1, 16384, 16, 128), 16384, jnp.bfloat16, True),
+    "seq_1152_blocks_of_384": ((2, 1152, 16, 128), 1152, jnp.bfloat16, True),
+    "float32": ((2, 2048, 16, 128), 2048, jnp.float32, True),
+    "head_dim_64": ((8, 1024, 16, 64), 1024, jnp.bfloat16, True),
+    "cross_length": ((2, 1024, 16, 128), 4096, jnp.bfloat16, True),
+    "non_causal": ((2, 2048, 16, 128), 2048, jnp.bfloat16, False),
+}
+
+
+@pytest.mark.parametrize("call", sorted(CALLS))
+def test_flash_kernels_compile_for_v5e(call, one_chip, as_on_tpu):
+    q_shape, s_k, dtype, causal = CALLS[call]
+    k_shape = (q_shape[0], s_k, *q_shape[2:])
+    plan = block_plan(q_shape, k_shape, causal, dtype=dtype)
+    assert plan is not None and tuple(plan) == KERNELS
+
+    def attention(q, k, v):
+        return flash_attention(q, k, v, causal)
+
+    def loss(q, k, v):
+        return jnp.sum(attention(q, k, v).astype(jnp.float32))
+
+    q = jax.ShapeDtypeStruct(q_shape, dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct(k_shape, dtype, sharding=one_chip)
+    forward = jax.jit(attention).lower(q, kv, kv).compile().as_text()
+    both = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert forward.count("tpu_custom_call") == 1
+    assert both.count("tpu_custom_call") == 3

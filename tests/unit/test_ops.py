@@ -106,6 +106,156 @@ def test_flash_attention_ragged_shape_falls_back():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+def _qkv_lengths(s_q, s_k, dtype=jnp.float32, seed=29):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(1, s, 2, 16), dtype=dtype)
+                 for s in (s_q, s_k, s_k))
+
+
+# The plans the gridded tiling has and the whole-sequence one had not:
+# (s_q, s_k, causal, block_q, block_k, dtype); blocks None = by shape
+TILINGS = {
+    "inner_blocks_skipped_256x256": (1024, 1024, True, 256, 256, jnp.float32),
+    "unequal_512x128": (1024, 1024, True, 512, 128, jnp.float32),
+    "unequal_128x512": (1024, 1024, True, 128, 512, jnp.float32),
+    "by_shape_1024_diagonal_strips": (1024, 1024, True, None, None,
+                                      jnp.float32),
+    "by_shape_384_ladder_bottom": (384, 384, True, None, None, jnp.float32),
+    "cross_length_aligned": (256, 512, True, 128, 256, jnp.float32),
+    "cross_length_by_shape_unaligned": (128, 384, True, None, None,
+                                        jnp.float32),
+    "non_causal_128x256": (512, 512, False, 128, 256, jnp.float32),
+    "non_causal_by_shape": (512, 256, False, None, None, jnp.float32),
+    "bf16_by_shape_strips": (512, 512, True, None, None, jnp.bfloat16),
+    "bf16_256x128": (512, 512, True, 256, 128, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("tiling", sorted(TILINGS))
+def test_flash_attention_tilings_match_reference(tiling):
+    """Forward and all three gradients against the reference, over block
+    plans forced through the arguments and chosen by the shape."""
+    from faabric_tpu.ops.flash_attention import uses_kernel
+
+    s_q, s_k, causal, block_q, block_k, dtype = TILINGS[tiling]
+    q, k, v = _qkv_lengths(s_q, s_k, dtype)
+    assert uses_kernel(q.shape, k.shape, causal, block_q, block_k)
+
+    def loss(attention):
+        return lambda q, k, v: jnp.sum(
+            attention(q, k, v).astype(jnp.float32) ** 2)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal, block_q, block_k)
+
+    def ref(q, k, v):
+        return _reference_attention(q, k, v, causal)
+
+    f32 = dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v), np.float32),
+        np.asarray(ref(q, k, v), np.float32), atol=2e-5 if f32 else 3e-2)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            atol=2e-4 if f32 else 0.5, rtol=1e-3 if f32 else 0.1)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (None, None)])
+def test_flash_attention_never_reads_a_skipped_block(blocks):
+    """NaN in the keys and values from position ``cut`` on, as in a cache
+    whose tail was never written: the queries before ``cut`` see none of
+    them, so a block above the diagonal (128-blocks) or the part of a
+    diagonal block above it (one 1024-block in strips) must not be read:
+    0 × NaN would poison the row."""
+    s, cut = 1024, 512
+    q, k, v = _qkv_lengths(s, s)
+    clean = flash_attention(q, k, v, True, *blocks)
+    poisoned = flash_attention(q, k.at[:, cut:].set(jnp.nan),
+                               v.at[:, cut:].set(jnp.nan), True, *blocks)
+    np.testing.assert_array_equal(np.asarray(poisoned[:, :cut]),
+                                  np.asarray(clean[:, :cut]))
+    assert np.isnan(np.asarray(poisoned[:, cut:])).all()
+
+
+def test_block_plan_counts_the_grid():
+    from faabric_tpu.ops.flash_attention import (
+        KERNELS,
+        block_plan,
+        uses_kernel,
+    )
+
+    # train_2k_1chip's call, the plan PERF.md quotes: two 1024-blocks a
+    # side, one of four steps above the diagonal, two on it in strips of
+    # 256 rows (10/16 of a block each), one below
+    cell = (4, 2048, 16, 128)
+    plan = block_plan(cell, cell)
+    assert tuple(plan) == KERNELS
+    for kernel in KERNELS:
+        p = plan[kernel]
+        assert (p.block_q, p.block_k, p.grid) == (1024, 1024, (64, 2, 2))
+        assert (p.visited, p.masked, p.skipped) == (192, 128, 64)
+        assert p.computed == (1 + 2 * 10 / 16) / 4
+
+    # whatever the blocks: every inner step is visited or skipped, more
+    # than the causal half is computed and never more than the square,
+    # and without a mask nothing is skipped
+    for block_q, block_k in [(512, 512), (256, 1024), (1024, 128),
+                             (None, None)]:
+        for q_shape, k_shape in [(cell, cell), ((2, 1024, 4, 64),
+                                                (2, 2048, 4, 64))]:
+            for causal in (True, False):
+                for p in block_plan(q_shape, k_shape, causal, block_q,
+                                    block_k).values():
+                    b_h, outer, inner = p.grid
+                    assert b_h == q_shape[0] * q_shape[2]
+                    assert p.visited + p.skipped == b_h * outer * inner
+                    assert 0 <= p.masked <= p.visited
+                    assert 0.5 < p.computed <= 1.0
+                    if not causal:
+                        assert (p.masked, p.skipped, p.computed) == (0, 0, 1.0)
+    forced = block_plan(cell, cell, True, 512, 512)["flash_bwd_dkv"]
+    assert (forced.grid, forced.visited, forced.masked, forced.skipped) == (
+        (64, 4, 4), 640, 256, 384)
+
+    # S = 128 · 9: the largest lane-tile multiple that divides it
+    odd = block_plan((1, 1152, 2, 128), (1, 1152, 2, 128))["flash_fwd"]
+    assert (odd.block_q, odd.block_k) == (384, 384)
+    # uses_kernel is block_plan's "is there one": ragged lengths and
+    # causal s_q > s_k take the reference
+    for q_shape, k_shape, causal in [((1, 200, 2, 16), (1, 200, 2, 16), True),
+                                     ((1, 512, 2, 16), (1, 256, 2, 16), True),
+                                     ((1, 512, 2, 16), (1, 256, 2, 16), False)]:
+        assert uses_kernel(q_shape, k_shape, causal) == (
+            block_plan(q_shape, k_shape, causal) is not None) == (not causal)
+
+
+def test_flash_attention_gradient_holds_the_three_kernels():
+    """The benchmark's rooflines find the kernels by these names: one
+    forward and the two backward passes, no fourth kernel and no fusion
+    of the two."""
+    q, k, v = _qkv_lengths(256, 256)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v)),
+        argnums=(0, 1, 2)))(q, k, v)
+
+    def kernel_names(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from kernel_names(sub)
+
+    assert sorted(kernel_names(jaxpr.jaxpr)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
 def test_model_flash_attention_impl_matches_reference():
     from faabric_tpu.models import ModelConfig, forward, init_params
 
