@@ -58,7 +58,7 @@ import urllib.request
 REPO = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(REPO, "chiprun_out", "chip_smoke")
 
-# The widest configuration the repo supports (bench.py _STEP_SIZES["large"]):
+# The smoke's full width:
 # ~134 M parameters, fp32 params + AdamW state ≈ 1.6 GB, bf16 compute.
 LARGE = dict(vocab_size=16384, d_model=1024, n_layers=8, n_heads=16,
              d_ff=4096, max_seq=1024)

@@ -45,7 +45,20 @@ class MoEConfig(ModelConfig):
     aux_loss_weight: float = 0.01
 
 
+def _refuse_other_kinds(cfg: MoEConfig) -> None:
+    """The expert layer is a two-matrix GELU behind one pre-norm, passed
+    once: a configuration that names another kind would be computed as
+    another network (the attention half honours ``rope_pairing``)."""
+    for field, kind in (("ffn", "gelu"), ("norm_placement", "pre"),
+                        ("n_passes", 1)):
+        if getattr(cfg, field) != kind:
+            raise ValueError(
+                f"the MoE family implements {field}={kind!r} only, "
+                f"not {field}={getattr(cfg, field)!r}")
+
+
 def init_moe_params(key: jax.Array, cfg: MoEConfig) -> dict:
+    _refuse_other_kinds(cfg)
     keys = jax.random.split(key, cfg.n_layers + 2)
 
     def dense(k, shape, fan_in):
@@ -178,6 +191,8 @@ def moe_forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
                 mesh: Optional[Mesh] = None
                 ) -> tuple[jax.Array, jax.Array]:
     """tokens (B, S) → (logits (B, S, V), aux_loss scalar)."""
+    _refuse_other_kinds(cfg)
+
     def constrain(arr, *spec):
         if mesh is not None:
             return jax.lax.with_sharding_constraint(
@@ -197,13 +212,13 @@ def moe_forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
     aux_total = jnp.zeros((), jnp.float32)
     for blk in params["blocks"]:
         x, _ = attention_sublayer(x, blk, positions, cfg, mesh)
-        h = _rms_norm(x, blk["ln2"])
+        h = _rms_norm(x, blk["ln2"], cfg.norm_eps)
         moe_out, aux = _moe_layer(h, blk, cfg, mesh)
         aux_total = aux_total + aux
         x = x + moe_out
         x = constrain(x, "dp", None, None)
 
-    x = _rms_norm(x, params["ln_f"])
+    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(cfg.compute_dtype)
               ).astype(jnp.float32)
     return logits, aux_total / max(1, cfg.n_layers)

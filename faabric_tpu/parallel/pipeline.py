@@ -171,7 +171,18 @@ def _global_positions(b_local: int, seq: int):
         (b_local, seq))
 
 
+# The kinds of block that _pp_block and _pp_moe_block implement: handed a
+# configuration that names another, they would train another network
+_PP_KINDS = {"ffn": "gelu", "norm_placement": "pre",
+             "rope_pairing": "neighbours", "norm_eps": 1e-6, "n_passes": 1}
+
+
 def _validate_pp_mesh(cfg: ModelConfig, mesh: Mesh) -> int:
+    for field, kind in _PP_KINDS.items():
+        if getattr(cfg, field) != kind:
+            raise ValueError(
+                f"pipeline stages implement {field}={kind!r} only, "
+                f"not {field}={getattr(cfg, field)!r}")
     n_stages = mesh.shape["pp"]
     if cfg.n_layers % n_stages:
         raise ValueError(

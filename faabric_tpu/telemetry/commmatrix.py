@@ -18,9 +18,8 @@ silently under-reporting traffic — and the governor's per-link decision
 is asserted straight off the rows (``codec=`` in the dist tests).
 
 This is the data HiCCL-style collective tuning needs before any
-optimization: the 0.62-vs-6.01 GiB/s allreduce gap stops being a single
-mystery number once each (src, dst, plane) link reports its own
-bytes/latency and the bench's attribution report ranks the suspects.
+optimization: a slow allreduce stops being a single mystery number
+once each (src, dst, plane) link reports its own bytes/latency.
 
 Cardinality guard: ranks ≥ ``FAABRIC_COMMMATRIX_MAX_RANKS`` (default 64)
 collapse into one ``other`` bucket per direction, so a 256-rank world

@@ -298,8 +298,8 @@ def render_snapshots(snapshots: dict, extra_labels: dict | None = None
 
 def snapshot_delta(before: dict, after: dict) -> dict:
     """Flat ``{"name{labels}": delta}`` of counter increments and
-    histogram sum/count growth between two snapshots — what bench.py
-    writes per section so rounds get per-phase traffic trajectories."""
+    histogram sum/count growth between two snapshots: the traffic of
+    one phase of a run."""
     out: dict[str, float] = {}
 
     def _index(snap):

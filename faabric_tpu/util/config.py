@@ -175,7 +175,7 @@ class SystemConfig:
         self.point_to_point_server_threads = _env_int("POINT_TO_POINT_SERVER_THREADS", 8)
 
         # native (C++ memcmp) brackets a 128 MiB image in ~75 ms vs
-        # compare ~170 ms and hash ~300 ms (bench.py extras.dirty_tracker);
+        # compare ~170 ms and hash ~300 ms (2-core CPU container);
         # hash still wins when baseline MEMORY matters (8 B/page)
         self.dirty_tracking_mode = _env("DIRTY_TRACKING_MODE", "native")
         self.dirty_region_hints = _env("DIRTY_REGION_HINTS", "0") in (

@@ -1,8 +1,8 @@
 """Test harness configuration.
 
 All tests run on a virtual 8-device CPU mesh so multi-chip sharding logic is
-exercised without TPU hardware (the driver separately dry-runs the multichip
-path; bench.py runs on the real chip).
+exercised without TPU hardware (the chip itself is reached only through
+``chiprun``: ``benchmarks/run.py`` and ``chip_smoke.py``).
 """
 
 import os

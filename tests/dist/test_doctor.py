@@ -44,8 +44,13 @@ def doctor_cluster():
     from tests.conftest import next_port_base
 
     base = next_port_base()
-    aliases = (f"dw1=127.0.0.1+{base},dw2=127.0.0.1+{base + 3000},"
-               f"dcli=127.0.0.1+{base + 6000}")
+    # Every port inside the one slot next_port_base() gave, the
+    # planner's too (+1600 lies clear of the hosts' service and MPI
+    # ranges): the default planner ports are test_multiprocess's, and
+    # xdist runs modules side by side
+    aliases = (f"dw1=127.0.0.1+{base},dw2=127.0.0.1+{base + 1000},"
+               f"dcli=127.0.0.1+{base + 2000},"
+               f"dpl=127.0.0.1+{base + 1600}")
     http_port = get_free_port()
     common = dict(
         os.environ,
@@ -80,7 +85,7 @@ def doctor_cluster():
         raise AssertionError("child never printed READY")
 
     try:
-        planner = spawn(common, "planner")
+        planner = spawn(common, "planner", str(base + 1600))
         await_ready(planner)
         # The slow link: ONLY dw1's sends toward dw2 pay the delay —
         # the reverse direction stays fast, giving the doctor a healthy
@@ -88,8 +93,8 @@ def doctor_cluster():
         w1 = spawn(
             {**common,
              "FAABRIC_FAULTS": "transport.bulk=delay:8ms@dest=dw2"},
-            "worker", "dw1")
-        w2 = spawn(common, "worker", "dw2")
+            "worker", "dw1", "dpl")
+        w2 = spawn(common, "worker", "dw2", "dpl")
         for p in (w1, w2):
             await_ready(p)
     except BaseException:
@@ -112,13 +117,21 @@ def doctor_cluster():
 
     os.environ["FAABRIC_HOST_ALIASES"] = aliases
     clear_host_aliases()
+    # This pytest process reports ITS link profiles and matrix as host
+    # dcli: start from a clean slate, or a link that an earlier test of
+    # this process left behind (xdist runs many files in one) outranks
+    # the planted one among the doctor's top findings
+    from faabric_tpu.telemetry import get_comm_matrix, reset_perf_profile
+
+    reset_perf_profile()
+    get_comm_matrix().reset()
 
     class NullFactory(ExecutorFactory):
         def create_executor(self, msg):
             raise RuntimeError("client runs nothing")
 
     me = WorkerRuntime(host="dcli", slots=0, factory=NullFactory(),
-                       planner_host="127.0.0.1")
+                       planner_host="dpl")
     me.start()
     me.dist_http_port = http_port
 

@@ -40,7 +40,11 @@ from faabric_tpu.telemetry.lifecycle import (
 
 PROCS = os.path.join(os.path.dirname(__file__), "procs.py")
 
-RUN_DELAY_S = 0.2
+# The plant has to outweigh busy neighbours: the digest's quantiles are
+# log-bucketed (edges at powers of √2), and at 0.2 s a loaded host's
+# executor-queue p99 reached run's own bucket and tied with it for the
+# dominant phase. 0.6 s sits two buckets higher, mid-bucket.
+RUN_DELAY_S = 0.6
 N_THREADS = 3
 BULK = 10       # per submit RPC: the pre-admit client serialization of
 BULKS = 4       # the frame is the one unledgerable head, kept small
@@ -54,13 +58,18 @@ BURST = 400
 @pytest.fixture(scope="module")
 def lifecycle_cluster():
     """Planner + two 64-slot workers, every executor run inflated by a
-    planted 200 ms delay fault; this process is a 0-slot client host."""
+    planted 600 ms delay fault; this process is a 0-slot client host."""
     from faabric_tpu.util.network import get_free_port
     from tests.conftest import next_port_base
 
     base = next_port_base()
-    aliases = (f"lfw1=127.0.0.1+{base},lfw2=127.0.0.1+{base + 3000},"
-               f"lfcli=127.0.0.1+{base + 6000}")
+    # Every port inside the one slot next_port_base() gave, the
+    # planner's too (+1600 lies clear of the hosts' service and MPI
+    # ranges): the default planner ports are test_multiprocess's, and
+    # xdist runs modules side by side
+    aliases = (f"lfw1=127.0.0.1+{base},lfw2=127.0.0.1+{base + 1000},"
+               f"lfcli=127.0.0.1+{base + 2000},"
+               f"lfpl=127.0.0.1+{base + 1600}")
     http_port = get_free_port()
     w1_http = get_free_port()
     common = dict(
@@ -68,11 +77,11 @@ def lifecycle_cluster():
         FAABRIC_HOST_ALIASES=aliases,
         JAX_PLATFORMS="cpu",
         DIST_HTTP_PORT=str(http_port),
-        # The planted dominant phase: every guest run pays 200 ms
+        # The planted dominant phase: every guest run pays RUN_DELAY_S
         FAABRIC_FAULTS=f"executor.run=delay:{int(RUN_DELAY_S * 1e3)}ms",
         # Fast sampling so the burst's queue depth is captured
         FAABRIC_TIMESERIES_INTERVAL_S="0.05",
-        # An SLO the 40 ms runs must burn (5 ms p99 target)
+        # An SLO the planted runs must burn (5 ms p99 target)
         FAABRIC_SLO="p99_e2e_ms=5,error_rate=0.01",
         FAABRIC_SLO_WINDOWS="10,30",
     )
@@ -96,11 +105,11 @@ def lifecycle_cluster():
         raise AssertionError("child never printed READY")
 
     try:
-        planner = spawn(common, "planner")
+        planner = spawn(common, "planner", str(base + 1600))
         await_ready(planner)
         w1 = spawn({**common, "WORKER_HTTP_PORT": str(w1_http)},
-                   "worker", "lfw1", "127.0.0.1", "64")
-        w2 = spawn(common, "worker", "lfw2", "127.0.0.1", "64")
+                   "worker", "lfw1", "lfpl", "64")
+        w2 = spawn(common, "worker", "lfw2", "lfpl", "64")
         for p in (w1, w2):
             await_ready(p)
     except BaseException:
@@ -127,7 +136,7 @@ def lifecycle_cluster():
             raise RuntimeError("client runs nothing")
 
     me = WorkerRuntime(host="lfcli", slots=0, factory=NullFactory(),
-                       planner_host="127.0.0.1")
+                       planner_host="lfpl")
     me.start()
     me.dist_http_port = http_port
     me.w1_http_port = w1_http
@@ -265,7 +274,7 @@ def test_dist_lifecycle_ledger_timeseries_slo_and_doctor(
     assert "run=" in text and "ingress_queue=" in text
 
     # -- phase B: flood the admission queue, then read the trend -------
-    # 400 messages against 128 slots of 200 ms runs: the backlog holds
+    # 400 messages against 128 slots of 600 ms runs: the backlog holds
     # admission credits for ≥1 s, so the 50 ms sampler must catch a
     # nonzero ingress-depth series.
     base_results = health["resultsTotal"]

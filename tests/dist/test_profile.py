@@ -26,7 +26,7 @@ from faabric_tpu.proto import ReturnValue, batch_exec_factory
 
 PROCS = os.path.join(os.path.dirname(__file__), "procs.py")
 
-SPIN_S = 8.0
+SPIN_S = 24.0
 
 
 @pytest.fixture(scope="module")
@@ -37,8 +37,13 @@ def profile_cluster():
     from tests.conftest import next_port_base
 
     base = next_port_base()
-    aliases = (f"pf1=127.0.0.1+{base},pf2=127.0.0.1+{base + 3000},"
-               f"pfcli=127.0.0.1+{base + 6000}")
+    # Every port inside the one slot next_port_base() gave, the
+    # planner's too (+1600 lies clear of the hosts' service and MPI
+    # ranges): the default planner ports are test_multiprocess's, and
+    # xdist runs modules side by side
+    aliases = (f"pf1=127.0.0.1+{base},pf2=127.0.0.1+{base + 1000},"
+               f"pfcli=127.0.0.1+{base + 2000},"
+               f"pfpl=127.0.0.1+{base + 1600}")
     http_port = get_free_port()
     # 10 ms cadence (default 25): finer drift resolution so the planted
     # GIL saturation reads well above threshold within the spin window,
@@ -66,10 +71,10 @@ def profile_cluster():
         raise AssertionError("child never printed READY")
 
     try:
-        planner = spawn("planner")
+        planner = spawn("planner", str(base + 1600))
         await_ready(planner)
-        w1 = spawn("worker", "pf1")
-        w2 = spawn("worker", "pf2")
+        w1 = spawn("worker", "pf1", "pfpl")
+        w2 = spawn("worker", "pf2", "pfpl")
         for p in (w1, w2):
             await_ready(p)
     except BaseException:
@@ -90,13 +95,19 @@ def profile_cluster():
 
     os.environ["FAABRIC_HOST_ALIASES"] = aliases
     clear_host_aliases()
+    # This pytest process reports ITS stacks as host pfcli: drop what
+    # the sampler gathered under earlier tests of this process (xdist
+    # runs many files in one), or an old stack's CPU outranks the plant
+    from faabric_tpu.telemetry.profiler import reset_profiler
+
+    reset_profiler()
 
     class NullFactory(ExecutorFactory):
         def create_executor(self, msg):
             raise RuntimeError("client runs nothing")
 
     me = WorkerRuntime(host="pfcli", slots=0, factory=NullFactory(),
-                       planner_host="127.0.0.1")
+                       planner_host="pfpl")
     me.start()
     me.dist_http_port = http_port
 
@@ -130,15 +141,25 @@ def test_dist_profile_hotspot_attribution_and_doctor(profile_cluster):
     req = batch_exec_factory("dist", "profile_spin", 1)
     req.messages[0].input_data = str(SPIN_S).encode()
     me.planner_client.call_functions(req)
-    time.sleep(SPIN_S * 0.75)
-
-    doc = _get(base, "/profile")
+    # Wait on the aggregation's own counts, not on a fixed sleep: under
+    # a pure-Python burn the burning host's sampler gets the GIL a few
+    # times a second (each /proc read hands it back), fewer with busy
+    # neighbours, so its 50-sample evidence floor fills slowly
+    time.sleep(SPIN_S * 0.25)
+    deadline = time.monotonic() + SPIN_S * 0.6
+    while True:
+        doc = _get(base, "/profile")
+        if time.monotonic() > deadline or all(
+                (doc["hosts"].get(h) or {}).get("samples", 0) >= 50
+                for h in ("pf1", "pf2")):
+            break
+        time.sleep(0.25)
     from faabric_tpu.runner.doctor import diagnose, fetch_live
 
     findings = diagnose(fetch_live(base))
 
     r = me.planner_client.get_message_result(
-        req.app_id, req.messages[0].id, timeout=30.0)
+        req.app_id, req.messages[0].id, timeout=SPIN_S + 30.0)
     assert r.return_value == int(ReturnValue.SUCCESS), r.output_data
     host = r.executed_host
     assert host in ("pf1", "pf2"), host

@@ -39,8 +39,13 @@ def statemap_cluster():
     from tests.conftest import next_port_base
 
     base = next_port_base()
-    aliases = (f"sw1=127.0.0.1+{base},sw2=127.0.0.1+{base + 3000},"
-               f"scli=127.0.0.1+{base + 6000}")
+    # Every port inside the one slot next_port_base() gave, the
+    # planner's too (+1600 lies clear of the hosts' service and MPI
+    # ranges): the default planner ports are test_multiprocess's, and
+    # xdist runs modules side by side
+    aliases = (f"sw1=127.0.0.1+{base},sw2=127.0.0.1+{base + 1000},"
+               f"scli=127.0.0.1+{base + 2000},"
+               f"spl=127.0.0.1+{base + 1600}")
     http_port = get_free_port()
     env = dict(os.environ, FAABRIC_HOST_ALIASES=aliases,
                JAX_PLATFORMS="cpu", FAABRIC_METRICS="1",
@@ -64,10 +69,10 @@ def statemap_cluster():
         raise AssertionError("child never printed READY")
 
     try:
-        planner = spawn("planner")
+        planner = spawn("planner", str(base + 1600))
         await_ready(planner)
-        w1 = spawn("worker", "sw1")
-        w2 = spawn("worker", "sw2")
+        w1 = spawn("worker", "sw1", "spl")
+        w2 = spawn("worker", "sw2", "spl")
         for p in (w1, w2):
             await_ready(p)
     except BaseException:
@@ -105,7 +110,7 @@ def statemap_cluster():
             raise RuntimeError("client runs nothing")
 
     me = WorkerRuntime(host="scli", slots=0, factory=NullFactory(),
-                       planner_host="127.0.0.1")
+                       planner_host="spl")
     me.start()
     me.dist_http_port = http_port
 

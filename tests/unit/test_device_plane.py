@@ -332,11 +332,13 @@ def test_waiter_outlasts_slow_executor(device_world, monkeypatch):
 
     activate(device_world)
     plane = device_world.device_plane()
-    monkeypatch.setattr(plane_mod, "DEVICE_PLANE_TIMEOUT_S", 0.05)
+    # A window the four rank threads can gather in on a loaded box (at
+    # 0.05 s two of four made it while five xdist workers ran beside)
+    monkeypatch.setattr(plane_mod, "DEVICE_PLANE_TIMEOUT_S", 0.25)
     orig = plane._execute
 
     def slow_execute(*args, **kwargs):
-        time.sleep(0.4)  # several timeout windows
+        time.sleep(1.0)  # several timeout windows
         return orig(*args, **kwargs)
 
     plane._execute = slow_execute

@@ -7,8 +7,7 @@ shedding (429 + Retry-After on the REST surface), and the pipelined
 wire shapes (EXECUTE_BATCHES, bulk SUBMIT_BATCH, batched mappings).
 
 All in-process and mock-mode (dispatch/mappings record instead of
-dialing); the real-cluster QPS scenario lives in bench.py
-``bench_invocations`` and the full-QPS chaos test in
+dialing); the full-QPS chaos test against real processes is
 tests/dist/test_chaos.py.
 """
 

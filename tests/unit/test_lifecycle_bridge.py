@@ -363,10 +363,7 @@ def test_a_planner_process_has_loaded_no_jax_after_an_invocation():
          "--port-offset", str(base), "--http-port", str(http_port)],
         cwd=REPO, env=env, stdout=subprocess.DEVNULL,
         stderr=subprocess.DEVNULL)
-    worker = subprocess.Popen(
-        [sys.executable, os.path.join(REPO, "tests", "dist", "procs.py"),
-         "worker", "brw", "brp", "2"], cwd=REPO, env=env,
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
+    worker = None
 
     def post(http_type, payload=""):
         body = json.dumps({"http_type": http_type,
@@ -388,6 +385,14 @@ def test_a_planner_process_has_loaded_no_jax_after_an_invocation():
             time.sleep(0.05)
 
     try:
+        # The REST endpoint starts after the planner's RPC server, and a
+        # worker that finds no planner to register with exits at once
+        wait_for("the planner's REST endpoint",
+                 lambda: post(GET_AVAILABLE_HOSTS)["hosts"] == [])
+        worker = subprocess.Popen(
+            [sys.executable, os.path.join(REPO, "tests", "dist", "procs.py"),
+             "worker", "brw", "brp", "2"], cwd=REPO, env=env,
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
         assert worker.stdout.readline().strip() == "READY"
         wait_for("the worker among the planner's hosts", lambda: [
             h["ip"] for h in post(GET_AVAILABLE_HOSTS)["hosts"]] == ["brw"])
@@ -407,12 +412,14 @@ def test_a_planner_process_has_loaded_no_jax_after_an_invocation():
         with open(f"/proc/{planner.pid}/maps") as f:
             assert "jaxlib" not in f.read()
     finally:
-        for p in (worker, planner):
+        procs = [p for p in (worker, planner) if p is not None]
+        for p in procs:
             p.terminate()
-        for p in (worker, planner):
+        for p in procs:
             try:
                 p.wait(timeout=10)
             except subprocess.TimeoutExpired:
                 p.kill()
                 p.wait()
-        worker.stdout.close()
+        if worker is not None:
+            worker.stdout.close()

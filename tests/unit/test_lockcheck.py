@@ -164,8 +164,7 @@ def test_not_installed_leaves_threading_untouched():
 
 
 def test_checked_lock_overhead_is_bounded():
-    """Sanity bound, not a benchmark (bench.py reports the real numbers
-    in the concurrency section): a checked acquire/release pair must
+    """Sanity bound, not a benchmark: a checked acquire/release pair must
     stay within interpreter noise — microseconds, not milliseconds."""
     import time as _time
 

@@ -1,5 +1,7 @@
 """MoE model family: routing semantics + expert parallelism over ep."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -164,3 +166,47 @@ def test_moe_top2_train_step_on_ep_mesh():
         losses.append(float(loss))
     assert all(np.isfinite(v) for v in losses)
     assert losses[-1] < losses[0]
+
+
+@pytest.mark.parametrize("kind", [dict(ffn="swiglu"),
+                                  dict(norm_placement="sandwich"),
+                                  dict(n_passes=2)],
+                         ids=lambda kind: next(iter(kind)))
+def test_moe_refuses_block_kinds_it_lacks(kind):
+    """The expert layer is a GELU pair behind one pre-norm, passed once:
+    another kind is refused by name at init and at forward, not computed
+    as another network."""
+    cfg = dataclasses.replace(CFG, **kind)
+    (field,) = kind
+    with pytest.raises(ValueError, match=field):
+        init_moe_params(jax.random.PRNGKey(0), cfg)
+    params = init_moe_params(jax.random.PRNGKey(0), CFG)
+    with pytest.raises(ValueError, match=field):
+        moe_forward(params, batch()[0], cfg)
+
+
+def test_moe_honours_norm_eps_and_rope_pairing():
+    """What the family can carry it carries: every norm of the layer
+    takes the configuration's epsilon (the expert half's and the final
+    one too), and the pairing reaches the attention half."""
+    params = init_moe_params(jax.random.PRNGKey(0), CFG)
+    # a residual stream small enough for epsilon to matter in every norm
+    params["embed"] = params["embed"] * 1e-2
+    tokens, _ = batch()
+    base, _ = moe_forward(params, tokens, CFG)
+    eps = 1e-2
+
+    # no layers: the final norm alone, against the formula
+    cfg = dataclasses.replace(CFG, norm_eps=eps, n_layers=0)
+    got, _ = moe_forward({**params, "blocks": []}, tokens, cfg)
+    x = params["embed"][tokens]
+    want = (x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+            ) @ params["lm_head"]
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+    loose, _ = moe_forward(params, tokens,
+                           dataclasses.replace(CFG, norm_eps=eps))
+    assert float(jnp.abs(loose - base).max()) > 1e-3
+    halves, _ = moe_forward(params, tokens,
+                            dataclasses.replace(CFG, rope_pairing="halves"))
+    assert float(jnp.abs(halves - base).max()) > 1e-3

@@ -3,6 +3,8 @@
 Capability analog: SURVEY §5.7 "scaling the big thing" — the pp axis was
 a name without a feature until round 3 (VERDICT r2 missing #2)."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -169,6 +171,26 @@ def test_pipeline_rejects_bad_configs():
     mesh_ep = build_mesh(jax.devices()[:8], MeshConfig(dp=2, ep=2, pp=2))
     with pytest.raises(ValueError, match="MoE config"):
         make_pp_loss(CFG, mesh_ep)
+
+
+@pytest.mark.parametrize("kind", [dict(ffn="swiglu"),
+                                  dict(norm_placement="sandwich"),
+                                  dict(rope_pairing="halves"),
+                                  dict(norm_eps=1e-5),
+                                  dict(n_passes=2)],
+                         ids=lambda kind: next(iter(kind)))
+@pytest.mark.parametrize("moe", [False, True], ids=["dense", "moe"])
+def test_pipeline_refuses_block_kinds_it_lacks(kind, moe):
+    """The stages' own blocks are GELU, pre-norm, neighbours, eps 1e-6,
+    one pass: a configuration that names another kind is refused by
+    name, not trained as another network."""
+    cfg = dataclasses.replace(_moe_cfg() if moe else CFG, **kind)
+    shape = MeshConfig(dp=2, pp=2, ep=2) if moe else MeshConfig(dp=4, pp=2)
+    mesh = build_mesh(jax.devices()[:8], shape)
+    (field,) = kind
+    for schedule_name in ("gpipe", "1f1b"):
+        with pytest.raises(ValueError, match=field):
+            make_pp_train_step(cfg, mesh, schedule_name=schedule_name)
 
 
 def test_pipeline_deep_config_pp4_tp2():

@@ -478,14 +478,3 @@ def test_coded_streams_pin_to_one_stripe(bulk_codec_pair):
     coded_stripes = [s for s in client.stripes() if s.coded_frames > 0]
     assert len(coded_stripes) == 1
     assert coded_stripes[0].coded_frames == 4
-
-
-def test_bench_gate_delta_stream_key_direction():
-    """ISSUE 11 satellite: delta_stream_gibs is REQUIRED and
-    higher-is-better (a rate, never a latency)."""
-    from tools.bench_gate import REQUIRED_KEYS, direction
-
-    assert "delta_stream_gibs" in REQUIRED_KEYS
-    assert direction("delta_stream_gibs") == 1
-    assert direction("delta_stream_wire_speedup") == 1
-    assert direction("host_allreduce_procs_coded_gibs") == 1
