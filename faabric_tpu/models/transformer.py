@@ -8,8 +8,13 @@ XLA), written for the MXU and the mesh:
   hidden shard over ``tp``, batch over ``dp``, sequence over ``sp``
   (Megatron-style sequence parallelism on the norm/MLP path — XLA inserts
   the gathers around attention);
-- blocks are ``jax.checkpoint``-wrapped so long-context activations
-  rematerialise instead of living in HBM;
+- under ``remat`` a block is ``jax.checkpoint``-wrapped and the backward
+  pass keeps what the chips can hold (:func:`remat_plan`, from the call's
+  shapes and the memory the chips report when the step is traced): the
+  first layers keep their matrix products and the attention kernel's
+  output (:data:`KEPT`) and make only their element-wise operations
+  again, the others keep their input alone and are recomputed whole, as
+  every layer is where no memory can be read;
 - static shapes and a Python-unrolled layer loop: everything under jit
   traces once.
 
@@ -27,6 +32,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -42,6 +48,9 @@ class ModelConfig:
     rope_theta: float = 10000.0
     compute_dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    # False: the backward pass keeps whatever autodiff saves. True: a
+    # block is checkpointed and keeps its input, and its matrix products
+    # too where remat_plan() finds room on the chips; the rest is recomputed
     remat: bool = True
     # "reference" = plain jnp attention; "flash" = the Pallas fused kernel
     # (ops/flash_attention.py) — identical numerics, no (S, S) scores in
@@ -309,9 +318,11 @@ def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
     h = _norm(x, blk["ln1"], cfg)
     qkv = jnp.einsum("bsd,dthe->tbshe", h,
                      blk["wqkv"].astype(cfg.compute_dtype))
-    q, k, v = qkv[0], qkv[1], qkv[2]
-    q = _rope(q, positions, cfg.rope_theta, cfg.rope_pairing)
-    k = _rope(k, positions, cfg.rope_theta, cfg.rope_pairing)
+    q, k, v = qkv[0], qkv[1], checkpoint_name(qkv[2], "v")
+    q = checkpoint_name(
+        _rope(q, positions, cfg.rope_theta, cfg.rope_pairing), "q_rope")
+    k = checkpoint_name(
+        _rope(k, positions, cfg.rope_theta, cfg.rope_pairing), "k_rope")
     if cache is not None:
         attn, cache = _attend_through_cache(q, k, v, cache, slot)
     elif cfg.attention_impl == "flash":
@@ -328,8 +339,9 @@ def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
                               batch_axis="dp", head_axis="tp")
     else:
         attn = _attention(q, k, v)
-    out = jnp.einsum("bshe,hed->bsd", attn,
-                     blk["wo"].astype(cfg.compute_dtype))
+    out = checkpoint_name(
+        jnp.einsum("bshe,hed->bsd", attn,
+                   blk["wo"].astype(cfg.compute_dtype)), "attn_proj")
     if cfg.norm_placement == "sandwich":
         out = _norm(out, blk["ln1_post"], cfg)
     return x + out, cache
@@ -344,12 +356,16 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
     None)."""
     x, cache = attention_sublayer(x, blk, positions, cfg, mesh, cache, slot)
     h = _norm(x, blk["ln2"], cfg)
-    ff = h @ blk["w1"].astype(cfg.compute_dtype)
+    ff = checkpoint_name(h @ blk["w1"].astype(cfg.compute_dtype), "ffn_up")
     if cfg.ffn == "swiglu":
-        ff = jax.nn.silu(h @ blk["wg"].astype(cfg.compute_dtype)) * ff
+        gate = checkpoint_name(h @ blk["wg"].astype(cfg.compute_dtype),
+                               "ffn_gate")
+        ff = jax.nn.silu(gate) * ff
     else:
         ff = jax.nn.gelu(ff)
-    out = ff @ blk["w2"].astype(cfg.compute_dtype)
+    ff = checkpoint_name(ff, "ffn_act")
+    out = checkpoint_name(ff @ blk["w2"].astype(cfg.compute_dtype),
+                          "ffn_down")
     if cfg.norm_placement == "sandwich":
         out = _norm(out, blk["ln2_post"], cfg)
     return x + out, cache
@@ -399,9 +415,131 @@ def run_passes(x: jax.Array, carry: Any, params: dict, cfg: ModelConfig,
     return chosen, carry
 
 
+# What a block's checkpoint keeps for the backward pass, by the names the
+# values take where they are made (``checkpoint_name`` in
+# :func:`attention_sublayer`, :func:`_block` and the flash kernel's forward
+# rule): the outputs of the matrix products (of the QKV product the
+# queries and keys after their rotary turn: the same bytes, and no turn to
+# make again), the attention kernel's output and row statistic, and the
+# activation the down-projection reads. The rest of a block (norms,
+# residual adds) is made again. What each is worth on the chip: PERF.md
+# section 6, PR 30.
+KEPT = ("q_rope", "k_rope", "v", "attn_out", "attn_lse", "attn_proj",
+        "ffn_up", "ffn_gate", "ffn_act", "ffn_down")
+
+
+def _per_chip(cfg: ModelConfig, tokens_shape, mesh: Optional[Mesh]) -> tuple:
+    """(tokens, heads, d_ff) of one chip's share, and the mesh's ways:
+    batch over ``dp``, sequence over ``sp``, heads and the MLP's hidden
+    over ``tp``."""
+    ways = dict(mesh.shape) if mesh is not None else {}
+    ways = {a: ways.get(a, 1) for a in ("dp", "tp", "sp")}
+    b, s = tokens_shape
+    return (-(-b // ways["dp"]) * -(-s // ways["sp"]),
+            -(-cfg.n_heads // ways["tp"]), -(-cfg.d_ff // ways["tp"]), ways)
+
+
+def _block_matrices(cfg: ModelConfig, heads: int, d_ff: int) -> int:
+    """Parameters of one block's matrices (QKV, output, up, down and a
+    gate) at these many heads and this MLP width."""
+    return cfg.d_model * (4 * heads * cfg.head_dim
+                          + (2 + (cfg.ffn == "swiglu")) * d_ff)
+
+
+def remat_plan(cfg: ModelConfig, tokens_shape, mesh: Optional[Mesh] = None,
+               free_bytes: Optional[int] = None) -> dict:
+    """Which blocks keep their products for the backward pass, as
+    ``flash_attention.block_plan`` sizes the kernels' blocks: a pure
+    function of the call's per-chip shapes and of ``free_bytes``, what one
+    chip has left once the state is resident and the step's own needs
+    (:func:`step_bytes`) are taken off. The first ``layers_kept`` blocks
+    are checkpointed under a policy that saves :data:`KEPT`, the others
+    whole. With no reading (``None``: the CPU backend has none) no block
+    keeps anything: nothing changes where nothing can be observed.
+    Returns the layers kept,
+    the bytes one layer's kept values take on one chip (``layer_bytes``),
+    those of all kept layers (``kept_bytes``), and the forward operations
+    the backward pass runs again for each token
+    (``recomputed_flops_per_token``: the block matrices at 2 a parameter
+    and causal attention's two products, of the blocks checkpointed
+    whole)."""
+    tokens, heads, d_ff, _ = _per_chip(cfg, tokens_shape, mesh)
+    item = jnp.dtype(cfg.compute_dtype).itemsize
+    swiglu, sandwich = cfg.ffn == "swiglu", cfg.norm_placement == "sandwich"
+    # a token's kept elements: q, k, v, the output projection, up (and
+    # gate) and the activation, and the down-projection where a norm reads
+    # it before the residual
+    width = (3 * heads * cfg.head_dim + cfg.d_model + (2 + swiglu) * d_ff
+             + sandwich * cfg.d_model)
+    layer_bytes = tokens * width * item
+    if cfg.attention_impl == "flash":
+        from faabric_tpu.ops.flash_attention import SUBLANE
+
+        # the kernel's output, and its row statistic as float32 rows
+        # (batch·heads, SUBLANE, sequence)
+        layer_bytes += tokens * heads * (cfg.head_dim * item + SUBLANE * 4)
+    layer_bytes *= cfg.n_passes
+    kept = 0
+    if free_bytes is not None:
+        kept = int(min(cfg.n_layers, max(0, free_bytes) // layer_bytes))
+    again = (2 * _block_matrices(cfg, cfg.n_heads, cfg.d_ff)
+             + 2 * tokens_shape[1] * cfg.d_model)
+    return {"layers_kept": kept, "layer_bytes": layer_bytes,
+            "kept_bytes": kept * layer_bytes,
+            "recomputed_flops_per_token":
+                (cfg.n_layers - kept) * cfg.n_passes * again}
+
+
+def step_bytes(cfg: ModelConfig, tokens_shape,
+               mesh: Optional[Mesh] = None) -> int:
+    """What a train step takes on one chip besides the state and what
+    :func:`remat_plan` keeps, from shapes alone and on the safe side: the
+    float32 logits and their gradient; the gradients' leaves (all of them
+    where replicas exchange them, ``dp·sp > 1``: XLA holds every leaf for
+    its combined all-reduce; on one replica a leaf goes into its AdamW
+    update as it is made, and two of the largest are counted); every
+    block's input; and one block's values with their cotangents while it
+    is differentiated."""
+    tokens, heads, d_ff, ways = _per_chip(cfg, tokens_shape, mesh)
+    item = jnp.dtype(cfg.compute_dtype).itemsize
+    swiglu = cfg.ffn == "swiglu"
+    logits = 2 * tokens * cfg.vocab_size * 4
+    table = cfg.vocab_size * cfg.d_model // ways["tp"]
+    if ways["dp"] * ways["sp"] > 1:
+        leaves = 2 * table + cfg.n_layers * _block_matrices(cfg, heads, d_ff)
+    else:
+        leaves = 2 * max(table, cfg.d_model * d_ff,
+                         3 * cfg.d_model * heads * cfg.head_dim)
+    grads = leaves * jnp.dtype(cfg.param_dtype).itemsize
+    inputs = cfg.n_layers * cfg.n_passes * tokens * cfg.d_model * item
+    block = 2 * tokens * item * (5 * heads * cfg.head_dim + 4 * cfg.d_model
+                                 + (2 + 2 * swiglu) * d_ff)
+    return logits + grads + inputs + block
+
+
+def _free_bytes(mesh: Optional[Mesh]) -> Optional[int]:
+    """What the least free of the step's chips has left, by the device's
+    own reading (``bytes_limit`` less ``bytes_in_use``); ``None`` where a
+    chip gives none (the CPU backend), or where other processes hold
+    chips of the mesh whose reading this one cannot see."""
+    if jax.process_count() > 1:
+        return None
+    devices = (mesh.devices.flat if mesh is not None
+               else jax.local_devices()[:1])
+    free = []
+    for device in devices:
+        stats = device.memory_stats() or {}
+        if "bytes_limit" not in stats or "bytes_in_use" not in stats:
+            return None
+        free.append(stats["bytes_limit"] - stats["bytes_in_use"])
+    return min(free)
+
+
 def forward(params: dict, tokens: jax.Array, cfg: ModelConfig,
             mesh: Optional[Mesh] = None) -> jax.Array:
-    """tokens (B, S) int32 → logits (B, S, V)."""
+    """tokens (B, S) int32 → logits (B, S, V). Under ``cfg.remat`` the
+    blocks are checkpointed as :func:`remat_plan` says, read from the
+    chips' memory when this is traced (the state resident by then)."""
     def maybe_constrain(x, *spec):
         if mesh is not None:
             return jax.lax.with_sharding_constraint(
@@ -415,12 +553,20 @@ def forward(params: dict, tokens: jax.Array, cfg: ModelConfig,
     x = params["embed"].astype(cfg.compute_dtype)[tokens]
     x = maybe_constrain(x, "dp", "sp", None)
 
-    block_fn = _block
+    block_fns = [_block] * cfg.n_layers
     if cfg.remat:
-        block_fn = jax.checkpoint(_block, static_argnums=(3, 4))
+        free = _free_bytes(mesh)
+        if free is not None:
+            free -= step_bytes(cfg, tokens.shape, mesh)
+        kept = remat_plan(cfg, tokens.shape, mesh, free)["layers_kept"]
+        keeping = jax.checkpoint(
+            _block, static_argnums=(3, 4),
+            policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+        whole = jax.checkpoint(_block, static_argnums=(3, 4))
+        block_fns = [keeping] * kept + [whole] * (cfg.n_layers - kept)
 
     def stack(x, carry, _t):
-        for blk in params["blocks"]:
+        for block_fn, blk in zip(block_fns, params["blocks"]):
             x, _ = block_fn(x, blk, positions, cfg, mesh)
             x = maybe_constrain(x, "dp", "sp", None)
         return x, carry

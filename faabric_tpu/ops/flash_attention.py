@@ -53,6 +53,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -606,6 +607,12 @@ def _flash_forward(q, k, v, causal, block_q, block_k):
 
 def _flash_fwd(q, k, v, causal, block_q, block_k):
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k)
+    # Named where they are made, so that a ``jax.checkpoint`` policy can
+    # keep them (models/transformer.py:KEPT): the backward kernels read
+    # these two, and a checkpoint that lost them would run flash_fwd again
+    out = checkpoint_name(out, "attn_out")
+    if lse is not None:
+        lse = checkpoint_name(lse, "attn_lse")
     return out, (q, k, v, out, lse)
 
 

@@ -1,5 +1,7 @@
 """Flagship model + mesh tests on the 8-device virtual mesh."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -409,3 +411,283 @@ def test_optimizer_schedule_and_clipping_train():
         losses.append(float(loss))
     assert all(np.isfinite(v) for v in losses)
     assert losses[-1] < losses[0]
+
+
+# ---------------------------------------------------------------------------
+# What the backward pass keeps of a block (remat_plan)
+# ---------------------------------------------------------------------------
+
+PYTHIA = dict(vocab_size=50304, d_model=2048, n_heads=16, d_ff=8192,
+              max_seq=2048, attention_impl="flash")
+MB = 1 << 20
+
+
+def _keep_all(monkeypatch, free=1 << 50):
+    """The plan as a chip with that many bytes free would make it: the
+    reading is the one thing a test can steer (the CPU backend gives
+    none)."""
+    from faabric_tpu.models import transformer
+
+    monkeypatch.setattr(transformer, "_free_bytes", lambda mesh: free)
+
+
+def test_remat_plan_keeps_every_layer_at_the_train_cells_shapes():
+    """``train_2k_1chip``: (4, 2048) tokens, 8 layers, one chip, 6.7 GB
+    left beside the state and the step: all eight layers keep q, k and v
+    (96 MiB), the kernel's output (32) and row statistic (4), the output
+    projection (32), the up-projection (128: ISSUE 30's 306 MB so far)
+    and the GELU's output (128), and nothing is computed twice."""
+    from faabric_tpu.models.transformer import remat_plan
+
+    cfg = ModelConfig(n_layers=8, **PYTHIA)
+    plan = remat_plan(cfg, (4, 2048), None, 6_700_000_000)
+    assert plan == {"layers_kept": 8, "layer_bytes": 420 * MB,
+                    "kept_bytes": 8 * 420 * MB,
+                    "recomputed_flops_per_token": 0}
+    assert 305e6 < plan["layer_bytes"] - 128 * MB < 307e6
+
+
+def test_remat_plan_keeps_what_fits_on_a_full_chip():
+    """``train_2k_b8_dp2tp2``'s per-chip shapes (24 layers, 4 of 8
+    sequences a chip, 8 of 16 heads and half of d_ff) with 1.7 GB left:
+    the layers that fit, the first ones, and the others' forward counted
+    as computed again."""
+    from faabric_tpu.models.transformer import remat_plan
+
+    cfg = ModelConfig(n_layers=24, **PYTHIA)
+    mesh = build_mesh(jax.devices()[:4], MeshConfig(dp=2, tp=2))
+    plan = remat_plan(cfg, (8, 2048), mesh, 1_700_000_000)
+    # q, k, v 48, attention 16 + 2, the output projection whole 32, up
+    # and the activation 64 MiB each
+    assert plan["layer_bytes"] == 226 * MB
+    assert plan["layers_kept"] == 7
+    assert plan["kept_bytes"] == 7 * 226 * MB <= 1_700_000_000
+    assert plan["kept_bytes"] + plan["layer_bytes"] > 1_700_000_000
+    block = 2 * 2048 * (4 * 2048 + 2 * 8192) + 2 * 2048 * 2048
+    assert plan["recomputed_flops_per_token"] == 17 * block
+    assert remat_plan(cfg, (8, 2048), mesh, -5)["layers_kept"] == 0
+
+
+@pytest.mark.parametrize("kinds,per_token", [
+    (dict(), 3 * 64 + 64 + 2 * 96),
+    (dict(attention_impl="flash"), 3 * 64 + 64 + 2 * 96 + 64),
+    (dict(ffn="swiglu"), 3 * 64 + 64 + 3 * 96),
+    (dict(norm_placement="sandwich"), 3 * 64 + 2 * 64 + 2 * 96),
+])
+def test_remat_plan_counts_what_each_kind_of_block_keeps(kinds, per_token):
+    from faabric_tpu.models.transformer import remat_plan
+
+    cfg = ModelConfig(vocab_size=320, d_model=64, n_layers=3, n_heads=4,
+                      d_ff=96, max_seq=128, compute_dtype=jnp.float32,
+                      **kinds)
+    plan = remat_plan(cfg, (2, 128), None, 1 << 40)
+    stat = 2 * 4 * 8 * 128 * 4 if kinds.get("attention_impl") else 0
+    assert plan["layer_bytes"] == 2 * 128 * per_token * 4 + stat
+    assert plan["layers_kept"] == 3
+
+
+@pytest.mark.parametrize("kinds", [
+    dict(attention_impl="flash"),
+    dict(ffn="swiglu", norm_placement="sandwich"),
+], ids=["gelu_pre_flash", "swiglu_sandwich"])
+def test_a_kept_block_saves_the_bytes_the_plan_counts(capsys, kinds):
+    """What ``jax.checkpoint`` saves of a block under the policy, beside
+    the block's own arguments, is ``layer_bytes`` to the byte."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    from faabric_tpu.models import transformer
+
+    cfg = ModelConfig(vocab_size=64, d_model=256, n_layers=1, n_heads=2,
+                      d_ff=384, max_seq=128, compute_dtype=jnp.float32,
+                      norm_impl="reference", **kinds)
+    blk = init_params(jax.random.PRNGKey(0), cfg)["blocks"][0]
+    x = jnp.zeros((4, 128, cfg.d_model))
+    positions = jnp.zeros((4, 128), jnp.int32)
+    keeping = jax.checkpoint(
+        transformer._block, static_argnums=(3, 4),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *transformer.KEPT))
+    print_saved_residuals(
+        lambda x, blk: keeping(x, blk, positions, cfg, None)[0].sum(),
+        x, blk)
+    saved = 0
+    for line in capsys.readouterr().out.splitlines():
+        shape = re.match(r"(f32|i32|float32|int32)\[([\d,]*)\] (.*)", line)
+        if shape and not shape.group(3).startswith(
+                ("from the argument", "from a constant")):
+            saved += 4 * int(np.prod(
+                [int(n) for n in shape.group(2).split(",")]))
+    plan = transformer.remat_plan(cfg, (4, 128), None, 1 << 40)
+    assert saved == plan["layer_bytes"] > 0
+
+
+def test_remat_plan_without_a_memory_reading_keeps_nothing():
+    """The CPU backend reads no memory: every block is checkpointed
+    whole, and the plan says what that computes twice."""
+    from faabric_tpu.models import transformer
+
+    cfg = ModelConfig(n_layers=8, **PYTHIA)
+    assert transformer._free_bytes(None) is None
+    plan = transformer.remat_plan(cfg, (4, 2048), None, None)
+    assert plan["layers_kept"] == 0 and plan["kept_bytes"] == 0
+    # 402.7 M block parameters at 2 a token, and causal attention
+    assert plan["recomputed_flops_per_token"] == 2 * 402_653_184 \
+        + 8 * 2 * 2048 * 2048
+
+
+@pytest.mark.parametrize("layers,batch,ways,in_use,temp,kept", [
+    # state and the compiled step's temporaries as the chip reported them
+    # (PERF.md section 5: 7.43 + 2.71 GB; section 7: 8.56 + 6.67 GB)
+    (8, 4, None, 7_429_755_904, 2_709_635_072, 8),
+    (24, 8, dict(dp=2, tp=2), 8_560_000_000, 6_670_000_000, 3),
+])
+def test_step_bytes_is_on_the_safe_side_of_what_the_chip_reported(
+        layers, batch, ways, in_use, temp, kept):
+    """The step's own needs, from shapes, are no less than the whole-block
+    step's measured temporaries, so that what the plan adds still fits;
+    and the chip's reading at trace time (its limit less the state) gives
+    the train cell all eight layers and the full four-chip job a few."""
+    from faabric_tpu.models.transformer import remat_plan, step_bytes
+
+    limit = 16_909_336_064
+    cfg = ModelConfig(n_layers=layers, **PYTHIA)
+    mesh = ways and build_mesh(jax.devices()[:4], MeshConfig(**ways))
+    needs = step_bytes(cfg, (batch, 2048), mesh)
+    assert needs >= temp
+    plan = remat_plan(cfg, (batch, 2048), mesh, limit - in_use - needs)
+    assert plan["layers_kept"] == kept
+    assert in_use + temp + plan["kept_bytes"] < limit
+
+
+FLASH_TOY = dict(vocab_size=64, d_model=256, n_layers=2, n_heads=2, d_ff=384,
+                 max_seq=128, compute_dtype=jnp.float32,
+                 attention_impl="flash")
+
+
+def _forward_uses(jaxpr, cfg):
+    """How often each block matrix enters a product that contracts its
+    input side (a forward product: the backward's contract the output
+    side, or make the matrix's own shape), and how many ``flash_fwd``
+    kernels the jaxpr holds."""
+    d, h, e, f = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
+    matrices = {(d, 3, h, e): {0}, (h, e, d): {0, 1}, (d, f): {0},
+                (f, d): {0}}
+    uses = dict.fromkeys(matrices, 0)
+    kernels = 0
+    for eqn, _ in _walk_jaxpr(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            kernels += eqn.params["name"] == "flash_fwd"
+        if eqn.primitive.name != "dot_general":
+            continue
+        contract = eqn.params["dimension_numbers"][0]
+        for operand, dims in zip(eqn.invars, contract):
+            shape = tuple(operand.aval.shape)
+            if matrices.get(shape) == set(dims):
+                uses[shape] += 1
+    return uses, kernels
+
+
+@pytest.mark.parametrize("ways", [None, dict(dp=2, tp=2)],
+                         ids=["one_chip", "dp2tp2"])
+def test_kept_blocks_run_their_products_and_flash_fwd_once(monkeypatch,
+                                                            ways):
+    """The guard that the mechanism engages, on the jaxpr of the loss's
+    gradient at a flash-eligible shape: under the keeping plan every
+    block matrix enters one forward product and the jaxpr holds one
+    ``flash_fwd`` a layer (under a mesh too, where the kernel sits inside
+    a ``shard_map``); checkpointed whole, the QKV, output and up
+    projections and the kernel run twice."""
+    cfg = ModelConfig(**FLASH_TOY)
+    mesh = ways and build_mesh(jax.devices()[:4], MeshConfig(**ways))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((4, 128), jnp.int32)  # no activation shaped as wo
+
+    def grad_jaxpr():
+        return jax.make_jaxpr(jax.grad(
+            lambda p: loss_fn(p, tokens, tokens, cfg, mesh)))(params).jaxpr
+
+    d, h, e, f = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
+    uses, kernels = _forward_uses(grad_jaxpr(), cfg)
+    assert kernels == 2 * cfg.n_layers
+    assert uses == {(d, 3, h, e): 4, (h, e, d): 4, (d, f): 4, (f, d): 2}
+
+    _keep_all(monkeypatch)
+    uses, kernels = _forward_uses(grad_jaxpr(), cfg)
+    assert kernels == cfg.n_layers
+    assert set(uses.values()) == {cfg.n_layers}
+
+    # one layer's worth of room: the first block keeps, the second does not
+    from faabric_tpu.models import transformer
+
+    one = transformer.remat_plan(cfg, tokens.shape, mesh, 1 << 50)
+    _keep_all(monkeypatch, transformer.step_bytes(cfg, tokens.shape, mesh)
+              + one["layer_bytes"])
+    uses, kernels = _forward_uses(grad_jaxpr(), cfg)
+    assert kernels == cfg.n_layers + 1
+    assert uses[(d, f)] == cfg.n_layers + 1
+
+
+@pytest.mark.parametrize("kinds", [
+    dict(ffn="gelu", norm_placement="pre"),
+    dict(ffn="swiglu", norm_placement="sandwich"),
+    dict(ffn="gelu", norm_placement="pre", attention_impl="flash",
+         d_model=256, n_heads=2),
+], ids=["gelu_pre", "swiglu_sandwich", "gelu_pre_flash"])
+def test_keeping_plan_changes_no_gradient(monkeypatch, kinds):
+    """The same products in the same precisions, kept instead of made
+    twice: loss and gradients under the keeping plan are those of
+    ``remat=False`` and of whole-block recomputation, to float32
+    round-off."""
+    import dataclasses
+
+    cfg = ModelConfig(**{**dict(vocab_size=96, d_model=64, n_layers=2,
+                                n_heads=4, d_ff=160, max_seq=128,
+                                compute_dtype=jnp.float32), **kinds})
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    rng = np.random.RandomState(3)
+    tokens, targets = (jnp.asarray(rng.randint(0, 96, (2, 128)), jnp.int32)
+                       for _ in range(2))
+
+    def value_and_grads(c):
+        return jax.jit(jax.value_and_grad(
+            lambda p: loss_fn(p, tokens, targets, c)))(params)
+
+    plain = value_and_grads(dataclasses.replace(cfg, remat=False))
+    whole = value_and_grads(cfg)
+    _keep_all(monkeypatch)  # each call above traces anew, plan and all
+    kept = value_and_grads(cfg)
+    for other in (plain, whole):
+        np.testing.assert_allclose(kept[0], other[0], rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(kept[1]), jax.tree.leaves(other[1])):
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-6)
+
+
+def test_kept_names_leave_generate_as_it_was(monkeypatch):
+    """The serve cells run ``_block`` outside any checkpoint: the names
+    are in ``generate()``'s jaxpr and lower to nothing, so the program is
+    the one that a block without names gives."""
+    from faabric_tpu.models import transformer
+    from faabric_tpu.models.generate import generate
+
+    cfg = ModelConfig(vocab_size=320, d_model=64, n_layers=2, n_heads=4,
+                      d_ff=96, max_seq=256, compute_dtype=jnp.float32)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    prompt = jnp.zeros((1, 32), jnp.int32)
+
+    def lowered():
+        jax.clear_caches()
+        fn = jax.jit(lambda p, t: generate(p, t, cfg, 8))
+        names = [e for e, _ in _walk_jaxpr(
+            jax.make_jaxpr(fn)(params, prompt).jaxpr)
+            if e.primitive.name == "name"]
+        return names, fn.lower(params, prompt).as_text()
+
+    names, with_names = lowered()
+    assert {e.params["name"] for e in names} >= {"q_rope", "attn_proj",
+                                                 "ffn_act"}
+    monkeypatch.setattr(transformer, "checkpoint_name", lambda x, name: x)
+    names, without = lowered()
+    assert not names
+    # but for the counter in the names of jnp's private functions
+    numbered = re.compile(r"(@_?[a-z_]+?)_\d+\b")
+    assert numbered.sub(r"\1", with_names) == numbered.sub(r"\1", without)
