@@ -26,6 +26,12 @@ Guests (user ``smoke``), all on the chips the planner pinned:
   steps of ``make_train_step`` on a fixed batch.
 - ``decode``  — ``generate()`` answering three requests (greedy, the same
   greedy again, sampled): the only path on which the fused norm runs.
+- ``latent_experts`` — the other kinds of block (latent attention in a
+  shortcut-connected double layer with a held share of the experts) at
+  the widths of ``benchmarks/configs/longcat-flash-omni.json``, one layer:
+  prefill of a few rows and two cached steps over the latent caches,
+  logits against ``benchmarks/reference/longcat.py``, so that a broken
+  lowering shows before a 40 s window of the benchmark does.
 - ``gang``    — with ≥ 2 chips: an MPI world through ``ctx.mpi_world()``,
   one rank per chip, collectives on device-resident arrays through the
   activated device plane, and the Pallas ring-permute kernel.
@@ -81,6 +87,13 @@ TOL_FLASH_FWD = 8e-3
 TOL_FLASH_BWD = 1.2e-2
 TOL_RMS_NORM = 8e-3
 TOL_MODEL_LOGITS = 4e-2
+# One double layer in bfloat16 against the float32 reference: its first run
+# on the v5e measured 0.012 (my chip run, PR 31); four layers read 0.02.
+TOL_LATENT_LOGITS = 4e-2
+LATENT_CONFIG = os.path.join(REPO, "benchmarks", "configs",
+                             "longcat-flash-omni.json")
+LATENT_CONFIG_TINY = os.path.join(REPO, "tests", "bench", "data", "configs",
+                                  "toy_longcat.json")
 
 PLANNER_HOST = "smoke-planner"
 WORKER_HOST = "smoke-worker"
@@ -443,6 +456,57 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                      f"decode program holds kernel calls {calls}")
         return reply(**out)
 
+    # ---- latent attention, shortcut layers, a held share of experts ---
+    @register_function("smoke", "latent_experts")
+    def latent_experts(ctx):
+        from benchmarks import program_longcat, weights_longcat
+        from benchmarks.reference import longcat as reference
+        from faabric_tpu.models.generate import (
+            forward_with_cache,
+            init_kv_cache,
+        )
+
+        dev = ctx.device
+        with open(LATENT_CONFIG if on_chip else LATENT_CONFIG_TINY) as f:
+            config = dict(json.load(f), num_layers=1)
+        sizes = weights_longcat.sizes_of(config)
+        kinds = program_longcat.model_config(config)
+        rows, s_p, steps = 4, run["prompt"], 2
+        ids = weights_longcat.token_rows(7, 1, 0, rows, s_p + steps,
+                                         sizes["vocab"])
+        with jax.default_device(dev):
+            params = weights_longcat.make_weights(7, sizes,
+                                                  kinds.param_dtype, dev)
+            cache = init_kv_cache(kinds, rows, 128 * -(-(s_p + steps) // 128))
+            prefill = jax.jit(lambda p, t, c: forward_with_cache(
+                p, t, c, 0, kinds))
+            step = jax.jit(lambda p, t, c, pos: forward_with_cache(
+                p, t, c, pos, kinds))
+            logits, cache = prefill(params, jnp.asarray(ids[:, :s_p]), cache)
+            got = [np.asarray(logits, np.float32)]
+            for pos in range(s_p, s_p + steps):
+                logits, cache = step(params, jnp.asarray(ids[:, pos:pos + 1]),
+                                     cache, jnp.int32(pos))
+                got.append(np.asarray(logits, np.float32))
+            got = np.concatenate(got, axis=1)
+            want = np.stack([np.asarray(reference.logits_of(
+                params, jnp.asarray(row), sizes)) for row in ids])
+            counted = np.asarray(cache[0]["counters"]).tolist()
+        out = dict(
+            device=_device_report(dev), n_params=sum(
+                int(x.size) for x in jax.tree.leaves(params)),
+            prefill_rel_err=_rel_err(got[:, :s_p], want[:, :s_p]),
+            cached_steps_rel_err=_rel_err(got[:, s_p:], want[:, s_p:]),
+            picks_held_zero_absent_experts_hit=counted)
+        _require(np.isfinite(got).all(), "a logit is not finite")
+        _require(sum(counted[:3]) == rows * (s_p + steps) * sizes["top_k"],
+                 f"the picks do not add up: {counted}")
+        for name in ("prefill_rel_err", "cached_steps_rel_err"):
+            _require(out[name] < TOL_LATENT_LOGITS, f"{name} {out[name]}")
+        if on_chip:
+            _require(dev.platform == "tpu", dev.platform)
+        return reply(**out)
+
     # ---- gang --------------------------------------------------------
     @register_function("smoke", "gang")
     def gang(ctx):
@@ -751,6 +815,8 @@ def _run_phases(cluster: Cluster, summary: dict, deadline: float) -> None:
     if len(pinned) != n - 1:
         raise SmokeFailed(f"train gang pinned chips {sorted(pinned)}")
     phases["decode"] = cluster.invoke("decode", 1, deadline)[0]
+    phases["latent_experts"] = cluster.invoke("latent_experts", 1,
+                                              deadline)[0]
     if n >= 2:
         phases["gang"] = sorted(
             cluster.invoke("gang", 1, deadline, mpi_world_size=n),

@@ -12,15 +12,25 @@ and every decode step compile once each. The whole greedy loop is one
 ``lax.scan`` under jit — no host round-trips between tokens, which is
 what keeps a TPU busy at small batch; a looped stack's passes are a
 rolled loop inside it, over the same weights.
+
+Caches go by the attention's kind. Latent attention keeps one latent and
+its turned rotary lanes a position, ``(passes, batch, slots, kv_lora_rank
++ qk_rope_dim)``, row-major and once an attention, whatever the number of
+heads; prefill expands keys and values from it, a cached step attends
+over it as it lies (``transformer._latent_attention``). A "shortcut"
+layer's state is its two attentions' caches and its expert layer's
+counters, summed on the device over the call.
 """
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
+from faabric_tpu.models.moe import COUNTERS
 from faabric_tpu.models.transformer import (
     ModelConfig,
     _block,
@@ -40,31 +50,55 @@ def _cache_slots(cfg: ModelConfig, reach: int) -> int:
     return min(rounded, cfg.max_seq)
 
 
+def _attention_cache_shapes(cfg: ModelConfig, batch: int, slots: int) -> dict:
+    """The arrays one attention's cache is made of, by its kind."""
+    if cfg.attention == "latent":
+        return {"latent": (cfg.n_passes, batch, slots,
+                           cfg.kv_lora_rank + cfg.qk_rope_dim)}
+    shape = (cfg.n_passes, batch, cfg.n_heads, slots, cfg.head_dim)
+    return {"k": shape, "v": shape}
+
+
 def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
                n_tokens: int) -> dict:
     """What one ``generate`` call of these static shapes allocates and
-    runs: ``cache_slots`` a cache, ``cache_bytes`` of all the caches (keys
-    and values, every layer, every pass), ``ut_passes`` of the stack
-    (prefill and each decode step pass it ``cfg.n_passes`` times).
-    ``generate`` sizes its cache from this; a server reports it beside
-    its answers."""
+    runs: ``cache_slots`` a cache, ``cache_bytes`` of all the caches (what
+    the attention's kind keeps a position, every attention of every layer,
+    every pass), ``ut_passes`` of the stack (prefill and each decode step
+    pass it ``cfg.n_passes`` times); where the layers have an expert layer
+    also ``experts_held`` here and the ``router_width``. ``generate``
+    sizes its cache from this; a server reports it beside its answers."""
     slots = _cache_slots(cfg, prompt_len + n_tokens)
     itemsize = jnp.dtype(cfg.compute_dtype).itemsize
-    return {
+    shortcut = cfg.layer == "shortcut"
+    values = sum(math.prod(shape) for shape in
+                 _attention_cache_shapes(cfg, batch, slots).values())
+    sizes = {
         "cache_slots": slots,
-        "cache_bytes": (2 * cfg.n_passes * cfg.n_layers * batch
-                        * cfg.n_heads * slots * cfg.head_dim * itemsize),
+        "cache_bytes": (1 + shortcut) * cfg.n_layers * values * itemsize,
         "ut_passes": cfg.n_passes * (1 + n_tokens),
     }
+    if shortcut:
+        sizes.update(experts_held=cfg.experts_held[1],
+                     router_width=cfg.routed_experts + cfg.zero_experts)
+    return sizes
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, slots: int) -> list[dict]:
-    """Zeroed per-layer caches, one a pass, head-major: (passes, batch,
-    heads, slots, head_dim)."""
-    shape = (cfg.n_passes, batch, cfg.n_heads, slots, cfg.head_dim)
-    return [{"k": jnp.zeros(shape, cfg.compute_dtype),
-             "v": jnp.zeros(shape, cfg.compute_dtype)}
-            for _ in range(cfg.n_layers)]
+    """Zeroed per-layer state of a call. Per-head attention: keys and
+    values, one cache a pass, head-major: (passes, batch, heads, slots,
+    head_dim). Latent attention: (passes, batch, slots, kv_lora_rank +
+    qk_rope_dim), once. A "shortcut" layer: its two attentions' caches
+    and its expert layer's counters (``transformer._block``)."""
+    def attention():
+        return {name: jnp.zeros(shape, cfg.compute_dtype) for name, shape
+                in _attention_cache_shapes(cfg, batch, slots).items()}
+
+    if cfg.layer == "shortcut":
+        return [{"attn": [attention(), attention()],
+                 "counters": jnp.zeros((len(COUNTERS),), jnp.int32)}
+                for _ in range(cfg.n_layers)]
+    return [attention() for _ in range(cfg.n_layers)]
 
 
 def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig):
@@ -144,6 +178,7 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
     key, sub = jax.random.split(key)
     next_tok = _pick_token(logits[:, -1], sub, greedy, temperature,
                            top_k, use_top_p, top_p)
+    counted_in_prefill = _counted(cfg, cache)
 
     def step(carry, _):
         tok, pos, cache, key = carry
@@ -155,9 +190,25 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
                           top_k, use_top_p, top_p)
         return (nxt, pos + 1, cache, key), tok
 
-    (_, _, _, _), toks = jax.lax.scan(step, (next_tok, s_p, cache, key),
-                                      None, length=n_tokens)
-    return toks.T  # (B, n_tokens)
+    (_, _, cache, _), toks = jax.lax.scan(step, (next_tok, s_p, cache, key),
+                                          None, length=n_tokens)
+    counters = {}
+    if counted_in_prefill is not None:
+        counted = _counted(cfg, cache)
+        counters = dict(zip(COUNTERS, counted))
+        # over the decode steps and layers, the held experts that got a
+        # token: what a step has to read of the experts' weights
+        counters["experts_hit_decode"] = (counters.pop("experts_hit")
+                                          - counted_in_prefill[-1])
+    return toks.T, counters  # (B, n_tokens)
+
+
+def _counted(cfg: ModelConfig, cache: list):
+    """What the expert layers have counted in this call so far, summed
+    over the layers (``moe.COUNTERS``); None where no layer counts."""
+    if cfg.layer != "shortcut":
+        return None
+    return sum(layer["counters"] for layer in cache)
 
 
 def generate(params, prompt, cfg: ModelConfig, n_tokens: int,
@@ -179,7 +230,30 @@ def generate(params, prompt, cfg: ModelConfig, n_tokens: int,
     processes long prompts in fixed-size chunks, bounding prefill
     attention memory. A looped stack (``cfg.n_passes`` above 1) keeps
     one cache a pass a layer; :func:`call_sizes` says what a call of
-    these shapes allocates."""
+    these shapes allocates. The other kinds of attention and layer are
+    single-chip so far: with ``mesh`` they raise ``ValueError``."""
+    return generate_with_counters(params, prompt, cfg, n_tokens, key,
+                                  temperature, top_k, top_p, mesh,
+                                  prefill_chunk)[0]
+
+
+def generate_with_counters(params, prompt, cfg: ModelConfig, n_tokens: int,
+                           key: jax.Array | None = None,
+                           temperature: float = 0.0, top_k: int = 0,
+                           top_p: float = 1.0, mesh=None,
+                           prefill_chunk: int = 0):
+    """:func:`generate`, with what the call counted on the device, from
+    the same program: ((B, n_tokens) int32, counters). ``counters`` is
+    empty but where the layers have an expert layer; there the picks of
+    the whole call (prefill and every step, all layers) are
+    ``picks_held`` (they fell on experts held here), ``picks_zero``
+    (zero-compute experts) and ``picks_absent`` (experts held elsewhere),
+    and ``experts_hit_decode`` is the held experts that got at least one
+    token, summed over the decode steps and layers."""
+    if mesh is not None and (cfg.attention, cfg.layer) != ("heads", "single"):
+        raise ValueError(
+            f"generate under a mesh implements attention='heads' and "
+            f"layer='single' only, not {cfg.attention!r} and {cfg.layer!r}")
     reach = prompt.shape[1] + n_tokens
     if reach > cfg.max_seq:
         raise ValueError(

@@ -15,6 +15,17 @@ through shardings.
 
 Static shapes throughout: capacity is fixed, overflow tokens drop (their
 residual passes through), standard for TPU switch routing.
+
+Beside that family, and for ``models/transformer.py``'s one block where a
+configuration names ``layer="shortcut"``: :func:`expert_layer`, the expert
+layer as one chip of an expert-parallel deployment holds it. The router
+has its published width; the chip is told which routed experts it holds,
+sorts the picks that fall on them by expert and runs one grouped product
+over them (a loop over the row tiles that hold a pick, each through its
+expert's matrices), so no token is dropped whatever the routing; gated
+(SwiGLU) experts, a stored bias that corrects the selection, zero-compute
+experts that return their input, and the picks of experts held elsewhere
+add nothing here.
 """
 
 from __future__ import annotations
@@ -46,11 +57,13 @@ class MoEConfig(ModelConfig):
 
 
 def _refuse_other_kinds(cfg: MoEConfig) -> None:
-    """The expert layer is a two-matrix GELU behind one pre-norm, passed
-    once: a configuration that names another kind would be computed as
-    another network (the attention half honours ``rope_pairing``)."""
+    """The expert layer is a two-matrix GELU behind one pre-norm and
+    per-head attention, one to a layer, passed once: a configuration that
+    names another kind would be computed as another network (the
+    attention half honours ``rope_pairing``)."""
     for field, kind in (("ffn", "gelu"), ("norm_placement", "pre"),
-                        ("n_passes", 1)):
+                        ("n_passes", 1), ("attention", "heads"),
+                        ("layer", "single")):
         if getattr(cfg, field) != kind:
             raise ValueError(
                 f"the MoE family implements {field}={kind!r} only, "
@@ -185,6 +198,140 @@ def _moe_layer(x: jax.Array, blk: dict, cfg: MoEConfig,
 
     out = jnp.einsum("bsec,ebcd->bsd", combine_w, out_e)
     return out.astype(x.dtype), aux.astype(jnp.float32)
+
+
+# A grouped product takes at most this many rows (picks) at once: a longer
+# call goes through the experts in token chunks, so that the products' rows
+# (room for experts_per_token times the tokens, at d_model lanes) stay a
+# few hundred MB at prefill.
+_MAX_ROWS = 24576
+
+# What expert_layer counts, in order: picks that fell on the experts held
+# here, on zero-compute experts, on experts held elsewhere, and the held
+# experts that got at least one token.
+COUNTERS = ("picks_held", "picks_zero", "picks_absent", "experts_hit")
+
+
+def route(u: jax.Array, router: dict, cfg: ModelConfig) -> tuple:
+    """u (T, D) → (picks (T, K) int32 over the router's whole width,
+    weights (T, K) float32). The product, the softmax and the selection
+    are float32 whatever the compute type: scores = softmax(u·w); the K
+    largest of scores + bias are picked; a pick weighs routed_scaling
+    times its score, not renormalised."""
+    logits = jnp.dot(u.astype(jnp.float32), router["w"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1)
+    _, picks = jax.lax.top_k(scores + router["bias"].astype(jnp.float32),
+                             cfg.experts_per_token)
+    weights = jnp.take_along_axis(scores, picks, axis=-1)
+    return picks, weights * cfg.routed_scaling
+
+
+def _row_tile(tokens: int) -> int:
+    """Rows of a tile of the grouped product: a multiple of 8 between 16
+    and 128, about a sixteenth of the tokens. A cached step of 64 tokens
+    gives an expert a row or two, and a tile is then mostly padding that
+    the MXU multiplies all the same; a prefill chunk gives it dozens."""
+    return min(128, max(16, tokens // 16 // 8 * 8))
+
+
+def _held_experts(u: jax.Array, local: jax.Array, weights: jax.Array,
+                  experts: dict, dtype) -> tuple:
+    """The held experts' part for u (T, D): ``local`` (T, K) is a pick's
+    index among the experts held, or their count where it fell elsewhere.
+    A grouped product: the picks sorted by expert, every expert's rows
+    padded to whole tiles (at least one), and a loop over those tiles,
+    each through its expert's three matrices (sliced in place, streamed
+    once a tile): time follows the picks that fell here, not the static
+    bound, and no token is dropped. Returns (the part
+    (T, D), tokens an expert (held,))."""
+    t, k = local.shape
+    held, d, _ = experts["w1"].shape
+    tile = _row_tile(t)
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)  # the held picks first
+    sizes = jnp.sum(flat[:, None] == jnp.arange(held)[None], axis=0,
+                    dtype=jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes                   # in the sorted order
+    # at least one tile an expert, picked or not: a step then reads every
+    # expert held once whatever the routing, and its time does not move
+    # with the share of the picks that the weights' draw gives this chip
+    # (PERF.md section 6, PR 31: what skipping the others is worth)
+    tiles = jnp.maximum(1, -(-sizes // tile))
+    tile_ends = jnp.cumsum(tiles)
+    tile_starts = tile_ends - tiles
+    # a tile reads ``tile`` entries from its first row on: room past the end
+    room = jnp.zeros((tile,), order.dtype)
+    sorted_picks = jnp.concatenate([order, room])
+    sorted_weights = jnp.concatenate(
+        [weights.reshape(-1)[order], room.astype(weights.dtype)])
+
+    def one_tile(i, out):
+        e = jnp.sum(i >= tile_ends, dtype=jnp.int32)  # the tile's expert
+        row = starts[e] + (i - tile_starts[e]) * tile
+        picks = jax.lax.dynamic_slice(sorted_picks, (row,), (tile,))
+        rows = u[picks // k]
+        wg, w1, w2 = (jax.lax.dynamic_index_in_dim(
+            experts[name], e, keepdims=False).astype(dtype)
+            for name in ("wg", "w1", "w2"))
+        got = (jax.nn.silu(rows @ wg) * (rows @ w1)) @ w2
+        weight = jax.lax.dynamic_slice(sorted_weights, (row,), (tile,))
+        # the rows past the expert's last are padding
+        mine = (row + jnp.arange(tile) < ends[e])[:, None]
+        got = jnp.where(mine, got * weight.astype(dtype)[:, None], 0)
+        return jax.lax.dynamic_update_slice(out, got, (i * tile, 0))
+
+    # static room for every pick and every expert's last, partial tile
+    out = jnp.zeros(((held + t * k // tile) * tile, d), dtype)
+    out = jax.lax.fori_loop(0, tile_ends[-1], one_tile, out)
+    # back to the picks' order: where each held pick's row lies in ``out``
+    here = flat < held
+    e = jnp.minimum(flat, held - 1)
+    at = tile_starts[e] * tile + jnp.argsort(order) - starts[e]
+    part = jnp.where(here[:, None], out[jnp.where(here, at, 0)], 0)
+    part = part.reshape(t, k, d).sum(axis=1, dtype=jnp.float32)
+    return part.astype(dtype), sizes
+
+
+def expert_layer(u: jax.Array, router: dict, experts: dict,
+                 cfg: ModelConfig) -> tuple:
+    """One chip's share of the expert layer: u (B, S, D), a normed state →
+    (m (B, S, D), counters int32 (4,) as :data:`COUNTERS`).
+
+        m = Σ_{picked e held here} w_e · Expert_e(u)
+          + Σ_{picked e zero-compute} w_e · u
+
+    with ``Expert_e(h) = (silu(h·wg_e) ⊙ h·w1_e)·w2_e``. Router outputs
+    below ``cfg.routed_experts`` are routed experts, of which this chip
+    holds ``cfg.experts_held = (first, count)`` (``experts`` has their
+    weights, ``count`` on the leading axis); the rest are zero-compute.
+    Static shapes, and no token dropped whatever the routing."""
+    b, s, d = u.shape
+    flat = u.reshape(b * s, d)
+    first, count = cfg.experts_held
+    k = cfg.experts_per_token
+    with jax.named_scope("moe_route"):
+        picks, weights = route(flat, router, cfg)
+        is_zero = picks >= cfg.routed_experts
+        is_held = (picks >= first) & (picks < first + count)
+        local = jnp.where(is_held, picks - first, count)
+        zero_weight = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1)
+    with jax.named_scope("moe_experts"):
+        tokens = b * s
+        chunks = -(-tokens * k // _MAX_ROWS)
+        bounds = [tokens * i // chunks for i in range(chunks + 1)]
+        parts, sizes = zip(*(
+            _held_experts(flat[lo:hi], local[lo:hi], weights[lo:hi],
+                          experts, u.dtype)
+            for lo, hi in zip(bounds, bounds[1:])))
+        m = jnp.concatenate(parts) \
+            + zero_weight.astype(u.dtype)[:, None] * flat
+    n_held = jnp.sum(is_held, dtype=jnp.int32)
+    n_zero = jnp.sum(is_zero, dtype=jnp.int32)
+    counters = jnp.stack([n_held, n_zero, tokens * k - n_held - n_zero,
+                          jnp.sum(sum(sizes) > 0, dtype=jnp.int32)])
+    return m.reshape(b, s, d), counters
 
 
 def moe_forward(params: dict, tokens: jax.Array, cfg: MoEConfig,

@@ -52,6 +52,14 @@ def _build_step(cfg: ModelConfig, mesh: Optional[Mesh],
     """The un-jitted step body shared by :func:`make_train_step` (one
     dispatch per step) and :func:`make_multi_step` (n steps per
     dispatch)."""
+    for field, kind in (("attention", "heads"), ("layer", "single")):
+        if getattr(cfg, field) != kind:
+            # served so far, never trained: the remat plan and the
+            # shardings of a step know per-head attention in single
+            # layers, and an expert layer has no balance loss here
+            raise ValueError(
+                f"the train step implements {field}={kind!r} only, "
+                f"not {field}={getattr(cfg, field)!r}")
 
     def grads_of(params, tokens, targets):
         return jax.value_and_grad(loss_fn)(params, tokens, targets,

@@ -79,16 +79,64 @@ class ModelConfig:
     # the last pass, for every token)
     n_passes: int = 1
     exit_threshold: float = 1.0
+    # "heads": a key and a value a head a position, one wqkv | "latent":
+    # low-rank attention: queries through a q_lora_rank bottleneck, keys
+    # and values of all heads from one kv_lora_rank latent a position and
+    # qk_rope_dim rotary lanes shared by the heads (each bottleneck normed
+    # and scaled by sqrt(d_model / rank)); a head's query and key have
+    # qk_nope_dim + qk_rope_dim lanes, its value v_head_dim; the cache
+    # holds the latent and the turned rotary lanes, not keys and values
+    attention: str = "heads"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # "single": attention, then the feed-forward | "shortcut": two of
+    # those in one layer, and an expert layer (models/moe.py:expert_layer)
+    # that reads the first feed-forward's normed input and joins the
+    # residual after the second. Its router has routed_experts +
+    # zero_experts outputs (the latter return their input and hold no
+    # weights) and picks experts_per_token of them; this chip holds the
+    # routed experts experts_held = (first, count), of width expert_d_ff,
+    # and computes their part and the zero-compute part only
+    layer: str = "single"
+    routed_experts: int = 0
+    zero_experts: int = 0
+    experts_held: tuple = (0, 0)
+    experts_per_token: int = 0
+    routed_scaling: float = 1.0
+    expert_d_ff: int = 0
 
     def __post_init__(self):
         for field, kinds in (("ffn", ("gelu", "swiglu")),
                              ("norm_placement", ("pre", "sandwich")),
-                             ("rope_pairing", ("neighbours", "halves"))):
+                             ("rope_pairing", ("neighbours", "halves")),
+                             ("attention", ("heads", "latent")),
+                             ("layer", ("single", "shortcut"))):
             if getattr(self, field) not in kinds:
                 raise ValueError(f"{field} {getattr(self, field)!r} is not "
                                  f"one of {kinds}")
         if self.n_passes < 1:
             raise ValueError(f"n_passes {self.n_passes} is below 1")
+        if self.attention == "latent" and not (
+                self.q_lora_rank > 0 and self.kv_lora_rank > 0
+                and self.qk_nope_dim > 0 and self.v_head_dim > 0
+                and self.qk_rope_dim > 0 and self.qk_rope_dim % 2 == 0):
+            raise ValueError(
+                "attention 'latent' needs q_lora_rank, kv_lora_rank, "
+                "qk_nope_dim, v_head_dim and an even qk_rope_dim")
+        if self.layer == "shortcut":
+            first, count = self.experts_held
+            if not (0 <= first and count > 0
+                    and first + count <= self.routed_experts
+                    and 0 < self.experts_per_token
+                    <= self.routed_experts + self.zero_experts
+                    and self.expert_d_ff > 0):
+                raise ValueError(
+                    "layer 'shortcut' needs routed_experts, expert_d_ff, "
+                    "experts_per_token within the router's width, and "
+                    f"experts_held within the routed ones; got {self}")
 
     @property
     def head_dim(self) -> int:
@@ -106,31 +154,69 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
         return (jax.random.normal(k, shape, cfg.param_dtype)
                 / np.sqrt(fan_in))
 
-    def ones():
-        return jnp.ones((cfg.d_model,), cfg.param_dtype)
+    def ones(width=cfg.d_model):
+        return jnp.ones((width,), cfg.param_dtype)
 
-    blocks = []
-    for i in range(cfg.n_layers):
-        bk = jax.random.split(keys[i], 4)
+    def latent_attention(k):
+        k = jax.random.split(k, 5)
+        d, h = cfg.d_model, cfg.n_heads
+        rq, rkv, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_dim
+        return {
+            "wqa": dense(k[0], (d, rq), d), "q_norm": ones(rq),
+            # what reads a bottleneck scaled up to a hidden state's size
+            # is drawn as if it read a hidden state
+            "wqb": dense(k[1], (rq, h, cfg.qk_nope_dim + rope), d),
+            "wkva": dense(k[2], (d, rkv + rope), d), "kv_norm": ones(rkv),
+            "wkvb": dense(k[3], (rkv, h, cfg.qk_nope_dim + cfg.v_head_dim),
+                          d),
+            "wo": dense(k[4], (h, cfg.v_head_dim, d), h * cfg.v_head_dim),
+        }
+
+    def half(key):
+        """Attention and its feed-forward: a whole "single" layer."""
+        bk = jax.random.split(key, 4)
         blk = {
             "ln1": ones(),
-            "wqkv": dense(bk[0], (cfg.d_model, 3, cfg.n_heads, cfg.head_dim),
-                          cfg.d_model),
-            "wo": dense(bk[1], (cfg.n_heads, cfg.head_dim, cfg.d_model),
-                        cfg.d_model),
             "ln2": ones(),
             "w1": dense(bk[2], (cfg.d_model, cfg.d_ff), cfg.d_model),
             "w2": dense(bk[3], (cfg.d_ff, cfg.d_model), cfg.d_ff),
         }
+        if cfg.attention == "latent":
+            blk.update(latent_attention(bk[0]))
+        else:
+            blk["wqkv"] = dense(
+                bk[0], (cfg.d_model, 3, cfg.n_heads, cfg.head_dim),
+                cfg.d_model)
+            blk["wo"] = dense(bk[1], (cfg.n_heads, cfg.head_dim, cfg.d_model),
+                              cfg.d_model)
         if cfg.ffn == "swiglu":
-            blk["wg"] = dense(jax.random.fold_in(keys[i], 4),
+            blk["wg"] = dense(jax.random.fold_in(key, 4),
                               (cfg.d_model, cfg.d_ff), cfg.d_model)
         if cfg.norm_placement == "sandwich":
             blk["ln1_post"], blk["ln2_post"] = ones(), ones()
-        blocks.append(blk)
+        return blk
+
+    def shortcut(key):
+        k = jax.random.split(key, 7)
+        d, f, held = cfg.d_model, cfg.expert_d_ff, cfg.experts_held[1]
+        width = cfg.routed_experts + cfg.zero_experts
+        return {
+            "halves": [half(k[0]), half(k[1])],
+            # the selection bias is a stored correction, a fraction of a
+            # mean score: non-zero here, so that a selection that leaves
+            # it out shows
+            "router": {"w": dense(k[2], (d, width), d),
+                       "bias": jax.random.normal(
+                           k[3], (width,), cfg.param_dtype) / (4 * width)},
+            "experts": {"wg": dense(k[4], (held, d, f), d),
+                        "w1": dense(k[5], (held, d, f), d),
+                        "w2": dense(k[6], (held, f, d), f)},
+        }
+
+    layer = shortcut if cfg.layer == "shortcut" else half
     params = {
         "embed": dense(keys[-2], (cfg.vocab_size, cfg.d_model), cfg.d_model),
-        "blocks": blocks,
+        "blocks": [layer(keys[i]) for i in range(cfg.n_layers)],
         "ln_f": ones(),
         "lm_head": dense(keys[-1], (cfg.d_model, cfg.vocab_size), cfg.d_model),
     }
@@ -147,21 +233,34 @@ def param_shardings(mesh: Mesh, cfg: ModelConfig) -> dict:
     def ns(*spec):
         return NamedSharding(mesh, P(*spec))
 
-    block = {
+    half = {
         "ln1": ns(),
-        "wqkv": ns(None, None, "tp", None),
         "wo": ns("tp", None, None),
         "ln2": ns(),
         "w1": ns(None, "tp"),
         "w2": ns("tp", None),
     }
+    if cfg.attention == "latent":
+        # the bottlenecks whole on every chip, what fans out of them by head
+        half.update(wqa=ns(), q_norm=ns(), wqb=ns(None, "tp", None),
+                    wkva=ns(), kv_norm=ns(), wkvb=ns(None, "tp", None))
+    else:
+        half["wqkv"] = ns(None, None, "tp", None)
     if cfg.ffn == "swiglu":
-        block["wg"] = ns(None, "tp")
+        half["wg"] = ns(None, "tp")
     if cfg.norm_placement == "sandwich":
-        block["ln1_post"], block["ln2_post"] = ns(), ns()
+        half["ln1_post"], half["ln2_post"] = ns(), ns()
+    block = half
+    if cfg.layer == "shortcut":
+        block = {"halves": [dict(half), dict(half)],
+                 "router": {"w": ns(), "bias": ns()},
+                 "experts": {"wg": ns(None, None, "tp"),
+                             "w1": ns(None, None, "tp"),
+                             "w2": ns(None, "tp", None)}}
     shardings = {
         "embed": ns("tp", None),
-        "blocks": [dict(block) for _ in range(cfg.n_layers)],
+        "blocks": [jax.tree.map(lambda x: x, block)
+                   for _ in range(cfg.n_layers)],
         "ln_f": ns(),
         "lm_head": ns(None, "tp"),
     }
@@ -214,12 +313,17 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _row_major(cache: jax.Array) -> jax.Array:
+    """Pin a cache row-major in memory, its last axis contiguous."""
+    return with_layout_constraint(cache, Layout(tuple(range(cache.ndim))))
+
+
 def _head_major(cache: jax.Array) -> jax.Array:
     """Pin a (passes, batch, heads, slots, head_dim) cache row-major in
     memory. Left to itself XLA:TPU lays the decode loop's carried cache
     heads-minor whatever the logical order, the heads padded to a tile's
     128 lanes: with 16 heads, eight times the bytes at every step."""
-    return with_layout_constraint(cache, Layout(tuple(range(cache.ndim))))
+    return _row_major(cache)
 
 
 def _cached_attention(q, cache_k, cache_v, length):
@@ -260,6 +364,100 @@ def _attend_through_cache(q, k, v, cache: dict, slot: tuple):
                                           keepdims=False)
              for name in ("k", "v")), start + q.shape[1])
     return attn, updated
+
+
+def _causal_softmax(logits: jax.Array, reach: Any, dtype) -> jax.Array:
+    """Softmax over keys of float32 logits (B, H, S_q, K), the queries the
+    last S_q of the first ``reach`` positions, the keys positions 0..K-1."""
+    s_q, keys = logits.shape[-2:]
+    q_pos = (reach - s_q) + jnp.arange(s_q)
+    mask = q_pos[:, None] >= jnp.arange(keys)[None, :]
+    logits = jnp.where(mask[None, None], logits, -1e30)
+    return jax.nn.softmax(logits, axis=-1).astype(dtype)
+
+
+def _latent_expanded(q_nope, q_rope, latent, wkvb, cfg: ModelConfig):
+    """Latent attention with every head's keys and values made from the
+    latents: q_nope (B, S_q, H, nope) and q_rope (B, S_q, H, rope), the
+    last S_q of the K positions whose ``latent`` (B, K, rank + rope) is
+    given. Prefill's path: K is the static reach. → (B, S_q, H, v)."""
+    rank, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
+    scale = 1.0 / float(np.sqrt(nope + cfg.qk_rope_dim))
+    kv = jnp.einsum("bkc,che->bkhe", latent[..., :rank], wkvb)
+    logits = (jnp.einsum("bqhe,bkhe->bhqk", q_nope, kv[..., :nope],
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqhe,bke->bhqk", q_rope, latent[..., rank:],
+                           preferred_element_type=jnp.float32))
+    probs = _causal_softmax(logits * scale, latent.shape[1], q_nope.dtype)
+    return jnp.einsum("bhqk,bkhe->bqhe", probs, kv[..., nope:])
+
+
+def _latent_absorbed(q_nope, q_rope, cache, length, wkvb, cfg: ModelConfig):
+    """The same attention over the first ``length`` slots of a latent
+    cache (B, slots, rank + rope) without expanding it: the keys' half of
+    ``wkvb`` goes into the query (nope → rank lanes a head), the values'
+    half onto the weighted sum of latents. A cached step's path: it reads
+    rank + rope values a position, whatever the number of heads."""
+    rank, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
+    scale = 1.0 / float(np.sqrt(nope + cfg.qk_rope_dim))
+    q = jnp.concatenate(
+        [jnp.einsum("bqhe,che->bqhc", q_nope, wkvb[..., :nope]), q_rope],
+        axis=-1)
+    logits = jnp.einsum("bqhc,bkc->bhqk", q, cache,
+                        preferred_element_type=jnp.float32)
+    probs = _causal_softmax(logits * scale, length, q.dtype)
+    # as in _cached_attention: a slot not written yet may hold anything,
+    # and 0 × NaN is NaN
+    written = (jnp.arange(cache.shape[1]) < length)[None, :, None]
+    mixed = jnp.einsum("bhqk,bkc->bqhc", probs,
+                       jnp.where(written, cache[..., :rank], 0))
+    return jnp.einsum("bqhc,che->bqhe", mixed, wkvb[..., nope:])
+
+
+def _latent_attention(h, blk: dict, positions, cfg: ModelConfig,
+                      cache: Optional[dict], slot: Optional[tuple]) -> tuple:
+    """Low-rank attention on a normed state h (B, S, D) → (heads' outputs
+    (B, S, H, v), the updated cache or None). The query goes through a
+    normed, scaled bottleneck; one latent a position (normed, scaled)
+    carries every head's keys and values, and ``qk_rope_dim`` rotary lanes
+    are shared by all heads. With a cache the tokens' latents and turned
+    rotary lanes are written into pass ``t``'s from position ``start`` on
+    (``slot = (t, start)``); where ``start`` is static (prefill: the reach
+    is known) keys and values are expanded from what the cache holds up
+    to there, else (a cached step) :func:`_latent_absorbed` attends over
+    the latent cache as it lies."""
+    dt = cfg.compute_dtype
+    rank, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
+    absorbed = cache is not None and not isinstance(slot[1], int)
+    with jax.named_scope("mla_decode" if absorbed else "mla_prefill"):
+        # each bottleneck normed, and scaled to a hidden state's size
+        cq = _rms_norm(h @ blk["wqa"].astype(dt), blk["q_norm"], cfg.norm_eps
+                       ) * float(np.sqrt(cfg.d_model / cfg.q_lora_rank))
+        q = jnp.einsum("bsr,rhe->bshe", cq, blk["wqb"].astype(dt))
+        q_nope = q[..., :nope]
+        q_rope = _rope(q[..., nope:], positions, cfg.rope_theta,
+                       cfg.rope_pairing)
+        kv = h @ blk["wkva"].astype(dt)
+        ckv = _rms_norm(kv[..., :rank], blk["kv_norm"], cfg.norm_eps
+                        ) * float(np.sqrt(cfg.d_model / rank))
+        kr = _rope(kv[:, :, None, rank:], positions, cfg.rope_theta,
+                   cfg.rope_pairing)[:, :, 0]
+        latent = jnp.concatenate([ckv, kr], axis=-1)
+        wkvb = blk["wkvb"].astype(dt)
+        if cache is None:
+            return _latent_expanded(q_nope, q_rope, latent, wkvb, cfg), None
+        t, start = slot
+        # a position's latent one contiguous row, as _head_major pins keys
+        cache = {"latent": _row_major(jax.lax.dynamic_update_slice(
+            cache["latent"], latent[None], (t, 0, start, 0)))}
+        mine = jax.lax.dynamic_index_in_dim(cache["latent"], t, 0,
+                                            keepdims=False)
+        reach = start + h.shape[1]
+        if absorbed:
+            return _latent_absorbed(q_nope, q_rope, mine, reach, wkvb,
+                                    cfg), cache
+        return _latent_expanded(q_nope, q_rope, mine[:, :reach], wkvb,
+                                cfg), cache
 
 
 def resolve_impls(cfg: ModelConfig, mesh: Optional[Mesh] = None) -> ModelConfig:
@@ -314,8 +512,12 @@ def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
     families (honours cfg.attention_impl / norm_impl). Without ``cache``
     the tokens attend causally among themselves; with this layer's
     ``cache`` they go through it (:func:`_attend_through_cache`).
-    Returns (x, the updated cache or None)."""
+    ``cfg.attention`` "latent" is :func:`_latent_attention`, behind the
+    same norms and residual. Returns (x, the updated cache or None)."""
     h = _norm(x, blk["ln1"], cfg)
+    if cfg.attention == "latent":
+        attn, cache = _latent_attention(h, blk, positions, cfg, cache, slot)
+        return _attention_residual(x, attn, blk, cfg), cache
     qkv = jnp.einsum("bsd,dthe->tbshe", h,
                      blk["wqkv"].astype(cfg.compute_dtype))
     q, k, v = qkv[0], qkv[1], checkpoint_name(qkv[2], "v")
@@ -339,23 +541,22 @@ def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
                               batch_axis="dp", head_axis="tp")
     else:
         attn = _attention(q, k, v)
+    return _attention_residual(x, attn, blk, cfg), cache
+
+
+def _attention_residual(x, attn, blk: dict, cfg: ModelConfig) -> jax.Array:
+    """The heads' outputs (B, S, H, e) through ``wo``, (a norm,) and onto
+    the residual."""
     out = checkpoint_name(
         jnp.einsum("bshe,hed->bsd", attn,
                    blk["wo"].astype(cfg.compute_dtype)), "attn_proj")
     if cfg.norm_placement == "sandwich":
         out = _norm(out, blk["ln1_post"], cfg)
-    return x + out, cache
+    return x + out
 
 
-def _block(x: jax.Array, blk: dict, positions: jax.Array,
-           cfg: ModelConfig, mesh: Optional[Mesh] = None,
-           cache: Optional[dict] = None,
-           slot: Optional[tuple] = None) -> tuple:
-    """The one transformer block, with or without a KV cache, in the
-    kinds the configuration names. Returns (x, the updated cache or
-    None)."""
-    x, cache = attention_sublayer(x, blk, positions, cfg, mesh, cache, slot)
-    h = _norm(x, blk["ln2"], cfg)
+def _feed_forward(h: jax.Array, blk: dict, cfg: ModelConfig) -> jax.Array:
+    """The feed-forward on a normed state, up to the residual."""
     ff = checkpoint_name(h @ blk["w1"].astype(cfg.compute_dtype), "ffn_up")
     if cfg.ffn == "swiglu":
         gate = checkpoint_name(h @ blk["wg"].astype(cfg.compute_dtype),
@@ -368,7 +569,42 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
                           "ffn_down")
     if cfg.norm_placement == "sandwich":
         out = _norm(out, blk["ln2_post"], cfg)
-    return x + out, cache
+    return out
+
+
+def _block(x: jax.Array, blk: dict, positions: jax.Array,
+           cfg: ModelConfig, mesh: Optional[Mesh] = None,
+           cache: Optional[dict] = None,
+           slot: Optional[tuple] = None) -> tuple:
+    """The one transformer block, with or without a KV cache, in the
+    kinds the configuration names. A "single" layer is attention and its
+    feed-forward, ``blk`` their weights and ``cache`` the attention's. A
+    "shortcut" layer is two of those (``blk["halves"]``) and an expert
+    layer that reads the first feed-forward's normed input and joins the
+    residual after the second; its cache is ``{"attn": [the two
+    attentions' caches], "counters": what its expert layer has counted
+    so far in this call (models/moe.py:COUNTERS)}``. Returns (x, the
+    updated cache or None)."""
+    if cfg.layer == "single":
+        x, cache = attention_sublayer(x, blk, positions, cfg, mesh, cache,
+                                      slot)
+        return x + _feed_forward(_norm(x, blk["ln2"], cfg), blk, cfg), cache
+
+    from faabric_tpu.models.moe import expert_layer
+
+    caches = [None, None] if cache is None else list(cache["attn"])
+    for i, half in enumerate(blk["halves"]):
+        x, caches[i] = attention_sublayer(x, half, positions, cfg, mesh,
+                                          caches[i], slot)
+        h = _norm(x, half["ln2"], cfg)
+        if i == 0:
+            branch, counted = expert_layer(h, blk["router"], blk["experts"],
+                                           cfg)
+        with jax.named_scope("dense_ffn"):
+            x = x + _feed_forward(h, half, cfg)
+    if cache is not None:
+        cache = {"attn": caches, "counters": cache["counters"] + counted}
+    return x + branch, cache
 
 
 def run_passes(x: jax.Array, carry: Any, params: dict, cfg: ModelConfig,

@@ -174,7 +174,8 @@ def _global_positions(b_local: int, seq: int):
 # The kinds of block that _pp_block and _pp_moe_block implement: handed a
 # configuration that names another, they would train another network
 _PP_KINDS = {"ffn": "gelu", "norm_placement": "pre",
-             "rope_pairing": "neighbours", "norm_eps": 1e-6, "n_passes": 1}
+             "rope_pairing": "neighbours", "norm_eps": 1e-6, "n_passes": 1,
+             "attention": "heads", "layer": "single"}
 
 
 def _validate_pp_mesh(cfg: ModelConfig, mesh: Mesh) -> int:

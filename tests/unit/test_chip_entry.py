@@ -91,7 +91,13 @@ def test_chip_smoke_rehearsal_drives_the_whole_flow_on_cpu():
     assert '"ok"' not in p.stdout
     summary = json.loads(lines[-2])
     phases = summary["phases"]
-    assert set(phases) == {"kernels", "train", "decode", "gang"}
+    assert set(phases) == {"kernels", "train", "decode", "latent_experts",
+                           "gang"}
+    latent = phases["latent_experts"]
+    assert max(latent["prefill_rel_err"],
+               latent["cached_steps_rel_err"]) < 4e-2
+    assert sum(latent["picks_held_zero_absent_experts_hit"][:3]) \
+        == 4 * (32 + 2) * 4
     assert phases["train"]["mesh"] == {"dp": 2, "tp": 2}
     assert phases["train"]["params_on_device_ids"] == [0, 1, 2, 3]
     assert phases["decode"]["request_compiles"][1] == 0
