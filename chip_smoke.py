@@ -497,7 +497,7 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                 int(x.size) for x in jax.tree.leaves(params)),
             prefill_rel_err=_rel_err(got[:, :s_p], want[:, :s_p]),
             cached_steps_rel_err=_rel_err(got[:, s_p:], want[:, s_p:]),
-            picks_held_zero_absent_experts_hit=counted)
+            picks_held_zero_absent_experts_hit_tiles=counted)
         _require(np.isfinite(got).all(), "a logit is not finite")
         _require(sum(counted[:3]) == rows * (s_p + steps) * sizes["top_k"],
                  f"the picks do not add up: {counted}")

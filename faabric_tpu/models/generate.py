@@ -194,12 +194,14 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
                                           None, length=n_tokens)
     counters = {}
     if counted_in_prefill is not None:
-        counted = _counted(cfg, cache)
-        counters = dict(zip(COUNTERS, counted))
+        counters = dict(zip(COUNTERS, _counted(cfg, cache)))
         # over the decode steps and layers, the held experts that got a
-        # token: what a step has to read of the experts' weights
-        counters["experts_hit_decode"] = (counters.pop("experts_hit")
-                                          - counted_in_prefill[-1])
+        # token (what a step has to read of the experts' weights) and
+        # the tiles it ran (what it read of them)
+        in_prefill = dict(zip(COUNTERS, counted_in_prefill))
+        for name in ("experts_hit", "tiles"):
+            counters[name + "_decode"] = (counters.pop(name)
+                                          - in_prefill[name])
     return toks.T, counters  # (B, n_tokens)
 
 
@@ -247,9 +249,11 @@ def generate_with_counters(params, prompt, cfg: ModelConfig, n_tokens: int,
     empty but where the layers have an expert layer; there the picks of
     the whole call (prefill and every step, all layers) are
     ``picks_held`` (they fell on experts held here), ``picks_zero``
-    (zero-compute experts) and ``picks_absent`` (experts held elsewhere),
-    and ``experts_hit_decode`` is the held experts that got at least one
-    token, summed over the decode steps and layers."""
+    (zero-compute experts) and ``picks_absent`` (experts held elsewhere);
+    ``experts_hit_decode`` is the held experts that got at least one
+    token and ``tiles_decode`` the row tiles the grouped products ran
+    (each reads one expert's matrices; an expert nobody picked has
+    none), both summed over the decode steps and layers."""
     if mesh is not None and (cfg.attention, cfg.layer) != ("heads", "single"):
         raise ValueError(
             f"generate under a mesh implements attention='heads' and "

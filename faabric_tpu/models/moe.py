@@ -22,10 +22,11 @@ layer as one chip of an expert-parallel deployment holds it. The router
 has its published width; the chip is told which routed experts it holds,
 sorts the picks that fall on them by expert and runs one grouped product
 over them (a loop over the row tiles that hold a pick, each through its
-expert's matrices), so no token is dropped whatever the routing; gated
-(SwiGLU) experts, a stored bias that corrects the selection, zero-compute
-experts that return their input, and the picks of experts held elsewhere
-add nothing here.
+expert's matrices: an expert that got no token has no tile and is not
+read), so no token is dropped whatever the routing; gated (SwiGLU)
+experts, a stored bias that corrects the selection, zero-compute experts
+that return their input, and the picks of experts held elsewhere add
+nothing here.
 """
 
 from __future__ import annotations
@@ -207,9 +208,11 @@ def _moe_layer(x: jax.Array, blk: dict, cfg: MoEConfig,
 _MAX_ROWS = 24576
 
 # What expert_layer counts, in order: picks that fell on the experts held
-# here, on zero-compute experts, on experts held elsewhere, and the held
-# experts that got at least one token.
-COUNTERS = ("picks_held", "picks_zero", "picks_absent", "experts_hit")
+# here, on zero-compute experts, on experts held elsewhere, the held
+# experts that got at least one token, and the row tiles the grouped
+# product ran (its loop's trip count: each reads one expert's matrices).
+COUNTERS = ("picks_held", "picks_zero", "picks_absent", "experts_hit",
+            "tiles")
 
 
 def route(u: jax.Array, router: dict, cfg: ModelConfig) -> tuple:
@@ -240,11 +243,12 @@ def _held_experts(u: jax.Array, local: jax.Array, weights: jax.Array,
     """The held experts' part for u (T, D): ``local`` (T, K) is a pick's
     index among the experts held, or their count where it fell elsewhere.
     A grouped product: the picks sorted by expert, every expert's rows
-    padded to whole tiles (at least one), and a loop over those tiles,
-    each through its expert's three matrices (sliced in place, streamed
-    once a tile): time follows the picks that fell here, not the static
-    bound, and no token is dropped. Returns (the part
-    (T, D), tokens an expert (held,))."""
+    padded to whole tiles (none where it got no pick), and a loop over
+    those tiles, each through its expert's three matrices (sliced in
+    place, streamed once a tile): time follows the experts that got a
+    pick here, not the static bound or the count held, and no token is
+    dropped. Returns (the part (T, D), tokens an expert (held,), the
+    tiles run)."""
     t, k = local.shape
     held, d, _ = experts["w1"].shape
     tile = _row_tile(t)
@@ -254,11 +258,10 @@ def _held_experts(u: jax.Array, local: jax.Array, weights: jax.Array,
                     dtype=jnp.int32)
     ends = jnp.cumsum(sizes)
     starts = ends - sizes                   # in the sorted order
-    # at least one tile an expert, picked or not: a step then reads every
-    # expert held once whatever the routing, and its time does not move
-    # with the share of the picks that the weights' draw gives this chip
-    # (PERF.md section 6, PR 31: what skipping the others is worth)
-    tiles = jnp.maximum(1, -(-sizes // tile))
+    # an expert nobody picked has no tile and is not read: it shares its
+    # ``tile_ends`` with the expert before it, and a tile's expert (the
+    # count of ends at or below the tile) steps over a run of them
+    tiles = -(-sizes // tile)
     tile_ends = jnp.cumsum(tiles)
     tile_starts = tile_ends - tiles
     # a tile reads ``tile`` entries from its first row on: room past the end
@@ -284,20 +287,21 @@ def _held_experts(u: jax.Array, local: jax.Array, weights: jax.Array,
 
     # static room for every pick and every expert's last, partial tile
     out = jnp.zeros(((held + t * k // tile) * tile, d), dtype)
-    out = jax.lax.fori_loop(0, tile_ends[-1], one_tile, out)
+    n_tiles = tile_ends[-1]
+    out = jax.lax.fori_loop(0, n_tiles, one_tile, out)
     # back to the picks' order: where each held pick's row lies in ``out``
     here = flat < held
     e = jnp.minimum(flat, held - 1)
     at = tile_starts[e] * tile + jnp.argsort(order) - starts[e]
     part = jnp.where(here[:, None], out[jnp.where(here, at, 0)], 0)
     part = part.reshape(t, k, d).sum(axis=1, dtype=jnp.float32)
-    return part.astype(dtype), sizes
+    return part.astype(dtype), sizes, n_tiles
 
 
 def expert_layer(u: jax.Array, router: dict, experts: dict,
                  cfg: ModelConfig) -> tuple:
     """One chip's share of the expert layer: u (B, S, D), a normed state →
-    (m (B, S, D), counters int32 (4,) as :data:`COUNTERS`).
+    (m (B, S, D), counters int32 (5,) as :data:`COUNTERS`).
 
         m = Σ_{picked e held here} w_e · Expert_e(u)
           + Σ_{picked e zero-compute} w_e · u
@@ -321,7 +325,7 @@ def expert_layer(u: jax.Array, router: dict, experts: dict,
         tokens = b * s
         chunks = -(-tokens * k // _MAX_ROWS)
         bounds = [tokens * i // chunks for i in range(chunks + 1)]
-        parts, sizes = zip(*(
+        parts, sizes, tiles = zip(*(
             _held_experts(flat[lo:hi], local[lo:hi], weights[lo:hi],
                           experts, u.dtype)
             for lo, hi in zip(bounds, bounds[1:])))
@@ -330,7 +334,8 @@ def expert_layer(u: jax.Array, router: dict, experts: dict,
     n_held = jnp.sum(is_held, dtype=jnp.int32)
     n_zero = jnp.sum(is_zero, dtype=jnp.int32)
     counters = jnp.stack([n_held, n_zero, tokens * k - n_held - n_zero,
-                          jnp.sum(sum(sizes) > 0, dtype=jnp.int32)])
+                          jnp.sum(sum(sizes) > 0, dtype=jnp.int32),
+                          sum(tiles)])
     return m.reshape(b, s, d), counters
 
 

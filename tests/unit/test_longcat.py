@@ -246,7 +246,55 @@ def test_a_pick_of_an_absent_expert_adds_exactly_nothing():
     got, counted = _expert_layer(sz, u, router, blk["experts"])
     assert not got.any()
     assert counted == {"picks_held": 0, "picks_zero": 0,
-                       "picks_absent": 21 * sz["top_k"], "experts_hit": 0}
+                       "picks_absent": 21 * sz["top_k"], "experts_hit": 0,
+                       "tiles": 0}
+
+
+@pytest.mark.parametrize("held, favoured, shape, hit", [
+    # rank 1 holds experts 4..7 of 16; 16..23 are zero-compute
+    pytest.param((4, 4), [5, 0, 9, 15], (1, 12), {5},
+                 id="every_pick_on_one_expert"),
+    pytest.param((4, 4), [4, 7, 0, 12, 16, 20], (3, 7), {4, 7},
+                 id="the_first_and_the_last_held"),
+    pytest.param((0, 16), [2, 7, 8, 13, 17, 22], (3, 7), {2, 7, 8, 13},
+                 id="runs_of_unpicked_experts_between"),
+    pytest.param((4, 4), [6, 1, 2, 3], (2, 20), {6},
+                 id="an_expert_with_three_tiles"),
+    pytest.param((0, 16), [], (4, 16), set(range(16)),
+                 id="the_routers_own_picks"),
+    pytest.param((4, 4), [0, 3, 9, 15], (3, 7), set(),
+                 id="no_pick_held"),
+])
+def test_the_tiles_follow_the_picks(held, favoured, shape, hit):
+    """A selection bias that confines the picks to the ``favoured``
+    experts (each token's own four of them): the grouped product runs
+    ceil(picks / tile) tiles for an expert and none for one nobody
+    picked, wherever those lie among the experts held, and the layer is
+    the reference's masked loop over every expert held."""
+    sz = sizes(layers=1, held=held)
+    blk = weights(sz)["blocks"][0]
+    router = blk["router"]
+    if favoured:
+        router = dict(router, bias=router["bias"].at[
+            jnp.asarray(favoured)].set(10.0))
+    u = jax.random.normal(jax.random.PRNGKey(5), (*shape, 64), jnp.float32)
+    got, counted = _expert_layer(sz, u, router, blk["experts"])
+    want, picks = zip(*(ref.expert_layer(row, dict(blk, router=router), sz,
+                                         "float32") for row in u))
+    picks = np.asarray(picks)
+    first, count = held
+    picked = np.asarray([(picks == first + e).sum() for e in range(count)])
+    assert {first + e for e in np.flatnonzero(picked)} == hit
+    tile = moe._row_tile(shape[0] * shape[1])
+    assert tile == 16
+    assert counted["tiles"] == sum(-(-n // tile) for n in picked)
+    assert counted["experts_hit"] == len(hit)
+    assert counted["picks_held"] == picked.sum()
+    if hit:
+        np.testing.assert_allclose(got, np.stack(want), atol=ATOL, rtol=0)
+        assert np.abs(got).max() > 0.05
+    else:
+        assert not got.any()
 
 
 def test_generate_is_one_scan_and_its_counters_add_up():
@@ -282,10 +330,13 @@ def test_generate_is_one_scan_and_its_counters_add_up():
     tokens, counters = generate_with_counters(params, prompt, cfg, 8)
     counters = {k: int(v) for k, v in counters.items()}
     assert set(counters) == {"picks_held", "picks_zero", "picks_absent",
-                             "experts_hit_decode"}
+                             "experts_hit_decode", "tiles_decode"}
     assert (counters["picks_held"] + counters["picks_zero"]
             + counters["picks_absent"]) == 3 * (16 + 8) * sz["top_k"] * 2
     assert 0 < counters["experts_hit_decode"] <= 8 * 2 * 4
+    # a tile an expert that got a token (3 rows a step: never two) and
+    # none for the others, so never above experts_held × layers × steps
+    assert counters["tiles_decode"] == counters["experts_hit_decode"]
     served = np.asarray(tokens)
     assert served.shape == (3, 8)
     np.testing.assert_array_equal(served,
