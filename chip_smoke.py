@@ -32,6 +32,14 @@ Guests (user ``smoke``), all on the chips the planner pinned:
   prefill of a few rows and two cached steps over the latent caches,
   logits against ``benchmarks/reference/longcat.py``, so that a broken
   lowering shows before a 40 s window of the benchmark does.
+- ``state_space`` — layers of two kinds in one model (a Mamba-2
+  state-space layer and a grouped-query attention layer without rotary,
+  the multipliers, a tied head) at the widths of
+  ``benchmarks/configs/granite-4.0-h-micro.json``, one layer of each kind:
+  prefill over a chunk and a quarter (the chunked form, its carry and a
+  last chunk that is not full), then two cached steps (the recurrence,
+  grouped heads over the cache), logits against
+  ``benchmarks/reference/granite.py``.
 - ``gang``    — with ≥ 2 chips: an MPI world through ``ctx.mpi_world()``,
   one rank per chip, collectives on device-resident arrays through the
   activated device plane, and the Pallas ring-permute kernel.
@@ -94,6 +102,14 @@ LATENT_CONFIG = os.path.join(REPO, "benchmarks", "configs",
                              "longcat-flash-omni.json")
 LATENT_CONFIG_TINY = os.path.join(REPO, "tests", "bench", "data", "configs",
                                   "toy_longcat.json")
+
+# One layer of each kind in bfloat16 against the float32 reference: its
+# first run on the v5e measured 0.0082 and 0.0084 (my chip run, PR 33).
+TOL_STATE_SPACE_LOGITS = 4e-2
+HYBRID_CONFIG = os.path.join(REPO, "benchmarks", "configs",
+                             "granite-4.0-h-micro.json")
+HYBRID_CONFIG_TINY = os.path.join(REPO, "tests", "bench", "data", "configs",
+                                  "toy_granite.json")
 
 PLANNER_HOST = "smoke-planner"
 WORKER_HOST = "smoke-worker"
@@ -456,15 +472,34 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                      f"decode program holds kernel calls {calls}")
         return reply(**out)
 
+    def cached_logits(kinds, params, ids, s_p: int):
+        """Prefill of ``ids[:, :s_p]`` and one cached step for each further
+        position through ``forward_with_cache`` → (float32 logits at every
+        position, the call's state)."""
+        from faabric_tpu.models.generate import (
+            forward_with_cache,
+            init_kv_cache,
+        )
+
+        cache = init_kv_cache(kinds, ids.shape[0],
+                              128 * -(-ids.shape[1] // 128))
+        prefill = jax.jit(lambda p, t, c: forward_with_cache(
+            p, t, c, 0, kinds))
+        step = jax.jit(lambda p, t, c, pos: forward_with_cache(
+            p, t, c, pos, kinds))
+        logits, cache = prefill(params, jnp.asarray(ids[:, :s_p]), cache)
+        got = [np.asarray(logits, np.float32)]
+        for pos in range(s_p, ids.shape[1]):
+            logits, cache = step(params, jnp.asarray(ids[:, pos:pos + 1]),
+                                 cache, jnp.int32(pos))
+            got.append(np.asarray(logits, np.float32))
+        return np.concatenate(got, axis=1), cache
+
     # ---- latent attention, shortcut layers, a held share of experts ---
     @register_function("smoke", "latent_experts")
     def latent_experts(ctx):
         from benchmarks import program_longcat, weights_longcat
         from benchmarks.reference import longcat as reference
-        from faabric_tpu.models.generate import (
-            forward_with_cache,
-            init_kv_cache,
-        )
 
         dev = ctx.device
         with open(LATENT_CONFIG if on_chip else LATENT_CONFIG_TINY) as f:
@@ -477,18 +512,7 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
         with jax.default_device(dev):
             params = weights_longcat.make_weights(7, sizes,
                                                   kinds.param_dtype, dev)
-            cache = init_kv_cache(kinds, rows, 128 * -(-(s_p + steps) // 128))
-            prefill = jax.jit(lambda p, t, c: forward_with_cache(
-                p, t, c, 0, kinds))
-            step = jax.jit(lambda p, t, c, pos: forward_with_cache(
-                p, t, c, pos, kinds))
-            logits, cache = prefill(params, jnp.asarray(ids[:, :s_p]), cache)
-            got = [np.asarray(logits, np.float32)]
-            for pos in range(s_p, s_p + steps):
-                logits, cache = step(params, jnp.asarray(ids[:, pos:pos + 1]),
-                                     cache, jnp.int32(pos))
-                got.append(np.asarray(logits, np.float32))
-            got = np.concatenate(got, axis=1)
+            got, cache = cached_logits(kinds, params, ids, s_p)
             want = np.stack([np.asarray(reference.logits_of(
                 params, jnp.asarray(row), sizes)) for row in ids])
             counted = np.asarray(cache[0]["counters"]).tolist()
@@ -503,6 +527,48 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                  f"the picks do not add up: {counted}")
         for name in ("prefill_rel_err", "cached_steps_rel_err"):
             _require(out[name] < TOL_LATENT_LOGITS, f"{name} {out[name]}")
+        if on_chip:
+            _require(dev.platform == "tpu", dev.platform)
+        return reply(**out)
+
+    # ---- state-space layers beside grouped-query attention ------------
+    @register_function("smoke", "state_space")
+    def state_space(ctx):
+        from benchmarks import program_granite, weights_granite
+        from benchmarks.reference import granite as reference
+        from faabric_tpu.models.generate import call_sizes
+
+        dev = ctx.device
+        with open(HYBRID_CONFIG if on_chip else HYBRID_CONFIG_TINY) as f:
+            config = dict(json.load(f), num_hidden_layers=2,
+                          layer_types=["mamba", "attention"])
+        sizes = weights_granite.sizes_of(config)
+        kinds = program_granite.model_config(config)
+        rows, steps = 4, 2
+        s_p = kinds.ssm_chunk + kinds.ssm_chunk // 4
+        ids = weights_granite.token_rows(7, 1, 0, rows, s_p + steps,
+                                         sizes["vocab"])
+        with jax.default_device(dev):
+            params = weights_granite.make_weights(7, sizes,
+                                                  kinds.param_dtype, dev)
+            got, cache = cached_logits(kinds, params, ids, s_p)
+            want = np.asarray(reference.logits_of_rows(
+                params, jnp.asarray(ids), sizes))
+        counted = call_sizes(kinds, rows, s_p, steps)
+        out = dict(
+            device=_device_report(dev), n_params=sum(
+                int(x.size) for x in jax.tree.leaves(params)),
+            prefill_rel_err=_rel_err(got[:, :s_p], want[:, :s_p]),
+            cached_steps_rel_err=_rel_err(got[:, s_p:], want[:, s_p:]),
+            **{name: counted[name] for name in (
+                "cache_bytes", "state_bytes", "scan_chunks")})
+        _require(np.isfinite(got).all(), "a logit is not finite")
+        _require(sorted(cache[0]) == ["conv", "state"]
+                 and sorted(cache[1]) == ["k", "v"],
+                 f"the layers' state is {[sorted(c) for c in cache]}")
+        for name in ("prefill_rel_err", "cached_steps_rel_err"):
+            _require(out[name] < TOL_STATE_SPACE_LOGITS,
+                     f"{name} {out[name]}")
         if on_chip:
             _require(dev.platform == "tpu", dev.platform)
         return reply(**out)
@@ -817,6 +883,7 @@ def _run_phases(cluster: Cluster, summary: dict, deadline: float) -> None:
     phases["decode"] = cluster.invoke("decode", 1, deadline)[0]
     phases["latent_experts"] = cluster.invoke("latent_experts", 1,
                                               deadline)[0]
+    phases["state_space"] = cluster.invoke("state_space", 1, deadline)[0]
     if n >= 2:
         phases["gang"] = sorted(
             cluster.invoke("gang", 1, deadline, mpi_world_size=n),

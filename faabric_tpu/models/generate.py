@@ -20,6 +20,14 @@ heads; prefill expands keys and values from it, a cached step attends
 over it as it lies (``transformer._latent_attention``). A "shortcut"
 layer's state is its two attentions' caches and its expert layer's
 counters, summed on the device over the call.
+
+State goes by the layer's kind too (``ModelConfig.layer_types``). An
+"attention" layer keeps keys and values over its key/value heads, fewer
+than the query heads under grouped-query attention; a "mamba" layer
+(models/ssm.py) keeps a convolution window and a recurrent state whose
+size does not depend on the reach. Both kinds live in one call's list.
+With ``prefill_chunk`` a chunk of the prompt starts from the state the
+chunk before left.
 """
 
 from __future__ import annotations
@@ -30,12 +38,16 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from faabric_tpu.models import ssm
 from faabric_tpu.models.moe import COUNTERS
 from faabric_tpu.models.transformer import (
     ModelConfig,
     _block,
+    embed,
+    head,
     resolve_impls,
     run_passes,
+    refuse_served_only,
 )
 
 
@@ -55,57 +67,90 @@ def _attention_cache_shapes(cfg: ModelConfig, batch: int, slots: int) -> dict:
     if cfg.attention == "latent":
         return {"latent": (cfg.n_passes, batch, slots,
                            cfg.kv_lora_rank + cfg.qk_rope_dim)}
-    shape = (cfg.n_passes, batch, cfg.n_heads, slots, cfg.head_dim)
+    shape = (cfg.n_passes, batch, cfg.kv_heads, slots, cfg.head_dim)
     return {"k": shape, "v": shape}
 
 
+def _prefill_chunks(prompt_len: int, prefill_chunk: int) -> list:
+    """The static (start, length) of the prompt's chunks."""
+    chunk = prefill_chunk if 0 < prefill_chunk < prompt_len else prompt_len
+    return [(pos, min(chunk, prompt_len - pos))
+            for pos in range(0, prompt_len, chunk)]
+
+
 def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
-               n_tokens: int) -> dict:
+               n_tokens: int, prefill_chunk: int = 0) -> dict:
     """What one ``generate`` call of these static shapes allocates and
     runs: ``cache_slots`` a cache, ``cache_bytes`` of all the caches (what
-    the attention's kind keeps a position, every attention of every layer,
-    every pass), ``ut_passes`` of the stack (prefill and each decode step
-    pass it ``cfg.n_passes`` times); where the layers have an expert layer
-    also ``experts_held`` here and the ``router_width``. ``generate``
-    sizes its cache from this; a server reports it beside its answers."""
+    the attention's kind keeps a position, every attention of every
+    attention layer, every pass), ``ut_passes`` of the stack (prefill and
+    each decode step pass it ``cfg.n_passes`` times); where the layers
+    have an expert layer also ``experts_held`` here and the
+    ``router_width``; where the configuration names its layers' kinds
+    also ``attention_layers``, ``ssm_layers``, ``state_bytes`` (the
+    windows and states of all state-space layers: the same at any reach)
+    and ``scan_chunks``, the chunks of the state-space scan a row a layer
+    in prefill. ``generate`` sizes its cache from this; a server reports
+    it beside its answers."""
     slots = _cache_slots(cfg, prompt_len + n_tokens)
     itemsize = jnp.dtype(cfg.compute_dtype).itemsize
     shortcut = cfg.layer == "shortcut"
     values = sum(math.prod(shape) for shape in
                  _attention_cache_shapes(cfg, batch, slots).values())
+    attention_layers = cfg.mixers.count("attention")
     sizes = {
         "cache_slots": slots,
-        "cache_bytes": (1 + shortcut) * cfg.n_layers * values * itemsize,
+        "cache_bytes": (1 + shortcut) * attention_layers * values * itemsize,
         "ut_passes": cfg.n_passes * (1 + n_tokens),
     }
     if shortcut:
         sizes.update(experts_held=cfg.experts_held[1],
                      router_width=cfg.routed_experts + cfg.zero_experts)
+    if cfg.layer_types:
+        ssm_layers = cfg.n_layers - attention_layers
+        kept = sum(math.prod(shape) for shape in
+                   ssm.state_shapes(cfg, batch).values())
+        sizes.update(
+            attention_layers=attention_layers, ssm_layers=ssm_layers,
+            state_bytes=ssm_layers * kept * itemsize,
+            scan_chunks=sum(-(-length // cfg.ssm_chunk) for _, length in
+                            _prefill_chunks(prompt_len, prefill_chunk))
+            if ssm_layers else 0)
     return sizes
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, slots: int) -> list[dict]:
-    """Zeroed per-layer state of a call. Per-head attention: keys and
-    values, one cache a pass, head-major: (passes, batch, heads, slots,
-    head_dim). Latent attention: (passes, batch, slots, kv_lora_rank +
-    qk_rope_dim), once. A "shortcut" layer: its two attentions' caches
-    and its expert layer's counters (``transformer._block``)."""
+    """Zeroed per-layer state of a call, by the layer's kind. Per-head
+    attention: keys and values, one cache a pass, head-major: (passes,
+    batch, key/value heads, slots, head_dim). Latent attention: (passes,
+    batch, slots, kv_lora_rank + qk_rope_dim), once. A "shortcut" layer:
+    its two attentions' caches and its expert layer's counters
+    (``transformer._block``). A "mamba" layer: its convolution window and
+    its recurrent state (``ssm.state_shapes``), whatever ``slots``."""
+    def zeros(shapes: dict):
+        return {name: jnp.zeros(shape, cfg.compute_dtype)
+                for name, shape in shapes.items()}
+
     def attention():
-        return {name: jnp.zeros(shape, cfg.compute_dtype) for name, shape
-                in _attention_cache_shapes(cfg, batch, slots).items()}
+        return zeros(_attention_cache_shapes(cfg, batch, slots))
 
     if cfg.layer == "shortcut":
         return [{"attn": [attention(), attention()],
                  "counters": jnp.zeros((len(COUNTERS),), jnp.int32)}
                 for _ in range(cfg.n_layers)]
-    return [attention() for _ in range(cfg.n_layers)]
+    return [zeros(ssm.state_shapes(cfg, batch)) if kind == "mamba"
+            else attention() for kind in cfg.mixers]
 
 
-def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig):
+def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig,
+                       last_only: bool = False):
     """tokens (B, S) entering at position ``start`` → (logits (B, S, V),
-    new cache). Pass ``t`` of the stack writes and attends ``cache[...][t]``
-    alone. A step whose depth depends on the data (an exit threshold
-    below 1.0) has no cached path."""
+    new cache); with ``last_only`` the head reads the last position alone,
+    logits (B, 1, V): what a server samples from (at a vocabulary of 100k
+    the logits of a 64 × 512 prompt are 13 GB). Pass ``t`` of the stack
+    writes and attends ``cache[...][t]`` alone; a state-space layer starts
+    from the window and state its cache holds. A step whose depth depends
+    on the data (an exit threshold below 1.0) has no cached path."""
     if cfg.exit_threshold < 1.0:
         raise ValueError(
             f"exit_threshold {cfg.exit_threshold} is below 1.0: cached "
@@ -113,20 +158,19 @@ def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig):
     cfg = resolve_impls(cfg)
     b, s = tokens.shape
     positions = jnp.broadcast_to(start + jnp.arange(s)[None], (b, s))
-    x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    x = embed(params, tokens, cfg)
 
     def stack(x, cache, t):
         new_cache = []
-        for blk, layer_cache in zip(params["blocks"], cache):
+        for blk, layer_cache, kind in zip(params["blocks"], cache,
+                                          cfg.mixers):
             x, updated = _block(x, blk, positions, cfg, cache=layer_cache,
-                                slot=(t, start))
+                                slot=(t, start), kind=kind)
             new_cache.append(updated)
         return x, new_cache
 
     x, cache = run_passes(x, cache, params, cfg, stack)
-    logits = (x @ params["lm_head"].astype(cfg.compute_dtype)
-              ).astype(jnp.float32)
-    return logits, cache
+    return head(params, x[:, -1:] if last_only else x, cfg), cache
 
 
 def _pick_token(logits, key, greedy: bool, temperature, top_k: int,
@@ -168,13 +212,15 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
                   for k, v in layer.items()} for layer in cache]
 
     # Chunked prefill: attention during prefill peaks at (chunk × slots)
-    # scores instead of (S_p × slots) — the long-prompt memory bound.
-    # Chunk boundaries are static.
-    chunk = prefill_chunk if 0 < prefill_chunk < s_p else s_p
+    # scores instead of (S_p × slots) — the long-prompt memory bound —
+    # and a state-space layer's chunk starts from the state the one
+    # before left. Chunk boundaries are static; the head reads the one
+    # position that is sampled from.
     with jax.named_scope("prefill"):
-        for pos in range(0, s_p, chunk):
+        for pos, length in _prefill_chunks(s_p, prefill_chunk):
             logits, cache = forward_with_cache(
-                params, prompt[:, pos:pos + chunk], cache, pos, cfg)
+                params, prompt[:, pos:pos + length], cache, pos, cfg,
+                last_only=True)
     key, sub = jax.random.split(key)
     next_tok = _pick_token(logits[:, -1], sub, greedy, temperature,
                            top_k, use_top_p, top_p)
@@ -232,8 +278,10 @@ def generate(params, prompt, cfg: ModelConfig, n_tokens: int,
     processes long prompts in fixed-size chunks, bounding prefill
     attention memory. A looped stack (``cfg.n_passes`` above 1) keeps
     one cache a pass a layer; :func:`call_sizes` says what a call of
-    these shapes allocates. The other kinds of attention and layer are
-    single-chip so far: with ``mesh`` they raise ``ValueError``."""
+    these shapes allocates. The other kinds of attention and layer
+    (latent attention, shortcut layers, state-space layers, grouped
+    key/value heads, the multipliers, a tied head) are single-chip so
+    far: with ``mesh`` they raise ``ValueError``."""
     return generate_with_counters(params, prompt, cfg, n_tokens, key,
                                   temperature, top_k, top_p, mesh,
                                   prefill_chunk)[0]
@@ -258,6 +306,8 @@ def generate_with_counters(params, prompt, cfg: ModelConfig, n_tokens: int,
         raise ValueError(
             f"generate under a mesh implements attention='heads' and "
             f"layer='single' only, not {cfg.attention!r} and {cfg.layer!r}")
+    if mesh is not None:
+        refuse_served_only(cfg, "generate under a mesh")
     reach = prompt.shape[1] + n_tokens
     if reach > cfg.max_seq:
         raise ValueError(

@@ -43,6 +43,7 @@ from faabric_tpu.models.transformer import (
     ModelConfig,
     _rms_norm,
     attention_sublayer,
+    refuse_served_only,
 )
 
 
@@ -69,6 +70,7 @@ def _refuse_other_kinds(cfg: MoEConfig) -> None:
             raise ValueError(
                 f"the MoE family implements {field}={kind!r} only, "
                 f"not {field}={getattr(cfg, field)!r}")
+    refuse_served_only(cfg, "the MoE family")
 
 
 def init_moe_params(key: jax.Array, cfg: MoEConfig) -> dict:

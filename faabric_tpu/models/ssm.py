@@ -1,0 +1,198 @@
+"""A state-space mixer (Mamba-2, Dao and Gu, "Transformers are SSMs",
+arXiv:2405.21060): the sub-layer a "mamba" layer has where an "attention"
+layer has attention, behind the same norm and residual
+(``transformer._block``).
+
+On a normed state ``h`` (B, S, D), with H heads of P lanes, G groups and a
+state of N a lane (``cfg.ssm_heads``, ``ssm_head_dim``, ``ssm_groups``,
+``ssm_d_state``):
+
+    [z (H·P), xBC (H·P + 2·G·N), dt (H)] = h · ssm_in         (no bias)
+    xBC = silu(conv(xBC))       depthwise, causal, ``ssm_d_conv`` taps, bias
+    [x (H, P), B (G, N), C (G, N)] = xBC       a group's heads share B and C
+    dt = softplus(dt + dt_bias);  A = −exp(A_log)                  (a head)
+    S_t = exp(dt_t·A)·S_{t−1} + dt_t·x_t ⊗ B_t             S a head: (P, N)
+    y_t = S_t·C_t + D·x_t
+    out = RMSNorm(y · silu(z); ssm_norm) · ssm_out    the gate before the norm
+
+Two forms over one set of weights. A cached step (S = 1) is the recurrence
+itself. Longer inputs go in chunks of ``cfg.ssm_chunk`` positions: inside
+a chunk the outputs are a masked product of C·Bᵀ with the decays between
+the two positions (from the cumulative sums of dt·A), between chunks the
+state is carried; the last chunk need not be full and the first starts
+from whatever state it is given. The chunks of one call are unrolled: a
+prefill holds no loop.
+
+The state of a call (``cache``): ``conv``, the last ``ssm_d_conv − 1``
+inputs of the convolution (B, d_conv − 1, H·P + 2·G·N), and ``state``,
+S (B, H, P, N): their size does not depend on the call's reach. Both forms
+leave the same two. Matrix products run in the compute type; dt, the
+decays, their cumulative sums, the update of S and the norm's statistic
+are float32; S and the window are stored in the compute type.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from faabric_tpu.models.transformer import _rms_norm
+
+# the leaves a "mamba" layer holds beside its norms and feed-forward
+MIXER_LEAVES = ("ssm_in", "conv_w", "conv_b", "dt_bias", "A_log", "D",
+                "ssm_norm", "ssm_out")
+
+
+def widths(cfg) -> tuple:
+    """(inner = H·P, B's and C's width G·N, the convolution's channels)."""
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    bc = cfg.ssm_groups * cfg.ssm_d_state
+    return inner, bc, inner + 2 * bc
+
+
+def state_shapes(cfg, batch: int) -> dict:
+    """The arrays one mixer keeps through a call."""
+    return {"conv": (batch, cfg.ssm_d_conv - 1, widths(cfg)[2]),
+            "state": (batch, cfg.ssm_heads, cfg.ssm_head_dim,
+                      cfg.ssm_d_state)}
+
+
+def init_mixer(key: jax.Array, cfg, dense) -> dict:
+    """The mixer's leaves as Mamba-2 initialises them: dt log-uniform in
+    [0.001, 0.1] through the inverse of softplus, A uniform in [1, 16],
+    D one."""
+    inner, _, channels = widths(cfg)
+    k = jax.random.split(key, 5)
+    dt = jnp.exp(jax.random.uniform(
+        k[3], (cfg.ssm_heads,), jnp.float32,
+        jnp.log(0.001), jnp.log(0.1)))
+    return {
+        "ssm_in": dense(k[0], (cfg.d_model, inner + channels
+                               + cfg.ssm_heads), cfg.d_model),
+        "conv_w": dense(k[1], (cfg.ssm_d_conv, channels), cfg.ssm_d_conv),
+        "conv_b": jnp.zeros((channels,), cfg.param_dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(cfg.param_dtype),
+        "A_log": jnp.log(jax.random.uniform(
+            k[4], (cfg.ssm_heads,), jnp.float32, 1.0, 16.0)
+        ).astype(cfg.param_dtype),
+        "D": jnp.ones((cfg.ssm_heads,), cfg.param_dtype),
+        "ssm_norm": jnp.ones((inner,), cfg.param_dtype),
+        "ssm_out": dense(k[2], (inner, cfg.d_model), inner),
+    }
+
+
+def _convolve(window: jax.Array, xbc: jax.Array, blk: dict) -> tuple:
+    """The causal depthwise convolution of xbc (B, S, C) behind the
+    ``window`` of the inputs before it (B, taps − 1, C), as a sum of
+    shifted products → (silu of it, the window the next call starts
+    from)."""
+    s = xbc.shape[1]
+    taps = blk["conv_w"].astype(xbc.dtype)
+    padded = jnp.concatenate([window, xbc], axis=1)
+    out = blk["conv_b"].astype(xbc.dtype) + sum(
+        taps[k] * padded[:, k:k + s] for k in range(taps.shape[0]))
+    return jax.nn.silu(out), padded[:, s:]
+
+
+def _step(x, b, c, dt, a, state):
+    """The recurrence at one position: x (B, H, P), b and c (B, H, N)
+    (each head its group's), dt (B, H) and a (H,) float32, ``state``
+    (B, H, P, N) → (y (B, H, P) float32, the new state float32)."""
+    decay = jnp.exp(dt * a)
+    pushed = (dt[..., None] * x.astype(jnp.float32))[..., None] \
+        * b.astype(jnp.float32)[:, :, None, :]
+    state = decay[..., None, None] * state.astype(jnp.float32) + pushed
+    y = jnp.einsum("bhpn,bhn->bhp", state, c.astype(jnp.float32))
+    return y, state
+
+
+def _chunk(x, b, c, dt, a, state):
+    """The same recurrence over one chunk of L positions at once: x (B, L,
+    H, P), b and c (B, L, G, N), dt (B, L, H) and a (H,) float32,
+    ``state`` (B, H, P, N) float32, the state before the chunk's first
+    position → (y (B, L, H, P) float32, the state after its last)."""
+    bsz, length, heads, lanes = x.shape
+    groups = b.shape[2]
+    per = heads // groups
+    dtype = x.dtype
+    cum = jnp.cumsum(dt * a, axis=1)                       # (B, L, H) ≤ 0
+    # position i reads what position j ≤ i pushed, decayed over (j, i]
+    by_head = cum.transpose(0, 2, 1)                       # (B, H, L)
+    causal = jnp.tril(jnp.ones((length, length), dtype=bool))
+    between = jnp.where(causal, by_head[..., :, None] - by_head[..., None, :],
+                        -jnp.inf)                          # (B, H, i, j)
+    weight = jnp.exp(between) * dt.transpose(0, 2, 1)[..., None, :]
+    cb = jnp.einsum("bign,bjgn->bgij", c, b,
+                    preferred_element_type=jnp.float32)
+    mixed = (weight.reshape(bsz, groups, per, length, length)
+             * cb[:, :, None]).astype(dtype)
+    inside = jnp.einsum("bgrij,bjgrp->bigrp", mixed,
+                        x.reshape(bsz, length, groups, per, lanes),
+                        preferred_element_type=jnp.float32)
+    # what the state before the chunk still gives position i
+    carried = jnp.einsum(
+        "bign,bgrpn->bigrp", c,
+        state.astype(dtype).reshape(bsz, groups, per, lanes, -1),
+        preferred_element_type=jnp.float32) \
+        * jnp.exp(cum).reshape(bsz, length, groups, per)[..., None]
+    y = (inside + carried).reshape(bsz, length, heads, lanes)
+    # the state after the chunk: the old one decayed over the whole chunk
+    # and every position's push decayed over what follows it
+    left = jnp.exp(cum[:, -1:, :] - cum) * dt               # (B, L, H)
+    pushed = jnp.einsum(
+        "bjgrp,bjgn->bgrpn",
+        (x * left[..., None].astype(dtype)).reshape(
+            bsz, length, groups, per, lanes), b,
+        preferred_element_type=jnp.float32)
+    state = jnp.exp(cum[:, -1, :])[..., None, None] * state \
+        + pushed.reshape(state.shape)
+    return y, state
+
+
+def mixer(h: jax.Array, blk: dict, cfg, cache: Optional[dict] = None
+          ) -> tuple:
+    """The mixer on a normed state h (B, S, D) → (its output (B, S, D),
+    the updated cache or None). Without ``cache`` the window and the state
+    start from zero (a whole sequence, as ``transformer.forward`` runs
+    it); with it they start from what the call before left."""
+    dtype = cfg.compute_dtype
+    bsz, s, _ = h.shape
+    inner, bc, channels = widths(cfg)
+    heads, lanes, groups = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups
+    start = cache if cache is not None else {
+        name: jnp.zeros(shape, dtype)
+        for name, shape in state_shapes(cfg, bsz).items()}
+    with jax.named_scope("ssm_decode" if s == 1 else "ssm_prefill"):
+        zxbcdt = h @ blk["ssm_in"].astype(dtype)
+        z = zxbcdt[..., :inner]
+        xbc, window = _convolve(start["conv"].astype(dtype),
+                                zxbcdt[..., inner:inner + channels], blk)
+        dt = jax.nn.softplus(zxbcdt[..., inner + channels:].astype(
+            jnp.float32) + blk["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(blk["A_log"].astype(jnp.float32))
+        x = xbc[..., :inner].reshape(bsz, s, heads, lanes)
+        b = xbc[..., inner:inner + bc].reshape(bsz, s, groups, -1)
+        c = xbc[..., inner + bc:].reshape(bsz, s, groups, -1)
+        if s == 1:
+            per_head = [jnp.repeat(m[:, 0], heads // groups, axis=1)
+                        for m in (b, c)]
+            y, state = _step(x[:, 0], *per_head, dt[:, 0], a, start["state"])
+            y = y[:, None]
+        else:
+            state = start["state"].astype(jnp.float32)
+            ys = []
+            for at in range(0, s, cfg.ssm_chunk):
+                span = slice(at, at + cfg.ssm_chunk)
+                y, state = _chunk(x[:, span], b[:, span], c[:, span],
+                                  dt[:, span], a, state)
+                ys.append(y)
+            y = jnp.concatenate(ys, axis=1)
+        y = y + blk["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+        gated = y.reshape(bsz, s, inner).astype(dtype) * jax.nn.silu(z)
+        out = _rms_norm(gated, blk["ssm_norm"], cfg.norm_eps) \
+            @ blk["ssm_out"].astype(dtype)
+    if cache is not None:
+        cache = {"conv": window, "state": state.astype(dtype)}
+    return out, cache

@@ -21,6 +21,7 @@ from faabric_tpu.models.transformer import (
     init_params,
     loss_fn,
     param_shardings,
+    refuse_served_only,
 )
 
 
@@ -60,6 +61,9 @@ def _build_step(cfg: ModelConfig, mesh: Optional[Mesh],
             raise ValueError(
                 f"the train step implements {field}={kind!r} only, "
                 f"not {field}={getattr(cfg, field)!r}")
+    # the chunked scan has no backward pass here, and remat_plan and the
+    # flash kernels know as many key/value heads as query heads
+    refuse_served_only(cfg, "the train step")
 
     def grads_of(params, tokens, targets):
         return jax.value_and_grad(loss_fn)(params, tokens, targets,

@@ -107,16 +107,71 @@ class ModelConfig:
     experts_per_token: int = 0
     routed_scaling: float = 1.0
     expert_d_ff: int = 0
+    # Each layer's mixer, the sub-layer before the feed-forward:
+    # "attention" | "mamba" (a Mamba-2 state-space mixer, models/ssm.py),
+    # one name a layer; empty: attention in every layer
+    layer_types: tuple = ()
+    # key/value heads; 0: as many as query heads. Fewer: grouped-query
+    # attention, n_heads / n_kv_heads query heads a key/value head, the
+    # cache over the key/value heads
+    n_kv_heads: int = 0
+    # "rope": queries and keys turn with their position | "none"
+    position: str = "rope"
+    # the scores' scale; 0: 1 / sqrt(head_dim)
+    attention_scale: float = 0.0
+    # x = embed[tokens] · embedding_multiplier; a sub-layer joins the
+    # residual as x + residual_multiplier · out; logits / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # the head is the embedding table transposed: no ``lm_head`` leaf
+    tie_embeddings: bool = False
+    # the state-space mixer's sizes: ssm_heads heads of ssm_head_dim
+    # lanes, each with a state of ssm_head_dim × ssm_d_state; B and C
+    # shared by the heads of one of ssm_groups groups; a causal depthwise
+    # convolution of ssm_d_conv taps; prefill in chunks of ssm_chunk
+    ssm_d_state: int = 0
+    ssm_d_conv: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
 
     def __post_init__(self):
         for field, kinds in (("ffn", ("gelu", "swiglu")),
                              ("norm_placement", ("pre", "sandwich")),
                              ("rope_pairing", ("neighbours", "halves")),
                              ("attention", ("heads", "latent")),
-                             ("layer", ("single", "shortcut"))):
+                             ("layer", ("single", "shortcut")),
+                             ("position", ("rope", "none"))):
             if getattr(self, field) not in kinds:
                 raise ValueError(f"{field} {getattr(self, field)!r} is not "
                                  f"one of {kinds}")
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types and (
+                len(self.layer_types) != self.n_layers
+                or set(self.layer_types) - {"attention", "mamba"}):
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers as "
+                f"{sorted(set(self.layer_types))}; it takes 'attention' or "
+                f"'mamba' for each of the {self.n_layers}")
+        if self.n_heads % self.kv_heads or (
+                self.kv_heads != self.n_heads and self.attention != "heads"):
+            raise ValueError(
+                f"n_kv_heads {self.n_kv_heads} does not group the "
+                f"{self.n_heads} heads of attention {self.attention!r}")
+        if "mamba" in self.layer_types and not (
+                self.ssm_d_state > 0 and self.ssm_d_conv > 1
+                and self.ssm_heads > 0 and self.ssm_head_dim > 0
+                and self.ssm_chunk > 0 and self.ssm_groups > 0
+                and self.ssm_heads % self.ssm_groups == 0
+                and self.n_passes == 1
+                and (self.attention, self.layer) == ("heads", "single")):
+            raise ValueError(
+                "a 'mamba' layer needs ssm_d_state, ssm_d_conv above 1, "
+                "ssm_heads in ssm_groups groups, ssm_head_dim and "
+                "ssm_chunk, in single layers of one pass beside per-head "
+                f"attention; got {self}")
         if self.n_passes < 1:
             raise ValueError(f"n_passes {self.n_passes} is below 1")
         if self.attention == "latent" and not (
@@ -141,6 +196,43 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def mixers(self) -> tuple:
+        """Every layer's mixer kind."""
+        return self.layer_types or ("attention",) * self.n_layers
+
+    @property
+    def score_scale(self) -> float:
+        return self.attention_scale or 1.0 / np.sqrt(self.head_dim)
+
+
+def served_only(cfg: ModelConfig) -> list:
+    """What of ``cfg`` only the served path on one chip implements
+    (:func:`forward`, ``generate()`` without a mesh), as ``field=value``:
+    the train step, the pipeline's stages and the MoE family refuse a
+    configuration that names one of these, by name."""
+    plain = ModelConfig()
+    fields = ("layer_types", "position", "attention_scale",
+              "embedding_multiplier", "residual_multiplier",
+              "logits_scaling", "tie_embeddings")
+    named = [f"{f}={getattr(cfg, f)!r}" for f in fields
+             if getattr(cfg, f) != getattr(plain, f)]
+    if cfg.kv_heads != cfg.n_heads:
+        named.append(f"n_kv_heads={cfg.n_kv_heads!r}")
+    return named
+
+
+def refuse_served_only(cfg: ModelConfig, who: str) -> None:
+    """Raise where ``cfg`` names a kind that ``who`` does not implement."""
+    named = served_only(cfg)
+    if named:
+        raise ValueError(f"{who} cannot run a configuration that names "
+                         + ", ".join(named))
 
 
 # ---------------------------------------------------------------------------
@@ -172,8 +264,9 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
             "wo": dense(k[4], (h, cfg.v_head_dim, d), h * cfg.v_head_dim),
         }
 
-    def half(key):
-        """Attention and its feed-forward: a whole "single" layer."""
+    def half(key, kind="attention"):
+        """A mixer (attention, or the state-space mixer) and its
+        feed-forward: a whole "single" layer."""
         bk = jax.random.split(key, 4)
         blk = {
             "ln1": ones(),
@@ -181,12 +274,24 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
             "w1": dense(bk[2], (cfg.d_model, cfg.d_ff), cfg.d_model),
             "w2": dense(bk[3], (cfg.d_ff, cfg.d_model), cfg.d_ff),
         }
-        if cfg.attention == "latent":
+        if kind == "mamba":
+            from faabric_tpu.models.ssm import init_mixer
+
+            blk.update(init_mixer(bk[0], cfg, dense))
+        elif cfg.attention == "latent":
             blk.update(latent_attention(bk[0]))
         else:
-            blk["wqkv"] = dense(
-                bk[0], (cfg.d_model, 3, cfg.n_heads, cfg.head_dim),
-                cfg.d_model)
+            if cfg.kv_heads == cfg.n_heads:
+                blk["wqkv"] = dense(
+                    bk[0], (cfg.d_model, 3, cfg.n_heads, cfg.head_dim),
+                    cfg.d_model)
+            else:
+                # queries and keys/values projected at their own widths
+                qk, kvk = jax.random.split(bk[0])
+                blk["wq"] = dense(qk, (cfg.d_model, cfg.n_heads,
+                                       cfg.head_dim), cfg.d_model)
+                blk["wkv"] = dense(kvk, (cfg.d_model, 2, cfg.kv_heads,
+                                         cfg.head_dim), cfg.d_model)
             blk["wo"] = dense(bk[1], (cfg.n_heads, cfg.head_dim, cfg.d_model),
                               cfg.d_model)
         if cfg.ffn == "swiglu":
@@ -213,13 +318,18 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
                         "w2": dense(k[6], (held, f, d), f)},
         }
 
-    layer = shortcut if cfg.layer == "shortcut" else half
+    if cfg.layer == "shortcut":
+        blocks = [shortcut(keys[i]) for i in range(cfg.n_layers)]
+    else:
+        blocks = [half(keys[i], kind) for i, kind in enumerate(cfg.mixers)]
     params = {
         "embed": dense(keys[-2], (cfg.vocab_size, cfg.d_model), cfg.d_model),
-        "blocks": [layer(keys[i]) for i in range(cfg.n_layers)],
+        "blocks": blocks,
         "ln_f": ones(),
-        "lm_head": dense(keys[-1], (cfg.d_model, cfg.vocab_size), cfg.d_model),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(keys[-1], (cfg.d_model, cfg.vocab_size),
+                                  cfg.d_model)
     if cfg.n_passes > 1:
         gk = jax.random.fold_in(key, cfg.n_layers)
         params["exit_gate"] = {"w": dense(gk, (cfg.d_model,), cfg.d_model),
@@ -244,26 +354,39 @@ def param_shardings(mesh: Mesh, cfg: ModelConfig) -> dict:
         # the bottlenecks whole on every chip, what fans out of them by head
         half.update(wqa=ns(), q_norm=ns(), wqb=ns(None, "tp", None),
                     wkva=ns(), kv_norm=ns(), wkvb=ns(None, "tp", None))
-    else:
+    elif cfg.kv_heads == cfg.n_heads:
         half["wqkv"] = ns(None, None, "tp", None)
+    else:
+        half.update(wq=ns(None, "tp", None), wkv=ns(None, None, "tp", None))
     if cfg.ffn == "swiglu":
         half["wg"] = ns(None, "tp")
     if cfg.norm_placement == "sandwich":
         half["ln1_post"], half["ln2_post"] = ns(), ns()
-    block = half
+    by_kind = {"attention": half}
+    if "mamba" in cfg.layer_types:
+        from faabric_tpu.models.ssm import MIXER_LEAVES
+
+        # the mixer whole on every chip: its in-projection's columns are
+        # five pieces of unlike widths, and nothing runs it under a mesh
+        by_kind["mamba"] = {
+            **{k: v for k, v in half.items()
+               if k not in ("wq", "wkv", "wqkv", "wo")},
+            **{name: ns() for name in MIXER_LEAVES}}
     if cfg.layer == "shortcut":
-        block = {"halves": [dict(half), dict(half)],
-                 "router": {"w": ns(), "bias": ns()},
-                 "experts": {"wg": ns(None, None, "tp"),
-                             "w1": ns(None, None, "tp"),
-                             "w2": ns(None, "tp", None)}}
+        by_kind["attention"] = {
+            "halves": [dict(half), dict(half)],
+            "router": {"w": ns(), "bias": ns()},
+            "experts": {"wg": ns(None, None, "tp"),
+                        "w1": ns(None, None, "tp"),
+                        "w2": ns(None, "tp", None)}}
     shardings = {
         "embed": ns("tp", None),
-        "blocks": [jax.tree.map(lambda x: x, block)
-                   for _ in range(cfg.n_layers)],
+        "blocks": [jax.tree.map(lambda x: x, by_kind[kind])
+                   for kind in cfg.mixers],
         "ln_f": ns(),
-        "lm_head": ns(None, "tp"),
     }
+    if not cfg.tie_embeddings:
+        shardings["lm_head"] = ns(None, "tp")
     if cfg.n_passes > 1:
         shardings["exit_gate"] = {"w": ns(), "b": ns()}
     return shardings
@@ -302,15 +425,19 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float,
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-    """Causal attention, (B, S, H, D); fp32 softmax accumulators."""
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    s = q.shape[1]
+def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
+               scale: Optional[float] = None) -> jax.Array:
+    """Causal attention, q (B, S, H, D) on keys and values (B, S, KV, D),
+    H / KV query heads a key/value head; fp32 softmax accumulators."""
+    scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    q = q.reshape(b, s, kv, h // kv, d)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", q, k).astype(jnp.float32) * scale
     mask = jnp.tril(jnp.ones((s, s), dtype=bool))
-    logits = jnp.where(mask[None, None], logits, -1e30)
+    logits = jnp.where(mask[None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    return jnp.einsum("bkgqs,bskd->bqkgd", probs, v).reshape(b, s, h, d)
 
 
 def _row_major(cache: jax.Array) -> jax.Array:
@@ -326,29 +453,42 @@ def _head_major(cache: jax.Array) -> jax.Array:
     return _row_major(cache)
 
 
-def _cached_attention(q, cache_k, cache_v, length):
+def _cached_attention(q, cache_k, cache_v, length, scale=None):
     """q (B, S_q, H, D) against the first ``length`` positions of a
-    head-major cache (B, H, slots, D); q's last position is length-1."""
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqhd,bhkd->bhqk", q, cache_k
-                        ).astype(jnp.float32) * scale
-    s_q = q.shape[1]
-    slots = cache_k.shape[2]
+    head-major cache (B, KV, slots, D); q's last position is length-1.
+    With fewer key/value heads than query heads (grouped-query attention)
+    the H / KV query heads of a group attend the group's one cache as it
+    lies: no copy of it a query head is made."""
+    scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
+    b, s_q, h, d = q.shape
+    kv, slots = cache_k.shape[1:3]
     q_pos = (length - s_q) + jnp.arange(s_q)
     k_pos = jnp.arange(slots)
     mask = q_pos[:, None] >= k_pos[None, :]
-    logits = jnp.where(mask[None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     # A slot the call has not written yet holds whatever its memory held:
     # XLA:TPU drops the zero fill of a cache that it sees written only
     # through a loop (``AllocateBuffer`` in the optimized HLO). Its weight
     # is 0, and 0 × NaN is NaN: such values never reach the sum.
     written = (k_pos < length)[None, None, :, None]
+    if kv != h:
+        with jax.named_scope("gqa_attention"):
+            q = q.reshape(b, s_q, kv, h // kv, d)
+            logits = jnp.einsum("bqkgd,bksd->bkgqs", q, cache_k
+                                ).astype(jnp.float32) * scale
+            logits = jnp.where(mask[None, None, None], logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+            return jnp.einsum("bkgqs,bksd->bqkgd", probs,
+                              jnp.where(written, cache_v, 0)
+                              ).reshape(b, s_q, h, d)
+    logits = jnp.einsum("bqhd,bhkd->bhqk", q, cache_k
+                        ).astype(jnp.float32) * scale
+    logits = jnp.where(mask[None, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bqhd", probs,
                       jnp.where(written, cache_v, 0))
 
 
-def _attend_through_cache(q, k, v, cache: dict, slot: tuple):
+def _attend_through_cache(q, k, v, cache: dict, slot: tuple, scale=None):
     """Write these tokens' keys and values into pass ``t``'s cache from
     position ``start`` on (``slot = (t, start)``), then attend over that
     pass's cache up to themselves: a pass reads no other pass's cache.
@@ -362,7 +502,7 @@ def _attend_through_cache(q, k, v, cache: dict, slot: tuple):
     attn = _cached_attention(
         q, *(jax.lax.dynamic_index_in_dim(updated[name], t, 0,
                                           keepdims=False)
-             for name in ("k", "v")), start + q.shape[1])
+             for name in ("k", "v")), start + q.shape[1], scale)
     return attn, updated
 
 
@@ -468,6 +608,10 @@ def resolve_impls(cfg: ModelConfig, mesh: Optional[Mesh] = None) -> ModelConfig:
     stays single-stream."""
     att, norm = cfg.attention_impl, cfg.norm_impl
     on_tpu = jax.default_backend() == "tpu"
+    if cfg.kv_heads != cfg.n_heads or cfg.attention_scale:
+        # the flash and ring kernels know as many key/value heads as
+        # query heads and the scale 1 / sqrt(head_dim)
+        att = "reference"
     if att == "auto":
         att = "flash" if on_tpu else "reference"
     if norm == "auto":
@@ -518,15 +662,23 @@ def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
     if cfg.attention == "latent":
         attn, cache = _latent_attention(h, blk, positions, cfg, cache, slot)
         return _attention_residual(x, attn, blk, cfg), cache
-    qkv = jnp.einsum("bsd,dthe->tbshe", h,
-                     blk["wqkv"].astype(cfg.compute_dtype))
-    q, k, v = qkv[0], qkv[1], checkpoint_name(qkv[2], "v")
-    q = checkpoint_name(
-        _rope(q, positions, cfg.rope_theta, cfg.rope_pairing), "q_rope")
-    k = checkpoint_name(
-        _rope(k, positions, cfg.rope_theta, cfg.rope_pairing), "k_rope")
+    if "wqkv" in blk:
+        qkv = jnp.einsum("bsd,dthe->tbshe", h,
+                         blk["wqkv"].astype(cfg.compute_dtype))
+        q, k, v = qkv[0], qkv[1], checkpoint_name(qkv[2], "v")
+    else:
+        q = jnp.einsum("bsd,dhe->bshe", h,
+                       blk["wq"].astype(cfg.compute_dtype))
+        k, v = jnp.einsum("bsd,dtke->tbske", h,
+                          blk["wkv"].astype(cfg.compute_dtype))
+    if cfg.position == "rope":
+        q = checkpoint_name(
+            _rope(q, positions, cfg.rope_theta, cfg.rope_pairing), "q_rope")
+        k = checkpoint_name(
+            _rope(k, positions, cfg.rope_theta, cfg.rope_pairing), "k_rope")
+    scale = cfg.score_scale
     if cache is not None:
-        attn, cache = _attend_through_cache(q, k, v, cache, slot)
+        attn, cache = _attend_through_cache(q, k, v, cache, slot, scale)
     elif cfg.attention_impl == "flash":
         from faabric_tpu.ops.flash_attention import flash_attention
 
@@ -540,7 +692,7 @@ def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
         attn = ring_attention(q, k, v, mesh, axis="sp",
                               batch_axis="dp", head_axis="tp")
     else:
-        attn = _attention(q, k, v)
+        attn = _attention(q, k, v, scale)
     return _attention_residual(x, attn, blk, cfg), cache
 
 
@@ -552,6 +704,13 @@ def _attention_residual(x, attn, blk: dict, cfg: ModelConfig) -> jax.Array:
                    blk["wo"].astype(cfg.compute_dtype)), "attn_proj")
     if cfg.norm_placement == "sandwich":
         out = _norm(out, blk["ln1_post"], cfg)
+    return _join(x, out, cfg)
+
+
+def _join(x: jax.Array, out: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """A sub-layer's output onto the residual."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
     return x + out
 
 
@@ -575,10 +734,13 @@ def _feed_forward(h: jax.Array, blk: dict, cfg: ModelConfig) -> jax.Array:
 def _block(x: jax.Array, blk: dict, positions: jax.Array,
            cfg: ModelConfig, mesh: Optional[Mesh] = None,
            cache: Optional[dict] = None,
-           slot: Optional[tuple] = None) -> tuple:
-    """The one transformer block, with or without a KV cache, in the
-    kinds the configuration names. A "single" layer is attention and its
-    feed-forward, ``blk`` their weights and ``cache`` the attention's. A
+           slot: Optional[tuple] = None, kind: str = "attention") -> tuple:
+    """The one transformer block, with or without its state of a call, in
+    the kinds the configuration names. A "single" layer is a mixer of its
+    layer's ``kind`` and a feed-forward, ``blk`` their weights: attention,
+    ``cache`` its keys and values, or the state-space mixer
+    (models/ssm.py), ``cache`` its convolution window and recurrent state,
+    behind the same norm and residual. A
     "shortcut" layer is two of those (``blk["halves"]``) and an expert
     layer that reads the first feed-forward's normed input and joins the
     residual after the second; its cache is ``{"attn": [the two
@@ -586,9 +748,16 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
     so far in this call (models/moe.py:COUNTERS)}``. Returns (x, the
     updated cache or None)."""
     if cfg.layer == "single":
-        x, cache = attention_sublayer(x, blk, positions, cfg, mesh, cache,
-                                      slot)
-        return x + _feed_forward(_norm(x, blk["ln2"], cfg), blk, cfg), cache
+        if kind == "mamba":
+            from faabric_tpu.models.ssm import mixer
+
+            out, cache = mixer(_norm(x, blk["ln1"], cfg), blk, cfg, cache)
+            x = _join(x, out, cfg)
+        else:
+            x, cache = attention_sublayer(x, blk, positions, cfg, mesh,
+                                          cache, slot)
+        return _join(x, _feed_forward(_norm(x, blk["ln2"], cfg), blk, cfg),
+                     cfg), cache
 
     from faabric_tpu.models.moe import expert_layer
 
@@ -601,10 +770,10 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
             branch, counted = expert_layer(h, blk["router"], blk["experts"],
                                            cfg)
         with jax.named_scope("dense_ffn"):
-            x = x + _feed_forward(h, half, cfg)
+            x = _join(x, _feed_forward(h, half, cfg), cfg)
     if cache is not None:
         cache = {"attn": caches, "counters": cache["counters"] + counted}
-    return x + branch, cache
+    return _join(x, branch, cfg), cache
 
 
 def run_passes(x: jax.Array, carry: Any, params: dict, cfg: ModelConfig,
@@ -786,20 +955,25 @@ def forward(params: dict, tokens: jax.Array, cfg: ModelConfig,
 
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    x = embed(params, tokens, cfg)
     x = maybe_constrain(x, "dp", "sp", None)
 
-    block_fns = [_block] * cfg.n_layers
+    # a layer's kind goes in by name: attention's is ``_block`` itself
+    of_kind = {"attention": _block, "mamba": partial(_block, kind="mamba")}
+    block_fns = [of_kind[kind] for kind in cfg.mixers]
     if cfg.remat:
         free = _free_bytes(mesh)
         if free is not None:
             free -= step_bytes(cfg, tokens.shape, mesh)
         kept = remat_plan(cfg, tokens.shape, mesh, free)["layers_kept"]
-        keeping = jax.checkpoint(
-            _block, static_argnums=(3, 4),
+        keeping = {kind: jax.checkpoint(
+            fn, static_argnums=(3, 4),
             policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
-        whole = jax.checkpoint(_block, static_argnums=(3, 4))
-        block_fns = [keeping] * kept + [whole] * (cfg.n_layers - kept)
+            for kind, fn in of_kind.items()}
+        whole = {kind: jax.checkpoint(fn, static_argnums=(3, 4))
+                 for kind, fn in of_kind.items()}
+        block_fns = [(keeping if i < kept else whole)[kind]
+                     for i, kind in enumerate(cfg.mixers)]
 
     def stack(x, carry, _t):
         for block_fn, blk in zip(block_fns, params["blocks"]):
@@ -808,8 +982,29 @@ def forward(params: dict, tokens: jax.Array, cfg: ModelConfig,
         return x, carry
 
     x, _ = run_passes(x, None, params, cfg, stack)
-    logits = x @ params["lm_head"].astype(cfg.compute_dtype)
-    return maybe_constrain(logits.astype(jnp.float32), "dp", "sp", None)
+    return maybe_constrain(head(params, x, cfg), "dp", "sp", None)
+
+
+def embed(params: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """tokens (B, S) → their rows of the table, in the compute type."""
+    x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def head(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The normed state (B, S, D) → float32 logits (B, S, V): through
+    ``lm_head``, or through the embedding table where the head is tied."""
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("bsd,vd->bsv", x,
+                            params["embed"].astype(cfg.compute_dtype))
+    else:
+        logits = x @ params["lm_head"].astype(cfg.compute_dtype)
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def token_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:

@@ -46,6 +46,7 @@ from faabric_tpu.models.transformer import (
     ModelConfig,
     _rms_norm,
     _rope,
+    refuse_served_only,
 )
 from faabric_tpu.parallel.ring_attention import _mark_varying
 
@@ -184,6 +185,7 @@ def _validate_pp_mesh(cfg: ModelConfig, mesh: Mesh) -> int:
             raise ValueError(
                 f"pipeline stages implement {field}={kind!r} only, "
                 f"not {field}={getattr(cfg, field)!r}")
+    refuse_served_only(cfg, "the pipeline")
     n_stages = mesh.shape["pp"]
     if cfg.n_layers % n_stages:
         raise ValueError(

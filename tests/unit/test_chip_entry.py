@@ -92,7 +92,15 @@ def test_chip_smoke_rehearsal_drives_the_whole_flow_on_cpu():
     summary = json.loads(lines[-2])
     phases = summary["phases"]
     assert set(phases) == {"kernels", "train", "decode", "latent_experts",
-                           "gang"}
+                           "state_space", "gang"}
+    hybrid = phases["state_space"]
+    assert max(hybrid["prefill_rel_err"],
+               hybrid["cached_steps_rel_err"]) < 4e-2
+    # one layer of each kind at the toy's widths, 4 rows: keys and values
+    # of 2 heads over 128 slots; a window and a state, whatever the reach
+    assert hybrid["cache_bytes"] == 2 * 4 * 2 * 128 * 16 * 2
+    assert hybrid["state_bytes"] == 4 * (3 * 192 + 8 * 16 * 32) * 2
+    assert hybrid["scan_chunks"] == 2
     latent = phases["latent_experts"]
     assert max(latent["prefill_rel_err"],
                latent["cached_steps_rel_err"]) < 4e-2
