@@ -18,7 +18,10 @@ Guests (user ``smoke``), all on the chips the planner pinned:
 - ``kernels`` — Pallas flash attention fwd and fwd+bwd, and the fused RMS
   norm, against float32 ``jnp`` references at the shapes the model runs
   and, for flash, the benchmark's (4 × 2048 and 1 × 8192 at 16 heads of
-  128), and a compile of both directions at S = 16384;
+  128), and a compile of both directions at S = 16384; the gated
+  feed-forward kernel of a cached step (ops/gated_ffn.py) at the widths
+  of ``benchmarks/configs/granite-4.0-h-micro.json``, 64 rows, against
+  the float32 ``jnp`` lines;
   plus the whole forward (one layer, full width) with the kernels against
   the same forward with the ``jnp`` impls.
 - ``train``   — a gang of one rank per chip; the leader lays the mesh over
@@ -94,6 +97,8 @@ TINY_RUN = dict(seq=128, batch_per_chip=2, train_steps=6, prompt=32,
 TOL_FLASH_FWD = 8e-3
 TOL_FLASH_BWD = 1.2e-2
 TOL_RMS_NORM = 8e-3
+# The activation and the output are rounded to bfloat16 once each
+TOL_GATED_FFN = 8e-3
 TOL_MODEL_LOGITS = 4e-2
 # One double layer in bfloat16 against the float32 reference: its first run
 # on the v5e measured 0.012 (my chip run, PR 31); four layers read 0.02.
@@ -199,6 +204,7 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
         flash_attention,
         uses_kernel as flash_uses_kernel,
     )
+    from faabric_tpu.ops.gated_ffn import gated_ffn, plan as ffn_plan
     from faabric_tpu.ops.rms_norm import (
         _reference_rms_norm,
         rms_norm,
@@ -302,6 +308,37 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
             out["on_kernel_path"]["rms_norm"] = norm_uses_kernel(
                 (1, run["prompt"], cfg.d_model))
 
+            # The feed-forward of a cached step at the widths of the
+            # state-space cell (tests: its toy's), against the jnp lines
+            with open(HYBRID_CONFIG if on_chip else HYBRID_CONFIG_TINY) as f:
+                widths = json.load(f)
+            d_model = int(widths["hidden_size"])
+            d_ff = int(widths["shared_intermediate_size"])
+            rows = 64 if on_chip else 8
+            out["gated_ffn_plan"] = ffn_plan(rows, d_model, d_ff,
+                                             jnp.bfloat16)
+            out["on_kernel_path"]["gated_ffn"] = \
+                out["gated_ffn_plan"] is not None
+            _require(out["on_kernel_path"]["gated_ffn"],
+                     f"gated_ffn refuses {rows} rows of {d_model} × {d_ff}")
+            h, wg, w1, w2 = (
+                jnp.asarray(rng.randn(*shape) / math.sqrt(fan_in),
+                            jnp.bfloat16)
+                for shape, fan_in in (((rows, d_model), 1),
+                                      ((d_model, d_ff), d_model),
+                                      ((d_model, d_ff), d_model),
+                                      ((d_ff, d_model), d_ff)))
+
+            def ref_ffn(h, wg, w1, w2):
+                with jax.default_matmul_precision("highest"):
+                    h, wg, w1, w2 = (t.astype(jnp.float32)
+                                     for t in (h, wg, w1, w2))
+                    return (jax.nn.silu(h @ wg) * (h @ w1)) @ w2
+
+            out["gated_ffn_rel_err"] = _rel_err(
+                jax.jit(gated_ffn)(h, wg, w1, w2),
+                jax.jit(ref_ffn)(h, wg, w1, w2))
+
             # The whole forward at full width, depth cut to one layer:
             # kernels against the jnp impls on the same weights
             one = dataclasses.replace(
@@ -323,6 +360,8 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
         _require(out["flash_fwd_rel_err"] <= TOL_FLASH_FWD, f"flash fwd {out}")
         _require(out["flash_bwd_rel_err"] <= TOL_FLASH_BWD, f"flash bwd {out}")
         _require(out["rms_norm_rel_err"] <= TOL_RMS_NORM, f"rms_norm {out}")
+        _require(out["gated_ffn_rel_err"] <= TOL_GATED_FFN,
+                 f"gated_ffn {out}")
         _require(out["model_logits_rel_err"] <= TOL_MODEL_LOGITS,
                  f"model logits {out}")
         if on_chip:

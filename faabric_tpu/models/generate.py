@@ -48,6 +48,7 @@ from faabric_tpu.models.transformer import (
     resolve_impls,
     run_passes,
     refuse_served_only,
+    streams_feed_forward,
 )
 
 
@@ -90,18 +91,28 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     also ``attention_layers``, ``ssm_layers``, ``state_bytes`` (the
     windows and states of all state-space layers: the same at any reach)
     and ``scan_chunks``, the chunks of the state-space scan a row a layer
-    in prefill. ``generate`` sizes its cache from this; a server reports
-    it beside its answers."""
+    in prefill. ``ffn_streamed_layers`` is the feed-forwards whose cached
+    step goes through the streaming kernel (ops/gated_ffn.py) and
+    ``ffn_streamed_bytes`` what they stream a step, every pass, for a call
+    without a mesh on parameters of ``cfg.param_dtype``
+    (``transformer.streams_feed_forward``); 0 where none does.
+    ``generate`` sizes its cache from this; a server reports it beside
+    its answers."""
     slots = _cache_slots(cfg, prompt_len + n_tokens)
     itemsize = jnp.dtype(cfg.compute_dtype).itemsize
     shortcut = cfg.layer == "shortcut"
     values = sum(math.prod(shape) for shape in
                  _attention_cache_shapes(cfg, batch, slots).values())
     attention_layers = cfg.mixers.count("attention")
+    streamed = streams_feed_forward(cfg, batch, 1, cfg.param_dtype)
+    feed_forwards = (1 + shortcut) * cfg.n_layers if streamed else 0
+    a_pass = streamed["streamed_bytes"] if streamed else 0
     sizes = {
         "cache_slots": slots,
         "cache_bytes": (1 + shortcut) * attention_layers * values * itemsize,
         "ut_passes": cfg.n_passes * (1 + n_tokens),
+        "ffn_streamed_layers": feed_forwards,
+        "ffn_streamed_bytes": feed_forwards * cfg.n_passes * a_pass,
     }
     if shortcut:
         sizes.update(experts_held=cfg.experts_held[1],
@@ -143,13 +154,15 @@ def init_kv_cache(cfg: ModelConfig, batch: int, slots: int) -> list[dict]:
 
 
 def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig,
-                       last_only: bool = False):
+                       last_only: bool = False, mesh=None):
     """tokens (B, S) entering at position ``start`` → (logits (B, S, V),
     new cache); with ``last_only`` the head reads the last position alone,
     logits (B, 1, V): what a server samples from (at a vocabulary of 100k
     the logits of a 64 × 512 prompt are 13 GB). Pass ``t`` of the stack
     writes and attends ``cache[...][t]`` alone; a state-space layer starts
-    from the window and state its cache holds. A step whose depth depends
+    from the window and state its cache holds. ``mesh`` is the one the
+    parameters are laid over, if any: the blocks take their one-chip
+    kernels only without it. A step whose depth depends
     on the data (an exit threshold below 1.0) has no cached path."""
     if cfg.exit_threshold < 1.0:
         raise ValueError(
@@ -164,8 +177,8 @@ def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig,
         new_cache = []
         for blk, layer_cache, kind in zip(params["blocks"], cache,
                                           cfg.mixers):
-            x, updated = _block(x, blk, positions, cfg, cache=layer_cache,
-                                slot=(t, start), kind=kind)
+            x, updated = _block(x, blk, positions, cfg, mesh,
+                                cache=layer_cache, slot=(t, start), kind=kind)
             new_cache.append(updated)
         return x, new_cache
 
@@ -220,7 +233,7 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
         for pos, length in _prefill_chunks(s_p, prefill_chunk):
             logits, cache = forward_with_cache(
                 params, prompt[:, pos:pos + length], cache, pos, cfg,
-                last_only=True)
+                last_only=True, mesh=mesh)
     key, sub = jax.random.split(key)
     next_tok = _pick_token(logits[:, -1], sub, greedy, temperature,
                            top_k, use_top_p, top_p)
@@ -230,7 +243,7 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
         tok, pos, cache, key = carry
         with jax.named_scope("decode_step"):
             logits, cache = forward_with_cache(params, tok[:, None], cache,
-                                               pos, cfg)
+                                               pos, cfg, mesh=mesh)
         key, sub = jax.random.split(key)
         nxt = _pick_token(logits[:, -1], sub, greedy, temperature,
                           top_k, use_top_p, top_p)

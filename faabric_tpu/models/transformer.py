@@ -714,8 +714,38 @@ def _join(x: jax.Array, out: jax.Array, cfg: ModelConfig) -> jax.Array:
     return x + out
 
 
-def _feed_forward(h: jax.Array, blk: dict, cfg: ModelConfig) -> jax.Array:
-    """The feed-forward on a normed state, up to the residual."""
+def streams_feed_forward(cfg: ModelConfig, rows: int, positions: int,
+                         weights_dtype, mesh: Optional[Mesh] = None):
+    """How a cached call's feed-forward of ``rows`` rows × ``positions``
+    streams its matrices through the one kernel (ops/gated_ffn.py:
+    ``gated_ffn.plan``), or None where it runs :func:`_feed_forward`'s own
+    lines: a decode step (one position a row) at a few rows, gated, with
+    no norm behind the down product, on one chip, the matrices lying in
+    the compute type already (a cast would write them out again). Shapes
+    and types alone decide; nothing names a model."""
+    from faabric_tpu.ops import gated_ffn
+
+    if (positions != 1 or mesh is not None or cfg.ffn != "swiglu"
+            or cfg.norm_placement == "sandwich"
+            or jnp.dtype(weights_dtype) != jnp.dtype(cfg.compute_dtype)):
+        return None
+    return gated_ffn.plan(rows, cfg.d_model, cfg.d_ff, cfg.compute_dtype)
+
+
+def _feed_forward(h: jax.Array, blk: dict, cfg: ModelConfig,
+                  streamed: bool = False) -> jax.Array:
+    """The feed-forward on a normed state, up to the residual. ``streamed``
+    says that the call may go through the streaming kernel (a cached call
+    on one chip: no gradient is ever taken there) where
+    :func:`streams_feed_forward` finds its shape."""
+    if streamed:
+        types = {blk[w].dtype for w in ("wg", "w1", "w2") if w in blk}
+        if len(types) == 1 and streams_feed_forward(
+                cfg, *h.shape[:2], types.pop()) is not None:
+            from faabric_tpu.ops.gated_ffn import gated_ffn
+
+            return gated_ffn(h[:, 0], blk["wg"], blk["w1"],
+                             blk["w2"])[:, None]
     ff = checkpoint_name(h @ blk["w1"].astype(cfg.compute_dtype), "ffn_up")
     if cfg.ffn == "swiglu":
         gate = checkpoint_name(h @ blk["wg"].astype(cfg.compute_dtype),
@@ -747,6 +777,7 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
     attentions' caches], "counters": what its expert layer has counted
     so far in this call (models/moe.py:COUNTERS)}``. Returns (x, the
     updated cache or None)."""
+    streamed = cache is not None and mesh is None
     if cfg.layer == "single":
         if kind == "mamba":
             from faabric_tpu.models.ssm import mixer
@@ -756,8 +787,8 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
         else:
             x, cache = attention_sublayer(x, blk, positions, cfg, mesh,
                                           cache, slot)
-        return _join(x, _feed_forward(_norm(x, blk["ln2"], cfg), blk, cfg),
-                     cfg), cache
+        return _join(x, _feed_forward(_norm(x, blk["ln2"], cfg), blk, cfg,
+                                      streamed), cfg), cache
 
     from faabric_tpu.models.moe import expert_layer
 
@@ -770,7 +801,7 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
             branch, counted = expert_layer(h, blk["router"], blk["experts"],
                                            cfg)
         with jax.named_scope("dense_ffn"):
-            x = _join(x, _feed_forward(h, half, cfg), cfg)
+            x = _join(x, _feed_forward(h, half, cfg, streamed), cfg)
     if cache is not None:
         cache = {"attn": caches, "counters": cache["counters"] + counted}
     return _join(x, branch, cfg), cache

@@ -93,6 +93,11 @@ def test_chip_smoke_rehearsal_drives_the_whole_flow_on_cpu():
     phases = summary["phases"]
     assert set(phases) == {"kernels", "train", "decode", "latent_experts",
                            "state_space", "gang"}
+    # the feed-forward kernel at the state-space toy's widths: one tile
+    kernels = phases["kernels"]
+    assert kernels["gated_ffn_rel_err"] < 8e-3
+    assert kernels["gated_ffn_plan"]["tile"] == 96
+    assert kernels["on_kernel_path"]["gated_ffn"] is True
     hybrid = phases["state_space"]
     assert max(hybrid["prefill_rel_err"],
                hybrid["cached_steps_rel_err"]) < 4e-2

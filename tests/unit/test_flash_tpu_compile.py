@@ -1,5 +1,5 @@
-"""The flash kernels compiled by the TPU's own compiler for a described
-v5e, at real widths, without a chip: what the interpreter cannot refuse
+"""The flash kernels and the feed-forward kernel compiled by the TPU's own
+compiler for a described v5e, at real widths, without a chip: what the interpreter cannot refuse
 (a block Mosaic cannot tile, more VMEM than a kernel may take) fails here
 and costs no chip time. Nothing runs, so nothing is said about results
 or speed. All of these stay in this one file: the worker that runs it is
@@ -80,3 +80,33 @@ def test_flash_kernels_compile_for_v5e(call, one_chip, as_on_tpu):
         q, kv, kv).compile().as_text()
     assert forward.count("tpu_custom_call") == 1
     assert both.count("tpu_custom_call") == 3
+
+
+# (rows, d_model, d_ff): the cached steps of serve_granite_1chip and
+# serve_longcat_1chip, and the rule's two edges at the first's widths
+FFN_CALLS = {
+    "serve_granite_1chip": (64, 2048, 8192),
+    "serve_longcat_1chip": (64, 6144, 12288),
+    "rows_8": (8, 2048, 8192),
+    "rows_128": (128, 2048, 8192),
+}
+
+
+@pytest.mark.parametrize("call", sorted(FFN_CALLS))
+def test_gated_ffn_compiles_for_v5e(call, one_chip, as_on_tpu):
+    """The tile :func:`gated_ffn.plan` picks is one Mosaic can cut and the
+    VMEM the call asks for is VMEM a kernel may have."""
+    from faabric_tpu.ops.gated_ffn import gated_ffn, plan
+
+    rows, d_model, d_ff = FFN_CALLS[call]
+    how = plan(rows, d_model, d_ff, jnp.bfloat16)
+    assert how is not None and how["tile"] % 128 == 0
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    compiled = jax.jit(gated_ffn).lower(
+        shaped(rows, d_model), shaped(d_model, d_ff), shaped(d_model, d_ff),
+        shaped(d_ff, d_model)).compile().as_text()
+    assert compiled.count("tpu_custom_call") == 1
+    assert "gated_ffn" in compiled

@@ -270,7 +270,10 @@ def test_call_sizes_and_the_two_kinds_of_state():
         "cache_slots": 640, "cache_bytes": 8192 * 64 * 640,
         "ut_passes": 129, "attention_layers": 4, "ssm_layers": 36,
         "state_bytes": 36 * 64 * (64 * 64 * 128 + 3 * 4352) * 2,
-        "scan_chunks": 2}
+        "scan_chunks": 2,
+        # every layer's feed-forward streams its three matrices a step
+        "ffn_streamed_layers": 40,
+        "ffn_streamed_bytes": 40 * 3 * 2048 * 8192 * 2}
     shapes = jax.eval_shape(lambda: init_kv_cache(cell, 64, 640))
     assert shapes[5]["k"].shape == (1, 64, 8, 640, 64)
     assert shapes[0]["state"].shape == (64, 64, 64, 128)
@@ -338,15 +341,19 @@ def test_the_accepted_configurations_build_what_they_built(name):
     assert cfg.mixers == ("attention",) * cfg.n_layers
     assert cfg.kv_heads == cfg.n_heads
     sized = call_sizes(cfg, 2, 128, 64)
+    # two rows: below the few rows the streaming kernel starts at
+    assert sized["ffn_streamed_layers"] == sized["ffn_streamed_bytes"] == 0
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, 2, 256))
     assert len(cache) == cfg.n_layers
     if name == "longcat-flash-omni":
         assert set(sized) == {"cache_slots", "cache_bytes", "ut_passes",
-                              "experts_held", "router_width"}
+                              "experts_held", "router_width",
+                              "ffn_streamed_layers", "ffn_streamed_bytes"}
         assert cache[0]["attn"][1]["latent"].shape == (1, 2, 256, 576)
         assert sized["cache_bytes"] == 8 * 2 * 256 * 576 * 2
     else:
-        assert set(sized) == {"cache_slots", "cache_bytes", "ut_passes"}
+        assert set(sized) == {"cache_slots", "cache_bytes", "ut_passes",
+                              "ffn_streamed_layers", "ffn_streamed_bytes"}
         assert sorted(cache[0]) == ["k", "v"]
         assert cache[-1]["k"].shape == (cfg.n_passes, 2, 16, 256, 128)
         assert sized["cache_bytes"] == (cfg.n_layers * cfg.n_passes * 2 * 2
@@ -409,3 +416,111 @@ def test_the_new_leaves_have_shardings_and_the_kinds_are_checked():
                 dict(n_kv_heads=3), dict(position="alibi")):
         with pytest.raises(ValueError):
             ModelConfig(**plain, **bad)
+
+
+# ---------------------------------------------------------------------------
+# Which feed-forward streams its matrices through ops/gated_ffn.py
+# ---------------------------------------------------------------------------
+
+def _plain(**other):
+    return ModelConfig(**{**dict(
+        vocab_size=128, d_model=64, n_layers=2, n_heads=4, d_ff=128,
+        max_seq=256, ffn="swiglu", compute_dtype=jnp.float32,
+        param_dtype=jnp.float32, remat=False), **other})
+
+
+def _kernel_calls(fn, *args) -> int:
+    return sum(e.primitive.name == "pallas_call" for e, _ in
+               _walk_jaxpr(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+# name → (what the configuration names, rows, positions a row, the calls a
+# layer that the traced program must hold)
+STREAMED = {
+    "rows_8": ({}, 8, 1, 1),
+    "rows_64": ({}, 64, 1, 1),
+    "rows_128": ({}, 128, 1, 1),
+    "bfloat16_parameters_and_products": (
+        {"compute_dtype": jnp.bfloat16, "param_dtype": jnp.bfloat16},
+        8, 1, 1),
+    "one_row": ({}, 1, 1, 0),
+    "rows_7": ({}, 7, 1, 0),
+    "rows_129": ({}, 129, 1, 0),
+    "a_prefill_chunk": ({}, 8, 16, 0),
+    "a_sandwich_norm": ({"norm_placement": "sandwich"}, 8, 1, 0),
+    "gelu": ({"ffn": "gelu"}, 8, 1, 0),
+    "float32_parameters_under_bfloat16_products": (
+        {"compute_dtype": jnp.bfloat16}, 8, 1, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STREAMED))
+def test_which_cached_call_streams_its_feed_forward(case):
+    """The rule is over what the call can see: the cached path, one
+    position a row, 8 to 128 rows, a gated feed-forward with no norm
+    behind it, the matrices in the compute type. ``call_sizes`` counts
+    what the traced program holds."""
+    named, rows, positions, per_layer = STREAMED[case]
+    cfg = _plain(**named)
+    # every leaf in the parameters' type, as a server's weights lie
+    params = jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, cfg.param_dtype),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, rows, 128))
+    tokens = jax.ShapeDtypeStruct((rows, positions), jnp.int32)
+    held = _kernel_calls(
+        lambda p, t, c: forward_with_cache(p, t, c, 20, cfg),
+        params, tokens, cache)
+    assert held == per_layer * cfg.n_layers
+    assert (transformer.streams_feed_forward(
+        cfg, rows, positions, cfg.param_dtype) is not None) == bool(per_layer)
+    if positions == 1:
+        sized = call_sizes(cfg, rows, 20, 5)
+        assert sized["ffn_streamed_layers"] == per_layer * cfg.n_layers
+        assert sized["ffn_streamed_bytes"] == (
+            per_layer * cfg.n_layers * 3 * 64 * 128
+            * jnp.dtype(cfg.compute_dtype).itemsize)
+
+
+def test_no_kernel_without_a_cache_or_under_a_mesh():
+    """``forward()`` keeps no cache, so a gradient may be taken through
+    it; under a mesh the matrices are laid over chips. Neither streams."""
+    from faabric_tpu.parallel import MeshConfig, build_mesh
+
+    cfg = _plain()
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32)
+    assert _kernel_calls(lambda p, t: forward(p, t, cfg), params, tokens) == 0
+    mesh = build_mesh(jax.devices()[:1], MeshConfig())
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, 8, 128))
+    assert _kernel_calls(
+        lambda p, t, c: forward_with_cache(p, t, c, 20, cfg, mesh=mesh),
+        params, tokens, cache) == 0
+    assert transformer.streams_feed_forward(
+        cfg, 8, 1, jnp.float32, mesh) is None
+    assert transformer.streams_feed_forward(cfg, 8, 1, jnp.float32)
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_the_streamed_step_serves_what_the_plain_lines_serve(rows):
+    """Rows do not mix, and four rows a call are too few for the kernel:
+    the tokens of ``rows`` rows served together, every layer's cached
+    step through the kernel, are those of the same rows served four at a
+    time through the feed-forward's own lines. Both kinds of layer, the
+    residual's multiplier."""
+    sz = sizes()
+    cfg, params = config(sz), weights(sz)
+    prompts = jnp.asarray(ids(rows, 13, index=3))
+    assert call_sizes(cfg, rows, 13, 5)["ffn_streamed_layers"] == 4
+    assert call_sizes(cfg, 4, 13, 5)["ffn_streamed_layers"] == 0
+    jaxpr = jax.make_jaxpr(lambda p, t: generate(p, t, cfg, 5))(
+        params, prompts).jaxpr
+    in_loop = [inside for e, inside in _walk_jaxpr(jaxpr)
+               if e.primitive.name == "pallas_call"]
+    # one a layer, all in the decode loop: prefill holds none
+    assert in_loop == [True] * sz["n_layers"]
+    together = np.asarray(generate(params, prompts, cfg, 5))
+    apart = np.concatenate([
+        np.asarray(generate(params, prompts[at:at + 4], cfg, 5))
+        for at in range(0, rows, 4)])
+    np.testing.assert_array_equal(together, apart)

@@ -368,7 +368,10 @@ def test_call_sizes_at_the_cells_widths():
         cfg = program_longcat.model_config(json.load(f))
     assert call_sizes(cfg, 64, 128, 128) == {
         "cache_slots": 256, "cache_bytes": 9216 * 64 * 256,
-        "ut_passes": 129, "experts_held": 16, "router_width": 768}
+        "ut_passes": 129, "experts_held": 16, "router_width": 768,
+        # the two dense feed-forwards of each of the 4 double layers
+        "ffn_streamed_layers": 8,
+        "ffn_streamed_bytes": 8 * 3 * 6144 * 12288 * 2}
     assert call_sizes(cfg, 1, 1, 127)["cache_bytes"] == 9216 * 128
     shapes = jax.eval_shape(lambda: init_kv_cache(cfg, 64, 256))
     assert len(shapes) == 4

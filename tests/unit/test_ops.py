@@ -275,6 +275,97 @@ def test_model_flash_attention_impl_matches_reference():
 
 
 # ---------------------------------------------------------------------------
+# The gated feed-forward of a few rows
+# ---------------------------------------------------------------------------
+
+# (rows, d_model, d_ff, tile, dtype): the rule's edges and its middle, a
+# d_ff of several tiles and of one, both types
+GATED_FFN_CALLS = {
+    "rows_8_f32": (8, 64, 256, 64, jnp.float32),
+    "rows_64_f32": (64, 64, 256, 64, jnp.float32),
+    "rows_128_f32": (128, 64, 256, 64, jnp.float32),
+    "rows_8_bf16": (8, 64, 256, 64, jnp.bfloat16),
+    "rows_64_bf16": (64, 128, 512, 128, jnp.bfloat16),
+    "rows_128_bf16": (128, 64, 256, 64, jnp.bfloat16),
+    "one_tile_f32": (16, 64, 128, None, jnp.float32),
+    "planned_tile_bf16": (64, 128, 384, None, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("call", sorted(GATED_FFN_CALLS))
+def test_gated_ffn_matches_the_feed_forwards_own_lines(call):
+    """The kernel, interpreted, against ``_feed_forward``'s ``swiglu``
+    lines on the same operands."""
+    from faabric_tpu.models.transformer import ModelConfig, _feed_forward
+    from faabric_tpu.ops.gated_ffn import gated_ffn, plan
+
+    rows, d_model, d_ff, tile, dtype = GATED_FFN_CALLS[call]
+    rng = np.random.RandomState(rows + d_ff)
+    h = jnp.asarray(rng.randn(rows, 1, d_model), dtype)
+    blk = {"wg": jnp.asarray(rng.randn(d_model, d_ff) / np.sqrt(d_model),
+                             dtype),
+           "w1": jnp.asarray(rng.randn(d_model, d_ff) / np.sqrt(d_model),
+                             dtype),
+           "w2": jnp.asarray(rng.randn(d_ff, d_model) / np.sqrt(d_ff), dtype)}
+    cfg = ModelConfig(d_model=d_model, d_ff=d_ff, n_heads=2, ffn="swiglu",
+                      compute_dtype=dtype, param_dtype=dtype)
+    how = plan(rows, d_model, d_ff, dtype, tile)
+    assert how["steps"] * how["tile"] == d_ff
+    if tile is not None:
+        assert how["steps"] == d_ff // tile > 1
+    got = gated_ffn(h[:, 0], blk["wg"], blk["w1"], blk["w2"], tile=tile)
+    want = _feed_forward(h, blk, cfg)[:, 0]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    f32 = dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=2e-5 if f32 else 3e-2, rtol=0 if f32 else 2e-2)
+
+
+def test_gated_ffn_plan_holds_the_two_cells_calls():
+    """``plan`` from shapes alone, as ``block_plan`` for the flash
+    kernels: the cached steps of ``serve_granite_1chip`` (64 × 2048 ×
+    8192) and ``serve_longcat_1chip`` (64 × 6144 × 12288) in bfloat16, the
+    rows it takes, and what it refuses."""
+    from faabric_tpu.ops.gated_ffn import (
+        MAX_ROWS,
+        MIN_ROWS,
+        STEP_BYTES,
+        gated_ffn,
+        plan,
+    )
+
+    granite = plan(64, 2048, 8192, jnp.bfloat16)
+    assert granite == {
+        "tile": 2048, "steps": 4,
+        # h, three tiles and the output twice; the float32 accumulator;
+        # gate, up and activation of a tile in float32
+        "vmem_bytes": 2 * (2 * 64 * 2048 + 3 * 2048 * 2048) * 2
+        + 64 * 2048 * 4 + 3 * 64 * 2048 * 4,
+        "streamed_bytes": 3 * 2048 * 8192 * 2}
+    assert granite["vmem_bytes"] == 53_477_376
+    assert 3 * 2048 * granite["tile"] * 2 == STEP_BYTES
+    longcat = plan(64, 6144, 12288, jnp.bfloat16)
+    assert longcat == {"tile": 512, "steps": 24, "vmem_bytes": 42_860_544,
+                       "streamed_bytes": 3 * 6144 * 12288 * 2}
+    assert 3 * 6144 * 512 * 2 <= STEP_BYTES < 3 * 6144 * 768 * 2
+    # float32 operands: half the columns a step
+    assert plan(64, 2048, 8192, jnp.float32)["tile"] == 1024
+    # a matrix wider than the budget at one lane tile still runs, at 128
+    assert plan(8, 65536, 256, jnp.bfloat16)["tile"] == 128
+    assert (MIN_ROWS, MAX_ROWS) == (8, 128)
+    for rows in (1, 7, 129, 8192):
+        assert plan(rows, 2048, 8192, jnp.bfloat16) is None
+    for rows in (8, 128):
+        assert plan(rows, 2048, 8192, jnp.bfloat16)["tile"] == 2048
+    # a tile that does not divide d_ff is refused, and the call raises
+    assert plan(64, 64, 256, jnp.float32, tile=96) is None
+    with pytest.raises(ValueError, match="gated_ffn does not take"):
+        gated_ffn(jnp.zeros((4, 64)), jnp.zeros((64, 256)),
+                  jnp.zeros((64, 256)), jnp.zeros((256, 64)))
+
+
+# ---------------------------------------------------------------------------
 # RMS norm
 # ---------------------------------------------------------------------------
 
