@@ -38,7 +38,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from faabric_tpu.models import ssm
+from faabric_tpu.models import scopes, ssm
 from faabric_tpu.models.moe import COUNTERS
 from faabric_tpu.models.transformer import (
     ModelConfig,
@@ -183,7 +183,10 @@ def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig,
         return x, new_cache
 
     x, cache = run_passes(x, cache, params, cfg, stack)
-    return head(params, x[:, -1:] if last_only else x, cfg), cache
+    if last_only:
+        with jax.named_scope(scopes.HEAD):
+            x = x[:, -1:]
+    return head(params, x, cfg), cache
 
 
 def _pick_token(logits, key, greedy: bool, temperature, top_k: int,
@@ -224,29 +227,32 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
         cache = [{k: jax.lax.with_sharding_constraint(v, kv_sharding)
                   for k, v in layer.items()} for layer in cache]
 
+    def pick(logits, key):
+        with jax.named_scope(scopes.SAMPLE):
+            key, sub = jax.random.split(key)
+            return key, _pick_token(logits[:, -1], sub, greedy, temperature,
+                                    top_k, use_top_p, top_p)
+
     # Chunked prefill: attention during prefill peaks at (chunk × slots)
     # scores instead of (S_p × slots) — the long-prompt memory bound —
     # and a state-space layer's chunk starts from the state the one
     # before left. Chunk boundaries are static; the head reads the one
     # position that is sampled from.
-    with jax.named_scope("prefill"):
+    with jax.named_scope(scopes.PREFILL):
         for pos, length in _prefill_chunks(s_p, prefill_chunk):
+            with jax.named_scope(scopes.EMBED):
+                chunk = prompt[:, pos:pos + length]
             logits, cache = forward_with_cache(
-                params, prompt[:, pos:pos + length], cache, pos, cfg,
-                last_only=True, mesh=mesh)
-    key, sub = jax.random.split(key)
-    next_tok = _pick_token(logits[:, -1], sub, greedy, temperature,
-                           top_k, use_top_p, top_p)
+                params, chunk, cache, pos, cfg, last_only=True, mesh=mesh)
+        key, next_tok = pick(logits, key)
     counted_in_prefill = _counted(cfg, cache)
 
     def step(carry, _):
         tok, pos, cache, key = carry
-        with jax.named_scope("decode_step"):
+        with jax.named_scope(scopes.DECODE_STEP):
             logits, cache = forward_with_cache(params, tok[:, None], cache,
                                                pos, cfg, mesh=mesh)
-        key, sub = jax.random.split(key)
-        nxt = _pick_token(logits[:, -1], sub, greedy, temperature,
-                          top_k, use_top_p, top_p)
+            key, nxt = pick(logits, key)
         return (nxt, pos + 1, cache, key), tok
 
     (_, _, cache, _), toks = jax.lax.scan(step, (next_tok, s_p, cache, key),

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from faabric_tpu.models import scopes
 from faabric_tpu.models.transformer import (
     ModelConfig,
     _rms_norm,
@@ -179,7 +180,9 @@ def moe_dispatch_combine(x: jax.Array, router: jax.Array, cfg: MoEConfig
 def _moe_layer(x: jax.Array, blk: dict, cfg: MoEConfig,
                mesh: Optional[Mesh]) -> tuple[jax.Array, jax.Array]:
     """x (B, S, D) → (out, aux_loss)."""
-    dispatch, combine_w, aux = moe_dispatch_combine(x, blk["router"], cfg)
+    with jax.named_scope(scopes.ROUTER):
+        dispatch, combine_w, aux = moe_dispatch_combine(x, blk["router"],
+                                                        cfg)
 
     def constrain(arr, *spec):
         if mesh is not None:
@@ -187,20 +190,21 @@ def _moe_layer(x: jax.Array, blk: dict, cfg: MoEConfig,
                 arr, NamedSharding(mesh, P(*spec)))
         return arr
 
-    xf = x.astype(jnp.float32)
-    expert_in = jnp.einsum("bsec,bsd->ebcd", dispatch, xf)
-    # Token buffers shard over ep with the experts → XLA all_to_alls the
-    # tokens to their expert's chips
-    expert_in = constrain(expert_in, "ep", "dp", None, None)
+    with jax.named_scope(scopes.EXPERTS):
+        xf = x.astype(jnp.float32)
+        expert_in = jnp.einsum("bsec,bsd->ebcd", dispatch, xf)
+        # Token buffers shard over ep with the experts → XLA all_to_alls
+        # the tokens to their expert's chips
+        expert_in = constrain(expert_in, "ep", "dp", None, None)
 
-    w1 = blk["w1"].astype(jnp.float32)
-    w2 = blk["w2"].astype(jnp.float32)
-    h = jax.nn.gelu(jnp.einsum("ebcd,edf->ebcf", expert_in, w1))
-    out_e = jnp.einsum("ebcf,efd->ebcd", h, w2)
-    out_e = constrain(out_e, "ep", "dp", None, None)
+        w1 = blk["w1"].astype(jnp.float32)
+        w2 = blk["w2"].astype(jnp.float32)
+        h = jax.nn.gelu(jnp.einsum("ebcd,edf->ebcf", expert_in, w1))
+        out_e = jnp.einsum("ebcf,efd->ebcd", h, w2)
+        out_e = constrain(out_e, "ep", "dp", None, None)
 
-    out = jnp.einsum("bsec,ebcd->bsd", combine_w, out_e)
-    return out.astype(x.dtype), aux.astype(jnp.float32)
+        out = jnp.einsum("bsec,ebcd->bsd", combine_w, out_e)
+        return out.astype(x.dtype), aux.astype(jnp.float32)
 
 
 # A grouped product takes at most this many rows (picks) at once: a longer
@@ -314,16 +318,16 @@ def expert_layer(u: jax.Array, router: dict, experts: dict,
     weights, ``count`` on the leading axis); the rest are zero-compute.
     Static shapes, and no token dropped whatever the routing."""
     b, s, d = u.shape
-    flat = u.reshape(b * s, d)
     first, count = cfg.experts_held
     k = cfg.experts_per_token
-    with jax.named_scope("moe_route"):
+    with jax.named_scope(scopes.ROUTER):
+        flat = u.reshape(b * s, d)
         picks, weights = route(flat, router, cfg)
         is_zero = picks >= cfg.routed_experts
         is_held = (picks >= first) & (picks < first + count)
         local = jnp.where(is_held, picks - first, count)
         zero_weight = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1)
-    with jax.named_scope("moe_experts"):
+    with jax.named_scope(scopes.EXPERTS):
         tokens = b * s
         chunks = -(-tokens * k // _MAX_ROWS)
         bounds = [tokens * i // chunks for i in range(chunks + 1)]
@@ -333,12 +337,12 @@ def expert_layer(u: jax.Array, router: dict, experts: dict,
             for lo, hi in zip(bounds, bounds[1:])))
         m = jnp.concatenate(parts) \
             + zero_weight.astype(u.dtype)[:, None] * flat
-    n_held = jnp.sum(is_held, dtype=jnp.int32)
-    n_zero = jnp.sum(is_zero, dtype=jnp.int32)
-    counters = jnp.stack([n_held, n_zero, tokens * k - n_held - n_zero,
-                          jnp.sum(sum(sizes) > 0, dtype=jnp.int32),
-                          sum(tiles)])
-    return m.reshape(b, s, d), counters
+        n_held = jnp.sum(is_held, dtype=jnp.int32)
+        n_zero = jnp.sum(is_zero, dtype=jnp.int32)
+        counters = jnp.stack([n_held, n_zero, tokens * k - n_held - n_zero,
+                              jnp.sum(sum(sizes) > 0, dtype=jnp.int32),
+                              sum(tiles)])
+        return m.reshape(b, s, d), counters
 
 
 def moe_forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
@@ -355,7 +359,8 @@ def moe_forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
 
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    with jax.named_scope(scopes.EMBED):
+        x = params["embed"].astype(cfg.compute_dtype)[tokens]
     x = constrain(x, "dp", None, None)
 
     # Resolve "auto" kernels + mesh downgrades (flash shard_maps over
@@ -366,15 +371,19 @@ def moe_forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
     aux_total = jnp.zeros((), jnp.float32)
     for blk in params["blocks"]:
         x, _ = attention_sublayer(x, blk, positions, cfg, mesh)
-        h = _rms_norm(x, blk["ln2"], cfg.norm_eps)
+        with jax.named_scope(scopes.EXPERTS):
+            h = _rms_norm(x, blk["ln2"], cfg.norm_eps)
         moe_out, aux = _moe_layer(h, blk, cfg, mesh)
         aux_total = aux_total + aux
-        x = x + moe_out
+        with jax.named_scope(scopes.EXPERTS):
+            x = x + moe_out
         x = constrain(x, "dp", None, None)
 
-    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = (x @ params["lm_head"].astype(cfg.compute_dtype)
-              ).astype(jnp.float32)
+    with jax.named_scope(scopes.FINAL_NORM):
+        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    with jax.named_scope(scopes.HEAD):
+        logits = (x @ params["lm_head"].astype(cfg.compute_dtype)
+                  ).astype(jnp.float32)
     return logits, aux_total / max(1, cfg.n_layers)
 
 
@@ -383,7 +392,9 @@ def moe_loss_fn(params: dict, tokens: jax.Array, targets: jax.Array,
     from faabric_tpu.models.transformer import token_nll
 
     logits, aux = moe_forward(params, tokens, cfg, mesh)
-    return jnp.mean(token_nll(logits, targets)) + cfg.aux_loss_weight * aux
+    with jax.named_scope(scopes.HEAD):
+        return (jnp.mean(token_nll(logits, targets))
+                + cfg.aux_loss_weight * aux)
 
 
 def make_moe_train_step(cfg: MoEConfig, mesh: Optional[Mesh] = None,
@@ -395,10 +406,12 @@ def make_moe_train_step(cfg: MoEConfig, mesh: Optional[Mesh] = None,
     optimizer = optimizer or make_optimizer()
 
     def step(params, opt_state, tokens, targets):
-        loss, grads = jax.value_and_grad(moe_loss_fn)(params, tokens,
-                                                      targets, cfg, mesh)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope(scopes.LOSS):
+            loss, grads = jax.value_and_grad(moe_loss_fn)(
+                params, tokens, targets, cfg, mesh)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return jax.jit(step, donate_argnums=(0, 1))

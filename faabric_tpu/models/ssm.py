@@ -164,35 +164,34 @@ def mixer(h: jax.Array, blk: dict, cfg, cache: Optional[dict] = None
     start = cache if cache is not None else {
         name: jnp.zeros(shape, dtype)
         for name, shape in state_shapes(cfg, bsz).items()}
-    with jax.named_scope("ssm_decode" if s == 1 else "ssm_prefill"):
-        zxbcdt = h @ blk["ssm_in"].astype(dtype)
-        z = zxbcdt[..., :inner]
-        xbc, window = _convolve(start["conv"].astype(dtype),
-                                zxbcdt[..., inner:inner + channels], blk)
-        dt = jax.nn.softplus(zxbcdt[..., inner + channels:].astype(
-            jnp.float32) + blk["dt_bias"].astype(jnp.float32))
-        a = -jnp.exp(blk["A_log"].astype(jnp.float32))
-        x = xbc[..., :inner].reshape(bsz, s, heads, lanes)
-        b = xbc[..., inner:inner + bc].reshape(bsz, s, groups, -1)
-        c = xbc[..., inner + bc:].reshape(bsz, s, groups, -1)
-        if s == 1:
-            per_head = [jnp.repeat(m[:, 0], heads // groups, axis=1)
-                        for m in (b, c)]
-            y, state = _step(x[:, 0], *per_head, dt[:, 0], a, start["state"])
-            y = y[:, None]
-        else:
-            state = start["state"].astype(jnp.float32)
-            ys = []
-            for at in range(0, s, cfg.ssm_chunk):
-                span = slice(at, at + cfg.ssm_chunk)
-                y, state = _chunk(x[:, span], b[:, span], c[:, span],
-                                  dt[:, span], a, state)
-                ys.append(y)
-            y = jnp.concatenate(ys, axis=1)
-        y = y + blk["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-        gated = y.reshape(bsz, s, inner).astype(dtype) * jax.nn.silu(z)
-        out = _rms_norm(gated, blk["ssm_norm"], cfg.norm_eps) \
-            @ blk["ssm_out"].astype(dtype)
+    zxbcdt = h @ blk["ssm_in"].astype(dtype)
+    z = zxbcdt[..., :inner]
+    xbc, window = _convolve(start["conv"].astype(dtype),
+                            zxbcdt[..., inner:inner + channels], blk)
+    dt = jax.nn.softplus(zxbcdt[..., inner + channels:].astype(
+        jnp.float32) + blk["dt_bias"].astype(jnp.float32))
+    a = -jnp.exp(blk["A_log"].astype(jnp.float32))
+    x = xbc[..., :inner].reshape(bsz, s, heads, lanes)
+    b = xbc[..., inner:inner + bc].reshape(bsz, s, groups, -1)
+    c = xbc[..., inner + bc:].reshape(bsz, s, groups, -1)
+    if s == 1:
+        per_head = [jnp.repeat(m[:, 0], heads // groups, axis=1)
+                    for m in (b, c)]
+        y, state = _step(x[:, 0], *per_head, dt[:, 0], a, start["state"])
+        y = y[:, None]
+    else:
+        state = start["state"].astype(jnp.float32)
+        ys = []
+        for at in range(0, s, cfg.ssm_chunk):
+            span = slice(at, at + cfg.ssm_chunk)
+            y, state = _chunk(x[:, span], b[:, span], c[:, span],
+                              dt[:, span], a, state)
+            ys.append(y)
+        y = jnp.concatenate(ys, axis=1)
+    y = y + blk["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    gated = y.reshape(bsz, s, inner).astype(dtype) * jax.nn.silu(z)
+    out = _rms_norm(gated, blk["ssm_norm"], cfg.norm_eps) \
+        @ blk["ssm_out"].astype(dtype)
     if cache is not None:
         cache = {"conv": window, "state": state.astype(dtype)}
     return out, cache

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from faabric_tpu.models import scopes
 from faabric_tpu.models.transformer import (
     ModelConfig,
     init_params,
@@ -66,8 +67,9 @@ def _build_step(cfg: ModelConfig, mesh: Optional[Mesh],
     refuse_served_only(cfg, "the train step")
 
     def grads_of(params, tokens, targets):
-        return jax.value_and_grad(loss_fn)(params, tokens, targets,
-                                           cfg, mesh)
+        with jax.named_scope(scopes.LOSS):
+            return jax.value_and_grad(loss_fn)(params, tokens, targets,
+                                               cfg, mesh)
 
     def step(params, opt_state, tokens, targets):
         if accum_steps > 1:
@@ -97,11 +99,13 @@ def _build_step(cfg: ModelConfig, mesh: Optional[Mesh],
             (loss_sum, g_sum), _ = jax.lax.scan(
                 acc, (jnp.zeros(()), zeros), (tok, tgt))
             loss = loss_sum / accum_steps
-            grads = jax.tree.map(lambda g: g / accum_steps, g_sum)
         else:
             loss, grads = grads_of(params, tokens, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            if accum_steps > 1:
+                grads = jax.tree.map(lambda g: g / accum_steps, g_sum)
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return step

@@ -36,6 +36,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from faabric_tpu.models import scopes
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -471,15 +473,14 @@ def _cached_attention(q, cache_k, cache_v, length, scale=None):
     # is 0, and 0 × NaN is NaN: such values never reach the sum.
     written = (k_pos < length)[None, None, :, None]
     if kv != h:
-        with jax.named_scope("gqa_attention"):
-            q = q.reshape(b, s_q, kv, h // kv, d)
-            logits = jnp.einsum("bqkgd,bksd->bkgqs", q, cache_k
-                                ).astype(jnp.float32) * scale
-            logits = jnp.where(mask[None, None, None], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-            return jnp.einsum("bkgqs,bksd->bqkgd", probs,
-                              jnp.where(written, cache_v, 0)
-                              ).reshape(b, s_q, h, d)
+        q = q.reshape(b, s_q, kv, h // kv, d)
+        logits = jnp.einsum("bqkgd,bksd->bkgqs", q, cache_k
+                            ).astype(jnp.float32) * scale
+        logits = jnp.where(mask[None, None, None], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+        return jnp.einsum("bkgqs,bksd->bqkgd", probs,
+                          jnp.where(written, cache_v, 0)
+                          ).reshape(b, s_q, h, d)
     logits = jnp.einsum("bqhd,bhkd->bhqk", q, cache_k
                         ).astype(jnp.float32) * scale
     logits = jnp.where(mask[None, None], logits, -1e30)
@@ -569,35 +570,34 @@ def _latent_attention(h, blk: dict, positions, cfg: ModelConfig,
     dt = cfg.compute_dtype
     rank, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
     absorbed = cache is not None and not isinstance(slot[1], int)
-    with jax.named_scope("mla_decode" if absorbed else "mla_prefill"):
-        # each bottleneck normed, and scaled to a hidden state's size
-        cq = _rms_norm(h @ blk["wqa"].astype(dt), blk["q_norm"], cfg.norm_eps
-                       ) * float(np.sqrt(cfg.d_model / cfg.q_lora_rank))
-        q = jnp.einsum("bsr,rhe->bshe", cq, blk["wqb"].astype(dt))
-        q_nope = q[..., :nope]
-        q_rope = _rope(q[..., nope:], positions, cfg.rope_theta,
-                       cfg.rope_pairing)
-        kv = h @ blk["wkva"].astype(dt)
-        ckv = _rms_norm(kv[..., :rank], blk["kv_norm"], cfg.norm_eps
-                        ) * float(np.sqrt(cfg.d_model / rank))
-        kr = _rope(kv[:, :, None, rank:], positions, cfg.rope_theta,
-                   cfg.rope_pairing)[:, :, 0]
-        latent = jnp.concatenate([ckv, kr], axis=-1)
-        wkvb = blk["wkvb"].astype(dt)
-        if cache is None:
-            return _latent_expanded(q_nope, q_rope, latent, wkvb, cfg), None
-        t, start = slot
-        # a position's latent one contiguous row, as _head_major pins keys
-        cache = {"latent": _row_major(jax.lax.dynamic_update_slice(
-            cache["latent"], latent[None], (t, 0, start, 0)))}
-        mine = jax.lax.dynamic_index_in_dim(cache["latent"], t, 0,
-                                            keepdims=False)
-        reach = start + h.shape[1]
-        if absorbed:
-            return _latent_absorbed(q_nope, q_rope, mine, reach, wkvb,
-                                    cfg), cache
-        return _latent_expanded(q_nope, q_rope, mine[:, :reach], wkvb,
+    # each bottleneck normed, and scaled to a hidden state's size
+    cq = _rms_norm(h @ blk["wqa"].astype(dt), blk["q_norm"], cfg.norm_eps
+                   ) * float(np.sqrt(cfg.d_model / cfg.q_lora_rank))
+    q = jnp.einsum("bsr,rhe->bshe", cq, blk["wqb"].astype(dt))
+    q_nope = q[..., :nope]
+    q_rope = _rope(q[..., nope:], positions, cfg.rope_theta,
+                   cfg.rope_pairing)
+    kv = h @ blk["wkva"].astype(dt)
+    ckv = _rms_norm(kv[..., :rank], blk["kv_norm"], cfg.norm_eps
+                    ) * float(np.sqrt(cfg.d_model / rank))
+    kr = _rope(kv[:, :, None, rank:], positions, cfg.rope_theta,
+               cfg.rope_pairing)[:, :, 0]
+    latent = jnp.concatenate([ckv, kr], axis=-1)
+    wkvb = blk["wkvb"].astype(dt)
+    if cache is None:
+        return _latent_expanded(q_nope, q_rope, latent, wkvb, cfg), None
+    t, start = slot
+    # a position's latent one contiguous row, as _head_major pins keys
+    cache = {"latent": _row_major(jax.lax.dynamic_update_slice(
+        cache["latent"], latent[None], (t, 0, start, 0)))}
+    mine = jax.lax.dynamic_index_in_dim(cache["latent"], t, 0,
+                                        keepdims=False)
+    reach = start + h.shape[1]
+    if absorbed:
+        return _latent_absorbed(q_nope, q_rope, mine, reach, wkvb,
                                 cfg), cache
+    return _latent_expanded(q_nope, q_rope, mine[:, :reach], wkvb,
+                            cfg), cache
 
 
 def resolve_impls(cfg: ModelConfig, mesh: Optional[Mesh] = None) -> ModelConfig:
@@ -648,6 +648,7 @@ def _sharded_flash(q, k, v, mesh: Mesh):
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
+@jax.named_scope(scopes.ATTENTION)
 def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
                        cfg: ModelConfig, mesh: Optional[Mesh] = None,
                        cache: Optional[dict] = None,
@@ -782,13 +783,16 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
         if kind == "mamba":
             from faabric_tpu.models.ssm import mixer
 
-            out, cache = mixer(_norm(x, blk["ln1"], cfg), blk, cfg, cache)
-            x = _join(x, out, cfg)
+            with jax.named_scope(scopes.MIXER):
+                out, cache = mixer(_norm(x, blk["ln1"], cfg), blk, cfg,
+                                   cache)
+                x = _join(x, out, cfg)
         else:
             x, cache = attention_sublayer(x, blk, positions, cfg, mesh,
                                           cache, slot)
-        return _join(x, _feed_forward(_norm(x, blk["ln2"], cfg), blk, cfg,
-                                      streamed), cfg), cache
+        with jax.named_scope(scopes.FEED_FORWARD):
+            return _join(x, _feed_forward(_norm(x, blk["ln2"], cfg), blk,
+                                          cfg, streamed), cfg), cache
 
     from faabric_tpu.models.moe import expert_layer
 
@@ -796,15 +800,18 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
     for i, half in enumerate(blk["halves"]):
         x, caches[i] = attention_sublayer(x, half, positions, cfg, mesh,
                                           caches[i], slot)
-        h = _norm(x, half["ln2"], cfg)
+        with jax.named_scope(scopes.FEED_FORWARD):
+            h = _norm(x, half["ln2"], cfg)
         if i == 0:
             branch, counted = expert_layer(h, blk["router"], blk["experts"],
                                            cfg)
-        with jax.named_scope("dense_ffn"):
+        with jax.named_scope(scopes.FEED_FORWARD):
             x = _join(x, _feed_forward(h, half, cfg, streamed), cfg)
-    if cache is not None:
-        cache = {"attn": caches, "counters": cache["counters"] + counted}
-    return _join(x, branch, cfg), cache
+    with jax.named_scope(scopes.EXPERTS):
+        if cache is not None:
+            cache = {"attn": caches,
+                     "counters": cache["counters"] + counted}
+        return _join(x, branch, cfg), cache
 
 
 def run_passes(x: jax.Array, carry: Any, params: dict, cfg: ModelConfig,
@@ -818,8 +825,8 @@ def run_passes(x: jax.Array, carry: Any, params: dict, cfg: ModelConfig,
     threshold; at 1.0 every token takes the last pass and the gate
     decides nothing."""
     def one_pass(x, carry, t):
-        with jax.named_scope("ut_pass"):
-            x, carry = stack(x, carry, t)
+        x, carry = stack(x, carry, t)
+        with jax.named_scope(scopes.FINAL_NORM):
             return _norm(x, params["ln_f"], cfg), carry
 
     if cfg.n_passes == 1:
@@ -1016,6 +1023,7 @@ def forward(params: dict, tokens: jax.Array, cfg: ModelConfig,
     return maybe_constrain(head(params, x, cfg), "dp", "sp", None)
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(params: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
     """tokens (B, S) → their rows of the table, in the compute type."""
     x = params["embed"].astype(cfg.compute_dtype)[tokens]
@@ -1024,6 +1032,7 @@ def embed(params: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
     return x
 
 
+@jax.named_scope(scopes.HEAD)
 def head(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """The normed state (B, S, D) → float32 logits (B, S, V): through
     ``lm_head``, or through the embedding table where the head is tied."""
@@ -1047,4 +1056,6 @@ def token_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
 
 def loss_fn(params: dict, tokens: jax.Array, targets: jax.Array,
             cfg: ModelConfig, mesh: Optional[Mesh] = None) -> jax.Array:
-    return jnp.mean(token_nll(forward(params, tokens, cfg, mesh), targets))
+    logits = forward(params, tokens, cfg, mesh)
+    with jax.named_scope(scopes.HEAD):
+        return jnp.mean(token_nll(logits, targets))
