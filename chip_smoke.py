@@ -21,7 +21,9 @@ Guests (user ``smoke``), all on the chips the planner pinned:
   128), and a compile of both directions at S = 16384; the gated
   feed-forward kernel of a cached step (ops/gated_ffn.py) at the widths
   of ``benchmarks/configs/granite-4.0-h-micro.json``, 64 rows, against
-  the float32 ``jnp`` lines;
+  the float32 ``jnp`` lines; the cached step's attention over a dense
+  cache (ops/cached_attention.py) at the same file's heads, 64 rows over
+  640 slots, against the block's own lines in float32;
   plus the whole forward (one layer, full width) with the kernels against
   the same forward with the ``jnp`` impls.
 - ``train``   — a gang of one rank per chip; the leader lays the mesh over
@@ -99,6 +101,8 @@ TOL_FLASH_BWD = 1.2e-2
 TOL_RMS_NORM = 8e-3
 # The activation and the output are rounded to bfloat16 once each
 TOL_GATED_FFN = 8e-3
+# The probabilities and the output are rounded to bfloat16 once each
+TOL_CACHED_ATTENTION = 8e-3
 TOL_MODEL_LOGITS = 4e-2
 # One double layer in bfloat16 against the float32 reference: its first run
 # on the v5e measured 0.012 (my chip run, PR 31); four layers read 0.02.
@@ -203,6 +207,10 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
         _reference_attention,
         flash_attention,
         uses_kernel as flash_uses_kernel,
+    )
+    from faabric_tpu.ops.cached_attention import (
+        cached_attention,
+        plan as attention_plan,
     )
     from faabric_tpu.ops.gated_ffn import gated_ffn, plan as ffn_plan
     from faabric_tpu.ops.rms_norm import (
@@ -339,6 +347,45 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                 jax.jit(gated_ffn)(h, wg, w1, w2),
                 jax.jit(ref_ffn)(h, wg, w1, w2))
 
+            # That cell's cached attention over a dense cache (tests: a
+            # toy's), unwritten slots holding NaN, against the block's
+            # own lines in float32 over the same values head-major
+            heads, kv = ((int(widths["num_attention_heads"]),
+                          int(widths["num_key_value_heads"]))
+                         if on_chip else (4, 2))
+            e, slots = (d_model // heads, 640) if on_chip else (64, 128)
+            reach = slots - 40
+            out["cached_attention_plan"] = attention_plan(
+                rows, heads, kv, slots, e, jnp.bfloat16)
+            out["on_kernel_path"]["cached_attention"] = \
+                out["cached_attention_plan"] is not None
+            _require(out["on_kernel_path"]["cached_attention"],
+                     f"cached_attention refuses {rows} rows of {heads} on "
+                     f"{kv} × {e} over {slots}")
+            q1 = jnp.asarray(rng.randn(rows, heads, e), jnp.bfloat16)
+            unwritten = (np.arange(slots) >= reach)[None, None, :, None]
+            dense = [jnp.asarray(np.where(
+                unwritten, np.nan, rng.randn(1, rows, slots, kv * e)),
+                jnp.bfloat16) for _ in range(2)]
+
+            def ref_cached(q1, keys, values):
+                from faabric_tpu.models.transformer import _cached_attention
+
+                with jax.default_matmul_precision("highest"):
+                    head_major = [
+                        c[0].reshape(rows, slots, kv, e).transpose(
+                            0, 2, 1, 3).astype(jnp.float32)
+                        for c in (keys, values)]
+                    return _cached_attention(
+                        q1[:, None].astype(jnp.float32), *head_major,
+                        reach, 1.0 / e)[:, 0]
+
+            out["cached_attention_rel_err"] = _rel_err(
+                jax.jit(lambda q1, keys, values: cached_attention(
+                    q1, keys, values, jnp.int32(reach), 1.0 / e))(
+                    q1, *dense),
+                jax.jit(ref_cached)(q1, *dense))
+
             # The whole forward at full width, depth cut to one layer:
             # kernels against the jnp impls on the same weights
             one = dataclasses.replace(
@@ -362,6 +409,8 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
         _require(out["rms_norm_rel_err"] <= TOL_RMS_NORM, f"rms_norm {out}")
         _require(out["gated_ffn_rel_err"] <= TOL_GATED_FFN,
                  f"gated_ffn {out}")
+        _require(out["cached_attention_rel_err"] <= TOL_CACHED_ATTENTION,
+                 f"cached_attention {out}")
         _require(out["model_logits_rel_err"] <= TOL_MODEL_LOGITS,
                  f"model logits {out}")
         if on_chip:

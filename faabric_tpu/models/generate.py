@@ -6,7 +6,12 @@ to ``_SLOT_MULTIPLE`` and never above ``max_seq``), so a decode step
 attends over the call's own reach and not over ``max_seq``. Each layer
 has one cache a pass of the stack, ``(passes, batch, heads, slots,
 head_dim)``, head-major and pinned so in memory: one head's keys are one
-contiguous ``(slots, head_dim)`` tile array. It is filled with
+contiguous ``(slots, head_dim)`` tile array. Where a call's steps take
+many rows (``transformer.streams_attention``) the cache lies dense
+instead, ``(passes, batch, slots, heads · head_dim)``, a position's keys
+one row of whole lanes whatever the head's width, and a step attends it
+through one kernel a layer (ops/cached_attention.py); prefill attends the
+same cache through a view. It is filled with
 ``lax.dynamic_update_slice``; attention masks by position, so prefill
 and every decode step compile once each. The whole greedy loop is one
 ``lax.scan`` under jit — no host round-trips between tokens, which is
@@ -48,6 +53,7 @@ from faabric_tpu.models.transformer import (
     resolve_impls,
     run_passes,
     refuse_served_only,
+    streams_attention,
     streams_feed_forward,
 )
 
@@ -63,12 +69,18 @@ def _cache_slots(cfg: ModelConfig, reach: int) -> int:
     return min(rounded, cfg.max_seq)
 
 
-def _attention_cache_shapes(cfg: ModelConfig, batch: int, slots: int) -> dict:
-    """The arrays one attention's cache is made of, by its kind."""
+def _attention_cache_shapes(cfg: ModelConfig, batch: int, slots: int,
+                            mesh=None) -> dict:
+    """The arrays one attention's cache is made of, by its kind. Keys and
+    values lie head-major, or dense (a position's key/value heads one row)
+    where the call's steps attend them through the kernel
+    (``transformer.streams_attention``)."""
     if cfg.attention == "latent":
         return {"latent": (cfg.n_passes, batch, slots,
                            cfg.kv_lora_rank + cfg.qk_rope_dim)}
     shape = (cfg.n_passes, batch, cfg.kv_heads, slots, cfg.head_dim)
+    if streams_attention(cfg, batch, 1, slots, cfg.compute_dtype, mesh):
+        shape = (cfg.n_passes, batch, slots, cfg.kv_heads * cfg.head_dim)
     return {"k": shape, "v": shape}
 
 
@@ -96,6 +108,11 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     ``ffn_streamed_bytes`` what they stream a step, every pass, for a call
     without a mesh on parameters of ``cfg.param_dtype``
     (``transformer.streams_feed_forward``); 0 where none does.
+    ``attention_streamed_layers`` is the attentions whose cached step
+    reads a dense cache through its kernel (ops/cached_attention.py) and
+    ``attention_streamed_bytes`` the keys and values they stream a step,
+    every pass, for a call without a mesh
+    (``transformer.streams_attention``); 0 where none does.
     ``generate`` sizes its cache from this; a server reports it beside
     its answers."""
     slots = _cache_slots(cfg, prompt_len + n_tokens)
@@ -107,12 +124,17 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     streamed = streams_feed_forward(cfg, batch, 1, cfg.param_dtype)
     feed_forwards = (1 + shortcut) * cfg.n_layers if streamed else 0
     a_pass = streamed["streamed_bytes"] if streamed else 0
+    attended = streams_attention(cfg, batch, 1, slots, cfg.compute_dtype)
+    attentions = (1 + shortcut) * attention_layers if attended else 0
     sizes = {
         "cache_slots": slots,
         "cache_bytes": (1 + shortcut) * attention_layers * values * itemsize,
         "ut_passes": cfg.n_passes * (1 + n_tokens),
         "ffn_streamed_layers": feed_forwards,
         "ffn_streamed_bytes": feed_forwards * cfg.n_passes * a_pass,
+        "attention_streamed_layers": attentions,
+        "attention_streamed_bytes": attentions * cfg.n_passes
+        * (attended["streamed_bytes"] if attended else 0),
     }
     if shortcut:
         sizes.update(experts_held=cfg.experts_held[1],
@@ -130,10 +152,14 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     return sizes
 
 
-def init_kv_cache(cfg: ModelConfig, batch: int, slots: int) -> list[dict]:
+def init_kv_cache(cfg: ModelConfig, batch: int, slots: int,
+                  mesh=None) -> list[dict]:
     """Zeroed per-layer state of a call, by the layer's kind. Per-head
     attention: keys and values, one cache a pass, head-major: (passes,
-    batch, key/value heads, slots, head_dim). Latent attention: (passes,
+    batch, key/value heads, slots, head_dim); where a step of ``batch``
+    rows attends through the kernel (``transformer.streams_attention``:
+    never under ``mesh``), dense: (passes, batch, slots, key/value heads ·
+    head_dim). Latent attention: (passes,
     batch, slots, kv_lora_rank + qk_rope_dim), once. A "shortcut" layer:
     its two attentions' caches and its expert layer's counters
     (``transformer._block``). A "mamba" layer: its convolution window and
@@ -143,7 +169,7 @@ def init_kv_cache(cfg: ModelConfig, batch: int, slots: int) -> list[dict]:
                 for name, shape in shapes.items()}
 
     def attention():
-        return zeros(_attention_cache_shapes(cfg, batch, slots))
+        return zeros(_attention_cache_shapes(cfg, batch, slots, mesh))
 
     if cfg.layer == "shortcut":
         return [{"attn": [attention(), attention()],
@@ -221,7 +247,7 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
 
     b, s_p = prompt.shape
     cache = init_kv_cache(
-        cfg, b, call_sizes(cfg, b, s_p, n_tokens)["cache_slots"])
+        cfg, b, call_sizes(cfg, b, s_p, n_tokens)["cache_slots"], mesh)
     if mesh is not None:
         kv_sharding = NamedSharding(mesh, P(None, "dp", "tp", None, None))
         cache = [{k: jax.lax.with_sharding_constraint(v, kv_sharding)

@@ -455,15 +455,19 @@ def _head_major(cache: jax.Array) -> jax.Array:
     return _row_major(cache)
 
 
-def _cached_attention(q, cache_k, cache_v, length, scale=None):
+def _cached_attention(q, cache_k, cache_v, length, scale=None,
+                      position_major: bool = False):
     """q (B, S_q, H, D) against the first ``length`` positions of a
-    head-major cache (B, KV, slots, D); q's last position is length-1.
+    head-major cache (B, KV, slots, D), or with ``position_major`` of a
+    (B, slots, KV, D) view of a dense one; q's last position is length-1.
     With fewer key/value heads than query heads (grouped-query attention)
     the H / KV query heads of a group attend the group's one cache as it
     lies: no copy of it a query head is made."""
     scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
     b, s_q, h, d = q.shape
     kv, slots = cache_k.shape[1:3]
+    if position_major:
+        kv, slots = slots, kv
     q_pos = (length - s_q) + jnp.arange(s_q)
     k_pos = jnp.arange(slots)
     mask = q_pos[:, None] >= k_pos[None, :]
@@ -471,40 +475,86 @@ def _cached_attention(q, cache_k, cache_v, length, scale=None):
     # XLA:TPU drops the zero fill of a cache that it sees written only
     # through a loop (``AllocateBuffer`` in the optimized HLO). Its weight
     # is 0, and 0 × NaN is NaN: such values never reach the sum.
-    written = (k_pos < length)[None, None, :, None]
+    written = ((k_pos < length)[None, :, None, None] if position_major
+               else (k_pos < length)[None, None, :, None])
     if kv != h:
+        cached = "bskd" if position_major else "bksd"
         q = q.reshape(b, s_q, kv, h // kv, d)
-        logits = jnp.einsum("bqkgd,bksd->bkgqs", q, cache_k
+        logits = jnp.einsum(f"bqkgd,{cached}->bkgqs", q, cache_k
                             ).astype(jnp.float32) * scale
         logits = jnp.where(mask[None, None, None], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        return jnp.einsum("bkgqs,bksd->bqkgd", probs,
+        return jnp.einsum(f"bkgqs,{cached}->bqkgd", probs,
                           jnp.where(written, cache_v, 0)
                           ).reshape(b, s_q, h, d)
-    logits = jnp.einsum("bqhd,bhkd->bhqk", q, cache_k
+    cached = "bkhd" if position_major else "bhkd"
+    logits = jnp.einsum(f"bqhd,{cached}->bhqk", q, cache_k
                         ).astype(jnp.float32) * scale
     logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bqhd", probs,
+    return jnp.einsum(f"bhqk,{cached}->bqhd", probs,
                       jnp.where(written, cache_v, 0))
 
 
-def _attend_through_cache(q, k, v, cache: dict, slot: tuple, scale=None):
+def streams_attention(cfg: ModelConfig, rows: int, positions: int,
+                      slots: int, cache_dtype,
+                      mesh: Optional[Mesh] = None):
+    """How a cached call of ``rows`` rows × ``positions`` attends caches
+    of ``slots`` slots through the one kernel over a dense cache
+    (ops/cached_attention.py: ``cached_attention.plan``), or None where
+    the cache lies head-major under :func:`_cached_attention`'s own
+    lines: a decode step (one position a row) of 8 rows or more,
+    attention over heads, on one chip, the cache in the compute type, a
+    position's keys whole lanes, a row's reach within the plan's VMEM.
+    ``generate`` lays a call's caches by it (a call decodes one position
+    a step), the block takes the kernel by it and ``call_sizes`` counts
+    by it. Shapes and types alone decide; nothing names a model."""
+    from faabric_tpu.ops import cached_attention
+
+    if (positions != 1 or mesh is not None or cfg.attention != "heads"
+            or jnp.dtype(cache_dtype) != jnp.dtype(cfg.compute_dtype)):
+        return None
+    return cached_attention.plan(rows, cfg.n_heads, cfg.kv_heads, slots,
+                                 cfg.head_dim, cfg.compute_dtype)
+
+
+def _attend_through_cache(q, k, v, cache: dict, slot: tuple, scale=None,
+                          streamed: bool = False):
     """Write these tokens' keys and values into pass ``t``'s cache from
     position ``start`` on (``slot = (t, start)``), then attend over that
     pass's cache up to themselves: a pass reads no other pass's cache.
+    The cache's own shape says how it lies: head-major (passes, B, KV,
+    slots, D), or dense (passes, B, slots, KV · D) where
+    :func:`streams_attention` laid it so. ``streamed`` says that the call
+    over a dense cache is a step :func:`streams_attention` finds: it goes
+    through the kernel; any other call over a dense cache (prefill)
+    through :func:`_cached_attention` over a (B, slots, KV, D) view.
     Returns (attention, the updated cache)."""
     t, start = slot
-    updated = {
-        name: _head_major(jax.lax.dynamic_update_slice(
-            cache[name], new.transpose(0, 2, 1, 3)[None],
-            (t, 0, 0, start, 0)))
-        for name, new in (("k", k), ("v", v))}
-    attn = _cached_attention(
-        q, *(jax.lax.dynamic_index_in_dim(updated[name], t, 0,
-                                          keepdims=False)
-             for name in ("k", "v")), start + q.shape[1], scale)
-    return attn, updated
+    b, s_q, _, d = q.shape
+    dense = cache["k"].ndim == 4
+
+    def write(old, new):
+        if dense:
+            return _row_major(jax.lax.dynamic_update_slice(
+                old, new.reshape(1, b, s_q, -1), (t, 0, start, 0)))
+        return _head_major(jax.lax.dynamic_update_slice(
+            old, new.transpose(0, 2, 1, 3)[None], (t, 0, 0, start, 0)))
+
+    updated = {name: write(cache[name], new)
+               for name, new in (("k", k), ("v", v))}
+    if streamed:
+        from faabric_tpu.ops.cached_attention import cached_attention
+
+        scale = 1.0 / np.sqrt(d) if scale is None else scale
+        return cached_attention(q[:, 0], updated["k"], updated["v"],
+                                start + 1, scale, t)[:, None], updated
+    mine = [jax.lax.dynamic_index_in_dim(updated[name], t, 0, keepdims=False)
+            for name in ("k", "v")]
+    if dense:
+        mine = [one.reshape(b, -1, k.shape[2], d) for one in mine]
+    return _cached_attention(q, *mine, start + s_q, scale,
+                             position_major=dense), updated
 
 
 def _causal_softmax(logits: jax.Array, reach: Any, dtype) -> jax.Array:
@@ -679,7 +729,11 @@ def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
             _rope(k, positions, cfg.rope_theta, cfg.rope_pairing), "k_rope")
     scale = cfg.score_scale
     if cache is not None:
-        attn, cache = _attend_through_cache(q, k, v, cache, slot, scale)
+        streamed = cache["k"].ndim == 4 and streams_attention(
+            cfg, *q.shape[:2], cache["k"].shape[2], cache["k"].dtype,
+            mesh) is not None
+        attn, cache = _attend_through_cache(q, k, v, cache, slot, scale,
+                                            streamed)
     elif cfg.attention_impl == "flash":
         from faabric_tpu.ops.flash_attention import flash_attention
 

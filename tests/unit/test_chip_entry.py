@@ -98,6 +98,10 @@ def test_chip_smoke_rehearsal_drives_the_whole_flow_on_cpu():
     assert kernels["gated_ffn_rel_err"] < 8e-3
     assert kernels["gated_ffn_plan"]["tile"] == 96
     assert kernels["on_kernel_path"]["gated_ffn"] is True
+    # the cached-attention kernel at a toy's heads: 4 on 2 of 64 lanes
+    assert kernels["cached_attention_rel_err"] < 8e-3
+    assert kernels["cached_attention_plan"]["steps"] == 1
+    assert kernels["on_kernel_path"]["cached_attention"] is True
     hybrid = phases["state_space"]
     assert max(hybrid["prefill_rel_err"],
                hybrid["cached_steps_rel_err"]) < 4e-2

@@ -1,4 +1,5 @@
-"""The flash kernels and the feed-forward kernel compiled by the TPU's own
+"""The flash kernels, the feed-forward kernel and the cached-attention
+kernel compiled by the TPU's own
 compiler for a described v5e, at real widths, without a chip: what the interpreter cannot refuse
 (a block Mosaic cannot tile, more VMEM than a kernel may take) fails here
 and costs no chip time. Nothing runs, so nothing is said about results
@@ -110,3 +111,78 @@ def test_gated_ffn_compiles_for_v5e(call, one_chip, as_on_tpu):
         shaped(d_ff, d_model)).compile().as_text()
     assert compiled.count("tpu_custom_call") == 1
     assert "gated_ffn" in compiled
+
+
+# (rows, heads, key/value heads, slots, head_dim): the cached step of
+# serve_granite_1chip, the rule's lower edge at its widths, equal heads of
+# 128 lanes over the longest reach a grid step holds
+ATTENTION_CALLS = {
+    "serve_granite_1chip": (64, 32, 8, 640, 64),
+    "rows_8": (8, 32, 8, 640, 64),
+    "equal_heads_of_128": (16, 16, 16, 512, 128),
+}
+
+
+@pytest.mark.parametrize("call", sorted(ATTENTION_CALLS))
+def test_cached_attention_compiles_for_v5e(call, one_chip, as_on_tpu):
+    """The blocks :func:`cached_attention.plan` picks are blocks Mosaic
+    can cut, the VMEM the call asks for is VMEM a kernel may have, and the
+    caches are held to HBM (no copy of a whole cache into VMEM ahead)."""
+    from faabric_tpu.ops.cached_attention import cached_attention, plan
+
+    rows, heads, kv, slots, d = ATTENTION_CALLS[call]
+    assert plan(rows, heads, kv, slots, d, jnp.bfloat16) is not None
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, n: cached_attention(q, k, v, n, 1 / 64)).lower(
+        shaped(rows, heads, d), shaped(1, rows, slots, kv * d),
+        shaped(1, rows, slots, kv * d), shaped(dtype=jnp.int32)
+    ).compile().as_text()
+    assert compiled.count("tpu_custom_call") == 1
+    assert "cached_attention" in compiled
+    assert "input_memory_space_colors" in compiled
+
+
+def test_a_dense_cache_lies_at_its_values_bytes(one_chip, as_on_tpu):
+    """Two cached steps of the cell's shape over caches the program makes
+    itself, compiled: dense, both caches lie in HBM at their 41.9 MB;
+    head-major and pinned row-major, a head of 64 lanes is padded to the
+    tile's 128: XLA holds one of the two in VMEM whole (nothing holds it
+    to HBM) and the one left in HBM takes 83.9 MB, both dense ones'
+    bytes."""
+    from faabric_tpu.models import transformer
+
+    rows, heads, kv, slots, d = ATTENTION_CALLS["serve_granite_1chip"]
+    values = rows * slots * kv * d * 2
+
+    def steps(cache_shape, streamed):
+        def run(q, k, v):
+            def step(carry, _):
+                cache, pos = carry
+                attn, cache = transformer._attend_through_cache(
+                    q, k, v, cache, (0, pos), 1 / 64, streamed)
+                return (cache, pos + 1), attn
+            cache = {name: jnp.zeros(cache_shape, jnp.bfloat16)
+                     for name in ("k", "v")}
+            return jax.lax.scan(step, (cache, jnp.int32(600)), None,
+                                length=2)[1]
+        arg = jax.ShapeDtypeStruct((rows, 1, heads, d), jnp.bfloat16,
+                                   sharding=one_chip)
+        new = jax.ShapeDtypeStruct((rows, 1, kv, d), jnp.bfloat16,
+                                   sharding=one_chip)
+        compiled = jax.jit(run).lower(arg, new, new).compile()
+        return compiled.memory_analysis().temp_size_in_bytes, \
+            compiled.as_text()
+
+    dense, text = steps((1, rows, slots, kv * d), True)
+    assert text.count("tpu_custom_call") == 1
+    assert values == 41_943_040
+    assert 2 * values <= dense < 2 * values + 1024 * 1024
+    assert "bf16[1,64,640,512]{3,2,1,0:T(8,128)(2,1)S(1)}" not in text
+    padded, text = steps((1, rows, kv, slots, d), False)
+    assert "tpu_custom_call" not in text
+    assert "bf16[1,64,8,640,64]{4,3,2,1,0:T(8,128)(2,1)S(1)}" in text
+    assert 2 * values <= padded < 2 * values + 1024 * 1024

@@ -273,9 +273,16 @@ def test_call_sizes_and_the_two_kinds_of_state():
         "scan_chunks": 2,
         # every layer's feed-forward streams its three matrices a step
         "ffn_streamed_layers": 40,
-        "ffn_streamed_bytes": 40 * 3 * 2048 * 8192 * 2}
+        "ffn_streamed_bytes": 40 * 3 * 2048 * 8192 * 2,
+        # and the four attentions stream their dense keys and values
+        "attention_streamed_layers": 4,
+        "attention_streamed_bytes": 4 * 2 * 64 * 640 * 512 * 2}
     shapes = jax.eval_shape(lambda: init_kv_cache(cell, 64, 640))
-    assert shapes[5]["k"].shape == (1, 64, 8, 640, 64)
+    # 64 rows: dense, a position's 8 heads of 64 one row of 512 lanes
+    assert shapes[5]["k"].shape == (1, 64, 640, 512)
+    # a row alone: head-major under the block's own lines
+    assert jax.eval_shape(lambda: init_kv_cache(cell, 1, 640)
+                          )[5]["k"].shape == (1, 1, 8, 640, 64)
     assert shapes[0]["state"].shape == (64, 64, 64, 128)
     assert shapes[0]["state"].dtype == jnp.bfloat16
     assert shapes[0]["conv"].shape == (64, 3, 4352)
@@ -343,17 +350,24 @@ def test_the_accepted_configurations_build_what_they_built(name):
     sized = call_sizes(cfg, 2, 128, 64)
     # two rows: below the few rows the streaming kernel starts at
     assert sized["ffn_streamed_layers"] == sized["ffn_streamed_bytes"] == 0
+    # and below the rows at which a step attends a dense cache
+    assert (sized["attention_streamed_layers"]
+            == sized["attention_streamed_bytes"] == 0)
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, 2, 256))
     assert len(cache) == cfg.n_layers
     if name == "longcat-flash-omni":
         assert set(sized) == {"cache_slots", "cache_bytes", "ut_passes",
                               "experts_held", "router_width",
-                              "ffn_streamed_layers", "ffn_streamed_bytes"}
+                              "ffn_streamed_layers", "ffn_streamed_bytes",
+                              "attention_streamed_layers",
+                              "attention_streamed_bytes"}
         assert cache[0]["attn"][1]["latent"].shape == (1, 2, 256, 576)
         assert sized["cache_bytes"] == 8 * 2 * 256 * 576 * 2
     else:
         assert set(sized) == {"cache_slots", "cache_bytes", "ut_passes",
-                              "ffn_streamed_layers", "ffn_streamed_bytes"}
+                              "ffn_streamed_layers", "ffn_streamed_bytes",
+                              "attention_streamed_layers",
+                              "attention_streamed_bytes"}
         assert sorted(cache[0]) == ["k", "v"]
         assert cache[-1]["k"].shape == (cfg.n_passes, 2, 16, 256, 128)
         assert sized["cache_bytes"] == (cfg.n_layers * cfg.n_passes * 2 * 2

@@ -371,7 +371,9 @@ def test_call_sizes_at_the_cells_widths():
         "ut_passes": 129, "experts_held": 16, "router_width": 768,
         # the two dense feed-forwards of each of the 4 double layers
         "ffn_streamed_layers": 8,
-        "ffn_streamed_bytes": 8 * 3 * 6144 * 12288 * 2}
+        "ffn_streamed_bytes": 8 * 3 * 6144 * 12288 * 2,
+        # latent caches are attended as they lie, by no kernel
+        "attention_streamed_layers": 0, "attention_streamed_bytes": 0}
     assert call_sizes(cfg, 1, 1, 127)["cache_bytes"] == 9216 * 128
     shapes = jax.eval_shape(lambda: init_kv_cache(cfg, 64, 256))
     assert len(shapes) == 4

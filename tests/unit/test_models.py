@@ -691,3 +691,105 @@ def test_kept_names_leave_generate_as_it_was(monkeypatch):
     # but for the counter in the names of jnp's private functions
     numbered = re.compile(r"(@_?[a-z_]+?)_\d+\b")
     assert numbered.sub(r"\1", with_names) == numbered.sub(r"\1", without)
+
+
+# ---------------------------------------------------------------------------
+# Which cached call attends a dense cache through ops/cached_attention.py
+# ---------------------------------------------------------------------------
+
+def _grouped(**other):
+    """4 query heads on 2 key/value heads of 64 lanes: a position's keys
+    are one row of 128 lanes."""
+    return ModelConfig(**{**dict(
+        vocab_size=96, d_model=256, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=128, max_seq=256, ffn="swiglu", compute_dtype=jnp.float32,
+        param_dtype=jnp.float32, remat=False), **other})
+
+
+# name → (what the configuration names, rows, positions a row, the cache's
+# type or None for the compute type, under a mesh, the plan found)
+ATTENDED = {
+    "rows_8": ({}, 8, 1, None, False, True),
+    "rows_64": ({}, 64, 1, None, False, True),
+    "equal_heads_of_128": ({"n_heads": 2, "n_kv_heads": 0}, 8, 1, None,
+                           False, True),
+    "bfloat16": ({"compute_dtype": jnp.bfloat16}, 8, 1, None, False, True),
+    "rotary_positions": ({"position": "rope"}, 8, 1, None, False, True),
+    "one_row": ({}, 1, 1, None, False, False),
+    "rows_7": ({}, 7, 1, None, False, False),
+    "a_prefill_chunk": ({}, 8, 16, None, False, False),
+    "latent_attention": (
+        {"attention": "latent", "n_kv_heads": 0, "q_lora_rank": 32,
+         "kv_lora_rank": 64, "qk_nope_dim": 32, "qk_rope_dim": 64,
+         "v_head_dim": 64}, 8, 1, None, False, False),
+    "a_mesh": ({}, 8, 1, None, True, False),
+    "a_float32_cache_under_bfloat16_products": (
+        {"compute_dtype": jnp.bfloat16}, 8, 1, jnp.float32, False, False),
+    "keys_of_96_lanes_a_position": ({"d_model": 192}, 8, 1, None, False,
+                                    False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ATTENDED))
+def test_which_cached_call_attends_a_dense_cache(case):
+    """The rule is over what the call can see: one position a row, 8 rows
+    or more, attention over heads, no mesh, the cache in the compute type,
+    a position's keys whole lanes. The cache's layout, the kernel in the
+    traced step and ``call_sizes`` follow it alike."""
+    from faabric_tpu.models import init_kv_cache, transformer
+    from faabric_tpu.models.generate import call_sizes, forward_with_cache
+
+    named, rows, positions, cache_dtype, meshed, found = ATTENDED[case]
+    cfg = _grouped(**named)
+    mesh = build_mesh(jax.devices()[:1], MeshConfig()) if meshed else None
+    slots = 128
+    how = transformer.streams_attention(
+        cfg, rows, positions, slots, cache_dtype or cfg.compute_dtype, mesh)
+    assert (how is not None) == found
+    if cfg.attention == "latent" or cache_dtype is not None:
+        return
+    # the layout is the call's (one position a step), whatever this
+    # forward's positions are
+    dense = transformer.streams_attention(
+        cfg, rows, 1, slots, cfg.compute_dtype, mesh) is not None
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, rows, slots, mesh))
+    width = cfg.kv_heads * cfg.head_dim
+    assert cache[0]["k"].shape == (
+        (1, rows, slots, width) if dense
+        else (1, rows, cfg.kv_heads, slots, cfg.head_dim))
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((rows, positions), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p, t, c: forward_with_cache(
+        p, t, c, 20, cfg, mesh=mesh))(params, tokens, cache)
+    kernels = [e.params["name"] for e, _ in
+               _walk_jaxpr(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    assert kernels.count("cached_attention") == found * cfg.n_layers
+    if positions == 1 and not meshed:
+        sized = call_sizes(cfg, rows, 100, 20)
+        assert sized["attention_streamed_layers"] == found * cfg.n_layers
+        assert sized["attention_streamed_bytes"] == (
+            found * cfg.n_layers * 2 * rows * slots * width
+            * jnp.dtype(cfg.compute_dtype).itemsize)
+
+
+@pytest.mark.parametrize("named", [
+    {}, {"position": "rope", "n_passes": 2, "norm_placement": "sandwich"}],
+    ids=["grouped", "looped_rotary"])
+def test_eight_rows_together_serve_what_each_row_serves_alone(named):
+    """Rows do not mix: 8 rows served together (a dense cache, prefill in
+    two chunks over a view of it, every step through the kernel) give the
+    tokens of the same rows served one at a time (a head-major cache under
+    the block's own lines)."""
+    from faabric_tpu.models.generate import call_sizes, generate
+
+    cfg = _grouped(**named)
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    prompt = jnp.asarray(np.random.RandomState(5).randint(
+        0, cfg.vocab_size, (8, 21)), jnp.int32)
+    assert call_sizes(cfg, 8, 21, 9)["attention_streamed_layers"] == 2
+    assert call_sizes(cfg, 1, 21, 9)["attention_streamed_layers"] == 0
+    together = np.asarray(generate(params, prompt, cfg, 9, prefill_chunk=16))
+    alone = np.concatenate([
+        np.asarray(generate(params, prompt[i:i + 1], cfg, 9,
+                            prefill_chunk=16)) for i in range(8)])
+    np.testing.assert_array_equal(together, alone)

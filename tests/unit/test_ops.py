@@ -366,6 +366,104 @@ def test_gated_ffn_plan_holds_the_two_cells_calls():
 
 
 # ---------------------------------------------------------------------------
+# Cached attention over a dense cache
+# ---------------------------------------------------------------------------
+
+# (rows, heads, key/value heads, slots, head_dim, dtype, rows a grid step):
+# grouped heads of 64 lanes at the cell's grouping (32 on 8) and equal
+# heads of 128 lanes, both types, the plan's own block and a smaller one
+CACHED_ATTENTION_CALLS = {
+    "grouped_32_on_8_of_64_bf16": (8, 32, 8, 128, 64, jnp.bfloat16, None),
+    "grouped_32_on_8_of_64_f32": (8, 32, 8, 128, 64, jnp.float32, 2),
+    "equal_heads_of_128_bf16": (16, 4, 4, 256, 128, jnp.bfloat16, 4),
+    "equal_heads_of_128_f32": (8, 2, 2, 128, 128, jnp.float32, None),
+}
+
+
+@pytest.mark.parametrize("where", ["first", "mid_reach", "last_slot"])
+@pytest.mark.parametrize("call", sorted(CACHED_ATTENTION_CALLS))
+def test_cached_attention_matches_the_blocks_own_lines(call, where):
+    """The kernel, interpreted, over a dense cache against
+    ``_cached_attention``'s ``jnp`` lines over the same values head-major:
+    a stated scale, pass 1 of 2, and NaN planted in every slot of K and V
+    the call has not written, which never reaches the output."""
+    from faabric_tpu.models.transformer import _cached_attention
+    from faabric_tpu.ops.cached_attention import cached_attention, plan
+
+    rows, heads, kv, slots, d, dtype, block_rows = CACHED_ATTENTION_CALLS[call]
+    length = {"first": 1, "mid_reach": slots // 2 + 3, "last_slot": slots}[
+        where]
+    scale = 0.37 / d
+    rng = np.random.RandomState(rows + slots + length)
+    q = jnp.asarray(rng.randn(rows, heads, d), dtype)
+    unwritten = (np.arange(slots) >= length)[None, None, :, None]
+    dense = [jnp.asarray(np.where(unwritten, np.nan,
+                                  rng.randn(2, rows, slots, kv * d)), dtype)
+             for _ in range(2)]
+    how = plan(rows, heads, kv, slots, d, dtype, block_rows)
+    assert how["steps"] * how["block_rows"] == rows
+    got = cached_attention(q, *dense, jnp.int32(length), scale,
+                           t=jnp.int32(1), block_rows=block_rows)
+    head_major = [c[1].reshape(rows, slots, kv, d).transpose(0, 2, 1, 3)
+                  for c in dense]
+    want = _cached_attention(q[:, None], *head_major, length, scale)[:, 0]
+    assert got.shape == want.shape == (rows, heads, d)
+    assert got.dtype == want.dtype
+    assert not np.isnan(np.asarray(got, np.float32)).any()
+    f32 = dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=2e-6 if f32 else 2e-2, rtol=0 if f32 else 2e-2)
+    # pass 0 holds other values: the kernel read pass 1 alone
+    other = cached_attention(q, *dense, jnp.int32(length), scale, t=0,
+                             block_rows=block_rows)
+    if length > 1:
+        assert np.abs(np.asarray(other, np.float32)
+                      - np.asarray(got, np.float32)).max() > 1e-2
+
+
+def test_cached_attention_plan_holds_the_cells_call():
+    """``plan`` from shapes alone: the cached step of
+    ``serve_granite_1chip`` (64 rows, 32 heads on 8 of 64 lanes, 640
+    slots) in bfloat16, and what it refuses."""
+    from faabric_tpu.ops.cached_attention import (
+        MIN_ROWS,
+        STEP_BYTES,
+        cached_attention,
+        plan,
+    )
+
+    granite = plan(64, 32, 8, 640, 64, jnp.bfloat16)
+    a_row = 2 * 640 * 512 * 2           # a row's keys and values
+    assert granite == {
+        "block_rows": 2, "steps": 32,
+        # two rows' keys, values, block-diagonal queries and outputs,
+        # twice; the values once more, zeroed; a row's scores,
+        # probabilities and weighted sum in float32
+        "vmem_bytes": 2 * 2 * (a_row + (32 + 4) * 512 * 2) + 2 * a_row // 2
+        + 32 * (2 * 640 + 2 * 512) * 4,
+        "streamed_bytes": 64 * a_row}
+    assert granite["vmem_bytes"] == 6_995_968
+    assert granite["streamed_bytes"] == 83_886_080
+    assert 2 * a_row <= STEP_BYTES < 4 * a_row
+    assert MIN_ROWS == 8
+    for rows in (1, 7):
+        assert plan(rows, 32, 8, 640, 64) is None
+    assert plan(8, 32, 8, 640, 64)["steps"] * \
+        plan(8, 32, 8, 640, 64)["block_rows"] == 8
+    # a position's keys that are not whole lanes; heads no multiple of
+    # the key/value heads; a row whose reach is over a step's room
+    assert plan(64, 4, 4, 640, 16) is None
+    assert plan(64, 12, 8, 640, 64) is None
+    assert plan(64, 32, 8, STEP_BYTES // (2 * 512 * 2) + 128, 64) is None
+    # rows a step that do not divide the rows: refused, and the call raises
+    assert plan(64, 32, 8, 640, 64, block_rows=3) is None
+    with pytest.raises(ValueError, match="cached_attention does not take"):
+        cached_attention(jnp.zeros((4, 4, 64)), jnp.zeros((1, 4, 128, 128)),
+                         jnp.zeros((1, 4, 128, 128)), 5, 1.0)
+
+
+# ---------------------------------------------------------------------------
 # RMS norm
 # ---------------------------------------------------------------------------
 

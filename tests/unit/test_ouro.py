@@ -241,7 +241,8 @@ def test_generate_is_one_outer_loop_with_the_passes_rolled_inside():
         "cache_slots": 256, "ut_passes": 4 * 65,
         "cache_bytes": 2 * 4 * 3 * 4 * 256 * 16 * 4,
         # one row, and a norm behind the down product: XLA's lines
-        "ffn_streamed_layers": 0, "ffn_streamed_bytes": 0}
+        "ffn_streamed_layers": 0, "ffn_streamed_bytes": 0,
+        "attention_streamed_layers": 0, "attention_streamed_bytes": 0}
     served = np.asarray(generate(params, prompt, cfg, 4))
     assert served.shape == (1, 4)
     want = ref.logits_of(params, jnp.concatenate(
