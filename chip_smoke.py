@@ -45,6 +45,15 @@ Guests (user ``smoke``), all on the chips the planner pinned:
   last chunk that is not full), then two cached steps (the recurrence,
   grouped heads over the cache), logits against
   ``benchmarks/reference/granite.py``.
+- ``shared_state`` — layers that lend and borrow state (Mamba-1, windowed
+  and full differential attention, a gated memory unit and a cross
+  attention on the full layer's cache; LayerNorm, biases, a tied head) at
+  the widths of ``benchmarks/configs/phi-4-mini-flash-reasoning.json``,
+  the shallowest stack with every kind, 64 rows: prefill in two chunks
+  whose second wraps the rings, the cross-decoder at the last position
+  alone, then two cached steps through the cached-attention kernel in its
+  differential, ring and read-only forms, logits of four rows against
+  ``benchmarks/reference/phi4flash.py``.
 - ``gang``    — with ≥ 2 chips: an MPI world through ``ctx.mpi_world()``,
   one rank per chip, collectives on device-resident arrays through the
   activated device plane, and the Pallas ring-permute kernel.
@@ -119,6 +128,14 @@ HYBRID_CONFIG = os.path.join(REPO, "benchmarks", "configs",
                              "granite-4.0-h-micro.json")
 HYBRID_CONFIG_TINY = os.path.join(REPO, "tests", "bench", "data", "configs",
                                   "toy_granite.json")
+
+# One layer of each kind of a decoder-hybrid-decoder in bfloat16 against
+# the float32 reference, the same measure and room as the two above.
+TOL_SHARED_STATE_LOGITS = 4e-2
+SHARED_CONFIG = os.path.join(REPO, "benchmarks", "configs",
+                             "phi-4-mini-flash-reasoning.json")
+SHARED_CONFIG_TINY = os.path.join(REPO, "tests", "bench", "data", "configs",
+                                  "toy_phi4flash.json")
 
 PLANNER_HOST = "smoke-planner"
 WORKER_HOST = "smoke-worker"
@@ -661,6 +678,79 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
             _require(dev.platform == "tpu", dev.platform)
         return reply(**out)
 
+    # ---- layers that lend and borrow state ---------------------------
+    @register_function("smoke", "shared_state")
+    def shared_state(ctx):
+        from benchmarks import program_phi4flash, weights_phi4flash
+        from benchmarks.reference import phi4flash as reference
+        from faabric_tpu.models.generate import (
+            call_sizes,
+            forward_with_cache,
+            init_kv_cache,
+        )
+
+        dev = ctx.device
+        with open(SHARED_CONFIG if on_chip else SHARED_CONFIG_TINY) as f:
+            # the shallowest stack with every kind: Mamba-1 at 0, 2 and 4
+            # (the memory's source), windows at 1 and 3, the full
+            # attention at 5, a gated memory unit and a cross attention
+            config = dict(json.load(f), num_hidden_layers=8)
+        sizes = weights_phi4flash.sizes_of(config)
+        kinds = program_phi4flash.model_config(config)
+        window = kinds.sliding_window
+        # the second chunk wraps the rings; the steps evict from them
+        chunks, steps = (window - window // 4, window // 2), 2
+        rows, compared = (64, 4) if on_chip else (8, 4)
+        s_p = sum(chunks)
+        ids = weights_phi4flash.token_rows(7, 1, 0, rows, s_p + steps,
+                                           sizes["vocab"])
+        with jax.default_device(dev):
+            params = weights_phi4flash.make_weights(
+                7, sizes, kinds.param_dtype, dev)
+            cache = init_kv_cache(kinds, rows, 128 * -(-ids.shape[1] // 128))
+            at = 0
+            for length in chunks:
+                logits, cache = jax.jit(
+                    lambda p, t, c, at=at: forward_with_cache(
+                        p, t, c, at, kinds, last_only=True))(
+                    params, jnp.asarray(ids[:, at:at + length]), cache)
+                at += length
+            got = [np.asarray(logits, np.float32)]
+            step = jax.jit(lambda p, t, c, pos: forward_with_cache(
+                p, t, c, pos, kinds))
+            for pos in range(s_p, ids.shape[1]):
+                logits, cache = step(params, jnp.asarray(
+                    ids[:, pos:pos + 1]), cache, jnp.int32(pos))
+                got.append(np.asarray(logits, np.float32))
+            got = np.concatenate(got, axis=1)[:compared]
+            want = np.asarray(reference.logits_of_rows(
+                params, jnp.asarray(ids[:compared]), sizes,
+                at=slice(s_p - 1, None)))
+        counted = call_sizes(kinds, rows, s_p, steps, chunks[0])
+        out = dict(
+            device=_device_report(dev), n_params=sum(
+                int(x.size) for x in jax.tree.leaves(params)),
+            rows=rows, prompt=s_p,
+            prefill_rel_err=_rel_err(got[:, :1], want[:, :1]),
+            cached_steps_rel_err=_rel_err(got[:, 1:], want[:, 1:]),
+            **{name: counted[name] for name in (
+                "window_slots", "window_cache_bytes", "shared_cache_bytes",
+                "state_bytes", "attention_streamed_layers",
+                "prefill_skipped_layers")})
+        _require(np.isfinite(got).all(), "a logit is not finite")
+        _require([None if c is None else sorted(c) for c in cache]
+                 == [["conv", "state"], ["k", "v"]] * 3 + [None, None],
+                 "the layers' state is "
+                 f"{[None if c is None else sorted(c) for c in cache]}")
+        _require(counted["attention_streamed_layers"] == 4,
+                 f"{counted['attention_streamed_layers']} attentions stream")
+        for name in ("prefill_rel_err", "cached_steps_rel_err"):
+            _require(out[name] < TOL_SHARED_STATE_LOGITS,
+                     f"{name} {out[name]}")
+        if on_chip:
+            _require(dev.platform == "tpu", dev.platform)
+        return reply(**out)
+
     # ---- gang --------------------------------------------------------
     @register_function("smoke", "gang")
     def gang(ctx):
@@ -972,6 +1062,7 @@ def _run_phases(cluster: Cluster, summary: dict, deadline: float) -> None:
     phases["latent_experts"] = cluster.invoke("latent_experts", 1,
                                               deadline)[0]
     phases["state_space"] = cluster.invoke("state_space", 1, deadline)[0]
+    phases["shared_state"] = cluster.invoke("shared_state", 1, deadline)[0]
     if n >= 2:
         phases["gang"] = sorted(
             cluster.invoke("gang", 1, deadline, mpi_world_size=n),

@@ -28,11 +28,23 @@ counters, summed on the device over the call.
 
 State goes by the layer's kind too (``ModelConfig.layer_types``). An
 "attention" layer keeps keys and values over its key/value heads, fewer
-than the query heads under grouped-query attention; a "mamba" layer
-(models/ssm.py) keeps a convolution window and a recurrent state whose
-size does not depend on the reach. Both kinds live in one call's list.
-With ``prefill_chunk`` a chunk of the prompt starts from the state the
-chunk before left.
+than the query heads under grouped-query attention; a "mamba" or
+"mamba1" layer (models/ssm.py) keeps a convolution window and a
+recurrent state whose size does not depend on the reach; a
+"window_attention" layer keeps a ring of the window's length whatever
+the reach, position p in slot p % slots. All kinds live in one call's
+list. With ``prefill_chunk`` a chunk of the prompt starts from the state
+the chunk before left (a ring is attended before the chunk overwrites
+it).
+
+A layer may read state another layer owns. A "cross_attention" layer
+attends the cache of layer ``cfg.cache_source`` and writes nothing; a
+"gated_memory" layer reads, at the same positions, what the recurrence of
+layer ``cfg.memory_source`` gave. Neither has an entry of its own in the
+call's list (``None``): the step hands the owner's updated cache, and the
+step's memory, along the layers. From ``cfg.stateless_from`` on no layer
+keeps anything, so a prefill runs those layers, the final norm and the
+head on the position it serves alone.
 """
 
 from __future__ import annotations
@@ -46,10 +58,13 @@ import jax.numpy as jnp
 from faabric_tpu.models import scopes, ssm
 from faabric_tpu.models.moe import COUNTERS
 from faabric_tpu.models.transformer import (
+    ATTENDING,
     ModelConfig,
     _block,
     embed,
     head,
+    lays_dense,
+    lender_of,
     resolve_impls,
     run_passes,
     refuse_served_only,
@@ -79,9 +94,29 @@ def _attention_cache_shapes(cfg: ModelConfig, batch: int, slots: int,
         return {"latent": (cfg.n_passes, batch, slots,
                            cfg.kv_lora_rank + cfg.qk_rope_dim)}
     shape = (cfg.n_passes, batch, cfg.kv_heads, slots, cfg.head_dim)
-    if streams_attention(cfg, batch, 1, slots, cfg.compute_dtype, mesh):
+    if lays_dense(cfg) or streams_attention(cfg, batch, 1, slots,
+                                            cfg.compute_dtype, mesh):
         shape = (cfg.n_passes, batch, slots, cfg.kv_heads * cfg.head_dim)
     return {"k": shape, "v": shape}
+
+
+def _ring_slots(cfg: ModelConfig, slots: int) -> int:
+    """Slots of a "window_attention" layer's ring in a call whose full
+    caches have ``slots``: the window, whatever the reach beyond it."""
+    return min(cfg.sliding_window, slots)
+
+
+def _state_shapes(cfg: ModelConfig, kind: str, batch: int, slots: int,
+                  mesh=None) -> dict:
+    """The arrays a layer of ``kind`` keeps through a call; none for a
+    layer that reads what another lends."""
+    if kind in ("mamba", "mamba1"):
+        return ssm.state_shapes(cfg, batch, kind)
+    if kind == "window_attention":
+        slots = _ring_slots(cfg, slots)
+    elif kind != "attention":
+        return {}
+    return _attention_cache_shapes(cfg, batch, slots, mesh)
 
 
 def _prefill_chunks(prompt_len: int, prefill_chunk: int) -> list:
@@ -103,52 +138,87 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     also ``attention_layers``, ``ssm_layers``, ``state_bytes`` (the
     windows and states of all state-space layers: the same at any reach)
     and ``scan_chunks``, the chunks of the state-space scan a row a layer
-    in prefill. ``ffn_streamed_layers`` is the feed-forwards whose cached
+    in prefill; where a layer keeps a ring or lends its state also
+    ``window_layers``, ``window_slots`` (a ring's), ``window_cache_bytes``
+    and ``shared_cache_bytes`` (the lent cache, once; both are part of
+    ``cache_bytes``), ``cross_layers`` and ``memory_layers`` (the layers
+    that borrow) and ``prefill_skipped_layers`` (those a prefill runs at
+    the served position alone). ``ffn_streamed_layers`` is the
+    feed-forwards whose cached
     step goes through the streaming kernel (ops/gated_ffn.py) and
     ``ffn_streamed_bytes`` what they stream a step, every pass, for a call
     without a mesh on parameters of ``cfg.param_dtype``
     (``transformer.streams_feed_forward``); 0 where none does.
     ``attention_streamed_layers`` is the attentions whose cached step
-    reads a dense cache through its kernel (ops/cached_attention.py) and
-    ``attention_streamed_bytes`` the keys and values they stream a step,
-    every pass, for a call without a mesh
+    reads a dense cache through its kernel (ops/cached_attention.py), its
+    own, a ring or a lent one, and ``attention_streamed_bytes`` the keys
+    and values they stream a step as the kernel reads them (a cache's
+    allocated slots, a lent cache once a reader), every pass, for a call
+    without a mesh
     (``transformer.streams_attention``); 0 where none does.
     ``generate`` sizes its cache from this; a server reports it beside
     its answers."""
     slots = _cache_slots(cfg, prompt_len + n_tokens)
     itemsize = jnp.dtype(cfg.compute_dtype).itemsize
     shortcut = cfg.layer == "shortcut"
-    values = sum(math.prod(shape) for shape in
-                 _attention_cache_shapes(cfg, batch, slots).values())
-    attention_layers = cfg.mixers.count("attention")
+
+    def kept(kind):
+        return sum(math.prod(shape) for shape in
+                   _state_shapes(cfg, kind, batch, slots).values()) * itemsize
+
+    count = cfg.mixers.count
+    attention_layers = count("attention")
     streamed = streams_feed_forward(cfg, batch, 1, cfg.param_dtype)
     feed_forwards = (1 + shortcut) * cfg.n_layers if streamed else 0
     a_pass = streamed["streamed_bytes"] if streamed else 0
-    attended = streams_attention(cfg, batch, 1, slots, cfg.compute_dtype)
-    attentions = (1 + shortcut) * attention_layers if attended else 0
+    # every attending layer reads a cache through the kernel, its own, a
+    # ring or a lent one, where a step of these rows over its slots does
+    attended = {kind: streams_attention(
+        cfg, batch, 1, _ring_slots(cfg, slots)
+        if kind == "window_attention" else slots, cfg.compute_dtype)
+        for kind in ATTENDING if count(kind)}
+    windows = count("window_attention") * kept("window_attention")
     sizes = {
         "cache_slots": slots,
-        "cache_bytes": (1 + shortcut) * attention_layers * values * itemsize,
+        "cache_bytes": (1 + shortcut) * attention_layers * kept("attention")
+        + windows,
         "ut_passes": cfg.n_passes * (1 + n_tokens),
         "ffn_streamed_layers": feed_forwards,
         "ffn_streamed_bytes": feed_forwards * cfg.n_passes * a_pass,
-        "attention_streamed_layers": attentions,
-        "attention_streamed_bytes": attentions * cfg.n_passes
-        * (attended["streamed_bytes"] if attended else 0),
+        "attention_streamed_layers": sum(
+            (1 + shortcut) * count(kind)
+            for kind, plan in attended.items() if plan),
+        "attention_streamed_bytes": sum(
+            (1 + shortcut) * count(kind) * cfg.n_passes
+            * plan["streamed_bytes"]
+            for kind, plan in attended.items() if plan),
     }
     if shortcut:
         sizes.update(experts_held=cfg.experts_held[1],
                      router_width=cfg.routed_experts + cfg.zero_experts)
     if cfg.layer_types:
-        ssm_layers = cfg.n_layers - attention_layers
-        kept = sum(math.prod(shape) for shape in
-                   ssm.state_shapes(cfg, batch).values())
+        chunks = _prefill_chunks(prompt_len, prefill_chunk)
         sizes.update(
-            attention_layers=attention_layers, ssm_layers=ssm_layers,
-            state_bytes=ssm_layers * kept * itemsize,
-            scan_chunks=sum(-(-length // cfg.ssm_chunk) for _, length in
-                            _prefill_chunks(prompt_len, prefill_chunk))
-            if ssm_layers else 0)
+            attention_layers=attention_layers,
+            ssm_layers=count("mamba") + count("mamba1"),
+            state_bytes=count("mamba") * kept("mamba")
+            + count("mamba1") * kept("mamba1"),
+            # Mamba-2 scans a chunk of ssm_chunk at once, Mamba-1 along
+            # the positions of one prefill chunk
+            scan_chunks=(sum(-(-length // cfg.ssm_chunk)
+                             for _, length in chunks) if count("mamba")
+                         else len(chunks) if count("mamba1") else 0))
+    if lays_dense(cfg) or count("mamba1"):
+        sizes.update(
+            window_layers=count("window_attention"),
+            window_slots=_ring_slots(cfg, slots)
+            if count("window_attention") else 0,
+            window_cache_bytes=windows,
+            shared_cache_bytes=kept("attention")
+            if count("cross_attention") else 0,
+            cross_layers=count("cross_attention"),
+            memory_layers=count("gated_memory"),
+            prefill_skipped_layers=cfg.n_layers - cfg.stateless_from)
     return sizes
 
 
@@ -162,8 +232,12 @@ def init_kv_cache(cfg: ModelConfig, batch: int, slots: int,
     head_dim). Latent attention: (passes,
     batch, slots, kv_lora_rank + qk_rope_dim), once. A "shortcut" layer:
     its two attentions' caches and its expert layer's counters
-    (``transformer._block``). A "mamba" layer: its convolution window and
-    its recurrent state (``ssm.state_shapes``), whatever ``slots``."""
+    (``transformer._block``). A "mamba" or "mamba1" layer: its convolution
+    window and its recurrent state (``ssm.state_shapes``), whatever
+    ``slots``. A "window_attention" layer: a ring of ``_ring_slots``. A
+    layer that reads what another lends: None. A configuration that
+    names a ring, a lent cache or differential pairs lays every cache
+    dense (``transformer.lays_dense``)."""
     def zeros(shapes: dict):
         return {name: jnp.zeros(shape, cfg.compute_dtype)
                 for name, shape in shapes.items()}
@@ -175,8 +249,9 @@ def init_kv_cache(cfg: ModelConfig, batch: int, slots: int,
         return [{"attn": [attention(), attention()],
                  "counters": jnp.zeros((len(COUNTERS),), jnp.int32)}
                 for _ in range(cfg.n_layers)]
-    return [zeros(ssm.state_shapes(cfg, batch)) if kind == "mamba"
-            else attention() for kind in cfg.mixers]
+    # a layer that reads what another lends keeps nothing: None
+    return [zeros(_state_shapes(cfg, kind, batch, slots, mesh)) or None
+            for kind in cfg.mixers]
 
 
 def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig,
@@ -184,8 +259,10 @@ def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig,
     """tokens (B, S) entering at position ``start`` → (logits (B, S, V),
     new cache); with ``last_only`` the head reads the last position alone,
     logits (B, 1, V): what a server samples from (at a vocabulary of 100k
-    the logits of a 64 × 512 prompt are 13 GB). Pass ``t`` of the stack
-    writes and attends ``cache[...][t]`` alone; a state-space layer starts
+    the logits of a 64 × 512 prompt are 13 GB), and so do the layers from
+    ``cfg.stateless_from`` on, which keep no state: exact, since such a
+    layer at a position reads that position alone and what was lent.
+    Pass ``t`` of the stack writes and attends ``cache[...][t]`` alone; a state-space layer starts
     from the window and state its cache holds. ``mesh`` is the one the
     parameters are laid over, if any: the blocks take their one-chip
     kernels only without it. A step whose depth depends
@@ -200,11 +277,21 @@ def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig,
     x = embed(params, tokens, cfg)
 
     def stack(x, cache, t):
-        new_cache = []
-        for blk, layer_cache, kind in zip(params["blocks"], cache,
-                                          cfg.mixers):
-            x, updated = _block(x, blk, positions, cfg, mesh,
-                                cache=layer_cache, slot=(t, start), kind=kind)
+        new_cache, lent, at, where = [], {}, start, positions
+        for i, (blk, layer_cache, kind) in enumerate(zip(
+                params["blocks"], cache, cfg.mixers)):
+            if last_only and i == cfg.stateless_from and x.shape[1] > 1:
+                # from here on a position's way reads nothing of the
+                # positions before it but what was lent: the one that is
+                # served goes on alone
+                x, where, at = x[:, -1:], where[:, -1:], start + s - 1
+                lent = {kind: lends[:, -1:] if kind == "gated_memory"
+                        else lends for kind, lends in lent.items()}
+            x, updated, lends = _block(
+                x, blk, where, cfg, mesh, cache=layer_cache, slot=(t, at),
+                kind=kind, layer=i, lent=lent.get(kind))
+            lent.update(lender_of(cfg, i, updated if lends is None
+                                  else lends))
             new_cache.append(updated)
         return x, new_cache
 
@@ -324,9 +411,10 @@ def generate(params, prompt, cfg: ModelConfig, n_tokens: int,
     attention memory. A looped stack (``cfg.n_passes`` above 1) keeps
     one cache a pass a layer; :func:`call_sizes` says what a call of
     these shapes allocates. The other kinds of attention and layer
-    (latent attention, shortcut layers, state-space layers, grouped
-    key/value heads, the multipliers, a tied head) are single-chip so
-    far: with ``mesh`` they raise ``ValueError``."""
+    (latent attention, shortcut layers, state-space layers, windows,
+    lent caches and memories, differential pairs, grouped key/value
+    heads, the multipliers, a tied head) are single-chip so far: with
+    ``mesh`` they raise ``ValueError``."""
     return generate_with_counters(params, prompt, cfg, n_tokens, key,
                                   temperature, top_k, top_p, mesh,
                                   prefill_chunk)[0]

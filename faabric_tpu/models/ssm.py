@@ -15,6 +15,24 @@ state of N a lane (``cfg.ssm_heads``, ``ssm_head_dim``, ``ssm_groups``,
     y_t = S_t·C_t + D·x_t
     out = RMSNorm(y · silu(z); ssm_norm) · ssm_out    the gate before the norm
 
+A "mamba1" layer has the mixer of Mamba-1 (Gu and Dao, arXiv:2312.00752),
+:func:`mixer1`, over the same convolution: E = ``cfg.ssm_inner`` lanes,
+each with a state of N = ``cfg.ssm_d_state``, dt a lane through a
+bottleneck of R = ``cfg.ssm_dt_rank``:
+
+    [x (E), z (E)] = h · ssm_in;  x = silu(conv(x))
+    [δ (R), B (N), C (N)] = x · ssm_x;  dt = softplus(δ · ssm_dt + dt_bias)
+    A = −exp(A_log)  (E, N);  S_t = exp(dt_t ⊗ A) ⊙ S_{t−1} + (dt_t ⊙ x_t) ⊗ B_t
+    y_t = S_t · C_t + D ⊙ x_t;  out = (y ⊙ silu(z)) · ssm_out
+
+The decay differs by lane and state index, so there is no matrix form over
+a chunk: a cached step is the recurrence, a longer input a ``lax.scan`` of
+the same step along its positions from the state the call before left, its
+carry one (B, N, E) float32 array; nothing of (B, positions, E, N) is ever
+made. ``y`` before the gate is the layer's memory, which "gated_memory"
+layers (:func:`gated_memory`: ``(silu(h · gmu_in) ⊙ y) · gmu_out``) read at
+the same position and keep nothing of.
+
 Two forms over one set of weights. A cached step (S = 1) is the recurrence
 itself. Longer inputs go in chunks of ``cfg.ssm_chunk`` positions: inside
 a chunk the outputs are a masked product of C·Bᵀ with the decays between
@@ -43,6 +61,10 @@ from faabric_tpu.models.transformer import _rms_norm
 # the leaves a "mamba" layer holds beside its norms and feed-forward
 MIXER_LEAVES = ("ssm_in", "conv_w", "conv_b", "dt_bias", "A_log", "D",
                 "ssm_norm", "ssm_out")
+# those of a "mamba1" layer, and of a "gated_memory" layer
+MIXER1_LEAVES = ("ssm_in", "conv_w", "conv_b", "ssm_x", "ssm_dt", "dt_bias",
+                 "A_log", "D", "ssm_out")
+GATED_MEMORY_LEAVES = ("gmu_in", "gmu_out")
 
 
 def widths(cfg) -> tuple:
@@ -52,8 +74,13 @@ def widths(cfg) -> tuple:
     return inner, bc, inner + 2 * bc
 
 
-def state_shapes(cfg, batch: int) -> dict:
-    """The arrays one mixer keeps through a call."""
+def state_shapes(cfg, batch: int, kind: str = "mamba") -> dict:
+    """The arrays one mixer of ``kind`` keeps through a call."""
+    if kind == "mamba1":
+        # S with the lanes last: a state index is a sublane, never a
+        # 16th of a padded tile
+        return {"conv": (batch, cfg.ssm_d_conv - 1, cfg.ssm_inner),
+                "state": (batch, cfg.ssm_d_state, cfg.ssm_inner)}
     return {"conv": (batch, cfg.ssm_d_conv - 1, widths(cfg)[2]),
             "state": (batch, cfg.ssm_heads, cfg.ssm_head_dim,
                       cfg.ssm_d_state)}
@@ -195,3 +222,92 @@ def mixer(h: jax.Array, blk: dict, cfg, cache: Optional[dict] = None
     if cache is not None:
         cache = {"conv": window, "state": state.astype(dtype)}
     return out, cache
+
+
+def init_mixer1(key: jax.Array, cfg, dense) -> dict:
+    """A "mamba1" layer's leaves as Mamba-1 initialises them: dt
+    log-uniform in [0.001, 0.1] through the inverse of softplus, A_log =
+    log(1..N) in every lane, D one."""
+    inner, n, rank = cfg.ssm_inner, cfg.ssm_d_state, cfg.ssm_dt_rank
+    k = jax.random.split(key, 6)
+    dt = jnp.exp(jax.random.uniform(k[4], (inner,), jnp.float32,
+                                    jnp.log(0.001), jnp.log(0.1)))
+    return {
+        "ssm_in": dense(k[0], (cfg.d_model, 2 * inner), cfg.d_model),
+        "conv_w": dense(k[1], (cfg.ssm_d_conv, inner), cfg.ssm_d_conv),
+        "conv_b": jnp.zeros((inner,), cfg.param_dtype),
+        "ssm_x": dense(k[2], (inner, rank + 2 * n), inner),
+        "ssm_dt": dense(k[3], (rank, inner), rank),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(cfg.param_dtype),
+        "A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)),
+            (inner, n)).astype(cfg.param_dtype),
+        "D": jnp.ones((inner,), cfg.param_dtype),
+        "ssm_out": dense(k[5], (inner, cfg.d_model), inner),
+    }
+
+
+def init_gated_memory(key: jax.Array, cfg, dense) -> dict:
+    k = jax.random.split(key, 2)
+    return {"gmu_in": dense(k[0], (cfg.d_model, cfg.ssm_inner), cfg.d_model),
+            "gmu_out": dense(k[1], (cfg.ssm_inner, cfg.d_model),
+                             cfg.ssm_inner)}
+
+
+def _step1(state, at, a):
+    """Mamba-1's recurrence at one position: ``state`` (B, N, E) float32,
+    ``at`` = (x (B, E), b and c (B, N), dt (B, E) float32), a (N, E)
+    float32 → (the new state, y (B, E) float32)."""
+    x, b, c, dt = at
+    f32 = jnp.float32
+    pushed = (dt * x.astype(f32))[:, None, :] * b.astype(f32)[:, :, None]
+    state = jnp.exp(dt[:, None, :] * a) * state + pushed
+    return state, jnp.sum(state * c.astype(f32)[:, :, None], axis=1)
+
+
+def mixer1(h: jax.Array, blk: dict, cfg, cache: Optional[dict] = None
+           ) -> tuple:
+    """The Mamba-1 mixer on a normed state h (B, S, D) → (its output
+    (B, S, D), the updated cache or None, the memory y (B, S, E) in the
+    compute type: the recurrence's output before the gate)."""
+    dtype = cfg.compute_dtype
+    bsz, s, _ = h.shape
+    inner, n, rank = cfg.ssm_inner, cfg.ssm_d_state, cfg.ssm_dt_rank
+    start = cache if cache is not None else {
+        name: jnp.zeros(shape, dtype)
+        for name, shape in state_shapes(cfg, bsz, "mamba1").items()}
+    xz = h @ blk["ssm_in"].astype(dtype)
+    z = xz[..., inner:]
+    x, window = _convolve(start["conv"].astype(dtype), xz[..., :inner], blk)
+    dbc = x @ blk["ssm_x"].astype(dtype)
+    b, c = dbc[..., rank:rank + n], dbc[..., rank + n:]
+    dt = jax.nn.softplus(
+        jnp.einsum("bsr,re->bse", dbc[..., :rank],
+                   blk["ssm_dt"].astype(dtype),
+                   preferred_element_type=jnp.float32)
+        + blk["dt_bias"].astype(jnp.float32))
+    a = -jnp.exp(blk["A_log"].astype(jnp.float32)).T           # (N, E)
+    state = start["state"].astype(jnp.float32)
+    if s == 1:
+        state, y = _step1(state, (x[:, 0], b[:, 0], c[:, 0], dt[:, 0]), a)
+        y = y[:, None]
+    else:
+        along = tuple(m.swapaxes(0, 1) for m in (x, b, c, dt))
+        state, y = jax.lax.scan(lambda st, at: _step1(st, at, a), state,
+                                along)
+        y = y.swapaxes(0, 1)
+    y = (y + blk["D"].astype(jnp.float32) * x.astype(jnp.float32)
+         ).astype(dtype)
+    out = (y * jax.nn.silu(z)) @ blk["ssm_out"].astype(dtype)
+    if cache is not None:
+        cache = {"conv": window, "state": state.astype(dtype)}
+    return out, cache, y
+
+
+def gated_memory(h: jax.Array, blk: dict, cfg, memory: jax.Array
+                 ) -> jax.Array:
+    """A gated memory unit on a normed state h (B, S, D) and the memory
+    (B, S, E) another layer's recurrence gave at the same positions."""
+    dtype = cfg.compute_dtype
+    gate = jax.nn.silu(h @ blk["gmu_in"].astype(dtype))
+    return (gate * memory.astype(dtype)) @ blk["gmu_out"].astype(dtype)

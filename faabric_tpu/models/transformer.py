@@ -39,6 +39,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from faabric_tpu.models import scopes
 
 
+# The kinds of mixer a layer can have (``ModelConfig.layer_types``), and
+# those of them that attend keys and values
+MIXER_KINDS = ("attention", "mamba", "mamba1", "window_attention",
+               "cross_attention", "gated_memory")
+ATTENDING = ("attention", "window_attention", "cross_attention")
+# the four vectors of head_dim that give a differential layer's weight
+LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     vocab_size: int = 32000
@@ -109,10 +118,32 @@ class ModelConfig:
     experts_per_token: int = 0
     routed_scaling: float = 1.0
     expert_d_ff: int = 0
-    # Each layer's mixer, the sub-layer before the feed-forward:
-    # "attention" | "mamba" (a Mamba-2 state-space mixer, models/ssm.py),
-    # one name a layer; empty: attention in every layer
+    # Each layer's mixer, the sub-layer before the feed-forward, one name
+    # a layer; empty: attention in every layer. "attention" | "mamba" (a
+    # Mamba-2 state-space mixer, models/ssm.py) | "mamba1" (a Mamba-1
+    # mixer, there too) | "window_attention" (attention over the last
+    # sliding_window positions, i − window < j ≤ i; its cache a ring) |
+    # "cross_attention" (a query and an output projection; it attends the
+    # keys and values of layer cache_source, an "attention" layer before
+    # it, and writes nothing) | "gated_memory" (a gate on what the
+    # recurrence of layer memory_source, a "mamba1" layer before it, gave
+    # at the same position before its own gate; it keeps nothing)
     layer_types: tuple = ()
+    sliding_window: int = 0
+    cache_source: int = -1
+    memory_source: int = -1
+    # Differential attention: the heads come in neighbouring pairs (2j,
+    # 2j+1), the key/value heads too; a pair is two softmax maps (head 2j
+    # on key head 2g, head 2j+1 on key head 2g+1, g = j // (n_heads /
+    # n_kv_heads)) over the one value of the group's two value heads side
+    # by side, the second subtracted with a learned weight, the difference
+    # normed over its 2·head_dim lanes (``sub_norm``) and scaled
+    differential: bool = False
+    # "rms": RMSNorm, a scale | "layer": LayerNorm, a scale and a bias
+    # (leaves ``ln1_b``, ``ln2_b``, ``ln_f_b``)
+    norm: str = "rms"
+    # biases on attention's projections (``bq``, ``bkv``, ``bo``)
+    attention_bias: bool = False
     # key/value heads; 0: as many as query heads. Fewer: grouped-query
     # attention, n_heads / n_kv_heads query heads a key/value head, the
     # cache over the key/value heads
@@ -138,6 +169,10 @@ class ModelConfig:
     ssm_head_dim: int = 0
     ssm_groups: int = 1
     ssm_chunk: int = 256
+    # a "mamba1" mixer's: ssm_inner lanes, each with a state of
+    # ssm_d_state, dt through a bottleneck of ssm_dt_rank, ssm_d_conv taps
+    ssm_inner: int = 0
+    ssm_dt_rank: int = 0
 
     def __post_init__(self):
         for field, kinds in (("ffn", ("gelu", "swiglu")),
@@ -145,18 +180,20 @@ class ModelConfig:
                              ("rope_pairing", ("neighbours", "halves")),
                              ("attention", ("heads", "latent")),
                              ("layer", ("single", "shortcut")),
-                             ("position", ("rope", "none"))):
+                             ("position", ("rope", "none")),
+                             ("norm", ("rms", "layer"))):
             if getattr(self, field) not in kinds:
                 raise ValueError(f"{field} {getattr(self, field)!r} is not "
                                  f"one of {kinds}")
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if self.layer_types and (
                 len(self.layer_types) != self.n_layers
-                or set(self.layer_types) - {"attention", "mamba"}):
+                or set(self.layer_types) - set(MIXER_KINDS)):
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers as "
-                f"{sorted(set(self.layer_types))}; it takes 'attention' or "
-                f"'mamba' for each of the {self.n_layers}")
+                f"{sorted(set(self.layer_types))}; it takes one of "
+                f"{MIXER_KINDS} for each of the {self.n_layers}")
+        self._check_shared_state()
         if self.n_heads % self.kv_heads or (
                 self.kv_heads != self.n_heads and self.attention != "heads"):
             raise ValueError(
@@ -176,6 +213,45 @@ class ModelConfig:
                 f"attention; got {self}")
         if self.n_passes < 1:
             raise ValueError(f"n_passes {self.n_passes} is below 1")
+
+    def _check_shared_state(self):
+        """The kinds whose layers lend or borrow state, the window, the
+        differential form and the Mamba-1 mixer's sizes."""
+        kinds = self.layer_types
+        named = set(kinds) & {"mamba1", "window_attention",
+                              "cross_attention", "gated_memory"}
+        if (named or self.differential) and (
+                self.n_passes != 1
+                or (self.attention, self.layer) != ("heads", "single")):
+            raise ValueError(
+                f"{sorted(named) or 'differential'} needs single layers of "
+                "one pass and per-head attention")
+        if "mamba1" in kinds and not (
+                self.ssm_inner > 0 and self.ssm_d_state > 0
+                and self.ssm_d_conv > 1 and self.ssm_dt_rank > 0):
+            raise ValueError(
+                "a 'mamba1' layer needs ssm_inner, ssm_d_state, "
+                f"ssm_dt_rank and ssm_d_conv above 1; got {self}")
+        if ("window_attention" in kinds) != (self.sliding_window > 0):
+            raise ValueError(
+                f"sliding_window {self.sliding_window} and the "
+                "'window_attention' layers go together")
+        for kind, field, lender in (
+                ("cross_attention", "cache_source", "attention"),
+                ("gated_memory", "memory_source", "mamba1")):
+            source = getattr(self, field)
+            borrowers = [i for i, k in enumerate(kinds) if k == kind]
+            if not borrowers and source == -1:
+                continue
+            if not (borrowers and 0 <= source < borrowers[0]
+                    and kinds[source] == lender):
+                raise ValueError(
+                    f"{field} {source} has to name a {lender!r} layer "
+                    f"before every {kind!r} layer {borrowers}")
+        if self.differential and (self.n_heads % 2 or self.kv_heads % 2):
+            raise ValueError(
+                f"differential attention pairs heads: {self.n_heads} on "
+                f"{self.kv_heads} are not pairs")
         if self.attention == "latent" and not (
                 self.q_lora_rank > 0 and self.kv_lora_rank > 0
                 and self.qk_nope_dim > 0 and self.v_head_dim > 0
@@ -212,6 +288,19 @@ class ModelConfig:
     def score_scale(self) -> float:
         return self.attention_scale or 1.0 / np.sqrt(self.head_dim)
 
+    @property
+    def stateless_from(self) -> int:
+        """The first layer from which on no layer keeps state of its own
+        (cross attentions and gated memory units alone): a position's
+        way through them reads that position's residual and memory and
+        the lent cache, so a prefill runs them at the positions it serves
+        only. ``n_layers`` where the last layer keeps state."""
+        at = self.n_layers
+        while at > 0 and self.mixers[at - 1] in ("cross_attention",
+                                                 "gated_memory"):
+            at -= 1
+        return at
+
 
 def served_only(cfg: ModelConfig) -> list:
     """What of ``cfg`` only the served path on one chip implements
@@ -221,7 +310,9 @@ def served_only(cfg: ModelConfig) -> list:
     plain = ModelConfig()
     fields = ("layer_types", "position", "attention_scale",
               "embedding_multiplier", "residual_multiplier",
-              "logits_scaling", "tie_embeddings")
+              "logits_scaling", "tie_embeddings", "sliding_window",
+              "cache_source", "memory_source", "differential", "norm",
+              "attention_bias", "ssm_inner", "ssm_dt_rank")
     named = [f"{f}={getattr(cfg, f)!r}" for f in fields
              if getattr(cfg, f) != getattr(plain, f)]
     if cfg.kv_heads != cfg.n_heads:
@@ -267,8 +358,8 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
         }
 
     def half(key, kind="attention"):
-        """A mixer (attention, or the state-space mixer) and its
-        feed-forward: a whole "single" layer."""
+        """A mixer (attention of a kind, a state-space mixer, a gated
+        memory unit) and its feed-forward: a whole "single" layer."""
         bk = jax.random.split(key, 4)
         blk = {
             "ln1": ones(),
@@ -276,31 +367,51 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
             "w1": dense(bk[2], (cfg.d_model, cfg.d_ff), cfg.d_model),
             "w2": dense(bk[3], (cfg.d_ff, cfg.d_model), cfg.d_ff),
         }
-        if kind == "mamba":
-            from faabric_tpu.models.ssm import init_mixer
+        if kind in ("mamba", "mamba1", "gated_memory"):
+            from faabric_tpu.models import ssm
 
-            blk.update(init_mixer(bk[0], cfg, dense))
+            blk.update({"mamba": ssm.init_mixer, "mamba1": ssm.init_mixer1,
+                        "gated_memory": ssm.init_gated_memory}[kind](
+                            bk[0], cfg, dense))
         elif cfg.attention == "latent":
             blk.update(latent_attention(bk[0]))
         else:
-            if cfg.kv_heads == cfg.n_heads:
+            if cfg.kv_heads == cfg.n_heads and kind == "attention" \
+                    and not cfg.differential:
                 blk["wqkv"] = dense(
                     bk[0], (cfg.d_model, 3, cfg.n_heads, cfg.head_dim),
                     cfg.d_model)
             else:
-                # queries and keys/values projected at their own widths
+                # queries and keys/values projected at their own widths;
+                # a cross attention has no keys and values of its own
                 qk, kvk = jax.random.split(bk[0])
                 blk["wq"] = dense(qk, (cfg.d_model, cfg.n_heads,
                                        cfg.head_dim), cfg.d_model)
-                blk["wkv"] = dense(kvk, (cfg.d_model, 2, cfg.kv_heads,
-                                         cfg.head_dim), cfg.d_model)
+                if kind != "cross_attention":
+                    blk["wkv"] = dense(kvk, (cfg.d_model, 2, cfg.kv_heads,
+                                             cfg.head_dim), cfg.d_model)
             blk["wo"] = dense(bk[1], (cfg.n_heads, cfg.head_dim, cfg.d_model),
                               cfg.d_model)
+            if cfg.attention_bias:
+                zeros = partial(jnp.zeros, dtype=cfg.param_dtype)
+                blk["bq"] = zeros((cfg.n_heads, cfg.head_dim))
+                blk["bo"] = zeros((cfg.d_model,))
+                if "wkv" in blk:
+                    blk["bkv"] = zeros((2, cfg.kv_heads, cfg.head_dim))
+            if cfg.differential:
+                lk = jax.random.split(jax.random.fold_in(key, 5), 4)
+                for name, k in zip(LAMBDAS, lk):
+                    blk[name] = 0.1 * jax.random.normal(
+                        k, (cfg.head_dim,), cfg.param_dtype)
+                blk["sub_norm"] = ones(2 * cfg.head_dim)
         if cfg.ffn == "swiglu":
             blk["wg"] = dense(jax.random.fold_in(key, 4),
                               (cfg.d_model, cfg.d_ff), cfg.d_model)
         if cfg.norm_placement == "sandwich":
             blk["ln1_post"], blk["ln2_post"] = ones(), ones()
+        if cfg.norm == "layer":
+            blk["ln1_b"] = jnp.zeros((cfg.d_model,), cfg.param_dtype)
+            blk["ln2_b"] = jnp.zeros((cfg.d_model,), cfg.param_dtype)
         return blk
 
     def shortcut(key):
@@ -329,6 +440,8 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
         "blocks": blocks,
         "ln_f": ones(),
     }
+    if cfg.norm == "layer":
+        params["ln_f_b"] = jnp.zeros((cfg.d_model,), cfg.param_dtype)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(keys[-1], (cfg.d_model, cfg.vocab_size),
                                   cfg.d_model)
@@ -345,6 +458,13 @@ def param_shardings(mesh: Mesh, cfg: ModelConfig) -> dict:
     def ns(*spec):
         return NamedSharding(mesh, P(*spec))
 
+    if (set(cfg.mixers) - {"attention", "mamba"} or cfg.differential
+            or cfg.norm != "rms" or cfg.attention_bias):
+        raise ValueError(
+            "no layout over a mesh for layers of the kinds "
+            f"{sorted(set(cfg.mixers))} with differential="
+            f"{cfg.differential}, norm={cfg.norm!r}, attention_bias="
+            f"{cfg.attention_bias}")
     half = {
         "ln1": ns(),
         "wo": ns("tp", None, None),
@@ -504,8 +624,10 @@ def streams_attention(cfg: ModelConfig, rows: int, positions: int,
     (ops/cached_attention.py: ``cached_attention.plan``), or None where
     the cache lies head-major under :func:`_cached_attention`'s own
     lines: a decode step (one position a row) of 8 rows or more,
-    attention over heads, on one chip, the cache in the compute type, a
-    position's keys whole lanes, a row's reach within the plan's VMEM.
+    attention over heads (in differential pairs or not), on one chip, the
+    cache in the compute type, a position's keys whole lanes, a row's
+    reach within the plan's VMEM. ``slots`` is what the layer's cache
+    holds: the call's reach, or a window's ring.
     ``generate`` lays a call's caches by it (a call decodes one position
     a step), the block takes the kernel by it and ``call_sizes`` counts
     by it. Shapes and types alone decide; nothing names a model."""
@@ -515,7 +637,181 @@ def streams_attention(cfg: ModelConfig, rows: int, positions: int,
             or jnp.dtype(cache_dtype) != jnp.dtype(cfg.compute_dtype)):
         return None
     return cached_attention.plan(rows, cfg.n_heads, cfg.kv_heads, slots,
-                                 cfg.head_dim, cfg.compute_dtype)
+                                 cfg.head_dim, cfg.compute_dtype,
+                                 paired=cfg.differential)
+
+
+def lays_dense(cfg: ModelConfig) -> bool:
+    """Whether a configuration's caches lie dense, ``(passes, rows, slots,
+    kv_heads · head_dim)``, at any number of rows: one that names a
+    window, a lent cache or the differential form goes through
+    :func:`_attend_lent_or_ring`, which knows that layout alone."""
+    return cfg.differential or bool(
+        {"window_attention", "cross_attention"} & set(cfg.layer_types))
+
+
+def lambda_init(layer: int) -> float:
+    """The fixed part of a differential layer's weight, by its depth."""
+    return 0.8 - 0.6 * float(np.exp(-0.3 * layer))
+
+
+# What the float32 scores of one :func:`_attend` may take: more rows than
+# fit go through it a block of rows at a time (at 64 rows × 40 heads × 256
+# × 768 the scores alone are 2 GB, their exponentials and mask as much
+# again).
+SCORE_BYTES = 512 * 1024 * 1024
+
+
+def _attend(q, keys, values, mask, written, scale: float,
+            differential: bool) -> jax.Array:
+    """q (B, S, H, D) on keys and values (B, K, KV, D) under ``mask``
+    (S, K); ``written`` (K,) says which of the K hold something, None:
+    all. Softmax in float32. Plain or grouped heads → (B, S, H, D). With
+    ``differential`` head (g, p, c) attends key head (g, c) and sums the
+    group's two value heads side by side → (B, S, groups, pairs a group,
+    2, 2·D): the two maps of every pair. Rows whose scores pass
+    ``SCORE_BYTES`` go in equal blocks, one after the other."""
+    b, s, h, d = q.shape
+    reach, kv = keys.shape[1:3]
+    fit = max(1, SCORE_BYTES // (4 * h * s * reach))
+    block = max(r for r in range(1, b + 1) if b % r == 0 and r <= fit)
+    if block < b:
+        def blocks(x):
+            return x.reshape(b // block, block, *x.shape[1:])
+
+        out = jax.lax.map(
+            lambda qkv: _attend(*qkv, mask, written, scale, differential),
+            (blocks(q), blocks(keys), blocks(values)))
+        return out.reshape(b, *out.shape[2:])
+    if written is not None:
+        # 0 × NaN is NaN: what was never written never reaches the sum
+        values = jnp.where(written[None, :, None, None], values, 0)
+    if differential:
+        groups = kv // 2
+        q = q.reshape(b, s, groups, h // kv, 2, d)
+        logits = jnp.einsum("bqgpcd,bkgcd->bgpcqk", q,
+                            keys.reshape(b, reach, groups, 2, d)
+                            ).astype(jnp.float32) * scale
+        probs = jax.nn.softmax(jnp.where(mask, logits, -1e30),
+                               axis=-1).astype(q.dtype)
+        return jnp.einsum("bgpcqk,bkge->bqgpce", probs,
+                          values.reshape(b, reach, groups, 2 * d))
+    q = q.reshape(b, s, kv, h // kv, d)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", q, keys
+                        ).astype(jnp.float32) * scale
+    probs = jax.nn.softmax(jnp.where(mask, logits, -1e30),
+                           axis=-1).astype(q.dtype)
+    return jnp.einsum("bkgqs,bskd->bqkgd", probs, values
+                      ).reshape(b, s, h, d)
+
+
+def _differential(maps: jax.Array, blk: dict, cfg: ModelConfig,
+                  layer: int) -> jax.Array:
+    """The two maps of every pair (B, S, ..., 2, 2·D) → the heads'
+    outputs (B, S, H, D): a1 − λ·a2, λ = exp(λq1·λk1) − exp(λq2·λk2) +
+    λ_init, RMSNorm over the 2·D lanes, times 1 − λ_init, and a pair's
+    2·D lanes as its two heads of D. λ and the difference in float32."""
+    b, s = maps.shape[:2]
+    lq1, lk1, lq2, lk2 = (blk[name].astype(jnp.float32) for name in LAMBDAS)
+    fixed = lambda_init(layer)
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + fixed
+    maps = maps.astype(jnp.float32)
+    diff = (maps[..., 0, :] - lam * maps[..., 1, :]).astype(cfg.compute_dtype)
+    normed = _rms_norm(diff, blk["sub_norm"], cfg.norm_eps) * (1.0 - fixed)
+    return normed.reshape(b, s, cfg.n_heads, cfg.head_dim)
+
+
+def _ring_positions(ring: int, start) -> jax.Array:
+    """The position each of a ring's slots holds before position
+    ``start`` is written: the last one below ``start`` that falls on the
+    slot (position p lies in slot p % ring); negative: never written."""
+    slot = jnp.arange(ring)
+    return start - 1 - ((start - 1 - slot) % ring)
+
+
+def _attend_lent_or_ring(q, k, v, cache: dict, slot: tuple,
+                         cfg: ModelConfig, kind: str,
+                         mesh: Optional[Mesh]) -> tuple:
+    """A cached call's attention over a dense cache (passes, B, slots,
+    KV · D) by the layer's ``kind`` → (the heads' outputs, or with
+    ``cfg.differential`` the pairs' two maps; the updated cache, None for
+    a cache that was lent).
+
+    "attention": the tokens' keys and values go in from position
+    ``start`` on and the tokens attend all up to themselves.
+    "cross_attention": ``cache`` is another layer's, which holds these
+    positions already; it is read and not written. "window_attention":
+    the cache is a ring, position p in slot p % slots. One position a
+    row is written, then attends the ring's written slots: without a
+    rotary turn at reading they are a set, and a full ring is the
+    window. A longer input (``start`` static) attends the ring as the
+    tokens before left it, beside its own keys, under the window's mask,
+    and then writes its last positions over the oldest.
+
+    A step :func:`streams_attention` finds goes through the kernel
+    (ops/cached_attention.py), every kind alike."""
+    t, start = slot
+    b, s_q, h, d = q.shape
+    slots = cache["k"].shape[2]
+    kv = cfg.kv_heads
+    window = cfg.sliding_window if kind == "window_attention" else 0
+    scale = cfg.score_scale
+
+    def write(old, new, at):
+        return _row_major(jax.lax.dynamic_update_slice(
+            old, new.reshape(1, b, new.shape[1], -1), (t, 0, at, 0)))
+
+    def mine(name):
+        return jax.lax.dynamic_index_in_dim(
+            cache[name], t, 0, keepdims=False).reshape(b, slots, kv, d)
+
+    q_pos = start + jnp.arange(s_q)
+    if window and s_q > 1:
+        if not isinstance(start, int):
+            raise ValueError("a window's ring takes more than one position "
+                             "a row only from a static start")
+        held = _ring_positions(slots, start)
+        k_pos = jnp.concatenate([held, q_pos])
+        mask = (k_pos[None, :] <= q_pos[:, None]) \
+            & (k_pos[None, :] > q_pos[:, None] - window)
+        out = _attend(q, jnp.concatenate([mine("k"), k], axis=1),
+                      jnp.concatenate([mine("v"), v], axis=1),
+                      mask & (k_pos >= 0)[None, :], k_pos >= 0, scale,
+                      cfg.differential)
+        # the last positions over the oldest, in at most two pieces
+        first = max(0, s_q - slots)
+        at = (start + first) % slots
+        wrap = first + min(slots - at, s_q - first)
+
+        def over_the_oldest(old, new):
+            old = write(old, new[:, first:wrap], at)
+            return write(old, new[:, wrap:], 0) if wrap < s_q else old
+
+        return out, {"k": over_the_oldest(cache["k"], k),
+                     "v": over_the_oldest(cache["v"], v)}
+
+    updated = None
+    if kind != "cross_attention":
+        at = start % slots if window else start
+        updated = cache = {name: write(cache[name], new, at)
+                           for name, new in (("k", k), ("v", v))}
+    reach = start + s_q
+    length = jnp.minimum(reach, slots) if window else reach
+    if streams_attention(cfg, b, s_q, slots, cache["k"].dtype,
+                         mesh) is not None:
+        from faabric_tpu.ops.cached_attention import cached_attention
+
+        out = cached_attention(q[:, 0], cache["k"], cache["v"], length,
+                               scale, t, paired=cfg.differential)
+        return (out.reshape(b, 1, kv // 2, h // kv, 2, 2 * d)
+                if cfg.differential else out[:, None]), updated
+    k_pos = jnp.arange(slots)
+    # a ring's slots hold the last positions in no order; all of them
+    # are within one position's window
+    mask = (k_pos[None, :] < length) if window \
+        else (k_pos[None, :] <= q_pos[:, None])
+    return _attend(q, mine("k"), mine("v"), mask, k_pos < length, scale,
+                   cfg.differential), updated
 
 
 def _attend_through_cache(q, k, v, cache: dict, slot: tuple, scale=None,
@@ -676,7 +972,22 @@ def resolve_impls(cfg: ModelConfig, mesh: Optional[Mesh] = None) -> ModelConfig:
     return cfg
 
 
-def _norm(x: jax.Array, scale: jax.Array, cfg: ModelConfig) -> jax.Array:
+def _layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
+                eps: float) -> jax.Array:
+    """LayerNorm over the last axis, its statistics in float32."""
+    wide = x.astype(jnp.float32)
+    mean = jnp.mean(wide, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(wide - mean), axis=-1, keepdims=True)
+    return ((wide - mean) * jax.lax.rsqrt(var + eps)).astype(x.dtype) \
+        * scale.astype(x.dtype) + bias.astype(x.dtype)
+
+
+def _norm(x: jax.Array, scale: jax.Array, cfg: ModelConfig,
+          bias: Optional[jax.Array] = None) -> jax.Array:
+    """The configuration's norm: RMSNorm with ``scale``, or LayerNorm
+    with ``scale`` and ``bias`` (``cfg.norm``)."""
+    if cfg.norm == "layer":
+        return _layer_norm(x, scale, bias, cfg.norm_eps)
     if cfg.norm_impl == "fused":
         from faabric_tpu.ops.rms_norm import rms_norm
 
@@ -698,7 +1009,6 @@ def _sharded_flash(q, k, v, mesh: Mesh):
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-@jax.named_scope(scopes.ATTENTION)
 def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
                        cfg: ModelConfig, mesh: Optional[Mesh] = None,
                        cache: Optional[dict] = None,
@@ -709,26 +1019,63 @@ def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
     ``cache`` they go through it (:func:`_attend_through_cache`).
     ``cfg.attention`` "latent" is :func:`_latent_attention`, behind the
     same norms and residual. Returns (x, the updated cache or None)."""
-    h = _norm(x, blk["ln1"], cfg)
+    return attention_of_kind(x, blk, positions, cfg, mesh, cache, slot)[:2]
+
+
+@jax.named_scope(scopes.ATTENTION)
+def attention_of_kind(x: jax.Array, blk: dict, positions: jax.Array,
+                      cfg: ModelConfig, mesh: Optional[Mesh] = None,
+                      cache: Optional[dict] = None,
+                      slot: Optional[tuple] = None, kind: str = "attention",
+                      layer: int = 0, lent: Optional[dict] = None) -> tuple:
+    """:func:`attention_sublayer` for a layer of ``kind`` at depth
+    ``layer`` (``ModelConfig.layer_types``). A "cross_attention" layer
+    projects queries alone and attends what ``lent`` holds: another
+    layer's cache in a cached call, else that layer's keys and values
+    ``{"k", "v"}`` (B, S, KV, D). Returns (x, the updated cache or None,
+    what the layer lends: its keys and values where it ran without a
+    cache, else None: a cached call lends the cache itself)."""
+    h = _norm(x, blk["ln1"], cfg, blk.get("ln1_b"))
     if cfg.attention == "latent":
         attn, cache = _latent_attention(h, blk, positions, cfg, cache, slot)
-        return _attention_residual(x, attn, blk, cfg), cache
+        return _attention_residual(x, attn, blk, cfg), cache, None
+    dt = cfg.compute_dtype
     if "wqkv" in blk:
-        qkv = jnp.einsum("bsd,dthe->tbshe", h,
-                         blk["wqkv"].astype(cfg.compute_dtype))
+        qkv = jnp.einsum("bsd,dthe->tbshe", h, blk["wqkv"].astype(dt))
         q, k, v = qkv[0], qkv[1], checkpoint_name(qkv[2], "v")
     else:
-        q = jnp.einsum("bsd,dhe->bshe", h,
-                       blk["wq"].astype(cfg.compute_dtype))
-        k, v = jnp.einsum("bsd,dtke->tbske", h,
-                          blk["wkv"].astype(cfg.compute_dtype))
+        q = jnp.einsum("bsd,dhe->bshe", h, blk["wq"].astype(dt))
+        k = v = None
+        if "wkv" in blk:
+            k, v = jnp.einsum("bsd,dtke->tbske", h, blk["wkv"].astype(dt))
+    if "bq" in blk:
+        q = q + blk["bq"].astype(dt)
+    if "bkv" in blk:
+        k, v = k + blk["bkv"][0].astype(dt), v + blk["bkv"][1].astype(dt)
     if cfg.position == "rope":
         q = checkpoint_name(
             _rope(q, positions, cfg.rope_theta, cfg.rope_pairing), "q_rope")
         k = checkpoint_name(
             _rope(k, positions, cfg.rope_theta, cfg.rope_pairing), "k_rope")
     scale = cfg.score_scale
-    if cache is not None:
+    lends = None
+    if lays_dense(cfg):
+        if slot is not None:        # a cached call: forward gives none
+            attn, cache = _attend_lent_or_ring(
+                q, k, v, lent if kind == "cross_attention" else cache,
+                slot, cfg, kind, mesh)
+        else:
+            if kind == "cross_attention":
+                k, v = lent["k"], lent["v"]
+            lends = {"k": k, "v": v}
+            at = jnp.arange(q.shape[1])
+            mask = at[None, :] <= at[:, None]
+            if kind == "window_attention":
+                mask &= at[None, :] > at[:, None] - cfg.sliding_window
+            attn = _attend(q, k, v, mask, None, scale, cfg.differential)
+        if cfg.differential:
+            attn = _differential(attn, blk, cfg, layer)
+    elif cache is not None:
         streamed = cache["k"].ndim == 4 and streams_attention(
             cfg, *q.shape[:2], cache["k"].shape[2], cache["k"].dtype,
             mesh) is not None
@@ -748,7 +1095,7 @@ def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
                               batch_axis="dp", head_axis="tp")
     else:
         attn = _attention(q, k, v, scale)
-    return _attention_residual(x, attn, blk, cfg), cache
+    return _attention_residual(x, attn, blk, cfg), cache, lends
 
 
 def _attention_residual(x, attn, blk: dict, cfg: ModelConfig) -> jax.Array:
@@ -757,6 +1104,8 @@ def _attention_residual(x, attn, blk: dict, cfg: ModelConfig) -> jax.Array:
     out = checkpoint_name(
         jnp.einsum("bshe,hed->bsd", attn,
                    blk["wo"].astype(cfg.compute_dtype)), "attn_proj")
+    if "bo" in blk:
+        out = out + blk["bo"].astype(cfg.compute_dtype)
     if cfg.norm_placement == "sandwich":
         out = _norm(out, blk["ln1_post"], cfg)
     return _join(x, out, cfg)
@@ -819,34 +1168,49 @@ def _feed_forward(h: jax.Array, blk: dict, cfg: ModelConfig,
 def _block(x: jax.Array, blk: dict, positions: jax.Array,
            cfg: ModelConfig, mesh: Optional[Mesh] = None,
            cache: Optional[dict] = None,
-           slot: Optional[tuple] = None, kind: str = "attention") -> tuple:
+           slot: Optional[tuple] = None, kind: str = "attention",
+           layer: int = 0, lent: Any = None) -> tuple:
     """The one transformer block, with or without its state of a call, in
     the kinds the configuration names. A "single" layer is a mixer of its
-    layer's ``kind`` and a feed-forward, ``blk`` their weights: attention,
-    ``cache`` its keys and values, or the state-space mixer
-    (models/ssm.py), ``cache`` its convolution window and recurrent state,
-    behind the same norm and residual. A
+    layer's ``kind`` and a feed-forward, ``blk`` their weights, behind
+    the same norm and residual: attention of a kind (``cache`` its keys
+    and values, a "window_attention"'s a ring), a state-space mixer
+    (models/ssm.py: "mamba", "mamba1"; ``cache`` its convolution window
+    and recurrent state), or a layer that keeps nothing and reads what
+    another lends (``lent``): a "cross_attention" the keys and values of
+    layer ``cfg.cache_source``, a "gated_memory" the memory of layer
+    ``cfg.memory_source``. A
     "shortcut" layer is two of those (``blk["halves"]``) and an expert
     layer that reads the first feed-forward's normed input and joins the
     residual after the second; its cache is ``{"attn": [the two
     attentions' caches], "counters": what its expert layer has counted
     so far in this call (models/moe.py:COUNTERS)}``. Returns (x, the
-    updated cache or None)."""
-    streamed = cache is not None and mesh is None
+    updated cache or None, what the layer lends to later ones or None:
+    a "mamba1" layer its memory (B, S, inner), an attention that ran
+    without a cache its keys and values; ``layer`` is its depth)."""
+    # a cached call on one chip (a layer that keeps nothing has no cache)
+    streamed = slot is not None and mesh is None
     if cfg.layer == "single":
-        if kind == "mamba":
-            from faabric_tpu.models.ssm import mixer
+        lends = None
+        if kind in ATTENDING:
+            x, cache, lends = attention_of_kind(
+                x, blk, positions, cfg, mesh, cache, slot, kind, layer, lent)
+        else:
+            from faabric_tpu.models import ssm
 
             with jax.named_scope(scopes.MIXER):
-                out, cache = mixer(_norm(x, blk["ln1"], cfg), blk, cfg,
-                                   cache)
+                h = _norm(x, blk["ln1"], cfg, blk.get("ln1_b"))
+                if kind == "mamba":
+                    out, cache = ssm.mixer(h, blk, cfg, cache)
+                elif kind == "mamba1":
+                    out, cache, lends = ssm.mixer1(h, blk, cfg, cache)
+                else:
+                    out = ssm.gated_memory(h, blk, cfg, lent)
                 x = _join(x, out, cfg)
-        else:
-            x, cache = attention_sublayer(x, blk, positions, cfg, mesh,
-                                          cache, slot)
         with jax.named_scope(scopes.FEED_FORWARD):
-            return _join(x, _feed_forward(_norm(x, blk["ln2"], cfg), blk,
-                                          cfg, streamed), cfg), cache
+            return _join(x, _feed_forward(
+                _norm(x, blk["ln2"], cfg, blk.get("ln2_b")), blk, cfg,
+                streamed), cfg), cache, lends
 
     from faabric_tpu.models.moe import expert_layer
 
@@ -865,7 +1229,17 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
         if cache is not None:
             cache = {"attn": caches,
                      "counters": cache["counters"] + counted}
-        return _join(x, branch, cfg), cache
+        return _join(x, branch, cfg), cache, None
+
+
+def lender_of(cfg: ModelConfig, layer: int, lends: Any) -> dict:
+    """What layer ``layer`` lends, keyed by the kind that borrows it."""
+    lent = {}
+    if layer == cfg.cache_source:
+        lent["cross_attention"] = lends
+    if layer == cfg.memory_source:
+        lent["gated_memory"] = lends
+    return lent
 
 
 def run_passes(x: jax.Array, carry: Any, params: dict, cfg: ModelConfig,
@@ -881,7 +1255,8 @@ def run_passes(x: jax.Array, carry: Any, params: dict, cfg: ModelConfig,
     def one_pass(x, carry, t):
         x, carry = stack(x, carry, t)
         with jax.named_scope(scopes.FINAL_NORM):
-            return _norm(x, params["ln_f"], cfg), carry
+            return _norm(x, params["ln_f"], cfg,
+                         params.get("ln_f_b")), carry
 
     if cfg.n_passes == 1:
         return one_pass(x, carry, 0)
@@ -1050,26 +1425,32 @@ def forward(params: dict, tokens: jax.Array, cfg: ModelConfig,
     x = embed(params, tokens, cfg)
     x = maybe_constrain(x, "dp", "sp", None)
 
-    # a layer's kind goes in by name: attention's is ``_block`` itself
-    of_kind = {"attention": _block, "mamba": partial(_block, kind="mamba")}
-    block_fns = [of_kind[kind] for kind in cfg.mixers]
+    # a layer's kind goes in by name, and its depth where a kind reads it
+    of_layer = [(kind, i if cfg.differential else 0)
+                for i, kind in enumerate(cfg.mixers)]
+    fns = {key: partial(_block, kind=key[0], layer=key[1])
+           for key in set(of_layer)}
+    block_fns = [fns[key] for key in of_layer]
     if cfg.remat:
         free = _free_bytes(mesh)
         if free is not None:
             free -= step_bytes(cfg, tokens.shape, mesh)
         kept = remat_plan(cfg, tokens.shape, mesh, free)["layers_kept"]
-        keeping = {kind: jax.checkpoint(
+        keeping = {key: jax.checkpoint(
             fn, static_argnums=(3, 4),
             policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
-            for kind, fn in of_kind.items()}
-        whole = {kind: jax.checkpoint(fn, static_argnums=(3, 4))
-                 for kind, fn in of_kind.items()}
-        block_fns = [(keeping if i < kept else whole)[kind]
-                     for i, kind in enumerate(cfg.mixers)]
+            for key, fn in fns.items()}
+        whole = {key: jax.checkpoint(fn, static_argnums=(3, 4))
+                 for key, fn in fns.items()}
+        block_fns = [(keeping if i < kept else whole)[key]
+                     for i, key in enumerate(of_layer)]
 
     def stack(x, carry, _t):
-        for block_fn, blk in zip(block_fns, params["blocks"]):
-            x, _ = block_fn(x, blk, positions, cfg, mesh)
+        lent = {}
+        for i, (block_fn, blk) in enumerate(zip(block_fns, params["blocks"])):
+            x, _, lends = block_fn(x, blk, positions, cfg, mesh,
+                                   lent=lent.get(cfg.mixers[i]))
+            lent.update(lender_of(cfg, i, lends))
             x = maybe_constrain(x, "dp", "sp", None)
         return x, carry
 

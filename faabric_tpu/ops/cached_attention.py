@@ -63,7 +63,8 @@ _MASKED = -1e30
 
 
 def plan(rows: int, heads: int, kv_heads: int, slots: int, head_dim: int,
-         dtype=jnp.bfloat16, block_rows: int | None = None):
+         dtype=jnp.bfloat16, block_rows: int | None = None,
+         paired: bool = False):
     """How :func:`cached_attention` runs a call of these shapes, or None
     where it does not: ``block_rows`` (rows a grid step), ``steps``,
     ``vmem_bytes`` (what the call asks for: a step's keys, values,
@@ -71,9 +72,14 @@ def plan(rows: int, heads: int, kv_heads: int, slots: int, head_dim: int,
     every block, the values once more with their unwritten slots zeroed,
     and a row's scores, probabilities and weighted sum in float32) and
     ``streamed_bytes`` (the keys and values once). A pure function of its
-    arguments. ``block_rows`` overrides the choice (sweeps and tests)."""
+    arguments. ``block_rows`` overrides the choice (sweeps and tests).
+    ``paired``: the differential form, in which a head keeps the columns
+    of its pair's two value heads (whole lanes: ``2 · head_dim``), so the
+    outputs are twice as wide."""
     width = kv_heads * head_dim
     if rows < MIN_ROWS or heads % kv_heads or width % LANE:
+        return None
+    if paired and (kv_heads % 2 or (2 * head_dim) % LANE):
         return None
     item = jnp.dtype(dtype).itemsize
     a_row = 2 * slots * width * item
@@ -85,7 +91,8 @@ def plan(rows: int, heads: int, kv_heads: int, slots: int, head_dim: int,
         block_rows = max(fits)
     elif rows % block_rows:
         return None
-    blocks = block_rows * (a_row + (heads + heads // kv_heads) * width * item)
+    kept = 2 * heads * head_dim if paired else heads // kv_heads * width
+    blocks = block_rows * (a_row + (heads * width + kept) * item)
     temporaries = (block_rows * a_row // 2
                    + heads * (2 * slots + 2 * width) * 4)
     return {"block_rows": block_rows, "steps": rows // block_rows,
@@ -94,12 +101,16 @@ def plan(rows: int, heads: int, kv_heads: int, slots: int, head_dim: int,
 
 
 def _cached_attention_kernel(scalars, q_ref, k_ref, v_ref, o_ref, *,
-                             scale: float, kv_heads: int):
+                             scale: float, kv_heads: int, paired: bool):
     """One grid step: a block of rows. q (rows, heads, width) holds the
     block-diagonal queries, the heads ordered (place in the group,
     key/value head); k and v (rows, slots, width) are those rows' caches;
     o (rows, heads / kv_heads, width) takes, at place g of the group and
-    key/value head k's columns, head (k, g)'s weighted sum."""
+    key/value head k's columns, head (k, g)'s weighted sum. ``paired``:
+    the heads in their own order, head h on key/value head 2·(h // (2·G))
+    + h % 2, G heads a key/value head; o (rows, heads, 2·head_dim) takes
+    head h's weighted sum over the columns of value heads 2·(h // (2·G))
+    and the next: whole lanes, sliced where they start."""
     length = scalars[0]
     block_rows, heads, width = q_ref.shape
     slots = k_ref.shape[1]
@@ -120,13 +131,21 @@ def _cached_attention_kernel(scalars, q_ref, k_ref, v_ref, o_ref, *,
                  ).astype(v_ref.dtype)
         mixed = jnp.dot(probs, jnp.where(written, v_ref[r], 0),
                         preferred_element_type=f32)
+        if paired:
+            lanes = 2 * head_dim
+            pair = jax.lax.broadcasted_iota(jnp.int32, (heads, 1), 0) \
+                // (2 * heads // kv_heads)
+            o_ref[r] = sum(
+                jnp.where(pair == g, mixed[:, g * lanes:(g + 1) * lanes], 0.0)
+                for g in range(kv_heads // 2)).astype(o_ref.dtype)
+            continue
         mixed = mixed.reshape(heads // kv_heads, kv_heads, width)
         o_ref[r] = jnp.sum(jnp.where(own[None], mixed, 0.0), axis=1
                            ).astype(o_ref.dtype)
 
 
 def cached_attention(q, cache_k, cache_v, length, scale: float, t=0,
-                     block_rows: int | None = None):
+                     block_rows: int | None = None, paired: bool = False):
     """q (rows, heads, head_dim), one position a row, the last of the
     first ``length`` positions of pass ``t`` of the dense caches
     (passes, rows, slots, kv_heads · head_dim) → (rows, heads, head_dim)
@@ -136,7 +155,8 @@ def cached_attention(q, cache_k, cache_v, length, scale: float, t=0,
     rows, heads, head_dim = q.shape
     _, _, slots, width = cache_k.shape
     kv_heads = width // head_dim
-    how = plan(rows, heads, kv_heads, slots, head_dim, q.dtype, block_rows)
+    how = plan(rows, heads, kv_heads, slots, head_dim, q.dtype, block_rows,
+               paired)
     if how is None:
         raise ValueError(
             f"cached_attention does not take {rows} rows of {heads} heads "
@@ -144,11 +164,22 @@ def cached_attention(q, cache_k, cache_v, length, scale: float, t=0,
             f"(block_rows {block_rows})")
     block_rows, group = how["block_rows"], heads // kv_heads
     item = jnp.dtype(q.dtype).itemsize
-    # head (k, g)'s lanes into key/value head k's columns of row (g, k)
-    grouped = q.reshape(rows, kv_heads, group, head_dim).transpose(0, 2, 1, 3)
-    diagonal = jnp.where(
-        jnp.eye(kv_heads, dtype=bool)[None, None, :, :, None],
-        grouped[:, :, :, None, :], 0).reshape(rows, heads, width)
+    if paired:
+        # head h's lanes into the columns of its key head, in place
+        h = jnp.arange(heads)
+        mine = (2 * (h // (2 * group)) + h % 2)[:, None] \
+            == jnp.arange(kv_heads)[None, :]
+        diagonal = jnp.where(mine[None, :, :, None], q[:, :, None, :],
+                             0).reshape(rows, heads, width)
+        out_block = (heads, 2 * head_dim)
+    else:
+        # head (k, g)'s lanes into key/value head k's columns of row (g, k)
+        grouped = q.reshape(rows, kv_heads, group, head_dim
+                            ).transpose(0, 2, 1, 3)
+        diagonal = jnp.where(
+            jnp.eye(kv_heads, dtype=bool)[None, None, :, :, None],
+            grouped[:, :, :, None, :], 0).reshape(rows, heads, width)
+        out_block = (group, width)
     scalars = jnp.stack([jnp.asarray(length, jnp.int32),
                          jnp.asarray(t, jnp.int32)])
     interpret = jax.default_backend() == "cpu"
@@ -160,7 +191,7 @@ def cached_attention(q, cache_k, cache_v, length, scale: float, t=0,
                             for c in (cache_k, cache_v))
     out = pl.pallas_call(
         functools.partial(_cached_attention_kernel, scale=float(scale),
-                          kv_heads=kv_heads),
+                          kv_heads=kv_heads, paired=paired),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(how["steps"],),
@@ -172,10 +203,10 @@ def cached_attention(q, cache_k, cache_v, length, scale: float, t=0,
                 pl.BlockSpec((None, block_rows, slots, width),
                              lambda i, s: (s[1], i, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((block_rows, group, width),
+            out_specs=pl.BlockSpec((block_rows, *out_block),
                                    lambda i, s: (i, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((rows, group, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, *out_block), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=how["vmem_bytes"] + _VMEM_HEADROOM),
@@ -183,9 +214,11 @@ def cached_attention(q, cache_k, cache_v, length, scale: float, t=0,
             flops=4 * rows * heads * slots * width,
             transcendentals=rows * heads * slots,
             bytes_accessed=how["streamed_bytes"]
-            + rows * (heads + group) * width * item),
+            + rows * (heads * width + out_block[0] * out_block[1]) * item),
         interpret=interpret,
         name="cached_attention",
     )(scalars, diagonal, cache_k, cache_v)
+    if paired:
+        return out
     return out.reshape(rows, group, kv_heads, head_dim
                        ).transpose(0, 2, 1, 3).reshape(rows, heads, head_dim)

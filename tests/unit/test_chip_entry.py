@@ -92,7 +92,7 @@ def test_chip_smoke_rehearsal_drives_the_whole_flow_on_cpu():
     summary = json.loads(lines[-2])
     phases = summary["phases"]
     assert set(phases) == {"kernels", "train", "decode", "latent_experts",
-                           "state_space", "gang"}
+                           "state_space", "shared_state", "gang"}
     # the feed-forward kernel at the state-space toy's widths: one tile
     kernels = phases["kernels"]
     assert kernels["gated_ffn_rel_err"] < 8e-3
@@ -110,6 +110,20 @@ def test_chip_smoke_rehearsal_drives_the_whole_flow_on_cpu():
     assert hybrid["cache_bytes"] == 2 * 4 * 2 * 128 * 16 * 2
     assert hybrid["state_bytes"] == 4 * (3 * 192 + 8 * 16 * 32) * 2
     assert hybrid["scan_chunks"] == 2
+    # every kind of a decoder-hybrid-decoder at the toy's widths, 8 rows:
+    # two rings of 16 slots, one shared cache of 128, three states; the
+    # two windows, the full attention and the cross attention stream
+    shared = phases["shared_state"]
+    assert max(shared["prefill_rel_err"],
+               shared["cached_steps_rel_err"]) < 4e-2
+    assert (shared["rows"], shared["prompt"]) == (8, 20)
+    one = 8 * 4 * 64 * 4
+    assert shared["window_slots"] == 16
+    assert shared["window_cache_bytes"] == 2 * 2 * 16 * one
+    assert shared["shared_cache_bytes"] == 2 * 128 * one
+    assert shared["state_bytes"] == 3 * 8 * (8 + 3) * 1024 * 4
+    assert shared["attention_streamed_layers"] == 4
+    assert shared["prefill_skipped_layers"] == 2
     latent = phases["latent_experts"]
     assert max(latent["prefill_rel_err"],
                latent["cached_steps_rel_err"]) < 4e-2

@@ -113,13 +113,18 @@ def test_gated_ffn_compiles_for_v5e(call, one_chip, as_on_tpu):
     assert "gated_ffn" in compiled
 
 
-# (rows, heads, key/value heads, slots, head_dim): the cached step of
-# serve_granite_1chip, the rule's lower edge at its widths, equal heads of
-# 128 lanes over the longest reach a grid step holds
+# (rows, heads, key/value heads, slots, head_dim[, paired]): the cached
+# step of serve_granite_1chip, the rule's lower edge at its widths, equal
+# heads of 128 lanes over the longest reach a grid step holds, and
+# serve_phi4flash_1chip's two calls in the differential form: the shared
+# cache at the call's reach (the largest row a grid step takes) and a
+# window's ring
 ATTENTION_CALLS = {
     "serve_granite_1chip": (64, 32, 8, 640, 64),
     "rows_8": (8, 32, 8, 640, 64),
     "equal_heads_of_128": (16, 16, 16, 512, 128),
+    "serve_phi4flash_1chip_shared": (64, 40, 20, 768, 64, True),
+    "serve_phi4flash_1chip_ring": (64, 40, 20, 512, 64, True),
 }
 
 
@@ -130,14 +135,17 @@ def test_cached_attention_compiles_for_v5e(call, one_chip, as_on_tpu):
     caches are held to HBM (no copy of a whole cache into VMEM ahead)."""
     from faabric_tpu.ops.cached_attention import cached_attention, plan
 
-    rows, heads, kv, slots, d = ATTENTION_CALLS[call]
-    assert plan(rows, heads, kv, slots, d, jnp.bfloat16) is not None
+    rows, heads, kv, slots, d, *paired = ATTENTION_CALLS[call]
+    paired = bool(paired)
+    assert plan(rows, heads, kv, slots, d, jnp.bfloat16,
+                paired=paired) is not None
 
     def shaped(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     compiled = jax.jit(
-        lambda q, k, v, n: cached_attention(q, k, v, n, 1 / 64)).lower(
+        lambda q, k, v, n: cached_attention(q, k, v, n, 1 / 64,
+                                            paired=paired)).lower(
         shaped(rows, heads, d), shaped(1, rows, slots, kv * d),
         shaped(1, rows, slots, kv * d), shaped(dtype=jnp.int32)
     ).compile().as_text()

@@ -196,7 +196,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         first, count = sz["experts_held"]
         mine = dict(blk, experts=jax.tree.map(
             lambda w: w[first:first + count], blk["experts"]))
-        got, _ = transformer._block(x, mine, positions, config(sz))
+        got, *_ = transformer._block(x, mine, positions, config(sz))
         total += np.asarray(got) - alike
         # and each share is the reference's share
         share = np.stack([np.asarray(ref.layer(row, mine, sz)[0])

@@ -202,7 +202,7 @@ def test_one_pass_of_the_gelu_kinds_is_the_first_block_bit_for_bit():
         h = _rms_norm(x, blk["ln2"])
         return x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]
 
-    got, cache = _block(x, blk, positions, cfg)
+    got, cache, _ = _block(x, blk, positions, cfg)
     assert cache is None
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(first_block(x)))
