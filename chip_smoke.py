@@ -23,7 +23,11 @@ Guests (user ``smoke``), all on the chips the planner pinned:
   of ``benchmarks/configs/granite-4.0-h-micro.json``, 64 rows, against
   the float32 ``jnp`` lines; the cached step's attention over a dense
   cache (ops/cached_attention.py) at the same file's heads, 64 rows over
-  640 slots, against the block's own lines in float32;
+  640 slots, against the block's own lines in float32; a prefill chunk's
+  Mamba-1 recurrence with S kept on the chip (ops/selective_scan.py) at
+  the widths of ``benchmarks/configs/phi-4-mini-flash-reasoning.json``,
+  64 rows × 256 positions from a carried state, against ``mixer1``'s own
+  ``lax.scan`` lines;
   plus the whole forward (one layer, full width) with the kernels against
   the same forward with the ``jnp`` impls.
 - ``train``   — a gang of one rank per chip; the leader lays the mesh over
@@ -50,7 +54,8 @@ Guests (user ``smoke``), all on the chips the planner pinned:
   attention on the full layer's cache; LayerNorm, biases, a tied head) at
   the widths of ``benchmarks/configs/phi-4-mini-flash-reasoning.json``,
   the shallowest stack with every kind, 64 rows: prefill in two chunks
-  whose second wraps the rings, the cross-decoder at the last position
+  whose second wraps the rings and starts the selective-scan kernel from
+  the S the first left, the cross-decoder at the last position
   alone, then two cached steps through the cached-attention kernel in its
   differential, ring and read-only forms, logits of four rows against
   ``benchmarks/reference/phi4flash.py``.
@@ -112,6 +117,11 @@ TOL_RMS_NORM = 8e-3
 TOL_GATED_FFN = 8e-3
 # The probabilities and the output are rounded to bfloat16 once each
 TOL_CACHED_ATTENTION = 8e-3
+# Against ``mixer1``'s own lines at the same rounding points: another
+# exponential's last bit and another order of the sum over the state
+# move ``y`` by a bfloat16 rounding (2⁻⁹) at most; its first run on the
+# v5e read 0 (my chip run, PR 40)
+TOL_SELECTIVE_SCAN = 2e-3
 TOL_MODEL_LOGITS = 4e-2
 # One double layer in bfloat16 against the float32 reference: its first run
 # on the v5e measured 0.012 (my chip run, PR 31); four layers read 0.02.
@@ -230,6 +240,10 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
         plan as attention_plan,
     )
     from faabric_tpu.ops.gated_ffn import gated_ffn, plan as ffn_plan
+    from faabric_tpu.ops.selective_scan import (
+        plan as scan_plan,
+        selective_scan,
+    )
     from faabric_tpu.ops.rms_norm import (
         _reference_rms_norm,
         rms_norm,
@@ -403,6 +417,44 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                     q1, *dense),
                 jax.jit(ref_cached)(q1, *dense))
 
+            # A prefill chunk's recurrence at the widths of the cell
+            # whose layers lend and borrow state (tests: its toy's), from
+            # a carried state, against ``mixer1``'s own lines
+            from benchmarks import program_phi4flash
+            from faabric_tpu.models.ssm import _scan1
+
+            with open(SHARED_CONFIG if on_chip else SHARED_CONFIG_TINY) as f:
+                kinds = program_phi4flash.model_config(json.load(f))
+            lanes, n = kinds.ssm_inner, kinds.ssm_d_state
+            length = 256 if on_chip else 24
+            out["selective_scan_plan"] = scan_plan(rows, length, lanes, n,
+                                                   jnp.bfloat16)
+            out["on_kernel_path"]["selective_scan"] = \
+                out["selective_scan_plan"] is not None
+            _require(out["on_kernel_path"]["selective_scan"],
+                     f"selective_scan refuses {rows} rows of {length} × "
+                     f"{lanes} with a state of {n}")
+            x, b_in, c_in, s0 = (
+                jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                for shape in ((rows, length, lanes), (rows, length, n),
+                              (rows, length, n), (rows, n, lanes)))
+            dt = jax.nn.softplus(jnp.asarray(
+                rng.randn(rows, length, lanes) - 2, jnp.float32))
+            a = -jnp.exp(jnp.asarray(rng.randn(n, lanes), jnp.float32))
+            d_skip = jnp.asarray(rng.randn(lanes), jnp.float32)
+
+            def ref_scan(x, dt, b_in, c_in, s0):
+                state, y = _scan1(s0.astype(jnp.float32), x, b_in, c_in, dt,
+                                  a)
+                return (y + d_skip * x.astype(jnp.float32)).astype(x.dtype), \
+                    state
+
+            got = jax.jit(lambda *call: selective_scan(
+                *call[:4], a, d_skip, call[4]))(x, dt, b_in, c_in, s0)
+            want = jax.jit(ref_scan)(x, dt, b_in, c_in, s0)
+            out["selective_scan_rel_err"] = max(
+                _rel_err(got[0], want[0]), _rel_err(got[1], want[1]))
+
             # The whole forward at full width, depth cut to one layer:
             # kernels against the jnp impls on the same weights
             one = dataclasses.replace(
@@ -428,6 +480,8 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                  f"gated_ffn {out}")
         _require(out["cached_attention_rel_err"] <= TOL_CACHED_ATTENTION,
                  f"cached_attention {out}")
+        _require(out["selective_scan_rel_err"] <= TOL_SELECTIVE_SCAN,
+                 f"selective_scan {out}")
         _require(out["model_logits_rel_err"] <= TOL_MODEL_LOGITS,
                  f"model logits {out}")
         if on_chip:
@@ -736,7 +790,7 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
             **{name: counted[name] for name in (
                 "window_slots", "window_cache_bytes", "shared_cache_bytes",
                 "state_bytes", "attention_streamed_layers",
-                "prefill_skipped_layers")})
+                "scan_streamed_layers", "prefill_skipped_layers")})
         _require(np.isfinite(got).all(), "a logit is not finite")
         _require([None if c is None else sorted(c) for c in cache]
                  == [["conv", "state"], ["k", "v"]] * 3 + [None, None],
@@ -744,6 +798,8 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                  f"{[None if c is None else sorted(c) for c in cache]}")
         _require(counted["attention_streamed_layers"] == 4,
                  f"{counted['attention_streamed_layers']} attentions stream")
+        _require(counted["scan_streamed_layers"] == 3,
+                 f"{counted['scan_streamed_layers']} scans keep S on chip")
         for name in ("prefill_rel_err", "cached_steps_rel_err"):
             _require(out[name] < TOL_SHARED_STATE_LOGITS,
                      f"{name} {out[name]}")

@@ -155,7 +155,12 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     and values they stream a step as the kernel reads them (a cache's
     allocated slots, a lent cache once a reader), every pass, for a call
     without a mesh
-    (``transformer.streams_attention``); 0 where none does.
+    (``transformer.streams_attention``); 0 where none does. Beside the
+    rings' and the lent state's counters, ``scan_streamed_layers`` is the
+    "mamba1" layers whose prefill chunks run their recurrence through the
+    kernel that keeps S on the chip (ops/selective_scan.py) and
+    ``scan_streamed_bytes`` what they stream a call's prefill, for a call
+    without a mesh (``ssm.streams_scan``); 0 where none does.
     ``generate`` sizes its cache from this; a server reports it beside
     its answers."""
     slots = _cache_slots(cfg, prompt_len + n_tokens)
@@ -209,7 +214,13 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
                              for _, length in chunks) if count("mamba")
                          else len(chunks) if count("mamba1") else 0))
     if lays_dense(cfg) or count("mamba1"):
+        scans = [ssm.streams_scan(cfg, batch, length) for _, length in
+                 _prefill_chunks(prompt_len, prefill_chunk)
+                 ] if count("mamba1") else []
         sizes.update(
+            scan_streamed_layers=count("mamba1") if any(scans) else 0,
+            scan_streamed_bytes=count("mamba1") * sum(
+                plan["streamed_bytes"] for plan in scans if plan),
             window_layers=count("window_attention"),
             window_slots=_ring_slots(cfg, slots)
             if count("window_attention") else 0,

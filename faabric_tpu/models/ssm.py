@@ -26,10 +26,14 @@ bottleneck of R = ``cfg.ssm_dt_rank``:
     y_t = S_t · C_t + D ⊙ x_t;  out = (y ⊙ silu(z)) · ssm_out
 
 The decay differs by lane and state index, so there is no matrix form over
-a chunk: a cached step is the recurrence, a longer input a ``lax.scan`` of
-the same step along its positions from the state the call before left, its
-carry one (B, N, E) float32 array; nothing of (B, positions, E, N) is ever
-made. ``y`` before the gate is the layer's memory, which "gated_memory"
+a chunk: a cached step is the recurrence, a longer input the same step
+along its positions from the state the call before left; nothing of (B,
+positions, E, N) is ever made. Where the call is a cached one on one chip
+(a prefill chunk) and :func:`streams_scan` finds its shape, the positions
+go through one kernel that keeps S, (B, N, E) float32, in VMEM from the
+first to the last (ops/selective_scan.py); elsewhere they are a
+``lax.scan`` whose carry S is. ``y`` before the gate is the layer's
+memory, which "gated_memory"
 layers (:func:`gated_memory`: ``(silu(h · gmu_in) ⊙ y) · gmu_out``) read at
 the same position and keep nothing of.
 
@@ -265,11 +269,36 @@ def _step1(state, at, a):
     return state, jnp.sum(state * c.astype(f32)[:, :, None], axis=1)
 
 
-def mixer1(h: jax.Array, blk: dict, cfg, cache: Optional[dict] = None
-           ) -> tuple:
+def _scan1(state, x, b, c, dt, a):
+    """:func:`_step1` along the positions: ``state`` (B, N, E) float32, x
+    and dt (B, S, E), b and c (B, S, N) → (the state after the last, y
+    (B, S, E) float32). The carry S lies in HBM."""
+    along = tuple(m.swapaxes(0, 1) for m in (x, b, c, dt))
+    state, y = jax.lax.scan(lambda st, at: _step1(st, at, a), state, along)
+    return state, y.swapaxes(0, 1)
+
+
+def streams_scan(cfg, rows: int, positions: int):
+    """How a cached call on one chip runs a "mamba1" layer's recurrence
+    over ``rows`` rows × ``positions`` through the one kernel that keeps S
+    on the chip (ops/selective_scan.py: ``selective_scan.plan``), or None
+    where it runs :func:`mixer1`'s own lines: more than one position,
+    lanes and a state size the chip's tiles divide. ``call_sizes`` counts
+    by it. Shapes alone decide; nothing names a model."""
+    from faabric_tpu.ops import selective_scan
+
+    return selective_scan.plan(rows, positions, cfg.ssm_inner,
+                               cfg.ssm_d_state, cfg.compute_dtype)
+
+
+def mixer1(h: jax.Array, blk: dict, cfg, cache: Optional[dict] = None,
+           streamed: bool = False) -> tuple:
     """The Mamba-1 mixer on a normed state h (B, S, D) → (its output
     (B, S, D), the updated cache or None, the memory y (B, S, E) in the
-    compute type: the recurrence's output before the gate)."""
+    compute type: the recurrence's output before the gate). ``streamed``
+    says that the call may go through the kernel (a cached call on one
+    chip: no gradient is ever taken there) where :func:`streams_scan`
+    finds its shape."""
     dtype = cfg.compute_dtype
     bsz, s, _ = h.shape
     inner, n, rank = cfg.ssm_inner, cfg.ssm_d_state, cfg.ssm_dt_rank
@@ -287,17 +316,20 @@ def mixer1(h: jax.Array, blk: dict, cfg, cache: Optional[dict] = None
                    preferred_element_type=jnp.float32)
         + blk["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(blk["A_log"].astype(jnp.float32)).T           # (N, E)
-    state = start["state"].astype(jnp.float32)
-    if s == 1:
-        state, y = _step1(state, (x[:, 0], b[:, 0], c[:, 0], dt[:, 0]), a)
-        y = y[:, None]
+    if streamed and streams_scan(cfg, bsz, s) is not None:
+        from faabric_tpu.ops.selective_scan import selective_scan
+
+        y, state = selective_scan(x, dt, b, c, a, blk["D"], start["state"])
     else:
-        along = tuple(m.swapaxes(0, 1) for m in (x, b, c, dt))
-        state, y = jax.lax.scan(lambda st, at: _step1(st, at, a), state,
-                                along)
-        y = y.swapaxes(0, 1)
-    y = (y + blk["D"].astype(jnp.float32) * x.astype(jnp.float32)
-         ).astype(dtype)
+        state = start["state"].astype(jnp.float32)
+        if s == 1:
+            state, y = _step1(state, (x[:, 0], b[:, 0], c[:, 0], dt[:, 0]),
+                              a)
+            y = y[:, None]
+        else:
+            state, y = _scan1(state, x, b, c, dt, a)
+        y = (y + blk["D"].astype(jnp.float32) * x.astype(jnp.float32)
+             ).astype(dtype)
     out = (y * jax.nn.silu(z)) @ blk["ssm_out"].astype(dtype)
     if cache is not None:
         cache = {"conv": window, "state": state.astype(dtype)}

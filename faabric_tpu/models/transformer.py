@@ -1203,7 +1203,8 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
                 if kind == "mamba":
                     out, cache = ssm.mixer(h, blk, cfg, cache)
                 elif kind == "mamba1":
-                    out, cache, lends = ssm.mixer1(h, blk, cfg, cache)
+                    out, cache, lends = ssm.mixer1(h, blk, cfg, cache,
+                                                   streamed)
                 else:
                     out = ssm.gated_memory(h, blk, cfg, lent)
                 x = _join(x, out, cfg)

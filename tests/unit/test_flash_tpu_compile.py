@@ -1,5 +1,5 @@
-"""The flash kernels, the feed-forward kernel and the cached-attention
-kernel compiled by the TPU's own
+"""The flash kernels, the feed-forward kernel, the cached-attention
+kernel and the selective-scan kernel compiled by the TPU's own
 compiler for a described v5e, at real widths, without a chip: what the interpreter cannot refuse
 (a block Mosaic cannot tile, more VMEM than a kernel may take) fails here
 and costs no chip time. Nothing runs, so nothing is said about results
@@ -111,6 +111,45 @@ def test_gated_ffn_compiles_for_v5e(call, one_chip, as_on_tpu):
         shaped(d_ff, d_model)).compile().as_text()
     assert compiled.count("tpu_custom_call") == 1
     assert "gated_ffn" in compiled
+
+
+# (rows, positions, lanes, state size): a prefill chunk of
+# serve_phi4flash_1chip, the smoke's longer chunk (three blocks of
+# positions), a chunk the block does not divide, and one shorter than a
+# sublane tile at the rule's fewest lanes
+SCAN_CALLS = {
+    "serve_phi4flash_1chip": (64, 256, 5120, 16),
+    "smoke_384": (64, 384, 5120, 16),
+    "short_last_block": (8, 200, 5120, 16),
+    "five_positions": (3, 5, 128, 8),
+}
+
+
+@pytest.mark.parametrize("call", sorted(SCAN_CALLS))
+def test_selective_scan_compiles_for_v5e(call, one_chip, as_on_tpu):
+    """The tile and block :func:`selective_scan.plan` picks are ones
+    Mosaic can cut, and the call fits the VMEM the plan names: it is
+    compiled with no more than that and the headroom."""
+    from faabric_tpu.ops.selective_scan import plan, selective_scan
+
+    rows, length, lanes, n = SCAN_CALLS[call]
+    how = plan(rows, length, lanes, n, jnp.bfloat16)
+    assert how is not None and how["vmem_bytes"] < 48 * 1024 * 1024
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(selective_scan).lower(
+        shaped(rows, length, lanes),
+        shaped(rows, length, lanes, dtype=jnp.float32),
+        shaped(rows, length, n), shaped(rows, length, n),
+        shaped(n, lanes, dtype=jnp.float32),
+        shaped(lanes, dtype=jnp.float32), shaped(rows, n, lanes)
+    ).compile().as_text()
+    assert compiled.count("tpu_custom_call") == 1
+    assert "selective_scan" in compiled
+    # S never lies in HBM but at the chunk's two ends: no loop is left
+    assert "while(" not in compiled
 
 
 # (rows, heads, key/value heads, slots, head_dim[, paired]): the cached

@@ -464,6 +464,123 @@ def test_cached_attention_plan_holds_the_cells_call():
 
 
 # ---------------------------------------------------------------------------
+# Mamba-1's recurrence over a chunk of positions
+# ---------------------------------------------------------------------------
+
+# (rows, positions, lanes, state size, dtype, from a carried state, tile):
+# a length the block of positions divides (three blocks of 128) and
+# lengths it does not (5, a block of its own and no sublane tile; 200, a
+# short last block), 8 and 3 rows, both types, from zero and from a state
+# the chunk before left, the plan's own tile and a smaller one
+SELECTIVE_SCAN_CALLS = {
+    "rows_8_of_384_bf16_carried": (8, 384, 256, 16, jnp.bfloat16, True, None),
+    "rows_8_of_384_f32_zero": (8, 384, 256, 16, jnp.float32, False,
+                               (4, 128, 128)),
+    "rows_3_of_5_bf16_zero": (3, 5, 256, 8, jnp.bfloat16, False, None),
+    "rows_3_of_5_f32_carried": (3, 5, 128, 16, jnp.float32, True, None),
+    "rows_8_of_200_bf16_zero": (8, 200, 128, 8, jnp.bfloat16, False, None),
+    "rows_3_of_200_f32_carried": (3, 200, 256, 16, jnp.float32, True,
+                                  (1, 128, 128)),
+}
+
+
+@pytest.mark.parametrize("call", sorted(SELECTIVE_SCAN_CALLS))
+def test_selective_scan_matches_mixer1s_own_lines(call):
+    """The kernel, interpreted, against ``mixer1``'s lines on the same
+    operands: ``_scan1``, a ``lax.scan`` of ``_step1`` along the positions,
+    ``D · x`` added in float32 and one cast. ``y`` and the state it leaves."""
+    from faabric_tpu.models.ssm import _scan1
+    from faabric_tpu.ops.selective_scan import plan, selective_scan
+
+    rows, length, lanes, n, dtype, carried, tile = SELECTIVE_SCAN_CALLS[call]
+    rng = np.random.RandomState(rows + length + lanes)
+    f32 = jnp.float32
+    x = jnp.asarray(rng.randn(rows, length, lanes), dtype)
+    dt = jax.nn.softplus(jnp.asarray(rng.randn(rows, length, lanes) - 2, f32))
+    b, c = (jnp.asarray(rng.randn(rows, length, n), dtype) for _ in range(2))
+    a = -jnp.exp(jnp.asarray(rng.randn(n, lanes), f32))
+    d = jnp.asarray(rng.randn(lanes), f32)
+    state = jnp.asarray(rng.randn(rows, n, lanes) if carried
+                        else np.zeros((rows, n, lanes)), dtype)
+    how = plan(rows, length, lanes, n, dtype, tile)
+    assert how["grid"] == (rows // how["rows"],
+                           -(-length // how["positions"]),
+                           lanes // how["lanes"])
+    if tile is not None:
+        assert (how["rows"], how["lanes"], how["positions"]) == tile
+    got_y, got_state = selective_scan(x, dt, b, c, a, d, state, tile=tile)
+
+    def own_lines(x, dt, b, c, state):
+        state, y = _scan1(state.astype(f32), x, b, c, dt, a)
+        return (y + d * x.astype(f32)).astype(dtype), state
+
+    want_y, want_state = jax.jit(own_lines)(x, dt, b, c, state)
+    assert got_y.shape == want_y.shape and got_y.dtype == want_y.dtype
+    assert got_state.shape == want_state.shape and got_state.dtype == f32
+    for got, want, tol in ((got_y, want_y,
+                            1e-5 if dtype == jnp.float32 else 1e-2),
+                           (got_state, want_state, 1e-5)):
+        want = np.asarray(want, np.float32)
+        assert np.abs(want).max() > 1.0
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   rtol=tol, atol=tol * np.abs(want).max())
+
+
+def test_selective_scan_plan_holds_the_cells_call():
+    """``plan`` from shapes alone: a prefill chunk of
+    ``serve_phi4flash_1chip`` (64 rows × 256 positions × 5120 lanes, a
+    state of 16) in bfloat16, and what it refuses."""
+    from faabric_tpu.ops.selective_scan import (
+        BLOCK,
+        MAX_LANES,
+        MAX_ROWS,
+        plan,
+        selective_scan,
+    )
+
+    cell = plan(64, 256, 5120, 16, jnp.bfloat16)
+    a_step = 8 * 128 * 512                # rows × positions × lanes
+    assert cell == {
+        "rows": 8, "lanes": 512, "positions": 128, "grid": (8, 2, 10),
+        # x and y in bfloat16 and dt in float32, B and C padded to a lane
+        # tile, A and D, the state in and out, all twice; eight rows' S,
+        # their B and C along the lanes, a row's dt · x and y
+        "vmem_bytes": 2 * (a_step * (2 + 2 + 4) + 2 * 8 * 128 * 128 * 2
+                           + 17 * 512 * 4 + 8 * 16 * 512 * (2 + 4))
+        + 8 * 16 * 5120 * 4 + 2 * 8 * 128 * 16 * 128 * 4
+        + 2 * 128 * 512 * 4,
+        # x, dt and y once, S in bfloat16 in and in float32 out
+        "streamed_bytes": 64 * (256 * 5120 * (2 + 4 + 2)
+                                + 16 * 5120 * (2 + 4))}
+    assert cell["vmem_bytes"] == 30_216_192
+    assert cell["streamed_bytes"] == 702_545_920
+    assert (MAX_ROWS, MAX_LANES, BLOCK) == (8, 512, 128)
+    # the smoke's longer chunk: three blocks; a length the block does not
+    # divide: a short last block; one below the block: a block of its own
+    assert plan(64, 384, 5120, 16)["grid"] == (8, 3, 10)
+    assert plan(8, 200, 5120, 16)["grid"] == (1, 2, 10)
+    assert plan(3, 5, 256, 8) == dict(
+        plan(3, 5, 256, 8), rows=3, lanes=256, positions=5, grid=(1, 1, 1))
+    # rows of no divisor up to 8 go one a step; lanes of no wider divisor
+    assert plan(11, 256, 5120, 16)["rows"] == 1
+    assert plan(64, 256, 128 * 7, 16)["lanes"] == 128
+    # a single position is the cached step's; lanes 128 does not divide;
+    # a state size 8 does not divide
+    assert plan(64, 1, 5120, 16) is None
+    assert plan(64, 256, 5000, 16) is None
+    assert plan(64, 256, 5120, 12) is None
+    # a tile that does not divide the call is refused, and the call raises
+    assert plan(64, 256, 5120, 16, tile=(5, 512, 128)) is None
+    assert plan(64, 256, 5120, 16, tile=(8, 768, 128)) is None
+    assert plan(64, 256, 5120, 16, tile=(8, 512, 100)) is None
+    with pytest.raises(ValueError, match="selective_scan does not take"):
+        selective_scan(jnp.zeros((2, 1, 128)), jnp.zeros((2, 1, 128)),
+                       jnp.zeros((2, 1, 8)), jnp.zeros((2, 1, 8)),
+                       jnp.zeros((8, 128)), jnp.zeros((128,)),
+                       jnp.zeros((2, 8, 128)))
+
+
+# ---------------------------------------------------------------------------
 # RMS norm
 # ---------------------------------------------------------------------------
 

@@ -124,10 +124,15 @@ def test_forward_matches_the_reference(toy):
 # than the window of 16 (a chunk of 24 wraps the ring while it is
 # written; chunks of 7 carry S, the convolution window and the ring four
 # times), and 8 rows, from which a cached step attends through the kernel
-# in all six attending layers
+# in all six attending layers. Every chunk of more than one position runs
+# the four Mamba-1 layers' recurrence through the kernel that keeps S on
+# the chip (1024 lanes, a state of 8): chunks of 17 and 13 are two sublane
+# tiles of positions and one position more, then one tile and five, and
+# the second kernel call starts from the S the first one left
 CACHED = {"one_chunk": (2, (PROMPT,)), "chunks_of_24": (2, (24, 6)),
           "chunks_of_7": (3, (7, 7, 7, 7, 2)), "one_row": (1, (16, 14)),
-          "streamed_8_rows": (8, (24, 6))}
+          "streamed_8_rows": (8, (24, 6)),
+          "scan_kernel_twice": (3, (17, 13))}
 
 
 @pytest.mark.parametrize("case", sorted(CACHED))
@@ -163,6 +168,90 @@ def test_prefill_with_the_skip_is_the_whole_stacks_last_position(toy):
     assert (2, 1, inner) in shapes and (2, PROMPT, inner) in shapes
     # the four Mamba-1 layers' two (the convolution's and the gate's)
     assert shapes.count((2, PROMPT, inner)) == 8
+
+
+def _kernels(traced) -> list:
+    """The names of the Pallas calls a traced program holds."""
+    return [e.params["name"] if "name" in e.params else
+            e.params["name_and_src_info"].name
+            for e, _ in _walk_jaxpr(traced.jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+def _scans_and_kernels(traced, state: tuple) -> tuple:
+    """(the ``lax.scan``s whose carry is S, the ``selective_scan``
+    kernels) a traced program holds."""
+    scans = [e for e, _ in _walk_jaxpr(traced.jaxpr)
+             if e.primitive.name == "scan"
+             and any(v.aval.shape == state for v in e.outvars)]
+    return len(scans), _kernels(traced).count("selective_scan")
+
+
+def test_the_scan_keeps_its_loop_where_no_kernel_may_run(toy):
+    """A cached call on one chip runs the four Mamba-1 layers' recurrence
+    through the kernel and holds no loop along the positions; ``forward``
+    without a cache (a gradient may be taken) and a block under a mesh
+    keep the ``lax.scan``, and a cached step ``_step1``."""
+    from faabric_tpu.parallel import MeshConfig, build_mesh
+
+    sizes, cfg, params = toy
+    toks = tokens(sizes, 2, PROMPT)
+    state = (2, cfg.ssm_d_state, cfg.ssm_inner)
+    cache = init_kv_cache(cfg, 2, 128)
+    assert _scans_and_kernels(jax.make_jaxpr(
+        lambda t, c: forward_with_cache(params, t, c, 0, cfg))(toks, cache),
+        state) == (0, 4)
+    assert _scans_and_kernels(jax.make_jaxpr(
+        lambda t, c: forward_with_cache(params, t, c, PROMPT, cfg))(
+        toks[:, :1], cache), state) == (0, 0)
+    assert _scans_and_kernels(jax.make_jaxpr(
+        lambda t: forward(params, t, cfg))(toks), state) == (4, 0)
+    assert cfg.mixers[0] == "mamba1"
+    x = jnp.zeros((2, PROMPT, cfg.d_model), jnp.float32)
+    where = jnp.broadcast_to(jnp.arange(PROMPT)[None], (2, PROMPT))
+    for mesh, held in ((build_mesh(config=MeshConfig(tp=2)), (1, 0)),
+                       (None, (0, 1))):
+        assert _scans_and_kernels(jax.make_jaxpr(
+            lambda x, c: transformer._block(
+                x, params["blocks"][0], where, cfg, mesh, cache=c,
+                slot=(0, 0), kind="mamba1"))(x, cache[0]), state) == held
+
+
+def test_call_sizes_counts_the_layers_whose_scan_streams():
+    """``scan_streamed_layers`` and ``scan_streamed_bytes`` at the cell's
+    sizes, from ``selective_scan.plan``; a call of one position a chunk
+    and a configuration without a "mamba1" layer read 0, and one that
+    names no kinds carries the key no more than ``scan_chunks``."""
+    from benchmarks import program, program_granite
+    from faabric_tpu.ops import selective_scan
+
+    cfg = program_phi4flash.model_config(values("published"))
+    sized = call_sizes(cfg, 64, 512, 256, 256)
+    a_call = selective_scan.plan(64, 256, 5120, 16, jnp.bfloat16)
+    assert sized["scan_chunks"] == 2 and sized["scan_streamed_layers"] == 9
+    assert sized["scan_streamed_bytes"] \
+        == 9 * 2 * a_call["streamed_bytes"] == 12_645_826_560
+    # the smoke's chunks, 384 and 256, and a last chunk of one position
+    assert call_sizes(cfg, 64, 640, 2, 384)["scan_streamed_bytes"] == 9 * (
+        selective_scan.plan(64, 384, 5120, 16)["streamed_bytes"]
+        + a_call["streamed_bytes"])
+    assert call_sizes(cfg, 64, 257, 2, 256)["scan_streamed_bytes"] \
+        == 9 * a_call["streamed_bytes"]
+    alone = call_sizes(cfg, 64, 1, 2)
+    assert (alone["scan_streamed_layers"], alone["scan_streamed_bytes"]) \
+        == (0, 0)
+    # lanes 128 does not divide: the plan refuses and the scan stays
+    odd = dataclasses.replace(cfg, ssm_inner=5000)
+    assert call_sizes(odd, 64, 512, 256, 256)["scan_streamed_layers"] == 0
+    for name, build in (("granite-4.0-h-micro", program_granite),
+                        ("pythia-1.4b", program)):
+        with open(os.path.join(REPO, "benchmarks", "configs",
+                               name + ".json")) as f:
+            other = call_sizes(build.model_config(json.load(f)), 64, 512,
+                               128, 256)
+        assert other.get("scan_streamed_layers", 0) == 0
+        assert ("scan_chunks" in other) == (name != "pythia-1.4b")
+    assert "scan_streamed_layers" not in other
 
 
 def test_a_ring_takes_a_chunk_only_from_a_static_start(toy):
@@ -213,10 +302,7 @@ def test_generate_serves_the_references_best_and_counts_its_state(toy):
     assert call_sizes(cfg, 1, PROMPT, 10)["attention_streamed_layers"] == 0
     traced = jax.make_jaxpr(lambda p: generate(params, p, cfg, 10,
                                                prefill_chunk=24))(toks)
-    kernels = [e.params["name"] if "name" in e.params else
-               e.params["name_and_src_info"].name
-               for e, _ in _walk_jaxpr(traced.jaxpr)
-               if e.primitive.name == "pallas_call"]
+    kernels = _kernels(traced)
     # six in the decode loop's step, and the two cross attentions of each
     # of prefill's two chunks, whose last position goes on alone
     assert kernels.count("cached_attention") == 6 + 2 * 2
