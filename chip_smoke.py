@@ -59,6 +59,14 @@ Guests (user ``smoke``), all on the chips the planner pinned:
   alone, then two cached steps through the cached-attention kernel in its
   differential, ring and read-only forms, logits of four rows against
   ``benchmarks/reference/phi4flash.py``.
+- ``long_latent`` — a feed-forward kind a layer (a dense layer, then an
+  expert layer with a shared expert under a sigmoid router) behind latent
+  attention under YaRN at the widths of ``benchmarks/configs/a.x-k1.json``,
+  the two leading layers, 8 rows: a prompt of 8,192 in chunks of 1,024
+  (the last attends a reach of 8,192 in blocks of a row's queries), then
+  two cached steps over the latent caches with the dense feed-forward
+  and the shared expert through the streaming kernel, logits of two rows
+  against ``benchmarks/reference/axk1.py``.
 - ``gang``    — with ≥ 2 chips: an MPI world through ``ctx.mpi_world()``,
   one rank per chip, collectives on device-resident arrays through the
   activated device plane, and the Pallas ring-permute kernel.
@@ -146,6 +154,14 @@ SHARED_CONFIG = os.path.join(REPO, "benchmarks", "configs",
                              "phi-4-mini-flash-reasoning.json")
 SHARED_CONFIG_TINY = os.path.join(REPO, "tests", "bench", "data", "configs",
                                   "toy_phi4flash.json")
+
+# The dense layer and one expert layer in bfloat16 against the float32
+# reference at a reach of 8,192, the same measure and room as the three
+# above.
+TOL_LONG_LATENT_LOGITS = 4e-2
+LONG_CONFIG = os.path.join(REPO, "benchmarks", "configs", "a.x-k1.json")
+LONG_CONFIG_TINY = os.path.join(REPO, "tests", "bench", "data", "configs",
+                                "toy_axk1.json")
 
 PLANNER_HOST = "smoke-planner"
 WORKER_HOST = "smoke-worker"
@@ -807,6 +823,74 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
             _require(dev.platform == "tpu", dev.platform)
         return reply(**out)
 
+    # ---- a dense layer, then an expert layer, at a long reach ---------
+    @register_function("smoke", "long_latent")
+    def long_latent(ctx):
+        from benchmarks import program_axk1, weights_axk1
+        from benchmarks.reference import axk1 as reference
+        from faabric_tpu.models.generate import (
+            call_sizes,
+            forward_with_cache,
+            init_kv_cache,
+        )
+
+        dev = ctx.device
+        with open(LONG_CONFIG if on_chip else LONG_CONFIG_TINY) as f:
+            # the leading dense layer and the first expert layer
+            config = dict(json.load(f), num_hidden_layers=2)
+        sizes = weights_axk1.sizes_of(config)
+        kinds = program_axk1.model_config(config)
+        s_p, chunk = (8192, 1024) if on_chip else (48, 16)
+        rows, compared, steps = 8, 2, 2
+        ids = weights_axk1.token_rows(7, 1, 0, rows, s_p + steps,
+                                      sizes["vocab"])
+        with jax.default_device(dev):
+            params = weights_axk1.make_weights(7, sizes, kinds.param_dtype,
+                                               dev)
+            cache = init_kv_cache(kinds, rows, 128 * -(-ids.shape[1] // 128))
+            for at in range(0, s_p, chunk):
+                logits, cache = jax.jit(
+                    lambda p, t, c, at=at: forward_with_cache(
+                        p, t, c, at, kinds, last_only=True))(
+                    params, jnp.asarray(ids[:, at:at + chunk]), cache)
+            got = [np.asarray(logits, np.float32)]
+            step = jax.jit(lambda p, t, c, pos: forward_with_cache(
+                p, t, c, pos, kinds))
+            for pos in range(s_p, ids.shape[1]):
+                logits, cache = step(params, jnp.asarray(
+                    ids[:, pos:pos + 1]), cache, jnp.int32(pos))
+                got.append(np.asarray(logits, np.float32))
+            got = np.concatenate(got, axis=1)[:compared]
+            want = np.asarray(reference.logits_of_rows(
+                params, jnp.asarray(ids[:compared]), sizes,
+                at=slice(s_p - 1, None)))
+            counted = np.asarray(cache[1]["counters"]).tolist()
+        sized = call_sizes(kinds, rows, s_p, steps, chunk)
+        out = dict(
+            device=_device_report(dev), n_params=sum(
+                int(x.size) for x in jax.tree.leaves(params)),
+            rows=rows, prompt=s_p,
+            prefill_rel_err=_rel_err(got[:, :1], want[:, :1]),
+            cached_steps_rel_err=_rel_err(got[:, 1:], want[:, 1:]),
+            picks_held_zero_absent_experts_hit_tiles=counted,
+            **{name: sized[name] for name in (
+                "prefill_chunks", "score_blocks", "expanded_bytes",
+                "ffn_streamed_layers", "dense_layers", "expert_layers")})
+        _require(np.isfinite(got).all(), "a logit is not finite")
+        _require([sorted(c) for c in cache]
+                 == [["latent"], ["counters", "latent"]],
+                 f"the layers' state is {[sorted(c) for c in cache]}")
+        _require(sum(counted[:3]) == rows * (s_p + steps) * sizes["top_k"]
+                 and counted[1] == 0, f"the picks do not add up: {counted}")
+        _require(sized["ffn_streamed_layers"] == 2,
+                 f"{sized['ffn_streamed_layers']} feed-forwards stream")
+        for name in ("prefill_rel_err", "cached_steps_rel_err"):
+            _require(out[name] < TOL_LONG_LATENT_LOGITS,
+                     f"{name} {out[name]}")
+        if on_chip:
+            _require(dev.platform == "tpu", dev.platform)
+        return reply(**out)
+
     # ---- gang --------------------------------------------------------
     @register_function("smoke", "gang")
     def gang(ctx):
@@ -1119,6 +1203,7 @@ def _run_phases(cluster: Cluster, summary: dict, deadline: float) -> None:
                                               deadline)[0]
     phases["state_space"] = cluster.invoke("state_space", 1, deadline)[0]
     phases["shared_state"] = cluster.invoke("shared_state", 1, deadline)[0]
+    phases["long_latent"] = cluster.invoke("long_latent", 1, deadline)[0]
     if n >= 2:
         phases["gang"] = sorted(
             cluster.invoke("gang", 1, deadline, mpi_world_size=n),

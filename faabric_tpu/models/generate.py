@@ -21,10 +21,14 @@ rolled loop inside it, over the same weights.
 Caches go by the attention's kind. Latent attention keeps one latent and
 its turned rotary lanes a position, ``(passes, batch, slots, kv_lora_rank
 + qk_rope_dim)``, row-major and once an attention, whatever the number of
-heads; prefill expands keys and values from it, a cached step attends
-over it as it lies (``transformer._latent_attention``). A "shortcut"
+heads; a prompt, or its first chunk, expands keys and values from it,
+a later chunk of a chunked prefill and a cached step attend over it as
+it lies, the scores in blocks of rows and queries
+(``transformer._latent_attention``, ``score_blocks``). A "shortcut"
 layer's state is its two attentions' caches and its expert layer's
-counters, summed on the device over the call.
+counters, summed on the device over the call; a "single" layer whose
+feed-forward is an expert layer (``ModelConfig.ffn_types``) keeps the
+same counters beside its mixer's state.
 
 State goes by the layer's kind too (``ModelConfig.layer_types``). An
 "attention" layer keeps keys and values over its key/value heads, fewer
@@ -62,12 +66,14 @@ from faabric_tpu.models.transformer import (
     ModelConfig,
     _block,
     embed,
+    feed_forward_widths,
     head,
     lays_dense,
     lender_of,
     resolve_impls,
     run_passes,
     refuse_served_only,
+    score_blocks,
     streams_attention,
     streams_feed_forward,
 )
@@ -134,7 +140,13 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     attention layer, every pass), ``ut_passes`` of the stack (prefill and
     each decode step pass it ``cfg.n_passes`` times); where the layers
     have an expert layer also ``experts_held`` here and the
-    ``router_width``; where the configuration names its layers' kinds
+    ``router_width``; where the configuration names its layers'
+    feed-forwards (``ffn_types``) also ``shared_experts``,
+    ``dense_layers`` and ``expert_layers``, ``prefill_chunks``,
+    ``score_blocks`` (the blocks one layer's prefill scores go in,
+    summed over the prompt's chunks) and ``expanded_bytes`` (what the
+    prefill keeps of expanded keys and values from chunk to chunk:
+    nothing); where the configuration names its layers' kinds
     also ``attention_layers``, ``ssm_layers``, ``state_bytes`` (the
     windows and states of all state-space layers: the same at any reach)
     and ``scan_chunks``, the chunks of the state-space scan a row a layer
@@ -148,7 +160,9 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     step goes through the streaming kernel (ops/gated_ffn.py) and
     ``ffn_streamed_bytes`` what they stream a step, every pass, for a call
     without a mesh on parameters of ``cfg.param_dtype``
-    (``transformer.streams_feed_forward``); 0 where none does.
+    (``transformer.streams_feed_forward``, each feed-forward planned at
+    its own width: an expert layer's shared experts count as one); 0
+    where none does.
     ``attention_streamed_layers`` is the attentions whose cached step
     reads a dense cache through its kernel (ops/cached_attention.py), its
     own, a ring or a lent one, and ``attention_streamed_bytes`` the keys
@@ -173,9 +187,11 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
 
     count = cfg.mixers.count
     attention_layers = count("attention")
-    streamed = streams_feed_forward(cfg, batch, 1, cfg.param_dtype)
-    feed_forwards = (1 + shortcut) * cfg.n_layers if streamed else 0
-    a_pass = streamed["streamed_bytes"] if streamed else 0
+    # every feed-forward by its own width: an "experts" layer's shared
+    # experts stream as one, at theirs
+    streamed = [plan for plan in (
+        streams_feed_forward(cfg, batch, 1, cfg.param_dtype, d_ff=width)
+        for width in feed_forward_widths(cfg)) if plan]
     # every attending layer reads a cache through the kernel, its own, a
     # ring or a lent one, where a step of these rows over its slots does
     attended = {kind: streams_attention(
@@ -188,8 +204,9 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
         "cache_bytes": (1 + shortcut) * attention_layers * kept("attention")
         + windows,
         "ut_passes": cfg.n_passes * (1 + n_tokens),
-        "ffn_streamed_layers": feed_forwards,
-        "ffn_streamed_bytes": feed_forwards * cfg.n_passes * a_pass,
+        "ffn_streamed_layers": len(streamed),
+        "ffn_streamed_bytes": cfg.n_passes * sum(
+            plan["streamed_bytes"] for plan in streamed),
         "attention_streamed_layers": sum(
             (1 + shortcut) * count(kind)
             for kind, plan in attended.items() if plan),
@@ -198,9 +215,22 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
             * plan["streamed_bytes"]
             for kind, plan in attended.items() if plan),
     }
-    if shortcut:
+    if shortcut or cfg.ffn_types:
         sizes.update(experts_held=cfg.experts_held[1],
                      router_width=cfg.routed_experts + cfg.zero_experts)
+    if cfg.ffn_types:
+        chunks = _prefill_chunks(prompt_len, prefill_chunk)
+        sizes.update(
+            shared_experts=cfg.shared_experts,
+            dense_layers=cfg.ffns.count("dense"),
+            expert_layers=cfg.ffns.count("experts"),
+            prefill_chunks=len(chunks),
+            # latent attention expands the first chunk's keys and values
+            # and attends the later chunks' reach absorbed: nothing stays
+            # expanded from chunk to chunk
+            score_blocks=sum(_score_blocks(cfg, batch, length, pos + length)
+                             for pos, length in chunks),
+            expanded_bytes=0)
     if cfg.layer_types:
         chunks = _prefill_chunks(prompt_len, prefill_chunk)
         sizes.update(
@@ -233,6 +263,18 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     return sizes
 
 
+def _score_blocks(cfg: ModelConfig, batch: int, queries: int,
+                  reach: int) -> int:
+    """The blocks one attention's prefill scores of ``queries`` positions
+    a row over ``reach`` go in (``transformer.score_blocks``: latent
+    attention's, expanded or absorbed; 1 for any other kind, whose blocks
+    are of rows alone and its own business)."""
+    if cfg.attention != "latent":
+        return 1
+    rows, in_queries = score_blocks(batch, cfg.n_heads, queries, reach)
+    return (batch // rows) * (queries // in_queries)
+
+
 def init_kv_cache(cfg: ModelConfig, batch: int, slots: int,
                   mesh=None) -> list[dict]:
     """Zeroed per-layer state of a call, by the layer's kind. Per-head
@@ -246,9 +288,10 @@ def init_kv_cache(cfg: ModelConfig, batch: int, slots: int,
     (``transformer._block``). A "mamba" or "mamba1" layer: its convolution
     window and its recurrent state (``ssm.state_shapes``), whatever
     ``slots``. A "window_attention" layer: a ring of ``_ring_slots``. A
-    layer that reads what another lends: None. A configuration that
-    names a ring, a lent cache or differential pairs lays every cache
-    dense (``transformer.lays_dense``)."""
+    layer that reads what another lends: None. A layer whose feed-forward
+    is an expert layer: its ``counters`` beside its mixer's state. A
+    configuration that names a ring, a lent cache or differential pairs
+    lays every cache dense (``transformer.lays_dense``)."""
     def zeros(shapes: dict):
         return {name: jnp.zeros(shape, cfg.compute_dtype)
                 for name, shape in shapes.items()}
@@ -260,9 +303,12 @@ def init_kv_cache(cfg: ModelConfig, batch: int, slots: int,
         return [{"attn": [attention(), attention()],
                  "counters": jnp.zeros((len(COUNTERS),), jnp.int32)}
                 for _ in range(cfg.n_layers)]
-    # a layer that reads what another lends keeps nothing: None
-    return [zeros(_state_shapes(cfg, kind, batch, slots, mesh)) or None
-            for kind in cfg.mixers]
+    # a layer that reads what another lends keeps nothing: None; one
+    # whose feed-forward is an expert layer keeps its counters too
+    counters = {"counters": jnp.zeros((len(COUNTERS),), jnp.int32)}
+    return [dict(zeros(_state_shapes(cfg, kind, batch, slots, mesh)),
+                 **(counters if ffn == "experts" else {})) or None
+            for kind, ffn in zip(cfg.mixers, cfg.ffns)]
 
 
 def forward_with_cache(params, tokens, cache, start, cfg: ModelConfig,
@@ -397,9 +443,10 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
 def _counted(cfg: ModelConfig, cache: list):
     """What the expert layers have counted in this call so far, summed
     over the layers (``moe.COUNTERS``); None where no layer counts."""
-    if cfg.layer != "shortcut":
+    if cfg.layer != "shortcut" and "experts" not in cfg.ffn_types:
         return None
-    return sum(layer["counters"] for layer in cache)
+    return sum(layer["counters"] for layer in cache
+               if layer is not None and "counters" in layer)
 
 
 def generate(params, prompt, cfg: ModelConfig, n_tokens: int,
@@ -422,9 +469,10 @@ def generate(params, prompt, cfg: ModelConfig, n_tokens: int,
     attention memory. A looped stack (``cfg.n_passes`` above 1) keeps
     one cache a pass a layer; :func:`call_sizes` says what a call of
     these shapes allocates. The other kinds of attention and layer
-    (latent attention, shortcut layers, state-space layers, windows,
-    lent caches and memories, differential pairs, grouped key/value
-    heads, the multipliers, a tied head) are single-chip so far: with
+    (latent attention, shortcut layers, expert layers as a feed-forward,
+    state-space layers, windows, lent caches and memories, differential
+    pairs, grouped key/value heads, the multipliers, a rotary scaling, a
+    tied head) are single-chip so far: with
     ``mesh`` they raise ``ValueError``."""
     return generate_with_counters(params, prompt, cfg, n_tokens, key,
                                   temperature, top_k, top_p, mesh,
@@ -438,7 +486,7 @@ def generate_with_counters(params, prompt, cfg: ModelConfig, n_tokens: int,
                            prefill_chunk: int = 0):
     """:func:`generate`, with what the call counted on the device, from
     the same program: ((B, n_tokens) int32, counters). ``counters`` is
-    empty but where the layers have an expert layer; there the picks of
+    empty but where layers have an expert layer; there the picks of
     the whole call (prefill and every step, all layers) are
     ``picks_held`` (they fell on experts held here), ``picks_zero``
     (zero-compute experts) and ``picks_absent`` (experts held elsewhere);

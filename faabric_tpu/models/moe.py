@@ -26,7 +26,11 @@ expert's matrices: an expert that got no token has no tile and is not
 read), so no token is dropped whatever the routing; gated (SwiGLU)
 experts, a stored bias that corrects the selection, zero-compute experts
 that return their input, and the picks of experts held elsewhere add
-nothing here.
+nothing here. Where the configuration gives an "experts" feed-forward to
+a "single" layer (``ModelConfig.ffn_types``) the same function is that
+layer's feed-forward: a sigmoid router whose picked weights are
+renormalised, without a stored bias, and shared experts that every token
+passes, are said by the configuration's fields.
 """
 
 from __future__ import annotations
@@ -42,6 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from faabric_tpu.models import scopes
 from faabric_tpu.models.transformer import (
     ModelConfig,
+    _feed_forward,
     _rms_norm,
     attention_sublayer,
     refuse_served_only,
@@ -223,16 +228,23 @@ COUNTERS = ("picks_held", "picks_zero", "picks_absent", "experts_hit",
 
 def route(u: jax.Array, router: dict, cfg: ModelConfig) -> tuple:
     """u (T, D) → (picks (T, K) int32 over the router's whole width,
-    weights (T, K) float32). The product, the softmax and the selection
-    are float32 whatever the compute type: scores = softmax(u·w); the K
-    largest of scores + bias are picked; a pick weighs routed_scaling
-    times its score, not renormalised."""
+    weights (T, K) float32). The product, the scores and the selection
+    are float32 whatever the compute type: scores = softmax(u·w), or
+    sigmoid(u·w) an output (``cfg.router_score``); the K largest of the
+    scores, plus the stored bias under ``cfg.router_bias``, are picked; a
+    pick weighs routed_scaling times its score, under
+    ``cfg.router_renormalise`` its score over the sum of the picks'."""
     logits = jnp.dot(u.astype(jnp.float32), router["w"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.softmax(logits, axis=-1)
-    _, picks = jax.lax.top_k(scores + router["bias"].astype(jnp.float32),
-                             cfg.experts_per_token)
+    scores = jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    chosen_by = scores + router["bias"].astype(jnp.float32) \
+        if cfg.router_bias else scores
+    _, picks = jax.lax.top_k(chosen_by, cfg.experts_per_token)
     weights = jnp.take_along_axis(scores, picks, axis=-1)
+    if cfg.router_renormalise:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
     return picks, weights * cfg.routed_scaling
 
 
@@ -305,18 +317,25 @@ def _held_experts(u: jax.Array, local: jax.Array, weights: jax.Array,
 
 
 def expert_layer(u: jax.Array, router: dict, experts: dict,
-                 cfg: ModelConfig) -> tuple:
+                 cfg: ModelConfig, shared: Optional[dict] = None,
+                 streamed: bool = False) -> tuple:
     """One chip's share of the expert layer: u (B, S, D), a normed state →
     (m (B, S, D), counters int32 (5,) as :data:`COUNTERS`).
 
         m = Σ_{picked e held here} w_e · Expert_e(u)
           + Σ_{picked e zero-compute} w_e · u
+          + Shared(u)
 
     with ``Expert_e(h) = (silu(h·wg_e) ⊙ h·w1_e)·w2_e``. Router outputs
     below ``cfg.routed_experts`` are routed experts, of which this chip
     holds ``cfg.experts_held = (first, count)`` (``experts`` has their
     weights, ``count`` on the leading axis); the rest are zero-compute.
-    Static shapes, and no token dropped whatever the routing."""
+    ``shared`` (``wg``, ``w1``, ``w2``; None: none) is the shared experts
+    that every token passes, computed once and whole as one gated
+    feed-forward (``transformer._feed_forward``'s lines, through the
+    streaming kernel where ``streamed`` says the call is a cached one on
+    one chip and its shape is one the kernel takes). Static shapes, and
+    no token dropped whatever the routing."""
     b, s, d = u.shape
     first, count = cfg.experts_held
     k = cfg.experts_per_token
@@ -337,6 +356,8 @@ def expert_layer(u: jax.Array, router: dict, experts: dict,
             for lo, hi in zip(bounds, bounds[1:])))
         m = jnp.concatenate(parts) \
             + zero_weight.astype(u.dtype)[:, None] * flat
+        if shared is not None:
+            m = m + _feed_forward(u, shared, cfg, streamed).reshape(-1, d)
         n_held = jnp.sum(is_held, dtype=jnp.int32)
         n_zero = jnp.sum(is_zero, dtype=jnp.int32)
         counters = jnp.stack([n_held, n_zero, tokens * k - n_held - n_zero,

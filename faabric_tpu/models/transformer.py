@@ -26,6 +26,7 @@ must carry DP/TP/SP strategies), exercised by __graft_entry__ and bench.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Optional
 
@@ -46,6 +47,44 @@ MIXER_KINDS = ("attention", "mamba", "mamba1", "window_attention",
 ATTENDING = ("attention", "window_attention", "cross_attention")
 # the four vectors of head_dim that give a differential layer's weight
 LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+# The kinds of feed-forward a "single" layer can have
+# (``ModelConfig.ffn_types``)
+FFN_KINDS = ("dense", "experts")
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """A rotary turn stretched past the reach it was trained at (YaRN):
+    lane pair i of a head's d/2 turns at ``f_i (1 − r_i) + (f_i / factor)
+    r_i``, ``f_i = theta^(−2i/d)``, with ``r`` a ramp from 0 to 1 between
+    the pairs that make ``beta_fast`` and ``beta_slow`` turns over
+    ``original_max_seq`` positions (:func:`yarn_range`): fast pairs turn
+    as they did, slow ones ``factor`` times slower. Cosines and sines are
+    multiplied by ``m(mscale) / m(mscale_all_dim)`` and the scores' scale
+    by ``m(mscale_all_dim)²``, ``m(x) = 0.1 · x · ln(factor) + 1``
+    (:func:`yarn_mscale`; ``m(0)`` = 1 and the scores' scale as it was)."""
+    factor: float
+    original_max_seq: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_range(scaling: RopeScaling, dim: int, theta: float) -> tuple:
+    """(low, high): the lane pairs of a head of ``dim`` rotary lanes
+    between which :class:`RopeScaling`'s ramp rises from 0 to 1."""
+    def pair_of(turns: float) -> float:
+        return dim * math.log(scaling.original_max_seq
+                              / (turns * 2.0 * math.pi)) \
+            / (2.0 * math.log(theta))
+
+    return (max(math.floor(pair_of(scaling.beta_fast)), 0),
+            min(math.ceil(pair_of(scaling.beta_slow)), dim - 1))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -173,6 +212,28 @@ class ModelConfig:
     # ssm_d_state, dt through a bottleneck of ssm_dt_rank, ssm_d_conv taps
     ssm_inner: int = 0
     ssm_dt_rank: int = 0
+    # Each "single" layer's feed-forward, one name a layer; empty: "dense"
+    # in every layer. "dense": the feed-forward of kind ``ffn`` at d_ff |
+    # "experts": an expert layer in its place (models/moe.py:expert_layer
+    # under the routed_experts … expert_d_ff fields above), and beside the
+    # routed experts shared_experts gated experts that every token
+    # passes, as one feed-forward of shared_experts · expert_d_ff (leaf
+    # ``shared``)
+    ffn_types: tuple = ()
+    shared_experts: int = 0
+    # The router: its scores a "softmax" or a "sigmoid" of its outputs;
+    # whether the picks' weights are divided by their sum (before
+    # routed_scaling); whether a stored bias (leaf ``bias``) corrects the
+    # selection
+    router_score: str = "softmax"
+    router_renormalise: bool = False
+    router_bias: bool = True
+    # latent attention: whether each normed bottleneck is scaled by
+    # sqrt(d_model / its rank)
+    latent_scale: bool = True
+    # the rotary turn stretched past the reach it was trained at; None:
+    # every pair at theta^(−2i/d)
+    rope_scaling: Optional[RopeScaling] = None
 
     def __post_init__(self):
         for field, kinds in (("ffn", ("gelu", "swiglu")),
@@ -181,7 +242,8 @@ class ModelConfig:
                              ("attention", ("heads", "latent")),
                              ("layer", ("single", "shortcut")),
                              ("position", ("rope", "none")),
-                             ("norm", ("rms", "layer"))):
+                             ("norm", ("rms", "layer")),
+                             ("router_score", ("softmax", "sigmoid"))):
             if getattr(self, field) not in kinds:
                 raise ValueError(f"{field} {getattr(self, field)!r} is not "
                                  f"one of {kinds}")
@@ -194,6 +256,7 @@ class ModelConfig:
                 f"{sorted(set(self.layer_types))}; it takes one of "
                 f"{MIXER_KINDS} for each of the {self.n_layers}")
         self._check_shared_state()
+        self._check_experts()
         if self.n_heads % self.kv_heads or (
                 self.kv_heads != self.n_heads and self.attention != "heads"):
             raise ValueError(
@@ -259,7 +322,32 @@ class ModelConfig:
             raise ValueError(
                 "attention 'latent' needs q_lora_rank, kv_lora_rank, "
                 "qk_nope_dim, v_head_dim and an even qk_rope_dim")
-        if self.layer == "shortcut":
+
+    def _check_experts(self):
+        """The feed-forward's kind a layer, the expert layer's sizes, the
+        shared experts and the rotary scaling."""
+        object.__setattr__(self, "ffn_types", tuple(self.ffn_types))
+        kinds = self.ffn_types
+        if kinds and (len(kinds) != self.n_layers
+                      or set(kinds) - set(FFN_KINDS)
+                      or self.layer != "single"):
+            raise ValueError(
+                f"ffn_types names {len(kinds)} layers as "
+                f"{sorted(set(kinds))}; it takes one of {FFN_KINDS} for "
+                f"each of the {self.n_layers} layers of kind 'single'")
+        if "experts" in kinds and not (
+                self.ffn == "swiglu" and self.norm_placement == "pre"
+                and self.n_passes == 1 and self.shared_experts >= 0):
+            raise ValueError(
+                "an 'experts' feed-forward needs ffn 'swiglu' behind one "
+                "pre-norm in a stack of one pass, and no negative "
+                f"shared_experts; got {self}")
+        if self.shared_experts and "experts" not in kinds:
+            raise ValueError(
+                f"shared_experts {self.shared_experts} stand beside the "
+                "routed experts of an 'experts' feed-forward; ffn_types "
+                "names none")
+        if self.layer == "shortcut" or "experts" in kinds:
             first, count = self.experts_held
             if not (0 <= first and count > 0
                     and first + count <= self.routed_experts
@@ -267,9 +355,18 @@ class ModelConfig:
                     <= self.routed_experts + self.zero_experts
                     and self.expert_d_ff > 0):
                 raise ValueError(
-                    "layer 'shortcut' needs routed_experts, expert_d_ff, "
+                    "an expert layer needs routed_experts, expert_d_ff, "
                     "experts_per_token within the router's width, and "
                     f"experts_held within the routed ones; got {self}")
+        scaling = self.rope_scaling
+        if scaling is not None and not (
+                scaling.factor >= 1.0 and scaling.original_max_seq > 0
+                and scaling.beta_fast > 0 and scaling.beta_slow > 0
+                and self.position == "rope"):
+            raise ValueError(
+                "rope_scaling needs a factor of 1 or more, the reach it "
+                "stretches and positive beta_fast and beta_slow, under "
+                f"position 'rope'; got {scaling}")
 
     @property
     def head_dim(self) -> int:
@@ -286,7 +383,20 @@ class ModelConfig:
 
     @property
     def score_scale(self) -> float:
-        return self.attention_scale or 1.0 / np.sqrt(self.head_dim)
+        """What the scores are multiplied by: ``attention_scale``, or 1 /
+        sqrt(a key's lanes), times the rotary scaling's ``m²``."""
+        lanes = (self.qk_nope_dim + self.qk_rope_dim
+                 if self.attention == "latent" else self.head_dim)
+        scale = self.attention_scale or 1.0 / np.sqrt(lanes)
+        if self.rope_scaling is not None and self.rope_scaling.mscale_all_dim:
+            scale = scale * yarn_mscale(self.rope_scaling.factor,
+                                        self.rope_scaling.mscale_all_dim) ** 2
+        return scale
+
+    @property
+    def ffns(self) -> tuple:
+        """Every layer's feed-forward kind."""
+        return self.ffn_types or ("dense",) * self.n_layers
 
     @property
     def stateless_from(self) -> int:
@@ -312,7 +422,9 @@ def served_only(cfg: ModelConfig) -> list:
               "embedding_multiplier", "residual_multiplier",
               "logits_scaling", "tie_embeddings", "sliding_window",
               "cache_source", "memory_source", "differential", "norm",
-              "attention_bias", "ssm_inner", "ssm_dt_rank")
+              "attention_bias", "ssm_inner", "ssm_dt_rank", "ffn_types",
+              "shared_experts", "router_score", "router_renormalise",
+              "router_bias", "latent_scale", "rope_scaling")
     named = [f"{f}={getattr(cfg, f)!r}" for f in fields
              if getattr(cfg, f) != getattr(plain, f)]
     if cfg.kv_heads != cfg.n_heads:
@@ -350,23 +462,52 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
             "wqa": dense(k[0], (d, rq), d), "q_norm": ones(rq),
             # what reads a bottleneck scaled up to a hidden state's size
             # is drawn as if it read a hidden state
-            "wqb": dense(k[1], (rq, h, cfg.qk_nope_dim + rope), d),
+            "wqb": dense(k[1], (rq, h, cfg.qk_nope_dim + rope),
+                         d if cfg.latent_scale else rq),
             "wkva": dense(k[2], (d, rkv + rope), d), "kv_norm": ones(rkv),
             "wkvb": dense(k[3], (rkv, h, cfg.qk_nope_dim + cfg.v_head_dim),
-                          d),
+                          d if cfg.latent_scale else rkv),
             "wo": dense(k[4], (h, cfg.v_head_dim, d), h * cfg.v_head_dim),
         }
 
-    def half(key, kind="attention"):
+    def gated(key, leading, f):
+        """The three matrices of a gated feed-forward of width ``f``,
+        ``leading`` axes before them (an expert layer's experts held)."""
+        k, d = jax.random.split(key, 3), cfg.d_model
+        return {"wg": dense(k[0], (*leading, d, f), d),
+                "w1": dense(k[1], (*leading, d, f), d),
+                "w2": dense(k[2], (*leading, f, d), f)}
+
+    def expert_leaves(key):
+        """A router (its selection bias a stored correction, a fraction
+        of a mean score: non-zero here, so that a selection that leaves
+        it out shows), the routed experts held and the shared ones."""
+        k = jax.random.split(key, 4)
+        width = cfg.routed_experts + cfg.zero_experts
+        leaves = {"router": {"w": dense(k[0], (cfg.d_model, width),
+                                        cfg.d_model)},
+                  "experts": gated(k[2], (cfg.experts_held[1],),
+                                   cfg.expert_d_ff)}
+        if cfg.router_bias:
+            leaves["router"]["bias"] = jax.random.normal(
+                k[1], (width,), cfg.param_dtype) / (4 * width)
+        if cfg.shared_experts:
+            leaves["shared"] = gated(
+                k[3], (), cfg.shared_experts * cfg.expert_d_ff)
+        return leaves
+
+    def half(key, kind="attention", ffn_kind="dense"):
         """A mixer (attention of a kind, a state-space mixer, a gated
-        memory unit) and its feed-forward: a whole "single" layer."""
+        memory unit) and its feed-forward, dense or an expert layer: a
+        whole "single" layer."""
         bk = jax.random.split(key, 4)
-        blk = {
-            "ln1": ones(),
-            "ln2": ones(),
-            "w1": dense(bk[2], (cfg.d_model, cfg.d_ff), cfg.d_model),
-            "w2": dense(bk[3], (cfg.d_ff, cfg.d_model), cfg.d_ff),
-        }
+        blk = {"ln1": ones(), "ln2": ones()}
+        if ffn_kind == "experts":
+            blk.update(expert_leaves(bk[2]))
+        else:
+            blk.update(
+                w1=dense(bk[2], (cfg.d_model, cfg.d_ff), cfg.d_model),
+                w2=dense(bk[3], (cfg.d_ff, cfg.d_model), cfg.d_ff))
         if kind in ("mamba", "mamba1", "gated_memory"):
             from faabric_tpu.models import ssm
 
@@ -404,7 +545,7 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
                     blk[name] = 0.1 * jax.random.normal(
                         k, (cfg.head_dim,), cfg.param_dtype)
                 blk["sub_norm"] = ones(2 * cfg.head_dim)
-        if cfg.ffn == "swiglu":
+        if cfg.ffn == "swiglu" and ffn_kind == "dense":
             blk["wg"] = dense(jax.random.fold_in(key, 4),
                               (cfg.d_model, cfg.d_ff), cfg.d_model)
         if cfg.norm_placement == "sandwich":
@@ -415,26 +556,14 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
         return blk
 
     def shortcut(key):
-        k = jax.random.split(key, 7)
-        d, f, held = cfg.d_model, cfg.expert_d_ff, cfg.experts_held[1]
-        width = cfg.routed_experts + cfg.zero_experts
-        return {
-            "halves": [half(k[0]), half(k[1])],
-            # the selection bias is a stored correction, a fraction of a
-            # mean score: non-zero here, so that a selection that leaves
-            # it out shows
-            "router": {"w": dense(k[2], (d, width), d),
-                       "bias": jax.random.normal(
-                           k[3], (width,), cfg.param_dtype) / (4 * width)},
-            "experts": {"wg": dense(k[4], (held, d, f), d),
-                        "w1": dense(k[5], (held, d, f), d),
-                        "w2": dense(k[6], (held, f, d), f)},
-        }
+        k = jax.random.split(key, 3)
+        return {"halves": [half(k[0]), half(k[1])], **expert_leaves(k[2])}
 
     if cfg.layer == "shortcut":
         blocks = [shortcut(keys[i]) for i in range(cfg.n_layers)]
     else:
-        blocks = [half(keys[i], kind) for i, kind in enumerate(cfg.mixers)]
+        blocks = [half(keys[i], kind, ffn_kind) for i, (kind, ffn_kind)
+                  in enumerate(zip(cfg.mixers, cfg.ffns))]
     params = {
         "embed": dense(keys[-2], (cfg.vocab_size, cfg.d_model), cfg.d_model),
         "blocks": blocks,
@@ -459,12 +588,12 @@ def param_shardings(mesh: Mesh, cfg: ModelConfig) -> dict:
         return NamedSharding(mesh, P(*spec))
 
     if (set(cfg.mixers) - {"attention", "mamba"} or cfg.differential
-            or cfg.norm != "rms" or cfg.attention_bias):
+            or cfg.norm != "rms" or cfg.attention_bias or cfg.ffn_types):
         raise ValueError(
             "no layout over a mesh for layers of the kinds "
             f"{sorted(set(cfg.mixers))} with differential="
             f"{cfg.differential}, norm={cfg.norm!r}, attention_bias="
-            f"{cfg.attention_bias}")
+            f"{cfg.attention_bias}, ffn_types={cfg.ffn_types}")
     half = {
         "ln1": ns(),
         "wo": ns("tp", None, None),
@@ -527,16 +656,34 @@ def _rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * scale.astype(x.dtype)
 
 
+def rope_frequencies(d: int, theta: float,
+                     scaling: Optional[RopeScaling] = None) -> jax.Array:
+    """The turn a position of each of a head's d/2 lane pairs, float32;
+    under ``scaling`` the slow pairs slower (:class:`RopeScaling`)."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if scaling is None:
+        return freqs
+    low, high = yarn_range(scaling, d, theta)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return freqs * (1.0 - ramp) + freqs / scaling.factor * ramp
+
+
 def _rope(x: jax.Array, positions: jax.Array, theta: float,
-          pairing: str = "neighbours") -> jax.Array:
+          pairing: str = "neighbours",
+          scaling: Optional[RopeScaling] = None) -> jax.Array:
     """Rotary embeddings over the head dim: x (B, S, H, D). Lane i of a
     pair turns with lane i+1 ("neighbours") or with lane i+D/2
     ("halves", the rotate-half form)."""
     d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = positions[:, :, None, None].astype(jnp.float32) \
-        * freqs[None, None, None, :]
+        * rope_frequencies(d, theta, scaling)[None, None, None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        size = yarn_mscale(scaling.factor, scaling.mscale) \
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim)
+        if size != 1.0:
+            cos, sin = cos * size, sin * size
     if pairing == "halves":
         x1, x2 = x[..., :d // 2], x[..., d // 2:]
         out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
@@ -853,81 +1000,153 @@ def _attend_through_cache(q, k, v, cache: dict, slot: tuple, scale=None,
                              position_major=dense), updated
 
 
-def _causal_softmax(logits: jax.Array, reach: Any, dtype) -> jax.Array:
-    """Softmax over keys of float32 logits (B, H, S_q, K), the queries the
-    last S_q of the first ``reach`` positions, the keys positions 0..K-1."""
+def _causal_softmax(logits: jax.Array, first: Any, dtype) -> jax.Array:
+    """Softmax over keys of float32 logits (B, H, S_q, K), the keys
+    positions 0..K-1, the queries positions ``first`` on."""
     s_q, keys = logits.shape[-2:]
-    q_pos = (reach - s_q) + jnp.arange(s_q)
+    q_pos = first + jnp.arange(s_q)
     mask = q_pos[:, None] >= jnp.arange(keys)[None, :]
     logits = jnp.where(mask[None, None], logits, -1e30)
     return jax.nn.softmax(logits, axis=-1).astype(dtype)
+
+
+def score_blocks(rows: int, heads: int, queries: int, keys: int) -> tuple:
+    """(rows, queries) of a block of latent attention's scores: the most
+    rows that divide ``rows`` whose float32 scores (rows, heads, queries,
+    keys) stay within ``SCORE_BYTES``, and where one row's do not, one
+    row and the most queries that divide ``queries`` and do (one query at
+    the least: nothing cuts the keys)."""
+    def most(n: int, fit: int) -> int:
+        return max(r for r in range(1, n + 1) if n % r == 0 and r <= fit)
+
+    a_query = 4 * heads * keys
+    in_rows = most(rows, max(1, SCORE_BYTES // (a_query * queries)))
+    in_queries = queries if SCORE_BYTES >= a_query * queries else most(
+        queries, max(1, SCORE_BYTES // a_query))
+    return in_rows, in_queries
+
+
+def _by_score_blocks(q_nope, q_rope, latent, first, prepare, attend):
+    """``attend(q_nope, q_rope, prepare(latent), first)`` over queries (B,
+    S_q, H, ·) at positions ``first`` on and the latents (B, K, ·) they
+    attend, so that no float32 scores pass ``SCORE_BYTES``
+    (:func:`score_blocks`): rows in equal blocks one after the other,
+    each preparing its own rows' keys, and where one row's scores are too
+    many, its queries in equal blocks over that row's keys; a block's
+    lines are the whole's."""
+    b, s_q, h, _ = q_nope.shape
+    rows, queries = score_blocks(b, h, s_q, latent.shape[1])
+    if rows < b:
+        def blocks(x):
+            return x.reshape(b // rows, rows, *x.shape[1:])
+
+        out = jax.lax.map(
+            lambda block: _by_score_blocks(*block, first, prepare, attend),
+            (blocks(q_nope), blocks(q_rope), blocks(latent)))
+        return out.reshape(b, *out.shape[2:])
+    keys = prepare(latent)
+    if queries == s_q:
+        return attend(q_nope, q_rope, keys, first)
+
+    def blocks(x):
+        return x.reshape(b, s_q // queries, queries, *x.shape[2:]
+                         ).swapaxes(0, 1)
+
+    firsts = first + queries * jnp.arange(s_q // queries)
+    out = jax.lax.map(lambda block: attend(*block[:2], keys, block[2]),
+                      (blocks(q_nope), blocks(q_rope), firsts))
+    return out.swapaxes(0, 1).reshape(b, s_q, *out.shape[3:])
 
 
 def _latent_expanded(q_nope, q_rope, latent, wkvb, cfg: ModelConfig):
     """Latent attention with every head's keys and values made from the
     latents: q_nope (B, S_q, H, nope) and q_rope (B, S_q, H, rope), the
     last S_q of the K positions whose ``latent`` (B, K, rank + rope) is
-    given. Prefill's path: K is the static reach. → (B, S_q, H, v)."""
+    given. The path of a call that starts at position 0: K is the static
+    reach. → (B, S_q, H, v). In :func:`_by_score_blocks`'s blocks, a
+    block of rows expanding its own keys and values."""
     rank, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
-    scale = 1.0 / float(np.sqrt(nope + cfg.qk_rope_dim))
-    kv = jnp.einsum("bkc,che->bkhe", latent[..., :rank], wkvb)
-    logits = (jnp.einsum("bqhe,bkhe->bhqk", q_nope, kv[..., :nope],
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bqhe,bke->bhqk", q_rope, latent[..., rank:],
-                           preferred_element_type=jnp.float32))
-    probs = _causal_softmax(logits * scale, latent.shape[1], q_nope.dtype)
-    return jnp.einsum("bhqk,bkhe->bqhe", probs, kv[..., nope:])
+
+    def expand(latent):
+        return (jnp.einsum("bkc,che->bkhe", latent[..., :rank], wkvb),
+                latent[..., rank:])
+
+    def attend(q_nope, q_rope, keys, first):
+        kv, kr = keys
+        logits = (jnp.einsum("bqhe,bkhe->bhqk", q_nope, kv[..., :nope],
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhe,bke->bhqk", q_rope, kr,
+                               preferred_element_type=jnp.float32))
+        probs = _causal_softmax(logits * cfg.score_scale, first,
+                                q_nope.dtype)
+        return jnp.einsum("bhqk,bkhe->bqhe", probs, kv[..., nope:])
+
+    return _by_score_blocks(q_nope, q_rope, latent,
+                            latent.shape[1] - q_nope.shape[1], expand, attend)
 
 
 def _latent_absorbed(q_nope, q_rope, cache, length, wkvb, cfg: ModelConfig):
     """The same attention over the first ``length`` slots of a latent
-    cache (B, slots, rank + rope) without expanding it: the keys' half of
-    ``wkvb`` goes into the query (nope → rank lanes a head), the values'
-    half onto the weighted sum of latents. A cached step's path: it reads
-    rank + rope values a position, whatever the number of heads."""
+    cache (B, slots, rank + rope) without expanding it, the queries the
+    last S_q of those positions: the keys' half of ``wkvb`` goes into the
+    query (nope → rank lanes a head), the values' half onto the weighted
+    sum of latents. It reads rank + rope values a position, whatever the
+    number of heads: a cached step's path, and that of a prefill chunk
+    that attends the chunks before it (``cache`` then the cache's first
+    ``length`` slots), in :func:`_by_score_blocks`'s blocks."""
     rank, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
-    scale = 1.0 / float(np.sqrt(nope + cfg.qk_rope_dim))
-    q = jnp.concatenate(
-        [jnp.einsum("bqhe,che->bqhc", q_nope, wkvb[..., :nope]), q_rope],
-        axis=-1)
-    logits = jnp.einsum("bqhc,bkc->bhqk", q, cache,
-                        preferred_element_type=jnp.float32)
-    probs = _causal_softmax(logits * scale, length, q.dtype)
     # as in _cached_attention: a slot not written yet may hold anything,
     # and 0 × NaN is NaN
     written = (jnp.arange(cache.shape[1]) < length)[None, :, None]
-    mixed = jnp.einsum("bhqk,bkc->bqhc", probs,
-                       jnp.where(written, cache[..., :rank], 0))
-    return jnp.einsum("bqhc,che->bqhe", mixed, wkvb[..., nope:])
+
+    def attend(q_nope, q_rope, cache, first):
+        q = jnp.concatenate(
+            [jnp.einsum("bqhe,che->bqhc", q_nope, wkvb[..., :nope]),
+             q_rope], axis=-1)
+        logits = jnp.einsum("bqhc,bkc->bhqk", q, cache,
+                            preferred_element_type=jnp.float32)
+        probs = _causal_softmax(logits * cfg.score_scale, first, q.dtype)
+        mixed = jnp.einsum("bhqk,bkc->bqhc", probs,
+                           jnp.where(written, cache[..., :rank], 0))
+        return jnp.einsum("bqhc,che->bqhe", mixed, wkvb[..., nope:])
+
+    return _by_score_blocks(q_nope, q_rope, cache,
+                            length - q_nope.shape[1], lambda c: c, attend)
 
 
 def _latent_attention(h, blk: dict, positions, cfg: ModelConfig,
                       cache: Optional[dict], slot: Optional[tuple]) -> tuple:
     """Low-rank attention on a normed state h (B, S, D) → (heads' outputs
     (B, S, H, v), the updated cache or None). The query goes through a
-    normed, scaled bottleneck; one latent a position (normed, scaled)
-    carries every head's keys and values, and ``qk_rope_dim`` rotary lanes
-    are shared by all heads. With a cache the tokens' latents and turned
-    rotary lanes are written into pass ``t``'s from position ``start`` on
-    (``slot = (t, start)``); where ``start`` is static (prefill: the reach
-    is known) keys and values are expanded from what the cache holds up
-    to there, else (a cached step) :func:`_latent_absorbed` attends over
-    the latent cache as it lies."""
+    normed bottleneck; one latent a position (normed) carries every
+    head's keys and values, and ``qk_rope_dim`` rotary lanes are shared
+    by all heads; under ``cfg.latent_scale`` each normed bottleneck is
+    scaled to a hidden state's size. With a cache the tokens' latents and
+    turned rotary lanes are written into pass ``t``'s from position
+    ``start`` on (``slot = (t, start)``). A call that starts at position 0
+    (``start`` static: a prompt, or its first chunk) expands keys and
+    values from what it wrote (:func:`_latent_expanded`); every other
+    call attends over the latent cache as it lies
+    (:func:`_latent_absorbed`): a cached step over all its slots, a later
+    chunk of a chunked prefill (``start`` static and above 0) over the
+    slots up to its reach, which on the chip takes half the time of
+    expanding the chunks before it again (PERF.md section 5, PR 41). No
+    float32 scores pass ``SCORE_BYTES`` on either path."""
     dt = cfg.compute_dtype
     rank, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
-    absorbed = cache is not None and not isinstance(slot[1], int)
-    # each bottleneck normed, and scaled to a hidden state's size
-    cq = _rms_norm(h @ blk["wqa"].astype(dt), blk["q_norm"], cfg.norm_eps
-                   ) * float(np.sqrt(cfg.d_model / cfg.q_lora_rank))
+    cq = _rms_norm(h @ blk["wqa"].astype(dt), blk["q_norm"], cfg.norm_eps)
+    if cfg.latent_scale:
+        cq = cq * float(np.sqrt(cfg.d_model / cfg.q_lora_rank))
     q = jnp.einsum("bsr,rhe->bshe", cq, blk["wqb"].astype(dt))
     q_nope = q[..., :nope]
     q_rope = _rope(q[..., nope:], positions, cfg.rope_theta,
-                   cfg.rope_pairing)
+                   cfg.rope_pairing, cfg.rope_scaling)
     kv = h @ blk["wkva"].astype(dt)
-    ckv = _rms_norm(kv[..., :rank], blk["kv_norm"], cfg.norm_eps
-                    ) * float(np.sqrt(cfg.d_model / rank))
+    ckv = _rms_norm(kv[..., :rank], blk["kv_norm"], cfg.norm_eps)
+    if cfg.latent_scale:
+        ckv = ckv * float(np.sqrt(cfg.d_model / rank))
     kr = _rope(kv[:, :, None, rank:], positions, cfg.rope_theta,
-               cfg.rope_pairing)[:, :, 0]
+               cfg.rope_pairing, cfg.rope_scaling)[:, :, 0]
     latent = jnp.concatenate([ckv, kr], axis=-1)
     wkvb = blk["wkvb"].astype(dt)
     if cache is None:
@@ -939,9 +1158,12 @@ def _latent_attention(h, blk: dict, positions, cfg: ModelConfig,
     mine = jax.lax.dynamic_index_in_dim(cache["latent"], t, 0,
                                         keepdims=False)
     reach = start + h.shape[1]
-    if absorbed:
+    if not isinstance(start, int):
         return _latent_absorbed(q_nope, q_rope, mine, reach, wkvb,
                                 cfg), cache
+    if start > 0:
+        return _latent_absorbed(q_nope, q_rope, mine[:, :reach], reach,
+                                wkvb, cfg), cache
     return _latent_expanded(q_nope, q_rope, mine[:, :reach], wkvb,
                             cfg), cache
 
@@ -954,7 +1176,8 @@ def resolve_impls(cfg: ModelConfig, mesh: Optional[Mesh] = None) -> ModelConfig:
     stays single-stream."""
     att, norm = cfg.attention_impl, cfg.norm_impl
     on_tpu = jax.default_backend() == "tpu"
-    if cfg.kv_heads != cfg.n_heads or cfg.attention_scale:
+    if cfg.kv_heads != cfg.n_heads or cfg.attention_scale \
+            or cfg.rope_scaling is not None:
         # the flash and ring kernels know as many key/value heads as
         # query heads and the scale 1 / sqrt(head_dim)
         att = "reference"
@@ -1054,9 +1277,11 @@ def attention_of_kind(x: jax.Array, blk: dict, positions: jax.Array,
         k, v = k + blk["bkv"][0].astype(dt), v + blk["bkv"][1].astype(dt)
     if cfg.position == "rope":
         q = checkpoint_name(
-            _rope(q, positions, cfg.rope_theta, cfg.rope_pairing), "q_rope")
+            _rope(q, positions, cfg.rope_theta, cfg.rope_pairing,
+                  cfg.rope_scaling), "q_rope")
         k = checkpoint_name(
-            _rope(k, positions, cfg.rope_theta, cfg.rope_pairing), "k_rope")
+            _rope(k, positions, cfg.rope_theta, cfg.rope_pairing,
+                  cfg.rope_scaling), "k_rope")
     scale = cfg.score_scale
     lends = None
     if lays_dense(cfg):
@@ -1119,21 +1344,36 @@ def _join(x: jax.Array, out: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def streams_feed_forward(cfg: ModelConfig, rows: int, positions: int,
-                         weights_dtype, mesh: Optional[Mesh] = None):
+                         weights_dtype, mesh: Optional[Mesh] = None,
+                         d_ff: int = 0):
     """How a cached call's feed-forward of ``rows`` rows × ``positions``
     streams its matrices through the one kernel (ops/gated_ffn.py:
     ``gated_ffn.plan``), or None where it runs :func:`_feed_forward`'s own
     lines: a decode step (one position a row) at a few rows, gated, with
     no norm behind the down product, on one chip, the matrices lying in
-    the compute type already (a cast would write them out again). Shapes
-    and types alone decide; nothing names a model."""
+    the compute type already (a cast would write them out again). ``d_ff``
+    is the matrices' own width (0: ``cfg.d_ff``; an expert layer's shared
+    experts have another). Shapes and types alone decide; nothing names a
+    model."""
     from faabric_tpu.ops import gated_ffn
 
     if (positions != 1 or mesh is not None or cfg.ffn != "swiglu"
             or cfg.norm_placement == "sandwich"
             or jnp.dtype(weights_dtype) != jnp.dtype(cfg.compute_dtype)):
         return None
-    return gated_ffn.plan(rows, cfg.d_model, cfg.d_ff, cfg.compute_dtype)
+    return gated_ffn.plan(rows, cfg.d_model, d_ff or cfg.d_ff,
+                          cfg.compute_dtype)
+
+
+def feed_forward_widths(cfg: ModelConfig) -> list:
+    """The width of every feed-forward a token passes whatever it picks,
+    in the layers' order: the dense ones, a "shortcut" layer's two, an
+    "experts" layer's shared experts (as one)."""
+    if cfg.layer == "shortcut":
+        return [cfg.d_ff] * (2 * cfg.n_layers)
+    shared = cfg.shared_experts * cfg.expert_d_ff
+    return [cfg.d_ff if kind == "dense" else shared
+            for kind in cfg.ffns if kind == "dense" or shared]
 
 
 def _feed_forward(h: jax.Array, blk: dict, cfg: ModelConfig,
@@ -1145,7 +1385,8 @@ def _feed_forward(h: jax.Array, blk: dict, cfg: ModelConfig,
     if streamed:
         types = {blk[w].dtype for w in ("wg", "w1", "w2") if w in blk}
         if len(types) == 1 and streams_feed_forward(
-                cfg, *h.shape[:2], types.pop()) is not None:
+                cfg, *h.shape[:2], types.pop(),
+                d_ff=blk["w1"].shape[1]) is not None:
             from faabric_tpu.ops.gated_ffn import gated_ffn
 
             return gated_ffn(h[:, 0], blk["wg"], blk["w1"],
@@ -1179,7 +1420,10 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
     and recurrent state), or a layer that keeps nothing and reads what
     another lends (``lent``): a "cross_attention" the keys and values of
     layer ``cfg.cache_source``, a "gated_memory" the memory of layer
-    ``cfg.memory_source``. A
+    ``cfg.memory_source``. Where ``blk`` holds a ``router`` the
+    feed-forward is an expert layer (models/moe.py:expert_layer: the
+    routed experts held and the shared ones; ``ModelConfig.ffn_types``)
+    and the cache carries its ``counters`` beside the mixer's state. A
     "shortcut" layer is two of those (``blk["halves"]``) and an expert
     layer that reads the first feed-forward's normed input and joins the
     residual after the second; its cache is ``{"attn": [the two
@@ -1191,7 +1435,11 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
     # a cached call on one chip (a layer that keeps nothing has no cache)
     streamed = slot is not None and mesh is None
     if cfg.layer == "single":
-        lends = None
+        lends = counters = None
+        if cache is not None and "counters" in cache:
+            # an "experts" feed-forward's, beside the mixer's state
+            cache = dict(cache)
+            counters = cache.pop("counters")
         if kind in ATTENDING:
             x, cache, lends = attention_of_kind(
                 x, blk, positions, cfg, mesh, cache, slot, kind, layer, lent)
@@ -1208,10 +1456,21 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
                 else:
                     out = ssm.gated_memory(h, blk, cfg, lent)
                 x = _join(x, out, cfg)
-        with jax.named_scope(scopes.FEED_FORWARD):
-            return _join(x, _feed_forward(
-                _norm(x, blk["ln2"], cfg, blk.get("ln2_b")), blk, cfg,
-                streamed), cfg), cache, lends
+        if "router" not in blk:
+            with jax.named_scope(scopes.FEED_FORWARD):
+                return _join(x, _feed_forward(
+                    _norm(x, blk["ln2"], cfg, blk.get("ln2_b")), blk, cfg,
+                    streamed), cfg), cache, lends
+        from faabric_tpu.models.moe import expert_layer
+
+        with jax.named_scope(scopes.EXPERTS):
+            h = _norm(x, blk["ln2"], cfg, blk.get("ln2_b"))
+        out, counted = expert_layer(h, blk["router"], blk["experts"], cfg,
+                                    blk.get("shared"), streamed)
+        with jax.named_scope(scopes.EXPERTS):
+            if counters is not None:
+                cache = dict(cache, counters=counters + counted)
+            return _join(x, out, cfg), cache, lends
 
     from faabric_tpu.models.moe import expert_layer
 

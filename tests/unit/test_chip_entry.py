@@ -92,7 +92,8 @@ def test_chip_smoke_rehearsal_drives_the_whole_flow_on_cpu():
     summary = json.loads(lines[-2])
     phases = summary["phases"]
     assert set(phases) == {"kernels", "train", "decode", "latent_experts",
-                           "state_space", "shared_state", "gang"}
+                           "state_space", "shared_state", "long_latent",
+                           "gang"}
     # the feed-forward kernel at the state-space toy's widths: one tile
     kernels = phases["kernels"]
     assert kernels["gated_ffn_rel_err"] < 8e-3
@@ -131,6 +132,17 @@ def test_chip_smoke_rehearsal_drives_the_whole_flow_on_cpu():
     assert len(counted) == 5 and sum(counted[:3]) == 4 * (32 + 2) * 4
     # tiles of 16 rows: one an expert hit and one more a full 16 picks
     assert counted[3] <= counted[4] <= counted[3] + counted[0] // 16
+    # a dense layer, then an expert layer with a shared expert, at the
+    # toy's widths, 8 rows: a prompt of 48 (past the toy's original reach
+    # of 32) in three chunks, the dense feed-forward and the shared
+    # expert of a step through the streaming kernel, no zero-compute pick
+    long = phases["long_latent"]
+    assert max(long["prefill_rel_err"], long["cached_steps_rel_err"]) < 4e-2
+    assert (long["rows"], long["prompt"], long["prefill_chunks"]) == (8, 48, 3)
+    assert (long["dense_layers"], long["expert_layers"],
+            long["ffn_streamed_layers"], long["expanded_bytes"]) == (1, 1, 2, 0)
+    counted = long["picks_held_zero_absent_experts_hit_tiles"]
+    assert sum(counted[:3]) == 8 * (48 + 2) * 4 and counted[1] == 0
     assert phases["train"]["mesh"] == {"dp": 2, "tp": 2}
     assert phases["train"]["params_on_device_ids"] == [0, 1, 2, 3]
     assert phases["decode"]["request_compiles"][1] == 0
