@@ -63,7 +63,9 @@ Guests (user ``smoke``), all on the chips the planner pinned:
   expert layer with a shared expert under a sigmoid router) behind latent
   attention under YaRN at the widths of ``benchmarks/configs/a.x-k1.json``,
   the two leading layers, 8 rows: a prompt of 8,192 in chunks of 1,024
-  (the last attends a reach of 8,192 in blocks of a row's queries), then
+  (each expands its reach's keys and values and attends them through
+  ops/latent_attention.py, the last a reach of 8,192 two rows at a time;
+  the rehearsal's toy lanes keep the ``jnp`` lines in blocks), then
   two cached steps over the latent caches with the dense feed-forward
   and the shared expert through the streaming kernel, logits of two rows
   against ``benchmarks/reference/axk1.py``.
@@ -875,6 +877,7 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
             picks_held_zero_absent_experts_hit_tiles=counted,
             **{name: sized[name] for name in (
                 "prefill_chunks", "score_blocks", "expanded_bytes",
+                "latent_streamed_layers", "latent_streamed_chunks",
                 "ffn_streamed_layers", "dense_layers", "expert_layers")})
         _require(np.isfinite(got).all(), "a logit is not finite")
         _require([sorted(c) for c in cache]
@@ -884,6 +887,13 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                  and counted[1] == 0, f"the picks do not add up: {counted}")
         _require(sized["ffn_streamed_layers"] == 2,
                  f"{sized['ffn_streamed_layers']} feed-forwards stream")
+        # at the cell's widths every chunk keeps its scores on the chip
+        # (ops/latent_attention.py); the toy's lanes are no whole tiles
+        streamed = (2, s_p // chunk, 0) if on_chip else (0, 0, s_p // chunk)
+        _require((sized["latent_streamed_layers"],
+                  sized["latent_streamed_chunks"],
+                  sized["score_blocks"]) == streamed,
+                 f"prefill's attention streams {sized}")
         for name in ("prefill_rel_err", "cached_steps_rel_err"):
             _require(out[name] < TOL_LONG_LATENT_LOGITS,
                      f"{name} {out[name]}")

@@ -21,10 +21,13 @@ rolled loop inside it, over the same weights.
 Caches go by the attention's kind. Latent attention keeps one latent and
 its turned rotary lanes a position, ``(passes, batch, slots, kv_lora_rank
 + qk_rope_dim)``, row-major and once an attention, whatever the number of
-heads; a prompt, or its first chunk, expands keys and values from it,
-a later chunk of a chunked prefill and a cached step attend over it as
-it lies, the scores in blocks of rows and queries
-(``transformer._latent_attention``, ``score_blocks``). A "shortcut"
+heads; a prompt's chunk expands its reach's keys and values from it and
+attends them through one kernel (ops/latent_attention.py) where
+``transformer.streams_latent_prefill`` finds its shape; else a prompt, or
+its first chunk, expands keys and values from it, a later chunk attends
+over it as it lies, the scores in blocks of rows and queries
+(``transformer._latent_attention``, ``score_blocks``); a cached step
+attends over it as it lies. A "shortcut"
 layer's state is its two attentions' caches and its expert layer's
 counters, summed on the device over the call; a "single" layer whose
 feed-forward is an expert layer (``ModelConfig.ffn_types``) keeps the
@@ -76,6 +79,7 @@ from faabric_tpu.models.transformer import (
     score_blocks,
     streams_attention,
     streams_feed_forward,
+    streams_latent_prefill,
 )
 
 
@@ -143,10 +147,16 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     ``router_width``; where the configuration names its layers'
     feed-forwards (``ffn_types``) also ``shared_experts``,
     ``dense_layers`` and ``expert_layers``, ``prefill_chunks``,
-    ``score_blocks`` (the blocks one layer's prefill scores go in,
-    summed over the prompt's chunks) and ``expanded_bytes`` (what the
-    prefill keeps of expanded keys and values from chunk to chunk:
-    nothing); where the configuration names its layers' kinds
+    ``score_blocks`` (the blocks of float32 scores that one layer's
+    prefill sends through HBM, summed over the prompt's chunks that the
+    kernel does not take), ``expanded_bytes`` (what the prefill keeps of
+    expanded keys and values from chunk to chunk: nothing),
+    ``latent_streamed_layers`` (the attentions whose prefill chunks keep
+    their scores on the chip, ops/latent_attention.py),
+    ``latent_streamed_chunks`` (the chunks a layer that do, by
+    ``transformer.streams_latent_prefill``) and ``latent_streamed_bytes``
+    (what the kernel streams for them, all layers); where the
+    configuration names its layers' kinds
     also ``attention_layers``, ``ssm_layers``, ``state_bytes`` (the
     windows and states of all state-space layers: the same at any reach)
     and ``scan_chunks``, the chunks of the state-space scan a row a layer
@@ -220,17 +230,27 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
                      router_width=cfg.routed_experts + cfg.zero_experts)
     if cfg.ffn_types:
         chunks = _prefill_chunks(prompt_len, prefill_chunk)
+        # a chunk the kernel takes sends no score through HBM
+        kept_on_chip = [streams_latent_prefill(cfg, batch, length,
+                                               pos + length)
+                        for pos, length in chunks]
+        taken = [plan for plan in kept_on_chip if plan]
         sizes.update(
             shared_experts=cfg.shared_experts,
             dense_layers=cfg.ffns.count("dense"),
             expert_layers=cfg.ffns.count("experts"),
             prefill_chunks=len(chunks),
-            # latent attention expands the first chunk's keys and values
-            # and attends the later chunks' reach absorbed: nothing stays
-            # expanded from chunk to chunk
-            score_blocks=sum(_score_blocks(cfg, batch, length, pos + length)
-                             for pos, length in chunks),
-            expanded_bytes=0)
+            score_blocks=sum(
+                _score_blocks(cfg, batch, length, pos + length)
+                for (pos, length), plan in zip(chunks, kept_on_chip)
+                if not plan),
+            # every chunk expands what it attends again, or attends it
+            # absorbed: nothing stays expanded from chunk to chunk
+            expanded_bytes=0,
+            latent_streamed_layers=attention_layers if taken else 0,
+            latent_streamed_chunks=len(taken),
+            latent_streamed_bytes=attention_layers * sum(
+                plan["streamed_bytes"] for plan in taken))
     if cfg.layer_types:
         chunks = _prefill_chunks(prompt_len, prefill_chunk)
         sizes.update(

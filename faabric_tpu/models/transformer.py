@@ -1114,8 +1114,61 @@ def _latent_absorbed(q_nope, q_rope, cache, length, wkvb, cfg: ModelConfig):
                             length - q_nope.shape[1], lambda c: c, attend)
 
 
+def streams_latent_prefill(cfg: ModelConfig, rows: int, queries: int,
+                           reach: int, mesh: Optional[Mesh] = None):
+    """How a cached call of ``rows`` rows × ``queries`` positions, the
+    last of ``reach`` and starting at a static position, attends through
+    the one kernel that keeps latent attention's scores on the chip
+    (ops/latent_attention.py: ``latent_attention.plan``), or None where
+    its scores go through HBM in :func:`_by_score_blocks`'s blocks:
+    latent attention on one chip, keys' and values' lanes whole tiles,
+    both lengths whole tiles too, a reach of ``MIN_REACH`` at the least.
+    The block takes the kernel by it and ``call_sizes`` counts by it.
+    Shapes and types alone decide; nothing names a model."""
+    from faabric_tpu.ops import latent_attention
+
+    if mesh is not None or cfg.attention != "latent":
+        return None
+    return latent_attention.plan(
+        rows, cfg.n_heads, queries, reach, cfg.qk_nope_dim, cfg.qk_rope_dim,
+        cfg.v_head_dim, cfg.compute_dtype)
+
+
+def _latent_streamed(q_nope, q_rope, latent, wkvb, cfg: ModelConfig,
+                     rows: int):
+    """:func:`_latent_expanded`'s attention through the kernel: every
+    head's keys and values made from the latents (B, K, rank + rope), a
+    position's heads side by side as the product leaves them, and the
+    queries, the last S_q of the K positions, attended with no score in
+    HBM. ``rows`` rows at a time (``plan["rows"]``), one after the other,
+    so that what is expanded at once stays within the kernel's
+    ``EXPANDED_BYTES``. → (B, S_q, H, v)."""
+    from faabric_tpu.ops.latent_attention import latent_attention
+
+    rank, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
+    b = q_nope.shape[0]
+
+    def attend(q_nope, q_rope, latent):
+        normed = latent[..., :rank]
+        keys, values = (normed @ w.reshape(rank, -1)
+                        for w in (wkvb[..., :nope], wkvb[..., nope:]))
+        return latent_attention(q_nope, q_rope, keys, latent[..., rank:],
+                                values, scale=cfg.score_scale)
+
+    if rows == b:
+        return attend(q_nope, q_rope, latent)
+
+    def blocks(x):
+        return x.reshape(b // rows, rows, *x.shape[1:])
+
+    out = jax.lax.map(lambda block: attend(*block),
+                      (blocks(q_nope), blocks(q_rope), blocks(latent)))
+    return out.reshape(b, *out.shape[2:])
+
+
 def _latent_attention(h, blk: dict, positions, cfg: ModelConfig,
-                      cache: Optional[dict], slot: Optional[tuple]) -> tuple:
+                      cache: Optional[dict], slot: Optional[tuple],
+                      mesh: Optional[Mesh] = None) -> tuple:
     """Low-rank attention on a normed state h (B, S, D) → (heads' outputs
     (B, S, H, v), the updated cache or None). The query goes through a
     normed bottleneck; one latent a position (normed) carries every
@@ -1123,15 +1176,18 @@ def _latent_attention(h, blk: dict, positions, cfg: ModelConfig,
     by all heads; under ``cfg.latent_scale`` each normed bottleneck is
     scaled to a hidden state's size. With a cache the tokens' latents and
     turned rotary lanes are written into pass ``t``'s from position
-    ``start`` on (``slot = (t, start)``). A call that starts at position 0
-    (``start`` static: a prompt, or its first chunk) expands keys and
-    values from what it wrote (:func:`_latent_expanded`); every other
-    call attends over the latent cache as it lies
-    (:func:`_latent_absorbed`): a cached step over all its slots, a later
-    chunk of a chunked prefill (``start`` static and above 0) over the
-    slots up to its reach, which on the chip takes half the time of
-    expanding the chunks before it again (PERF.md section 5, PR 41). No
-    float32 scores pass ``SCORE_BYTES`` on either path."""
+    ``start`` on (``slot = (t, start)``). A call at a static ``start`` (a
+    prompt or one of its chunks) whose shape :func:`streams_latent_prefill`
+    finds expands the keys and values of its whole reach from the cache
+    and attends them through the kernel (:func:`_latent_streamed`): no
+    score reaches HBM. Where the plan refuses, a call that starts at
+    position 0 expands keys and values from what it wrote
+    (:func:`_latent_expanded`) and a later chunk attends over the latent
+    cache as it lies (:func:`_latent_absorbed`), which in the ``jnp``
+    lines takes half the time of expanding the chunks before it again
+    (PERF.md section 5, PR 41); no float32 scores pass ``SCORE_BYTES``
+    on either. A cached step (``start`` traced) attends absorbed over all
+    its slots."""
     dt = cfg.compute_dtype
     rank, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
     cq = _rms_norm(h @ blk["wqa"].astype(dt), blk["q_norm"], cfg.norm_eps)
@@ -1161,6 +1217,10 @@ def _latent_attention(h, blk: dict, positions, cfg: ModelConfig,
     if not isinstance(start, int):
         return _latent_absorbed(q_nope, q_rope, mine, reach, wkvb,
                                 cfg), cache
+    how = streams_latent_prefill(cfg, *h.shape[:2], reach, mesh)
+    if how is not None:
+        return _latent_streamed(q_nope, q_rope, mine[:, :reach], wkvb, cfg,
+                                how["rows"]), cache
     if start > 0:
         return _latent_absorbed(q_nope, q_rope, mine[:, :reach], reach,
                                 wkvb, cfg), cache
@@ -1260,7 +1320,8 @@ def attention_of_kind(x: jax.Array, blk: dict, positions: jax.Array,
     cache, else None: a cached call lends the cache itself)."""
     h = _norm(x, blk["ln1"], cfg, blk.get("ln1_b"))
     if cfg.attention == "latent":
-        attn, cache = _latent_attention(h, blk, positions, cfg, cache, slot)
+        attn, cache = _latent_attention(h, blk, positions, cfg, cache, slot,
+                                        mesh)
         return _attention_residual(x, attn, blk, cfg), cache, None
     dt = cfg.compute_dtype
     if "wqkv" in blk:

@@ -399,15 +399,26 @@ def test_call_sizes_at_the_cells_widths():
         "ut_passes": 65, "experts_held": 12, "router_width": 192,
         "shared_experts": 1, "dense_layers": 1, "expert_layers": 6,
         "prefill_chunks": 8,
-        # a chunk of 1,024 queries over 1,024 … 8,192 keys, 64 heads:
-        # two rows at once, then a row, then a row's queries in 2, 2, 4,
-        # 4, 4 and 4 blocks
-        "score_blocks": 4 + 8 * (1 + 2 + 2 + 4 + 4 + 4 + 4),
-        "expanded_bytes": 0,
+        # every chunk of 1,024 queries over 1,024 … 8,192 keys keeps its
+        # scores on the chip (ops/latent_attention.py): no block of
+        # float32 scores goes through HBM
+        "score_blocks": 0, "expanded_bytes": 0,
+        "latent_streamed_layers": 7, "latent_streamed_chunks": 8,
+        # queries in, outputs out, the reach's keys and values once
+        "latent_streamed_bytes": 7 * sum(
+            8 * 64 * 2 * (128 + 64 + 128) * (1024 + reach)
+            for reach in range(1024, 8193, 1024)),
         # the dense layer's feed-forward and the six shared experts
         "ffn_streamed_layers": 7, "ffn_streamed_bytes": dense + 6 * shared,
         # latent caches are attended as they lie, by no kernel
         "attention_streamed_layers": 0, "attention_streamed_bytes": 0}
+    # where the plan refuses (a prompt the tiles do not divide), a chunk
+    # of 1,000 queries over 1,000 … 8,000 keys, 64 heads, goes in blocks:
+    # two rows at once, then a row, then a row's queries in 2, 2, 4, 4, 4
+    # and 4 blocks
+    ragged = call_sizes(cfg, 8, 8000, 64, 1000)
+    assert ragged["score_blocks"] == 4 + 8 * (1 + 2 + 2 + 4 + 4 + 4 + 4)
+    assert ragged["latent_streamed_layers"] == 0
     for pos in range(1024, 8193, 1024):
         rows, queries = transformer.score_blocks(8, 64, 1024, pos)
         assert 4 * rows * 64 * queries * pos <= transformer.SCORE_BYTES

@@ -141,6 +141,10 @@ def test_chip_smoke_rehearsal_drives_the_whole_flow_on_cpu():
     assert (long["rows"], long["prompt"], long["prefill_chunks"]) == (8, 48, 3)
     assert (long["dense_layers"], long["expert_layers"],
             long["ffn_streamed_layers"], long["expanded_bytes"]) == (1, 1, 2, 0)
+    # the toy's keys are no whole lane tiles: the plan refuses, the scores
+    # go in blocks, a block a chunk
+    assert (long["latent_streamed_layers"], long["latent_streamed_chunks"],
+            long["score_blocks"]) == (0, 0, 3)
     counted = long["picks_held_zero_absent_experts_hit_tiles"]
     assert sum(counted[:3]) == 8 * (48 + 2) * 4 and counted[1] == 0
     assert phases["train"]["mesh"] == {"dp": 2, "tp": 2}

@@ -1,5 +1,6 @@
 """The flash kernels, the feed-forward kernel, the cached-attention
-kernel and the selective-scan kernel compiled by the TPU's own
+kernel, the selective-scan kernel and latent attention's prefill kernel
+compiled by the TPU's own
 compiler for a described v5e, at real widths, without a chip: what the interpreter cannot refuse
 (a block Mosaic cannot tile, more VMEM than a kernel may take) fails here
 and costs no chip time. Nothing runs, so nothing is said about results
@@ -191,6 +192,46 @@ def test_cached_attention_compiles_for_v5e(call, one_chip, as_on_tpu):
     assert compiled.count("tpu_custom_call") == 1
     assert "cached_attention" in compiled
     assert "input_memory_space_colors" in compiled
+
+
+# (rows, queries, reach): serve_axk1_1chip's first chunk, a middle one
+# and its last at the rows their plan sends at once, 64 heads of 128 + 64
+# lanes on values of 128; blocks the offset does not square
+LATENT_CALLS = {
+    "serve_axk1_1chip_first_chunk": (8, 1024, 1024),
+    "serve_axk1_1chip_fourth_chunk": (4, 1024, 4096),
+    "serve_axk1_1chip_last_chunk": (2, 1024, 8192),
+    "an_offset_of_half_a_block": (2, 1024, 1536),
+    "the_least_reach": (8, 256, 256),
+}
+
+
+@pytest.mark.parametrize("call", sorted(LATENT_CALLS))
+def test_latent_attention_compiles_for_v5e(call, one_chip, as_on_tpu):
+    """The blocks :func:`latent_attention.plan` picks are blocks Mosaic
+    can cut (a head's lane tile of a row of all heads, rotary lanes of
+    half a tile) within the VMEM a kernel may have; one kernel, and no
+    array with a query and a key axis beside it."""
+    from faabric_tpu.ops.latent_attention import latent_attention, plan
+
+    rows, queries, reach = LATENT_CALLS[call]
+    heads, nope, rope, v = 64, 128, 64, 128
+    how = plan(rows, heads, queries, reach, nope, rope, v)
+    assert how is not None and how["rows"] == rows
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *call: latent_attention(*call, scale=0.13086)).lower(
+        shaped(rows, queries, heads, nope), shaped(rows, queries, heads, rope),
+        shaped(rows, reach, heads * nope), shaped(rows, reach, rope),
+        shaped(rows, reach, heads * v)).compile().as_text()
+    assert compiled.count("tpu_custom_call") == 1
+    assert "latent_attention" in compiled
+    # (the output, (rows, queries, heads · v), is no such array)
+    assert f"f32[{rows},{heads},{queries},{reach}]" not in compiled
+    assert f"{heads},{queries},{reach}]" not in compiled
 
 
 def test_a_dense_cache_lies_at_its_values_bytes(one_chip, as_on_tpu):

@@ -6,8 +6,13 @@ the latent cache (``transformer._latent_expanded``, the path of a call
 that starts at position 0, in its blocks); the expansion alone (what a
 path that kept them expanded would save, at ``expanded_bytes`` of memory
 a layer); the absorbed form over the latents as they lie
-(``transformer._latent_absorbed``, the program's path for a later chunk,
-in the same blocks of rows and queries).
+(``transformer._latent_absorbed``, in the same blocks of rows and
+queries); and the kernel that keeps the scores on the chip
+(``transformer._latent_streamed``, the program's path where
+``streams_latent_prefill`` finds the shape: the reach's keys and values
+expanded again a block of rows at a time and attended through
+``ops/latent_attention.py``), with the kernel alone on keys and values
+already expanded beside it.
 
     python3 tools/probe_latent_prefill.py [config.json] [rows] [chunk] [prompt]
 
@@ -32,6 +37,7 @@ def main(argv) -> int:
 
     from benchmarks import program_axk1
     from faabric_tpu.models import transformer
+    from faabric_tpu.ops.latent_attention import latent_attention
 
     path = argv[0] if argv else os.path.join(
         ROOT, "benchmarks", "configs", "a.x-k1.json")
@@ -62,7 +68,10 @@ def main(argv) -> int:
 
     absorbed = jax.jit(lambda qn, qr, lat: transformer._latent_absorbed(
         qn, qr, lat, lat.shape[1], wkvb, cfg))
-    sums = {"expanded_ms": 0.0, "expansion_ms": 0.0, "absorbed_ms": 0.0}
+    kernel = jax.jit(lambda qn, qr, keys, kr, values: latent_attention(
+        qn, qr, keys, kr, values, scale=cfg.score_scale))
+    sums = {"expanded_ms": 0.0, "expansion_ms": 0.0, "absorbed_ms": 0.0,
+            "streamed_ms": 0.0, "kernel_ms": 0.0}
     for reach in range(chunk, prompt + 1, chunk):
         latent = jax.random.normal(k[3], (rows, reach, rank + rope), dt)
         line = {"reach": reach,
@@ -70,8 +79,24 @@ def main(argv) -> int:
                 "expanded_ms": timed(expanded, q_nope, q_rope, latent),
                 "expansion_ms": timed(expansion, latent),
                 "absorbed_ms": timed(absorbed, q_nope, q_rope, latent)}
+        how = transformer.streams_latent_prefill(cfg, rows, chunk, reach)
+        if how is not None:
+            streamed = jax.jit(
+                lambda qn, qr, lat, how=how: transformer._latent_streamed(
+                    qn, qr, lat, wkvb, cfg, how["rows"]))
+            # as the expansion leaves them: a position's heads side by side
+            keys = jax.random.normal(k[2], (rows, reach, h * nope), dt)
+            values = jax.random.normal(
+                k[0], (rows, reach, h * cfg.v_head_dim), dt)
+            line.update(
+                plan={name: how[name] for name in (
+                    "block_q", "block_k", "rows", "streamed_bytes")},
+                streamed_ms=timed(streamed, q_nope, q_rope, latent),
+                kernel_ms=timed(kernel, q_nope, q_rope, keys,
+                                latent[..., rank:], values))
+            del keys, values
         for name in sums:
-            sums[name] += line[name]
+            sums[name] += line.get(name, float("nan"))
         print(json.dumps(line), flush=True)
     kept = rows * prompt * h * (nope + cfg.v_head_dim) \
         * jnp.dtype(dt).itemsize
