@@ -248,8 +248,8 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
         init_params,
         resolve_impls,
     )
+    from faabric_tpu.ops.attention import attend
     from faabric_tpu.ops.flash_attention import (
-        _reference_attention,
         flash_attention,
         uses_kernel as flash_uses_kernel,
     )
@@ -283,9 +283,10 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
         b, h, d = run["batch_per_chip"], cfg.n_heads, cfg.head_dim
 
         def ref_attn(q, k, v):
+            s = q.shape[1]
             with jax.default_matmul_precision("highest"):
-                return _reference_attention(
-                    *(t.astype(jnp.float32) for t in (q, k, v)), True)
+                return attend(*(t.astype(jnp.float32) for t in (q, k, v)),
+                              jnp.tril(jnp.ones((s, s), dtype=bool)))
 
         def loss_of(attn):
             return lambda q, k, v, g: jnp.sum(
@@ -397,8 +398,8 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                 jax.jit(ref_ffn)(h, wg, w1, w2))
 
             # That cell's cached attention over a dense cache (tests: a
-            # toy's), unwritten slots holding NaN, against the block's
-            # own lines in float32 over the same values head-major
+            # toy's), unwritten slots holding NaN, against ``attend`` in
+            # float32 over the same values head-major
             heads, kv = ((int(widths["num_attention_heads"]),
                           int(widths["num_key_value_heads"]))
                          if on_chip else (4, 2))
@@ -418,16 +419,16 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                 jnp.bfloat16) for _ in range(2)]
 
             def ref_cached(q1, keys, values):
-                from faabric_tpu.models.transformer import _cached_attention
-
+                written = jnp.arange(slots) < reach
                 with jax.default_matmul_precision("highest"):
                     head_major = [
                         c[0].reshape(rows, slots, kv, e).transpose(
                             0, 2, 1, 3).astype(jnp.float32)
                         for c in (keys, values)]
-                    return _cached_attention(
+                    return attend(
                         q1[:, None].astype(jnp.float32), *head_major,
-                        reach, 1.0 / e)[:, 0]
+                        written[None, :], written, 1.0 / e,
+                        head_major=True)[:, 0]
 
             out["cached_attention_rel_err"] = _rel_err(
                 jax.jit(lambda q1, keys, values: cached_attention(

@@ -7,11 +7,13 @@ attends over the call's own reach and not over ``max_seq``. Each layer
 has one cache a pass of the stack, ``(passes, batch, heads, slots,
 head_dim)``, head-major and pinned so in memory: one head's keys are one
 contiguous ``(slots, head_dim)`` tile array. Where a call's steps take
-many rows (``transformer.streams_attention``) the cache lies dense
-instead, ``(passes, batch, slots, heads · head_dim)``, a position's keys
-one row of whole lanes whatever the head's width, and a step attends it
-through one kernel a layer (ops/cached_attention.py); prefill attends the
-same cache through a view. It is filled with
+many rows, or the configuration names a ring, a lent cache or
+differential pairs, the cache lies dense instead, ``(passes, batch,
+slots, heads · head_dim)``, a position's keys one row of whole lanes
+whatever the head's width (``transformer.cache_layout`` decides), and a
+step of many rows attends it through one kernel a layer
+(ops/cached_attention.py); any other call attends the cache as it lies
+(``ops/attention.py``). It is filled with
 ``lax.dynamic_update_slice``; attention masks by position, so prefill
 and every decode step compile once each. The whole greedy loop is one
 ``lax.scan`` under jit — no host round-trips between tokens, which is
@@ -68,10 +70,10 @@ from faabric_tpu.models.transformer import (
     ATTENDING,
     ModelConfig,
     _block,
+    cache_layout,
     embed,
     feed_forward_widths,
     head,
-    lays_dense,
     lender_of,
     resolve_impls,
     run_passes,
@@ -97,15 +99,13 @@ def _cache_slots(cfg: ModelConfig, reach: int) -> int:
 def _attention_cache_shapes(cfg: ModelConfig, batch: int, slots: int,
                             mesh=None) -> dict:
     """The arrays one attention's cache is made of, by its kind. Keys and
-    values lie head-major, or dense (a position's key/value heads one row)
-    where the call's steps attend them through the kernel
-    (``transformer.streams_attention``)."""
+    values lie as ``transformer.cache_layout`` says: head-major, or dense
+    (a position's key/value heads one row)."""
     if cfg.attention == "latent":
         return {"latent": (cfg.n_passes, batch, slots,
                            cfg.kv_lora_rank + cfg.qk_rope_dim)}
     shape = (cfg.n_passes, batch, cfg.kv_heads, slots, cfg.head_dim)
-    if lays_dense(cfg) or streams_attention(cfg, batch, 1, slots,
-                                            cfg.compute_dtype, mesh):
+    if cache_layout(cfg, batch, slots, mesh) == "dense":
         shape = (cfg.n_passes, batch, slots, cfg.kv_heads * cfg.head_dim)
     return {"k": shape, "v": shape}
 
@@ -263,7 +263,8 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
             scan_chunks=(sum(-(-length // cfg.ssm_chunk)
                              for _, length in chunks) if count("mamba")
                          else len(chunks) if count("mamba1") else 0))
-    if lays_dense(cfg) or count("mamba1"):
+    if cfg.differential or any(count(kind) for kind in (
+            "window_attention", "cross_attention", "mamba1")):
         scans = [ssm.streams_scan(cfg, batch, length) for _, length in
                  _prefill_chunks(prompt_len, prefill_chunk)
                  ] if count("mamba1") else []
@@ -298,20 +299,17 @@ def _score_blocks(cfg: ModelConfig, batch: int, queries: int,
 def init_kv_cache(cfg: ModelConfig, batch: int, slots: int,
                   mesh=None) -> list[dict]:
     """Zeroed per-layer state of a call, by the layer's kind. Per-head
-    attention: keys and values, one cache a pass, head-major: (passes,
-    batch, key/value heads, slots, head_dim); where a step of ``batch``
-    rows attends through the kernel (``transformer.streams_attention``:
-    never under ``mesh``), dense: (passes, batch, slots, key/value heads ·
-    head_dim). Latent attention: (passes,
-    batch, slots, kv_lora_rank + qk_rope_dim), once. A "shortcut" layer:
-    its two attentions' caches and its expert layer's counters
+    attention: keys and values, one cache a pass, as
+    ``transformer.cache_layout`` lays them: head-major, (passes, batch,
+    key/value heads, slots, head_dim), or dense, (passes, batch, slots,
+    key/value heads · head_dim). Latent attention: (passes, batch, slots,
+    kv_lora_rank + qk_rope_dim), once. A "shortcut" layer: its two
+    attentions' caches and its expert layer's counters
     (``transformer._block``). A "mamba" or "mamba1" layer: its convolution
     window and its recurrent state (``ssm.state_shapes``), whatever
     ``slots``. A "window_attention" layer: a ring of ``_ring_slots``. A
     layer that reads what another lends: None. A layer whose feed-forward
-    is an expert layer: its ``counters`` beside its mixer's state. A
-    configuration that names a ring, a lent cache or differential pairs
-    lays every cache dense (``transformer.lays_dense``)."""
+    is an expert layer: its ``counters`` beside its mixer's state."""
     def zeros(shapes: dict):
         return {name: jnp.zeros(shape, cfg.compute_dtype)
                 for name, shape in shapes.items()}

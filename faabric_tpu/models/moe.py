@@ -48,7 +48,7 @@ from faabric_tpu.models.transformer import (
     ModelConfig,
     _feed_forward,
     _rms_norm,
-    attention_sublayer,
+    attention_of_kind,
     refuse_served_only,
 )
 from faabric_tpu.ops import add_rows
@@ -413,7 +413,7 @@ def moe_forward(params: dict, tokens: jax.Array, cfg: MoEConfig,
 
     aux_total = jnp.zeros((), jnp.float32)
     for blk in params["blocks"]:
-        x, _ = attention_sublayer(x, blk, positions, cfg, mesh)
+        x, _, _ = attention_of_kind(x, blk, positions, cfg, mesh)
         with jax.named_scope(scopes.EXPERTS):
             h = _rms_norm(x, blk["ln2"], cfg.norm_eps)
         moe_out, aux = _moe_layer(h, blk, cfg, mesh)

@@ -38,6 +38,8 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from faabric_tpu.models import scopes
+from faabric_tpu.ops import attention as ops_attention
+from faabric_tpu.ops.attention import attend
 
 
 # The kinds of mixer a layer can have (``ModelConfig.layer_types``), and
@@ -694,73 +696,12 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float,
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
-               scale: Optional[float] = None) -> jax.Array:
-    """Causal attention, q (B, S, H, D) on keys and values (B, S, KV, D),
-    H / KV query heads a key/value head; fp32 softmax accumulators."""
-    scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
-    b, s, h, d = q.shape
-    kv = k.shape[2]
-    q = q.reshape(b, s, kv, h // kv, d)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", q, k).astype(jnp.float32) * scale
-    mask = jnp.tril(jnp.ones((s, s), dtype=bool))
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bkgqs,bskd->bqkgd", probs, v).reshape(b, s, h, d)
-
-
 def _row_major(cache: jax.Array) -> jax.Array:
-    """Pin a cache row-major in memory, its last axis contiguous."""
+    """Pin a cache row-major in memory, its last axis contiguous. Left to
+    itself XLA:TPU lays the decode loop's carried head-major cache heads-
+    minor whatever the logical order, the heads padded to a tile's 128
+    lanes: with 16 heads, eight times the bytes at every step."""
     return with_layout_constraint(cache, Layout(tuple(range(cache.ndim))))
-
-
-def _head_major(cache: jax.Array) -> jax.Array:
-    """Pin a (passes, batch, heads, slots, head_dim) cache row-major in
-    memory. Left to itself XLA:TPU lays the decode loop's carried cache
-    heads-minor whatever the logical order, the heads padded to a tile's
-    128 lanes: with 16 heads, eight times the bytes at every step."""
-    return _row_major(cache)
-
-
-def _cached_attention(q, cache_k, cache_v, length, scale=None,
-                      position_major: bool = False):
-    """q (B, S_q, H, D) against the first ``length`` positions of a
-    head-major cache (B, KV, slots, D), or with ``position_major`` of a
-    (B, slots, KV, D) view of a dense one; q's last position is length-1.
-    With fewer key/value heads than query heads (grouped-query attention)
-    the H / KV query heads of a group attend the group's one cache as it
-    lies: no copy of it a query head is made."""
-    scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
-    b, s_q, h, d = q.shape
-    kv, slots = cache_k.shape[1:3]
-    if position_major:
-        kv, slots = slots, kv
-    q_pos = (length - s_q) + jnp.arange(s_q)
-    k_pos = jnp.arange(slots)
-    mask = q_pos[:, None] >= k_pos[None, :]
-    # A slot the call has not written yet holds whatever its memory held:
-    # XLA:TPU drops the zero fill of a cache that it sees written only
-    # through a loop (``AllocateBuffer`` in the optimized HLO). Its weight
-    # is 0, and 0 × NaN is NaN: such values never reach the sum.
-    written = ((k_pos < length)[None, :, None, None] if position_major
-               else (k_pos < length)[None, None, :, None])
-    if kv != h:
-        cached = "bskd" if position_major else "bksd"
-        q = q.reshape(b, s_q, kv, h // kv, d)
-        logits = jnp.einsum(f"bqkgd,{cached}->bkgqs", q, cache_k
-                            ).astype(jnp.float32) * scale
-        logits = jnp.where(mask[None, None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        return jnp.einsum(f"bkgqs,{cached}->bqkgd", probs,
-                          jnp.where(written, cache_v, 0)
-                          ).reshape(b, s_q, h, d)
-    cached = "bkhd" if position_major else "bhkd"
-    logits = jnp.einsum(f"bqhd,{cached}->bhqk", q, cache_k
-                        ).astype(jnp.float32) * scale
-    logits = jnp.where(mask[None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum(f"bhqk,{cached}->bqhd", probs,
-                      jnp.where(written, cache_v, 0))
 
 
 def streams_attention(cfg: ModelConfig, rows: int, positions: int,
@@ -769,15 +710,12 @@ def streams_attention(cfg: ModelConfig, rows: int, positions: int,
     """How a cached call of ``rows`` rows × ``positions`` attends caches
     of ``slots`` slots through the one kernel over a dense cache
     (ops/cached_attention.py: ``cached_attention.plan``), or None where
-    the cache lies head-major under :func:`_cached_attention`'s own
-    lines: a decode step (one position a row) of 8 rows or more,
-    attention over heads (in differential pairs or not), on one chip, the
-    cache in the compute type, a position's keys whole lanes, a row's
-    reach within the plan's VMEM. ``slots`` is what the layer's cache
-    holds: the call's reach, or a window's ring.
-    ``generate`` lays a call's caches by it (a call decodes one position
-    a step), the block takes the kernel by it and ``call_sizes`` counts
-    by it. Shapes and types alone decide; nothing names a model."""
+    it attends through :func:`attend`: a decode step (one position a row)
+    of 8 rows or more, attention over heads (in differential pairs or
+    not), on one chip, the cache in the compute type, a position's keys
+    whole lanes, a row's reach within the plan's VMEM. ``slots`` is what
+    the layer's cache holds: the call's reach, or a window's ring. Shapes
+    and types alone decide; nothing names a model."""
     from faabric_tpu.ops import cached_attention
 
     if (positions != 1 or mesh is not None or cfg.attention != "heads"
@@ -788,68 +726,28 @@ def streams_attention(cfg: ModelConfig, rows: int, positions: int,
                                  paired=cfg.differential)
 
 
-def lays_dense(cfg: ModelConfig) -> bool:
-    """Whether a configuration's caches lie dense, ``(passes, rows, slots,
-    kv_heads · head_dim)``, at any number of rows: one that names a
-    window, a lent cache or the differential form goes through
-    :func:`_attend_lent_or_ring`, which knows that layout alone."""
-    return cfg.differential or bool(
-        {"window_attention", "cross_attention"} & set(cfg.layer_types))
+def cache_layout(cfg: ModelConfig, rows: int, slots: int,
+                 mesh: Optional[Mesh] = None) -> str:
+    """How an attention's keys and values lie in a call of ``rows`` rows
+    whose cache holds ``slots``: "dense", ``(passes, rows, slots,
+    kv_heads · head_dim)``, a position's key/value heads one row of whole
+    lanes, where the call's decode steps go through the kernel
+    (:func:`streams_attention`) or the configuration names a window, a
+    lent cache or differential pairs (the cached path knows no other
+    layout for those); else "head_major", ``(passes, rows, kv_heads,
+    slots, head_dim)``, one head's keys one contiguous tile array. The one
+    place that decides: ``generate`` allocates by it, :func:`_attend_cached`
+    writes and reads by it, ``call_sizes`` sizes by it."""
+    if cfg.differential or {"window_attention", "cross_attention"} & set(
+            cfg.layer_types) or streams_attention(
+                cfg, rows, 1, slots, cfg.compute_dtype, mesh):
+        return "dense"
+    return "head_major"
 
 
 def lambda_init(layer: int) -> float:
     """The fixed part of a differential layer's weight, by its depth."""
     return 0.8 - 0.6 * float(np.exp(-0.3 * layer))
-
-
-# What the float32 scores of one :func:`_attend` may take: more rows than
-# fit go through it a block of rows at a time (at 64 rows × 40 heads × 256
-# × 768 the scores alone are 2 GB, their exponentials and mask as much
-# again).
-SCORE_BYTES = 512 * 1024 * 1024
-
-
-def _attend(q, keys, values, mask, written, scale: float,
-            differential: bool) -> jax.Array:
-    """q (B, S, H, D) on keys and values (B, K, KV, D) under ``mask``
-    (S, K); ``written`` (K,) says which of the K hold something, None:
-    all. Softmax in float32. Plain or grouped heads → (B, S, H, D). With
-    ``differential`` head (g, p, c) attends key head (g, c) and sums the
-    group's two value heads side by side → (B, S, groups, pairs a group,
-    2, 2·D): the two maps of every pair. Rows whose scores pass
-    ``SCORE_BYTES`` go in equal blocks, one after the other."""
-    b, s, h, d = q.shape
-    reach, kv = keys.shape[1:3]
-    fit = max(1, SCORE_BYTES // (4 * h * s * reach))
-    block = max(r for r in range(1, b + 1) if b % r == 0 and r <= fit)
-    if block < b:
-        def blocks(x):
-            return x.reshape(b // block, block, *x.shape[1:])
-
-        out = jax.lax.map(
-            lambda qkv: _attend(*qkv, mask, written, scale, differential),
-            (blocks(q), blocks(keys), blocks(values)))
-        return out.reshape(b, *out.shape[2:])
-    if written is not None:
-        # 0 × NaN is NaN: what was never written never reaches the sum
-        values = jnp.where(written[None, :, None, None], values, 0)
-    if differential:
-        groups = kv // 2
-        q = q.reshape(b, s, groups, h // kv, 2, d)
-        logits = jnp.einsum("bqgpcd,bkgcd->bgpcqk", q,
-                            keys.reshape(b, reach, groups, 2, d)
-                            ).astype(jnp.float32) * scale
-        probs = jax.nn.softmax(jnp.where(mask, logits, -1e30),
-                               axis=-1).astype(q.dtype)
-        return jnp.einsum("bgpcqk,bkge->bqgpce", probs,
-                          values.reshape(b, reach, groups, 2 * d))
-    q = q.reshape(b, s, kv, h // kv, d)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", q, keys
-                        ).astype(jnp.float32) * scale
-    probs = jax.nn.softmax(jnp.where(mask, logits, -1e30),
-                           axis=-1).astype(q.dtype)
-    return jnp.einsum("bkgqs,bskd->bqkgd", probs, values
-                      ).reshape(b, s, h, d)
 
 
 def _differential(maps: jax.Array, blk: dict, cfg: ModelConfig,
@@ -876,11 +774,11 @@ def _ring_positions(ring: int, start) -> jax.Array:
     return start - 1 - ((start - 1 - slot) % ring)
 
 
-def _attend_lent_or_ring(q, k, v, cache: dict, slot: tuple,
-                         cfg: ModelConfig, kind: str,
-                         mesh: Optional[Mesh]) -> tuple:
-    """A cached call's attention over a dense cache (passes, B, slots,
-    KV · D) by the layer's ``kind`` → (the heads' outputs, or with
+def _attend_cached(q, k, v, cache: dict, slot: tuple, cfg: ModelConfig,
+                   kind: str, mesh: Optional[Mesh]) -> tuple:
+    """A cached call's attention over pass ``t``'s cache (``slot = (t,
+    start)``; a pass reads no other pass's), laid as :func:`cache_layout`
+    says, by the layer's ``kind`` → (the heads' outputs, or with
     ``cfg.differential`` the pairs' two maps; the updated cache, None for
     a cache that was lent).
 
@@ -895,36 +793,40 @@ def _attend_lent_or_ring(q, k, v, cache: dict, slot: tuple,
     tokens before left it, beside its own keys, under the window's mask,
     and then writes its last positions over the oldest.
 
-    A step :func:`streams_attention` finds goes through the kernel
-    (ops/cached_attention.py), every kind alike."""
+    A step :func:`streams_attention` plans goes through the kernel
+    (ops/cached_attention.py), every kind alike; any other call through
+    :func:`attend` over the cache as it lies."""
     t, start = slot
     b, s_q, h, d = q.shape
-    slots = cache["k"].shape[2]
-    kv = cfg.kv_heads
+    kv, slots = cfg.kv_heads, cache["k"].shape[-2]
+    dense = cache_layout(cfg, b, slots, mesh) == "dense"
     window = cfg.sliding_window if kind == "window_attention" else 0
     scale = cfg.score_scale
 
     def write(old, new, at):
-        return _row_major(jax.lax.dynamic_update_slice(
-            old, new.reshape(1, b, new.shape[1], -1), (t, 0, at, 0)))
+        if dense:
+            new, index = new.reshape(1, b, new.shape[1], -1), (t, 0, at, 0)
+        else:
+            new, index = new.transpose(0, 2, 1, 3)[None], (t, 0, 0, at, 0)
+        return _row_major(jax.lax.dynamic_update_slice(old, new, index))
 
     def mine(name):
-        return jax.lax.dynamic_index_in_dim(
-            cache[name], t, 0, keepdims=False).reshape(b, slots, kv, d)
+        one = jax.lax.dynamic_index_in_dim(cache[name], t, 0, keepdims=False)
+        return one.reshape(b, slots, kv, d) if dense else one
 
-    q_pos = start + jnp.arange(s_q)
     if window and s_q > 1:
         if not isinstance(start, int):
             raise ValueError("a window's ring takes more than one position "
                              "a row only from a static start")
+        q_pos = start + jnp.arange(s_q)
         held = _ring_positions(slots, start)
         k_pos = jnp.concatenate([held, q_pos])
         mask = (k_pos[None, :] <= q_pos[:, None]) \
             & (k_pos[None, :] > q_pos[:, None] - window)
-        out = _attend(q, jnp.concatenate([mine("k"), k], axis=1),
-                      jnp.concatenate([mine("v"), v], axis=1),
-                      mask & (k_pos >= 0)[None, :], k_pos >= 0, scale,
-                      cfg.differential)
+        out = attend(q, jnp.concatenate([mine("k"), k], axis=1),
+                     jnp.concatenate([mine("v"), v], axis=1),
+                     mask & (k_pos >= 0)[None, :], k_pos >= 0, scale,
+                     cfg.differential)
         # the last positions over the oldest, in at most two pieces
         first = max(0, s_q - slots)
         at = (start + first) % slots
@@ -953,51 +855,15 @@ def _attend_lent_or_ring(q, k, v, cache: dict, slot: tuple,
         return (out.reshape(b, 1, kv // 2, h // kv, 2, 2 * d)
                 if cfg.differential else out[:, None]), updated
     k_pos = jnp.arange(slots)
-    # a ring's slots hold the last positions in no order; all of them
-    # are within one position's window
-    mask = (k_pos[None, :] < length) if window \
-        else (k_pos[None, :] <= q_pos[:, None])
-    return _attend(q, mine("k"), mine("v"), mask, k_pos < length, scale,
-                   cfg.differential), updated
-
-
-def _attend_through_cache(q, k, v, cache: dict, slot: tuple, scale=None,
-                          streamed: bool = False):
-    """Write these tokens' keys and values into pass ``t``'s cache from
-    position ``start`` on (``slot = (t, start)``), then attend over that
-    pass's cache up to themselves: a pass reads no other pass's cache.
-    The cache's own shape says how it lies: head-major (passes, B, KV,
-    slots, D), or dense (passes, B, slots, KV · D) where
-    :func:`streams_attention` laid it so. ``streamed`` says that the call
-    over a dense cache is a step :func:`streams_attention` finds: it goes
-    through the kernel; any other call over a dense cache (prefill)
-    through :func:`_cached_attention` over a (B, slots, KV, D) view.
-    Returns (attention, the updated cache)."""
-    t, start = slot
-    b, s_q, _, d = q.shape
-    dense = cache["k"].ndim == 4
-
-    def write(old, new):
-        if dense:
-            return _row_major(jax.lax.dynamic_update_slice(
-                old, new.reshape(1, b, s_q, -1), (t, 0, start, 0)))
-        return _head_major(jax.lax.dynamic_update_slice(
-            old, new.transpose(0, 2, 1, 3)[None], (t, 0, 0, start, 0)))
-
-    updated = {name: write(cache[name], new)
-               for name, new in (("k", k), ("v", v))}
-    if streamed:
-        from faabric_tpu.ops.cached_attention import cached_attention
-
-        scale = 1.0 / np.sqrt(d) if scale is None else scale
-        return cached_attention(q[:, 0], updated["k"], updated["v"],
-                                start + 1, scale, t)[:, None], updated
-    mine = [jax.lax.dynamic_index_in_dim(updated[name], t, 0, keepdims=False)
-            for name in ("k", "v")]
-    if dense:
-        mine = [one.reshape(b, -1, k.shape[2], d) for one in mine]
-    return _cached_attention(q, *mine, start + s_q, scale,
-                             position_major=dense), updated
+    if window:
+        # a ring's slots hold the last positions in no order; all of them
+        # are within one position's window
+        mask = k_pos[None, :] < length
+    else:
+        q_pos = (reach - s_q) + jnp.arange(s_q)
+        mask = q_pos[:, None] >= k_pos[None, :]
+    return attend(q, mine("k"), mine("v"), mask, k_pos < length, scale,
+                  cfg.differential, head_major=not dense), updated
 
 
 def _causal_softmax(logits: jax.Array, first: Any, dtype) -> jax.Array:
@@ -1013,16 +879,16 @@ def _causal_softmax(logits: jax.Array, first: Any, dtype) -> jax.Array:
 def score_blocks(rows: int, heads: int, queries: int, keys: int) -> tuple:
     """(rows, queries) of a block of latent attention's scores: the most
     rows that divide ``rows`` whose float32 scores (rows, heads, queries,
-    keys) stay within ``SCORE_BYTES``, and where one row's do not, one
-    row and the most queries that divide ``queries`` and do (one query at
-    the least: nothing cuts the keys)."""
+    keys) stay within ``SCORE_BYTES`` (ops/attention.py), and where one
+    row's do not, one row and the most queries that divide ``queries``
+    and do (one query at the least: nothing cuts the keys)."""
     def most(n: int, fit: int) -> int:
         return max(r for r in range(1, n + 1) if n % r == 0 and r <= fit)
 
-    a_query = 4 * heads * keys
-    in_rows = most(rows, max(1, SCORE_BYTES // (a_query * queries)))
-    in_queries = queries if SCORE_BYTES >= a_query * queries else most(
-        queries, max(1, SCORE_BYTES // a_query))
+    limit, a_query = ops_attention.SCORE_BYTES, 4 * heads * keys
+    in_rows = most(rows, max(1, limit // (a_query * queries)))
+    in_queries = queries if limit >= a_query * queries else most(
+        queries, max(1, limit // a_query))
     return in_rows, in_queries
 
 
@@ -1095,8 +961,8 @@ def _latent_absorbed(q_nope, q_rope, cache, length, wkvb, cfg: ModelConfig):
     that attends the chunks before it (``cache`` then the cache's first
     ``length`` slots), in :func:`_by_score_blocks`'s blocks."""
     rank, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
-    # as in _cached_attention: a slot not written yet may hold anything,
-    # and 0 × NaN is NaN
+    # as in attend: a slot not written yet may hold anything, and 0 × NaN
+    # is NaN
     written = (jnp.arange(cache.shape[1]) < length)[None, :, None]
 
     def attend(q_nope, q_rope, cache, first):
@@ -1208,7 +1074,7 @@ def _latent_attention(h, blk: dict, positions, cfg: ModelConfig,
     if cache is None:
         return _latent_expanded(q_nope, q_rope, latent, wkvb, cfg), None
     t, start = slot
-    # a position's latent one contiguous row, as _head_major pins keys
+    # a position's latent one contiguous row, as _row_major pins keys
     cache = {"latent": _row_major(jax.lax.dynamic_update_slice(
         cache["latent"], latent[None], (t, 0, start, 0)))}
     mine = jax.lax.dynamic_index_in_dim(cache["latent"], t, 0,
@@ -1237,9 +1103,11 @@ def resolve_impls(cfg: ModelConfig, mesh: Optional[Mesh] = None) -> ModelConfig:
     att, norm = cfg.attention_impl, cfg.norm_impl
     on_tpu = jax.default_backend() == "tpu"
     if cfg.kv_heads != cfg.n_heads or cfg.attention_scale \
-            or cfg.rope_scaling is not None:
+            or cfg.rope_scaling is not None or cfg.differential \
+            or cfg.sliding_window:
         # the flash and ring kernels know as many key/value heads as
-        # query heads and the scale 1 / sqrt(head_dim)
+        # query heads, the scale 1 / sqrt(head_dim) and the causal mask
+        # alone
         att = "reference"
     if att == "auto":
         att = "flash" if on_tpu else "reference"
@@ -1292,32 +1160,26 @@ def _sharded_flash(q, k, v, mesh: Mesh):
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-def attention_sublayer(x: jax.Array, blk: dict, positions: jax.Array,
-                       cfg: ModelConfig, mesh: Optional[Mesh] = None,
-                       cache: Optional[dict] = None,
-                       slot: Optional[tuple] = None) -> tuple:
-    """Norm, attention, (norm,) residual — shared by the dense and MoE
-    families (honours cfg.attention_impl / norm_impl). Without ``cache``
-    the tokens attend causally among themselves; with this layer's
-    ``cache`` they go through it (:func:`_attend_through_cache`).
-    ``cfg.attention`` "latent" is :func:`_latent_attention`, behind the
-    same norms and residual. Returns (x, the updated cache or None)."""
-    return attention_of_kind(x, blk, positions, cfg, mesh, cache, slot)[:2]
-
-
 @jax.named_scope(scopes.ATTENTION)
 def attention_of_kind(x: jax.Array, blk: dict, positions: jax.Array,
                       cfg: ModelConfig, mesh: Optional[Mesh] = None,
                       cache: Optional[dict] = None,
                       slot: Optional[tuple] = None, kind: str = "attention",
                       layer: int = 0, lent: Optional[dict] = None) -> tuple:
-    """:func:`attention_sublayer` for a layer of ``kind`` at depth
-    ``layer`` (``ModelConfig.layer_types``). A "cross_attention" layer
-    projects queries alone and attends what ``lent`` holds: another
-    layer's cache in a cached call, else that layer's keys and values
-    ``{"k", "v"}`` (B, S, KV, D). Returns (x, the updated cache or None,
-    what the layer lends: its keys and values where it ran without a
-    cache, else None: a cached call lends the cache itself)."""
+    """Norm, attention of the layer's ``kind`` at depth ``layer``
+    (``ModelConfig.layer_types``), (norm,) residual: shared by the dense
+    and MoE families. Without ``slot`` the tokens attend among themselves:
+    through the flash or ring kernel where ``cfg.attention_impl`` names
+    one, else :func:`attend` under the kind's mask. With ``slot`` (a cached
+    call) they go through the layer's ``cache`` (:func:`_attend_cached`).
+    A "cross_attention" layer projects queries alone and attends what
+    ``lent`` holds: another layer's cache in a cached call, else that
+    layer's keys and values ``{"k", "v"}`` (B, S, KV, D).
+    ``cfg.attention`` "latent" is :func:`_latent_attention`, behind the
+    same norms and residual. Returns (x, the updated cache or None, what
+    the layer lends: its keys and values where it ran without a cache in
+    a configuration that lends them, else None: a cached call lends the
+    cache itself)."""
     h = _norm(x, blk["ln1"], cfg, blk.get("ln1_b"))
     if cfg.attention == "latent":
         attn, cache = _latent_attention(h, blk, positions, cfg, cache, slot,
@@ -1343,44 +1205,35 @@ def attention_of_kind(x: jax.Array, blk: dict, positions: jax.Array,
         k = checkpoint_name(
             _rope(k, positions, cfg.rope_theta, cfg.rope_pairing,
                   cfg.rope_scaling), "k_rope")
-    scale = cfg.score_scale
     lends = None
-    if lays_dense(cfg):
-        if slot is not None:        # a cached call: forward gives none
-            attn, cache = _attend_lent_or_ring(
-                q, k, v, lent if kind == "cross_attention" else cache,
-                slot, cfg, kind, mesh)
-        else:
-            if kind == "cross_attention":
-                k, v = lent["k"], lent["v"]
+    if slot is not None:
+        attn, cache = _attend_cached(
+            q, k, v, lent if kind == "cross_attention" else cache, slot,
+            cfg, kind, mesh)
+    else:
+        if kind == "cross_attention":
+            k, v = lent["k"], lent["v"]
+        if cfg.cache_source >= 0:
             lends = {"k": k, "v": v}
+        if cfg.attention_impl == "flash":
+            from faabric_tpu.ops.flash_attention import flash_attention
+
+            attn = (_sharded_flash(q, k, v, mesh) if mesh is not None
+                    else flash_attention(q, k, v, True))
+        elif cfg.attention_impl == "ring" and mesh is not None:
+            from faabric_tpu.parallel.ring_attention import ring_attention
+
+            attn = ring_attention(q, k, v, mesh, axis="sp",
+                                  batch_axis="dp", head_axis="tp")
+        else:
             at = jnp.arange(q.shape[1])
             mask = at[None, :] <= at[:, None]
             if kind == "window_attention":
                 mask &= at[None, :] > at[:, None] - cfg.sliding_window
-            attn = _attend(q, k, v, mask, None, scale, cfg.differential)
-        if cfg.differential:
-            attn = _differential(attn, blk, cfg, layer)
-    elif cache is not None:
-        streamed = cache["k"].ndim == 4 and streams_attention(
-            cfg, *q.shape[:2], cache["k"].shape[2], cache["k"].dtype,
-            mesh) is not None
-        attn, cache = _attend_through_cache(q, k, v, cache, slot, scale,
-                                            streamed)
-    elif cfg.attention_impl == "flash":
-        from faabric_tpu.ops.flash_attention import flash_attention
-
-        if mesh is not None:
-            attn = _sharded_flash(q, k, v, mesh)
-        else:
-            attn = flash_attention(q, k, v, True)
-    elif cfg.attention_impl == "ring" and mesh is not None:
-        from faabric_tpu.parallel.ring_attention import ring_attention
-
-        attn = ring_attention(q, k, v, mesh, axis="sp",
-                              batch_axis="dp", head_axis="tp")
-    else:
-        attn = _attention(q, k, v, scale)
+            attn = attend(q, k, v, mask, None, cfg.score_scale,
+                          cfg.differential)
+    if cfg.differential:
+        attn = _differential(attn, blk, cfg, layer)
     return _attention_residual(x, attn, blk, cfg), cache, lends
 
 
@@ -1537,8 +1390,8 @@ def _block(x: jax.Array, blk: dict, positions: jax.Array,
 
     caches = [None, None] if cache is None else list(cache["attn"])
     for i, half in enumerate(blk["halves"]):
-        x, caches[i] = attention_sublayer(x, half, positions, cfg, mesh,
-                                          caches[i], slot)
+        x, caches[i], _ = attention_of_kind(x, half, positions, cfg, mesh,
+                                            caches[i], slot)
         with jax.named_scope(scopes.FEED_FORWARD):
             h = _norm(x, half["ln2"], cfg)
         if i == 0:
@@ -1610,7 +1463,7 @@ def run_passes(x: jax.Array, carry: Any, params: dict, cfg: ModelConfig,
 
 # What a block's checkpoint keeps for the backward pass, by the names the
 # values take where they are made (``checkpoint_name`` in
-# :func:`attention_sublayer`, :func:`_block` and the flash kernel's forward
+# :func:`attention_of_kind`, :func:`_block` and the flash kernel's forward
 # rule): the outputs of the matrix products (of the QKV product the
 # queries and keys after their rotary turn: the same bytes, and no turn to
 # make again), the attention kernel's output and row statistic, and the

@@ -23,8 +23,8 @@ its own key/value head's columns. The heads of a group share the group's
 keys and values with no copy a head; the MXU does ``kv_heads`` times the
 useful work and idles all the same.
 
-The arithmetic is ``models/transformer.py:_cached_attention``'s: scores
-accumulated in float32 times the scale, positions at or beyond ``length``
+The arithmetic is ``ops/attention.py:attend``'s: scores accumulated in
+float32 times the scale, positions at or beyond ``length``
 masked to −1e30, softmax in float32, probabilities cast to the inputs'
 type before the product with the values, and unwritten slots of the values
 zeroed before it (a slot the call has not written may hold anything, and
@@ -34,8 +34,8 @@ zeroed before it (a slot the call has not written may hold anything, and
 call asks for and the bytes it streams, from the call's shape alone; the
 kernel takes its blocks from it. It is None where the kernel does not
 apply (fewer than ``MIN_ROWS`` rows, a cache row that is not whole lanes,
-a row's reach that does not fit ``STEP_BYTES``), and the caller keeps the
-head-major cache and its own lines.
+a row's reach that does not fit ``STEP_BYTES``), and the caller attends
+through ``ops/attention.py`` over its cache as it lies.
 
 On CPU (tests) the kernel runs in interpreter mode automatically.
 """

@@ -57,6 +57,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from faabric_tpu.ops.attention import attend
+
 NEG_INF = -1e30
 # Inside the kernels a per-row softmax statistic (m, l, lse, delta) is a
 # (rows, LANE) float32 tile with every lane holding the row's value: it
@@ -120,17 +122,13 @@ def _stat_cols(stat, n_cols: int):
     return jnp.tile(stat, (1, reps))[:, :n_cols]
 
 
-def _reference_attention(q, k, v, causal: bool = True):
-    """Plain jnp attention (the model's _attention twin) — used for the
-    backward pass and for numerics tests."""
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    if causal:
-        s_q, s_k = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
-        logits = jnp.where(mask[None, None], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+def _mask(q, k, causal: bool):
+    """What :func:`attend` (the ``jnp`` lines, where no plan tiles a call,
+    and their backward pass) masks: row i sees keys up to i + S_k − S_q,
+    the queries the last positions; None where not ``causal``."""
+    s_q, s_k = q.shape[1], k.shape[1]
+    return jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q) \
+        if causal else None
 
 
 # ---------------------------------------------------------------------------
@@ -574,7 +572,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k):
     s_k = k.shape[1]
     plan = block_plan(q.shape, k.shape, causal, block_q, block_k, q.dtype)
     if plan is None:
-        return _reference_attention(q, k, v, causal), None
+        return attend(q, k, v, _mask(q, k, causal)), None
     fwd = plan["flash_fwd"]
 
     # Fold (B, H) into the grid's first axis; kernel sees 2-D tiles
@@ -687,7 +685,7 @@ def _flash_bwd(causal, block_q, block_k, res, g):
     if lse is None:
         # Forward fell back to reference numerics; match them in reverse
         _, vjp = jax.vjp(
-            lambda q, k, v: _reference_attention(q, k, v, causal), q, k, v)
+            lambda q, k, v: attend(q, k, v, _mask(q, k, causal)), q, k, v)
         return vjp(g)
     return _run_bwd_kernels(q, k, v, g, out, lse, causal, block_q, block_k)
 
@@ -706,9 +704,7 @@ def _reference_lse(q, k, causal: bool):
     scale = 1.0 / np.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
-        s_q, s_k = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
-        logits = jnp.where(mask[None, None], logits, NEG_INF)
+        logits = jnp.where(_mask(q, k, causal), logits, NEG_INF)
     # (B, H, S_q) → fold to the kernel's (B·H, S_q) layout
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     b, h, s_q = lse.shape
@@ -742,7 +738,7 @@ def _flash_lse_bwd(causal, block_q, block_k, res, cotangents):
     if lse is None:
         # Reference numerics in reverse for the fallback path
         def ref(q, k, v):
-            return (_reference_attention(q, k, v, causal),
+            return (attend(q, k, v, _mask(q, k, causal)),
                     _reference_lse(q, k, causal))
 
         _, vjp = jax.vjp(ref, q, k, v)

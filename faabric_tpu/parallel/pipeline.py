@@ -48,6 +48,7 @@ from faabric_tpu.models.transformer import (
     _rope,
     refuse_served_only,
 )
+from faabric_tpu.ops.attention import attend
 from faabric_tpu.parallel.ring_attention import _mark_varying
 
 
@@ -165,8 +166,8 @@ def _head_nll(y, ln_f, lm_head, targets_m, cfg: ModelConfig):
 def _global_positions(b_local: int, seq: int):
     """GLOBAL row ids for this device's sequence shard — the single
     definition of 'global row = axis_index(sp) · seq_local + local'
-    shared by both schedule bodies (and consistent with the row0 offset
-    in _pp_attention_sublayer). A no-op offset at sp=1."""
+    shared by both schedule bodies (and consistent with the rows a shard's
+    queries cover in _pp_attention_sublayer). A no-op offset at sp=1."""
     return jnp.broadcast_to(
         jax.lax.axis_index("sp") * seq + jnp.arange(seq)[None],
         (b_local, seq))
@@ -211,21 +212,6 @@ def _pp_specs(cfg: ModelConfig, mesh: Mesh):
     return param_specs, P(None, "dp", "sp")
 
 
-def _pp_attention_offset(q, k, v, row_offset):
-    """Causal attention where q covers the GLOBAL rows [row_offset,
-    row_offset + Sq) of a sequence whose K/V span all Skv rows. Reduces
-    to models/transformer._attention exactly at row_offset=0, Skv==Sq
-    (same op order and fp32 softmax accumulators)."""
-    scale = 1.0 / (q.shape[-1] ** 0.5)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    sq, skv = q.shape[1], k.shape[1]
-    rows = row_offset + jnp.arange(sq)[:, None]
-    mask = jnp.arange(skv)[None, :] <= rows
-    logits = jnp.where(mask[None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-
-
 def _pp_attention_sublayer(x, blk, positions, cfg: ModelConfig):
     """Megatron attention on tp-local shards (qkv column-parallel, wo
     row-parallel + psum) — shared by the dense and MoE pp blocks.
@@ -246,8 +232,10 @@ def _pp_attention_sublayer(x, blk, positions, cfg: ModelConfig):
     k = _rope(k, positions, cfg.rope_theta)
     k_full = jax.lax.all_gather(k, "sp", axis=1, tiled=True)
     v_full = jax.lax.all_gather(v, "sp", axis=1, tiled=True)
-    row0 = jax.lax.axis_index("sp") * q.shape[1]
-    attn = _pp_attention_offset(q, k_full, v_full, row0)
+    # q covers this shard's GLOBAL rows of the whole K/V
+    rows = jax.lax.axis_index("sp") * q.shape[1] + jnp.arange(q.shape[1])
+    attn = attend(q, k_full, v_full,
+                  jnp.arange(k_full.shape[1])[None, :] <= rows[:, None])
     attn_out = jnp.einsum("bshe,hed->bsd", attn,
                           blk["wo"].astype(cfg.compute_dtype))
     return x + jax.lax.psum(attn_out, "tp")

@@ -56,9 +56,11 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
     """
     n = mesh.shape[axis]
     if n == 1:
-        from faabric_tpu.ops.flash_attention import _reference_attention
+        from faabric_tpu.ops.attention import attend
 
-        return _reference_attention(q, k, v, causal)
+        s = q.shape[1]
+        return attend(q, k, v, jnp.tril(jnp.ones((s, s), dtype=bool))
+                      if causal else None)
     return _compiled_ring(mesh, axis, causal, batch_axis, head_axis)(q, k, v)
 
 

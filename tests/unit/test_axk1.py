@@ -40,6 +40,7 @@ from faabric_tpu.models.generate import (
     generate_with_counters,
     init_kv_cache,
 )
+from faabric_tpu.ops import attention as ops_attention
 
 ATOL = 1e-4
 SEED = 2147484041
@@ -225,7 +226,7 @@ def test_expanded_attention_in_blocks_equals_the_unblocked_lines(
     latent = jax.random.normal(k[2], (6, 40, 24))
     wkvb = jax.random.normal(k[3], (16, 4, 32)) / 4.0
     whole = transformer._latent_expanded(q_nope, q_rope, latent, wkvb, cfg)
-    monkeypatch.setattr(transformer, "SCORE_BYTES", limit)
+    monkeypatch.setattr(ops_attention, "SCORE_BYTES", limit)
     assert transformer.score_blocks(6, 4, 24, 40) == blocks
     jaxpr = jax.make_jaxpr(
         lambda *a: transformer._latent_expanded(*a, wkvb, cfg))(
@@ -262,7 +263,7 @@ def test_call_sizes_counts_the_blocks_of_a_chunked_prefill(monkeypatch):
     uses."""
     sz = sizes()
     cfg = config(sz)
-    monkeypatch.setattr(transformer, "SCORE_BYTES", 4 * 4 * 8 * 32)
+    monkeypatch.setattr(ops_attention, "SCORE_BYTES", 4 * 4 * 8 * 32)
     # chunks of 16 at reaches 16, 32, 48 over 2 rows: a row's 16 queries
     # fit at 16 keys (2 rows in one block), 8 at 32 (2 × 2), 4 at 48
     assert [transformer.score_blocks(2, 4, 16, reach)
@@ -421,7 +422,7 @@ def test_call_sizes_at_the_cells_widths():
     assert ragged["latent_streamed_layers"] == 0
     for pos in range(1024, 8193, 1024):
         rows, queries = transformer.score_blocks(8, 64, 1024, pos)
-        assert 4 * rows * 64 * queries * pos <= transformer.SCORE_BYTES
+        assert 4 * rows * 64 * queries * pos <= ops_attention.SCORE_BYTES
     shapes = jax.eval_shape(lambda: init_kv_cache(cfg, 8, 8320))
     assert [sorted(layer) for layer in shapes] == [["latent"]] + [
         ["counters", "latent"]] * 6

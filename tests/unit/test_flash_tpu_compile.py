@@ -269,25 +269,39 @@ def test_add_rows_compiles_for_v5e(call, one_chip, as_on_tpu):
 
 
 def test_a_dense_cache_lies_at_its_values_bytes(one_chip, as_on_tpu):
-    """Two cached steps of the cell's shape over caches the program makes
-    itself, compiled: dense, both caches lie in HBM at their 41.9 MB;
-    head-major and pinned row-major, a head of 64 lanes is padded to the
-    tile's 128: XLA holds one of the two in VMEM whole (nothing holds it
-    to HBM) and the one left in HBM takes 83.9 MB, both dense ones'
-    bytes."""
+    """Two cached steps of the cell's shape over caches laid as the
+    program lays them, compiled: dense, both caches lie in HBM at their
+    41.9 MB; head-major (as under a mesh, which never takes the kernel)
+    and pinned row-major, a head of 64 lanes is padded to the tile's 128:
+    XLA holds one of the two in VMEM whole (nothing holds it to HBM) and
+    the one left in HBM takes 83.9 MB, both dense ones' bytes."""
+    import json
+    import os
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from benchmarks import program_granite
     from faabric_tpu.models import transformer
+    from faabric_tpu.models.generate import _attention_cache_shapes
 
     rows, heads, kv, slots, d = ATTENTION_CALLS["serve_granite_1chip"]
     values = rows * slots * kv * d * 2
+    with open(os.path.join(os.path.dirname(__file__), "..", "..",
+                           "benchmarks", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        cfg = program_granite.model_config(json.load(f))
 
-    def steps(cache_shape, streamed):
+    def steps(mesh):
+        shape = _attention_cache_shapes(cfg, rows, slots, mesh)["k"]
+
         def run(q, k, v):
             def step(carry, _):
                 cache, pos = carry
-                attn, cache = transformer._attend_through_cache(
-                    q, k, v, cache, (0, pos), 1 / 64, streamed)
+                attn, cache = transformer._attend_cached(
+                    q, k, v, cache, (0, pos), cfg, "attention", mesh)
                 return (cache, pos + 1), attn
-            cache = {name: jnp.zeros(cache_shape, jnp.bfloat16)
+            cache = {name: jnp.zeros(shape, jnp.bfloat16)
                      for name in ("k", "v")}
             return jax.lax.scan(step, (cache, jnp.int32(600)), None,
                                 length=2)[1]
@@ -299,12 +313,12 @@ def test_a_dense_cache_lies_at_its_values_bytes(one_chip, as_on_tpu):
         return compiled.memory_analysis().temp_size_in_bytes, \
             compiled.as_text()
 
-    dense, text = steps((1, rows, slots, kv * d), True)
+    dense, text = steps(None)
     assert text.count("tpu_custom_call") == 1
     assert values == 41_943_040
     assert 2 * values <= dense < 2 * values + 1024 * 1024
     assert "bf16[1,64,640,512]{3,2,1,0:T(8,128)(2,1)S(1)}" not in text
-    padded, text = steps((1, rows, kv, slots, d), False)
+    padded, text = steps(Mesh(np.array(list(one_chip.device_set)), ("dp",)))
     assert "tpu_custom_call" not in text
     assert "bf16[1,64,8,640,64]{4,3,2,1,0:T(8,128)(2,1)S(1)}" in text
     assert 2 * values <= padded < 2 * values + 1024 * 1024

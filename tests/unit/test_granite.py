@@ -33,6 +33,7 @@ from benchmarks import weights_granite
 from benchmarks.reference import granite as ref
 from faabric_tpu.models import ModelConfig, forward, init_params, ssm
 from faabric_tpu.models import transformer
+from faabric_tpu.ops.attention import attend
 from faabric_tpu.models.generate import (
     call_sizes,
     forward_with_cache,
@@ -217,15 +218,18 @@ def test_grouped_query_attention_reads_the_cache_as_it_lies():
     q = jnp.einsum("sd,dhe->she", h, blk["wq"])[None]
     k, v = jnp.einsum("sd,dtke->tske", h, blk["wkv"])[:, None]
     cache = {name: jnp.zeros((1, 1, 2, 128, 16)) for name in ("k", "v")}
-    attn, cache = transformer._attend_through_cache(
-        q, k, v, cache, (0, 0), cfg.score_scale)
+    assert transformer.cache_layout(cfg, 1, 128) == "head_major"
+    attn, cache = transformer._attend_cached(q, k, v, cache, (0, 0), cfg,
+                                             "attention", None)
     close(jnp.einsum("she,hed->sd", attn[0], blk["wo"]), want)
     assert cache["k"].shape == (1, 1, 2, 128, 16)
     repeated = [jnp.repeat(cache[name][0], 2, axis=1) for name in ("k", "v")]
-    close(attn, transformer._cached_attention(q, *repeated, 21,
-                                              cfg.score_scale))
+    at = jnp.arange(128)
+    close(attn, attend(q, *repeated, at[None, :] <= at[:21, None], at < 21,
+                       cfg.score_scale, head_major=True))
     # without a cache too (``forward``)
-    close(transformer._attention(q, k, v, cfg.score_scale), attn)
+    causal = at[None, :21] <= at[:21, None]
+    close(attend(q, k, v, causal, None, cfg.score_scale), attn)
 
 
 def test_attention_never_reads_a_slot_the_call_has_not_written():
@@ -280,7 +284,7 @@ def test_call_sizes_and_the_two_kinds_of_state():
     shapes = jax.eval_shape(lambda: init_kv_cache(cell, 64, 640))
     # 64 rows: dense, a position's 8 heads of 64 one row of 512 lanes
     assert shapes[5]["k"].shape == (1, 64, 640, 512)
-    # a row alone: head-major under the block's own lines
+    # a row alone: head-major, through ``attend``
     assert jax.eval_shape(lambda: init_kv_cache(cell, 1, 640)
                           )[5]["k"].shape == (1, 1, 8, 640, 64)
     assert shapes[0]["state"].shape == (64, 64, 64, 128)

@@ -777,9 +777,9 @@ def test_which_cached_call_attends_a_dense_cache(case):
     ids=["grouped", "looped_rotary"])
 def test_eight_rows_together_serve_what_each_row_serves_alone(named):
     """Rows do not mix: 8 rows served together (a dense cache, prefill in
-    two chunks over a view of it, every step through the kernel) give the
-    tokens of the same rows served one at a time (a head-major cache under
-    the block's own lines)."""
+    two chunks through ``attend`` over it, every step through the kernel)
+    give the tokens of the same rows served one at a time (a head-major
+    cache through ``attend``)."""
     from faabric_tpu.models.generate import call_sizes, generate
 
     cfg = _grouped(**named)

@@ -9,7 +9,8 @@ import jax
 import jax.numpy as jnp
 
 from faabric_tpu.ops import flash_attention, rms_norm
-from faabric_tpu.ops.flash_attention import _reference_attention
+from faabric_tpu.ops.attention import attend
+from faabric_tpu.ops.flash_attention import _mask
 from faabric_tpu.ops.rms_norm import _reference_rms_norm
 from faabric_tpu.parallel import (
     MeshConfig,
@@ -19,10 +20,140 @@ from faabric_tpu.parallel import (
 )
 
 
+def reference(q, k, v, causal=True):
+    """The ``jnp`` lines the flash kernels fall back to."""
+    return attend(q, k, v, _mask(q, k, causal))
+
+
 def qkv(b=2, s=256, h=4, d=32, seed=0):
     rng = np.random.RandomState(seed)
     return tuple(jnp.asarray(rng.randn(b, s, h, d), dtype=jnp.float32)
                  for _ in range(3))
+
+
+# ---------------------------------------------------------------------------
+# The one jnp attention
+# ---------------------------------------------------------------------------
+
+def _numpy_attention(q, keys, values, mask, written, scale, pairs):
+    """Float64, one head at a time, keys and values (B, K, KV, D)."""
+    q, keys, values = (np.asarray(x, np.float64) for x in (q, keys, values))
+    b, s, h, d = q.shape
+    reach, kv = keys.shape[1:3]
+    if written is not None:
+        values = np.where(np.asarray(written)[None, :, None, None], values, 0)
+    mask = np.ones((s, reach), bool) if mask is None else np.asarray(mask)
+    heads = []
+    for head in range(h):
+        if pairs:
+            group, c = head // (2 * (h // kv)), head % 2
+            key = keys[:, :, 2 * group + c]
+            value = np.concatenate([values[:, :, 2 * group],
+                                    values[:, :, 2 * group + 1]], axis=-1)
+        else:
+            key, value = keys[:, :, head // (h // kv)], \
+                values[:, :, head // (h // kv)]
+        with np.errstate(invalid="ignore"):
+            scores = np.einsum("bqd,bkd->bqk", q[:, :, head], key) * scale
+        scores = np.where(mask, scores, -np.inf)
+        probs = np.exp(scores - scores.max(-1, keepdims=True))
+        probs /= probs.sum(-1, keepdims=True)
+        heads.append(np.einsum("bqk,bke->bqe", probs, value))
+    out = np.stack(heads, axis=2)
+    return out.reshape(b, s, kv // 2, h // kv, 2, 2 * d) if pairs else out
+
+
+def _attention_case(case):
+    """(q, keys, values, mask, written, scale, pairs, head_major) as the
+    callers of ``attend`` build them; keys and values position-major."""
+    rng = np.random.RandomState(len(case))
+    b, s, h, kv, d = 2, 5, 4, 4, 8
+
+    def normal(*shape):
+        return jnp.asarray(rng.randn(*shape), jnp.float32)
+
+    def causal_over(slots, length):
+        # the cached path's: the queries the last of ``length`` written
+        q_pos = (length - s) + jnp.arange(s)
+        return q_pos[:, None] >= jnp.arange(slots)[None, :]
+
+    def unwritten(x, written):
+        return jnp.where(written[None, :, None, None], x, jnp.nan)
+
+    if case == "whole_sequence_causal":
+        at = jnp.arange(s)
+        return (normal(b, s, h, d), normal(b, s, kv, d), normal(b, s, kv, d),
+                at[None, :] <= at[:, None], None, 0.3, False, False)
+    if case == "query_offset":
+        # the pipeline's: a shard's queries are rows 6..10 of 16
+        k_pos = jnp.arange(16)
+        return (normal(b, s, h, d), normal(b, 16, kv, d),
+                normal(b, 16, kv, d),
+                k_pos[None, :] <= 6 + jnp.arange(s)[:, None], None, None,
+                False, False)
+    if case in ("head_major_cache", "dense_cache"):
+        written = jnp.arange(12) < 9
+        keys, values = (unwritten(normal(b, 12, kv, d), written)
+                        for _ in range(2))
+        return (normal(b, s, h, d), keys, values, causal_over(12, 9),
+                written, 0.25, False, case == "head_major_cache")
+    if case == "grouped_heads":
+        written = jnp.arange(12) < 9
+        keys, values = (unwritten(normal(b, 12, 2, d), written)
+                        for _ in range(2))
+        return (normal(b, s, 8, d), keys, values, causal_over(12, 9),
+                written, 0.25, False, False)
+    if case == "window_band_over_a_ring":
+        # a ring of 6 left by positions 0..3 (slots 4, 5 never written),
+        # then 5 positions from 4 on under a window of 3
+        ring, window, start = 6, 3, 4
+        held = jnp.array([0, 1, 2, 3, -5, -4])
+        q_pos = start + jnp.arange(s)
+        k_pos = jnp.concatenate([held, q_pos])
+        written = k_pos >= 0
+        keys, values = (unwritten(normal(b, ring + s, kv, d), written)
+                        for _ in range(2))
+        mask = (k_pos[None, :] <= q_pos[:, None]) \
+            & (k_pos[None, :] > q_pos[:, None] - window) & written[None, :]
+        return (normal(b, s, h, d), keys, values, mask, written, 0.25,
+                False, False)
+    assert case in ("differential_pairs", "rows_in_blocks")
+    at = jnp.arange(s)
+    return (normal(4, s, 8, d), normal(4, s, 4, d), normal(4, s, 4, d),
+            at[None, :] <= at[:, None], None, 0.2,
+            case == "differential_pairs", False)
+
+
+@pytest.mark.parametrize("case", [
+    "whole_sequence_causal", "query_offset", "head_major_cache",
+    "dense_cache", "grouped_heads", "window_band_over_a_ring",
+    "differential_pairs", "rows_in_blocks"])
+def test_attend_is_a_float64_attention(case, monkeypatch):
+    """``ops/attention.attend`` in every form its callers take against a
+    float64 attention written out here: NaN in slots never written never
+    reaches the output, and rows whose scores pass ``SCORE_BYTES`` go in
+    blocks with the same result."""
+    from faabric_tpu.ops import attention
+
+    q, keys, values, mask, written, scale, pairs, head_major = \
+        _attention_case(case)
+    if case == "rows_in_blocks":
+        # a row's scores alone: each row a block of its own
+        monkeypatch.setattr(attention, "SCORE_BYTES",
+                            4 * q.shape[2] * q.shape[1] * keys.shape[1])
+    laid = [x.transpose(0, 2, 1, 3) for x in (keys, values)] \
+        if head_major else (keys, values)
+    got = np.asarray(attend(q, *laid, mask, written, scale, pairs,
+                            head_major))
+    want = _numpy_attention(q, keys, values, mask, written,
+                            scale or 1 / np.sqrt(q.shape[-1]), pairs)
+    assert got.shape == want.shape
+    assert not np.isnan(got).any()
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
+    blocked = "scan" in str(jax.make_jaxpr(
+        lambda *x: attend(*x, mask, written, scale, pairs, head_major))(
+            q, *laid))
+    assert blocked == (case == "rows_in_blocks")
 
 
 # ---------------------------------------------------------------------------
@@ -32,14 +163,14 @@ def qkv(b=2, s=256, h=4, d=32, seed=0):
 def test_flash_attention_matches_reference_causal():
     q, k, v = qkv()
     out = flash_attention(q, k, v)
-    ref = _reference_attention(q, k, v, causal=True)
+    ref = reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
 def test_flash_attention_non_causal():
     q, k, v = qkv(s=128)
     out = flash_attention(q, k, v, False)
-    ref = _reference_attention(q, k, v, causal=False)
+    ref = reference(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -53,7 +184,7 @@ def test_flash_attention_gradients(s):
         return jnp.sum(flash_attention(q, k, v) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(_reference_attention(q, k, v) ** 2)
+        return jnp.sum(reference(q, k, v) ** 2)
 
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -67,7 +198,7 @@ def test_flash_attention_gradients_non_causal():
     gf = jax.grad(lambda q, k, v: jnp.sum(
         flash_attention(q, k, v, False) ** 2), argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(lambda q, k, v: jnp.sum(
-        _reference_attention(q, k, v, False) ** 2), argnums=(0, 1, 2))(q, k, v)
+        reference(q, k, v, False) ** 2), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=1e-3)
@@ -82,7 +213,7 @@ def test_flash_attention_gradients_cross_length():
     gf = jax.grad(lambda q, k, v: jnp.sum(
         flash_attention(q, k, v) ** 2), argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(lambda q, k, v: jnp.sum(
-        _reference_attention(q, k, v) ** 2), argnums=(0, 1, 2))(q, k, v)
+        reference(q, k, v) ** 2), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=1e-3)
@@ -94,7 +225,7 @@ def test_flash_attention_gradients_ragged_fallback():
     gf = jax.grad(lambda q, k, v: jnp.sum(
         flash_attention(q, k, v) ** 2), argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(lambda q, k, v: jnp.sum(
-        _reference_attention(q, k, v) ** 2), argnums=(0, 1, 2))(q, k, v)
+        reference(q, k, v) ** 2), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
 
@@ -102,7 +233,7 @@ def test_flash_attention_gradients_ragged_fallback():
 def test_flash_attention_ragged_shape_falls_back():
     q, k, v = qkv(s=100)  # not divisible by any block size
     out = flash_attention(q, k, v)
-    ref = _reference_attention(q, k, v)
+    ref = reference(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -149,7 +280,7 @@ def test_flash_attention_tilings_match_reference(tiling):
         return flash_attention(q, k, v, causal, block_q, block_k)
 
     def ref(q, k, v):
-        return _reference_attention(q, k, v, causal)
+        return reference(q, k, v, causal)
 
     f32 = dtype == jnp.float32
     np.testing.assert_allclose(
@@ -383,11 +514,10 @@ CACHED_ATTENTION_CALLS = {
 @pytest.mark.parametrize("where", ["first", "mid_reach", "last_slot"])
 @pytest.mark.parametrize("call", sorted(CACHED_ATTENTION_CALLS))
 def test_cached_attention_matches_the_blocks_own_lines(call, where):
-    """The kernel, interpreted, over a dense cache against
-    ``_cached_attention``'s ``jnp`` lines over the same values head-major:
-    a stated scale, pass 1 of 2, and NaN planted in every slot of K and V
-    the call has not written, which never reaches the output."""
-    from faabric_tpu.models.transformer import _cached_attention
+    """The kernel, interpreted, over a dense cache against ``attend``
+    over the same values head-major: a stated scale, pass 1 of 2, and NaN
+    planted in every slot of K and V the call has not written, which never
+    reaches the output."""
     from faabric_tpu.ops.cached_attention import cached_attention, plan
 
     rows, heads, kv, slots, d, dtype, block_rows = CACHED_ATTENTION_CALLS[call]
@@ -406,7 +536,9 @@ def test_cached_attention_matches_the_blocks_own_lines(call, where):
                            t=jnp.int32(1), block_rows=block_rows)
     head_major = [c[1].reshape(rows, slots, kv, d).transpose(0, 2, 1, 3)
                   for c in dense]
-    want = _cached_attention(q[:, None], *head_major, length, scale)[:, 0]
+    written = jnp.arange(slots) < length
+    want = attend(q[:, None], *head_major, written[None, :], written, scale,
+                  head_major=True)[:, 0]
     assert got.shape == want.shape == (rows, heads, d)
     assert got.dtype == want.dtype
     assert not np.isnan(np.asarray(got, np.float32)).any()
@@ -615,7 +747,7 @@ def test_ring_attention_matches_reference(sp):
     q, k, v = qkv(b=2, s=512, h=4, d=32)
     qs, ks, vs = (shard_sequence(x, mesh) for x in (q, k, v))
     out = ring_attention(qs, ks, vs, mesh)
-    ref = _reference_attention(q, k, v, causal=True)
+    ref = reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -624,7 +756,7 @@ def test_ring_attention_non_causal():
     q, k, v = qkv(b=1, s=256, h=2, d=16, seed=3)
     qs, ks, vs = (shard_sequence(x, mesh) for x in (q, k, v))
     out = ring_attention(qs, ks, vs, mesh, causal=False)
-    ref = _reference_attention(q, k, v, causal=False)
+    ref = reference(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -632,7 +764,7 @@ def test_ring_attention_single_device_axis():
     mesh = build_mesh(jax.devices()[:8], MeshConfig(dp=8, sp=1))
     q, k, v = qkv(b=1, s=64, h=2, d=16)
     out = ring_attention(q, k, v, mesh)
-    ref = _reference_attention(q, k, v, causal=True)
+    ref = reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -659,7 +791,7 @@ def test_flash_cross_length_causal():
     k = jnp.asarray(rng.randn(1, 256, 2, 32), dtype=jnp.float32)
     v = jnp.asarray(rng.randn(1, 256, 2, 32), dtype=jnp.float32)
     out = flash_attention(q, k, v)
-    ref = _reference_attention(q, k, v, causal=True)
+    ref = reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -675,7 +807,7 @@ def test_ring_attention_gradients(sp):
         return jnp.sum(ring_attention(q, k, v, mesh) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(_reference_attention(q, k, v, causal=True) ** 2)
+        return jnp.sum(reference(q, k, v, causal=True) ** 2)
 
     qs, ks, vs = (shard_sequence(x, mesh) for x in (q, k, v))
     gf = jax.grad(loss_ring, argnums=(0, 1, 2))(qs, ks, vs)
@@ -805,7 +937,7 @@ def test_flash_with_lse_matches_logsumexp():
 
     q, k, v = qkv(b=2, s=256, h=2, d=16)
     out, lse = flash_attention_with_lse(q, k, v)
-    ref = _reference_attention(q, k, v, causal=True)
+    ref = reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
     scale = 1.0 / np.sqrt(16)
@@ -835,7 +967,7 @@ def test_flash_with_lse_gradients_including_lse_cotangent():
         return jnp.sum(out ** 2) + 0.3 * jnp.sum(jnp.sin(lse))
 
     def loss_ref(q, k, v):
-        out = _reference_attention(q, k, v, causal=True)
+        out = reference(q, k, v, causal=True)
         lse = _reference_lse(q, k, True)
         return jnp.sum(out ** 2) + 0.3 * jnp.sum(jnp.sin(lse))
 
@@ -875,7 +1007,7 @@ def test_flash_attention_bf16_forward_and_gradients():
     q, k, v = (jnp.asarray(rng.randn(1, 256, 2, 16), jnp.bfloat16)
                for _ in range(3))
     out = flash_attention(q, k, v)
-    ref = _reference_attention(q, k, v, causal=True)
+    ref = reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=3e-2)
 
@@ -883,7 +1015,7 @@ def test_flash_attention_bf16_forward_and_gradients():
         flash_attention(q, k, v).astype(jnp.float32) ** 2),
         argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(lambda q, k, v: jnp.sum(
-        _reference_attention(q, k, v).astype(jnp.float32) ** 2),
+        reference(q, k, v).astype(jnp.float32) ** 2),
         argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a, np.float32),

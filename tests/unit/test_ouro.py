@@ -176,12 +176,8 @@ def test_one_pass_of_the_gelu_kinds_is_the_first_block_bit_for_bit():
     primitives: the one block with the default kinds computes the same
     bits, without a cache."""
     from faabric_tpu.models import init_params
-    from faabric_tpu.models.transformer import (
-        _attention,
-        _block,
-        _rms_norm,
-        _rope,
-    )
+    from faabric_tpu.models.transformer import _block, _rms_norm, _rope
+    from faabric_tpu.ops.attention import attend
 
     cfg = ModelConfig(vocab_size=128, d_model=32, n_layers=1, n_heads=4,
                       d_ff=64, max_seq=32, compute_dtype=jnp.float32,
@@ -197,7 +193,8 @@ def test_one_pass_of_the_gelu_kinds_is_the_first_block_bit_for_bit():
         qkv = jnp.einsum("bsd,dthe->tbshe", h, blk["wqkv"])
         q = _rope(qkv[0], positions, cfg.rope_theta)
         k = _rope(qkv[1], positions, cfg.rope_theta)
-        x = x + jnp.einsum("bshe,hed->bsd", _attention(q, k, qkv[2]),
+        causal = jnp.tril(jnp.ones((16, 16), dtype=bool))
+        x = x + jnp.einsum("bshe,hed->bsd", attend(q, k, qkv[2], causal),
                            blk["wo"])
         h = _rms_norm(x, blk["ln2"])
         return x + jax.nn.gelu(h @ blk["w1"]) @ blk["w2"]

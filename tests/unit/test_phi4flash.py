@@ -46,6 +46,7 @@ from faabric_tpu.models.generate import (
     init_kv_cache,
 )
 from faabric_tpu.ops import cached_attention
+from faabric_tpu.ops.attention import attend
 from tests.unit.test_models import _walk_jaxpr
 
 RTOL = 1e-4
@@ -314,8 +315,8 @@ def test_the_kernel_is_the_jnp_lines(form):
     """``cached_attention`` in the differential form (a head keeps its
     pair's two value heads), over a ring whose written slots are in no
     order, over a cache it is lent (read, another pass of two, and the
-    cache comes back unchanged), against ``transformer._attend`` over the
-    same cache; and the plain form untouched beside it."""
+    cache comes back unchanged), against ``attend`` over the same cache;
+    and the plain form untouched beside it."""
     rows, heads, kv, slots, d = 8, 8, 4, 24, 64
     key = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(key[0], (rows, heads, d))
@@ -330,7 +331,7 @@ def test_the_kernel_is_the_jnp_lines(form):
                                             0.125, jnp.int32(t),
                                             paired=paired)
     at = jnp.arange(slots)
-    want = transformer._attend(
+    want = attend(
         q[:, None], k[t].reshape(rows, slots, kv, d),
         v[t].reshape(rows, slots, kv, d), (at < length)[None], at < length,
         0.125, paired)
@@ -423,7 +424,9 @@ def test_the_granite_cell_builds_what_it_built():
         "cache_slots": 640, "ffn_streamed_bytes": 4026531840,
         "ffn_streamed_layers": 40, "scan_chunks": 2, "ssm_layers": 36,
         "state_bytes": 2476081152, "ut_passes": 129}
-    assert not transformer.lays_dense(cfg) and cfg.stateless_from == 40
+    assert cfg.stateless_from == 40
+    assert [transformer.cache_layout(cfg, rows, 640) for rows in (64, 2)] \
+        == ["dense", "head_major"]
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, 64, 640))
     assert cache[5]["k"].shape == (1, 64, 640, 512)
     assert cache[4]["state"].shape == (64, 64, 64, 128)
