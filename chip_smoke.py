@@ -806,10 +806,11 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
             rows=rows, prompt=s_p,
             prefill_rel_err=_rel_err(got[:, :1], want[:, :1]),
             cached_steps_rel_err=_rel_err(got[:, 1:], want[:, 1:]),
-            **{name: counted[name] for name in (
+            **{name: counted.get(name, 0) for name in (
                 "window_slots", "window_cache_bytes", "shared_cache_bytes",
                 "state_bytes", "attention_streamed_layers",
-                "scan_streamed_layers", "prefill_skipped_layers")})
+                "scan_streamed_layers", "prefill_skipped_layers",
+                "prefill_attention_streamed_layers")})
         _require(np.isfinite(got).all(), "a logit is not finite")
         _require([None if c is None else sorted(c) for c in cache]
                  == [["conv", "state"], ["k", "v"]] * 3 + [None, None],
@@ -819,6 +820,13 @@ def _register_guests(model: dict, run: dict, on_chip: bool,
                  f"{counted['attention_streamed_layers']} attentions stream")
         _require(counted["scan_streamed_layers"] == 3,
                  f"{counted['scan_streamed_layers']} scans keep S on chip")
+        # both chunks of the two rings (the second wraps them) and of the
+        # full attention keep their scores on the chip, at the cell's
+        # widths; the toy's chunks are no whole tiles
+        _require(out["prefill_attention_streamed_layers"]
+                 == (3 if on_chip else 0),
+                 f"{out['prefill_attention_streamed_layers']} prefill "
+                 "attentions keep their scores on chip")
         for name in ("prefill_rel_err", "cached_steps_rel_err"):
             _require(out[name] < TOL_SHARED_STATE_LOGITS,
                      f"{name} {out[name]}")

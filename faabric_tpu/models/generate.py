@@ -12,8 +12,10 @@ differential pairs, the cache lies dense instead, ``(passes, batch,
 slots, heads · head_dim)``, a position's keys one row of whole lanes
 whatever the head's width (``transformer.cache_layout`` decides), and a
 step of many rows attends it through one kernel a layer
-(ops/cached_attention.py); any other call attends the cache as it lies
-(``ops/attention.py``). It is filled with
+(ops/cached_attention.py), a prompt's chunk through another that keeps
+its scores on the chip (ops/prefill_attention.py) where
+``transformer.streams_prefill_attention`` finds its shape; any other call
+attends the cache as it lies (``ops/attention.py``). It is filled with
 ``lax.dynamic_update_slice``; attention masks by position, so prefill
 and every decode step compile once each. The whole greedy loop is one
 ``lax.scan`` under jit — no host round-trips between tokens, which is
@@ -82,6 +84,7 @@ from faabric_tpu.models.transformer import (
     streams_attention,
     streams_feed_forward,
     streams_latent_prefill,
+    streams_prefill_attention,
 )
 
 
@@ -185,6 +188,14 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     kernel that keeps S on the chip (ops/selective_scan.py) and
     ``scan_streamed_bytes`` what they stream a call's prefill, for a call
     without a mesh (``ssm.streams_scan``); 0 where none does.
+    Where a prefill chunk attends a dense cache through the kernel that
+    keeps its scores on the chip (ops/prefill_attention.py, by
+    ``transformer.streams_prefill_attention``), and only there,
+    ``prefill_attention_streamed_layers`` is the attending layers whose
+    chunks do, ``prefill_attention_streamed_chunks`` the chunks a layer
+    that do (the most over those layers) and
+    ``prefill_attention_streamed_bytes`` what the kernel streams for
+    them, all layers and passes (``prefill_attention.plan``).
     ``generate`` sizes its cache from this; a server reports it beside
     its answers."""
     slots = _cache_slots(cfg, prompt_len + n_tokens)
@@ -225,6 +236,24 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
             * plan["streamed_bytes"]
             for kind, plan in attended.items() if plan),
     }
+    # the attending layers that prefill runs at more than one position,
+    # by kind and chunk: the chunks whose scores stay on the chip
+    prefilled = cfg.mixers[:cfg.stateless_from]
+    on_chip = {kind: [plan for plan in (streams_prefill_attention(
+        cfg, batch, length, _ring_slots(cfg, slots)
+        if kind == "window_attention" else slots, cfg.compute_dtype, pos,
+        cfg.sliding_window if kind == "window_attention" else 0)
+        for pos, length in _prefill_chunks(prompt_len, prefill_chunk))
+        if plan] for kind in ATTENDING if kind in prefilled}
+    on_chip = {kind: plans for kind, plans in on_chip.items() if plans}
+    if on_chip:
+        sizes.update(
+            prefill_attention_streamed_layers=sum(
+                prefilled.count(kind) for kind in on_chip),
+            prefill_attention_streamed_chunks=max(map(len, on_chip.values())),
+            prefill_attention_streamed_bytes=cfg.n_passes * sum(
+                prefilled.count(kind) * plan["streamed_bytes"]
+                for kind, plans in on_chip.items() for plan in plans))
     if shortcut or cfg.ffn_types:
         sizes.update(experts_held=cfg.experts_held[1],
                      router_width=cfg.routed_experts + cfg.zero_experts)

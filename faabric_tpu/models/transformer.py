@@ -726,6 +726,33 @@ def streams_attention(cfg: ModelConfig, rows: int, positions: int,
                                  paired=cfg.differential)
 
 
+def streams_prefill_attention(cfg: ModelConfig, rows: int, positions: int,
+                              slots: int, cache_dtype, start,
+                              window: int = 0,
+                              mesh: Optional[Mesh] = None):
+    """How a cached call of ``rows`` rows × ``positions`` from a static
+    ``start`` attends a dense cache of ``slots`` (a ring under ``window``)
+    through the one kernel that keeps its scores on the chip
+    (ops/prefill_attention.py: ``prefill_attention.plan``), or None where
+    it attends through :func:`attend`: more than one position a row,
+    attention over heads (in differential pairs or not) on one chip, the
+    cache dense (:func:`cache_layout`) and in the compute type, the
+    plan's lanes and lengths. :func:`_attend_cached` takes the kernel by
+    it and ``call_sizes`` counts by it. Shapes and types alone decide;
+    nothing names a model."""
+    from faabric_tpu.ops import prefill_attention
+
+    if (positions == 1 or not isinstance(start, int) or mesh is not None
+            or cfg.attention != "heads"
+            or jnp.dtype(cache_dtype) != jnp.dtype(cfg.compute_dtype)
+            or cache_layout(cfg, rows, slots, mesh) != "dense"):
+        return None
+    return prefill_attention.plan(
+        rows, cfg.n_heads, cfg.kv_heads, positions, slots, cfg.head_dim,
+        cfg.compute_dtype, paired=cfg.differential, start=start,
+        window=window)
+
+
 def cache_layout(cfg: ModelConfig, rows: int, slots: int,
                  mesh: Optional[Mesh] = None) -> str:
     """How an attention's keys and values lie in a call of ``rows`` rows
@@ -794,14 +821,26 @@ def _attend_cached(q, k, v, cache: dict, slot: tuple, cfg: ModelConfig,
     and then writes its last positions over the oldest.
 
     A step :func:`streams_attention` plans goes through the kernel
-    (ops/cached_attention.py), every kind alike; any other call through
-    :func:`attend` over the cache as it lies."""
+    (ops/cached_attention.py), every kind alike; a longer call that
+    :func:`streams_prefill_attention` plans through the prefill kernel
+    (ops/prefill_attention.py), a ring beside the tokens' own keys; any
+    other call through :func:`attend` over the cache as it lies."""
     t, start = slot
     b, s_q, h, d = q.shape
     kv, slots = cfg.kv_heads, cache["k"].shape[-2]
     dense = cache_layout(cfg, b, slots, mesh) == "dense"
     window = cfg.sliding_window if kind == "window_attention" else 0
     scale = cfg.score_scale
+    streamed = streams_prefill_attention(cfg, b, s_q, slots,
+                                         cache["k"].dtype, start, window,
+                                         mesh) is not None
+
+    def prefill_kernel(cache, *own):
+        from faabric_tpu.ops.prefill_attention import prefill_attention
+
+        return prefill_attention(q, cache["k"], cache["v"], *own, t=t,
+                                 start=start, scale=scale, window=window,
+                                 paired=cfg.differential)
 
     def write(old, new, at):
         if dense:
@@ -818,15 +857,18 @@ def _attend_cached(q, k, v, cache: dict, slot: tuple, cfg: ModelConfig,
         if not isinstance(start, int):
             raise ValueError("a window's ring takes more than one position "
                              "a row only from a static start")
-        q_pos = start + jnp.arange(s_q)
-        held = _ring_positions(slots, start)
-        k_pos = jnp.concatenate([held, q_pos])
-        mask = (k_pos[None, :] <= q_pos[:, None]) \
-            & (k_pos[None, :] > q_pos[:, None] - window)
-        out = attend(q, jnp.concatenate([mine("k"), k], axis=1),
-                     jnp.concatenate([mine("v"), v], axis=1),
-                     mask & (k_pos >= 0)[None, :], k_pos >= 0, scale,
-                     cfg.differential)
+        if streamed:
+            out = prefill_kernel(cache, k, v)
+        else:
+            q_pos = start + jnp.arange(s_q)
+            held = _ring_positions(slots, start)
+            k_pos = jnp.concatenate([held, q_pos])
+            mask = (k_pos[None, :] <= q_pos[:, None]) \
+                & (k_pos[None, :] > q_pos[:, None] - window)
+            out = attend(q, jnp.concatenate([mine("k"), k], axis=1),
+                         jnp.concatenate([mine("v"), v], axis=1),
+                         mask & (k_pos >= 0)[None, :], k_pos >= 0, scale,
+                         cfg.differential)
         # the last positions over the oldest, in at most two pieces
         first = max(0, s_q - slots)
         at = (start + first) % slots
@@ -854,6 +896,8 @@ def _attend_cached(q, k, v, cache: dict, slot: tuple, cfg: ModelConfig,
                                scale, t, paired=cfg.differential)
         return (out.reshape(b, 1, kv // 2, h // kv, 2, 2 * d)
                 if cfg.differential else out[:, None]), updated
+    if streamed:
+        return prefill_kernel(cache), updated
     k_pos = jnp.arange(slots)
     if window:
         # a ring's slots hold the last positions in no order; all of them

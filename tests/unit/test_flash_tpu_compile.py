@@ -234,6 +234,53 @@ def test_latent_attention_compiles_for_v5e(call, one_chip, as_on_tpu):
     assert f"{heads},{queries},{reach}]" not in compiled
 
 
+# (rows, heads, key/value heads, queries, slots, start, window, paired):
+# serve_phi4flash_1chip's two chunks over a ring of 512 beside the chunk's
+# own keys and over the shared cache of 768, serve_granite_1chip's two
+# over its cache of 640; 64 lanes a head
+PREFILL_CALLS = {
+    "serve_phi4flash_1chip_ring_first": (64, 40, 20, 256, 512, 0, 512, True),
+    "serve_phi4flash_1chip_ring_second": (64, 40, 20, 256, 512, 256, 512,
+                                          True),
+    "serve_phi4flash_1chip_full_second": (64, 40, 20, 256, 768, 256, 0,
+                                          True),
+    "serve_granite_1chip_first": (64, 32, 8, 256, 640, 0, 0, False),
+    "serve_granite_1chip_second": (64, 32, 8, 256, 640, 256, 0, False),
+}
+
+
+@pytest.mark.parametrize("call", sorted(PREFILL_CALLS))
+def test_prefill_attention_compiles_for_v5e(call, one_chip, as_on_tpu):
+    """The blocks :func:`prefill_attention.plan` picks are blocks Mosaic
+    can cut (a key/value tile of a position's heads, the query heads that
+    read it side by side), the table of steps goes to SMEM, the caches
+    are held to HBM, within the VMEM a kernel may have: one kernel, and
+    no array with a query and a key axis beside it."""
+    from faabric_tpu.ops.prefill_attention import plan, prefill_attention
+
+    rows, heads, kv, queries, slots, start, window, paired = \
+        PREFILL_CALLS[call]
+    d = 64
+    assert plan(rows, heads, kv, queries, slots, d, jnp.bfloat16, paired,
+                start, window) is not None
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    own = (shaped(rows, queries, kv, d),) * 2 if window else ()
+    compiled = jax.jit(
+        lambda q, k, v, *own: prefill_attention(
+            q, k, v, *own, start=start, scale=1 / 64, window=window,
+            paired=paired)).lower(
+        shaped(rows, queries, heads, d), shaped(1, rows, slots, kv * d),
+        shaped(1, rows, slots, kv * d), *own).compile().as_text()
+    assert compiled.count("tpu_custom_call") == 1
+    assert "prefill_attention" in compiled
+    assert "input_memory_space_colors" in compiled
+    assert f",{queries},{slots}]" not in compiled
+    assert f",{queries},{slots + queries}]" not in compiled
+
+
 # (tokens, lanes of a row, tile): a sub-chunk of serve_axk1_1chip's
 # prefill (2,731 tokens of 7,168 lanes) and one of serve_longcat_1chip's
 # (2,048 of 6,144), in tiles of 128 rows; a row narrower than a lane tile

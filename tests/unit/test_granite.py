@@ -280,7 +280,11 @@ def test_call_sizes_and_the_two_kinds_of_state():
         "ffn_streamed_bytes": 40 * 3 * 2048 * 8192 * 2,
         # and the four attentions stream their dense keys and values
         "attention_streamed_layers": 4,
-        "attention_streamed_bytes": 4 * 2 * 64 * 640 * 512 * 2}
+        "attention_streamed_bytes": 4 * 2 * 64 * 640 * 512 * 2,
+        # both prefill chunks of the four keep their scores on the chip
+        "prefill_attention_streamed_layers": 4,
+        "prefill_attention_streamed_chunks": 2,
+        "prefill_attention_streamed_bytes": 4 * (184549376 + 201326592)}
     shapes = jax.eval_shape(lambda: init_kv_cache(cell, 64, 640))
     # 64 rows: dense, a position's 8 heads of 64 one row of 512 lanes
     assert shapes[5]["k"].shape == (1, 64, 640, 512)
@@ -311,6 +315,44 @@ def test_generate_is_one_loop_and_serves_the_references_best():
     full = np.concatenate([np.asarray(prompt), served[:, :-1]], axis=1)
     want = np.asarray(ref.logits_of_rows(params, jnp.asarray(full), sz))
     np.testing.assert_array_equal(served, want[:, 20:].argmax(-1))
+
+
+def test_generate_through_the_prefill_kernel_serves_the_lines_tokens(
+        monkeypatch):
+    """Heads of the cell's 64 lanes (8 query heads on 2 key/value heads,
+    two a lane tile), 8 rows (a dense cache), a prompt of 256 in two
+    chunks of 128: both chunks' attention goes through the kernel, the
+    program holds one loop (the decode scan, which the readers find as
+    the trace's one ``while``), and the tokens are those of the same call
+    through ``attend``."""
+    sz = dict(sizes(), d_model=512, n_heads=8, n_kv_heads=2, head_dim=64)
+    cfg, params = config(sz), weights(sz)
+    prompt = jnp.asarray(ids(8, 256, index=9))
+    sized = call_sizes(cfg, 8, 256, 4, 128)
+    assert (sized["prefill_attention_streamed_layers"],
+            sized["prefill_attention_streamed_chunks"]) == (1, 2)
+
+    def traced():
+        jaxpr = jax.make_jaxpr(lambda p, t: generate(
+            p, t, cfg, 4, prefill_chunk=128))(params, prompt)
+        eqns = [e for e, _ in _walk_jaxpr(jaxpr.jaxpr)]
+        return ([e.params["name"] for e in eqns
+                 if e.primitive.name == "pallas_call"
+                 and "prefill" in e.params["name"]],
+                [e.primitive.name for e in eqns
+                 if e.primitive.name in ("scan", "while")])
+
+    assert traced() == (["prefill_attention"] * 2, ["scan"])
+    through_kernel = generate(params, prompt, cfg, 4, prefill_chunk=128)
+    monkeypatch.setattr(transformer, "streams_prefill_attention",
+                        lambda *a, **k: None)
+    jax.clear_caches()
+    assert traced()[0] == []
+    lines = generate(params, prompt, cfg, 4, prefill_chunk=128)
+    jax.clear_caches()
+    assert through_kernel.shape == (8, 4)
+    np.testing.assert_array_equal(np.asarray(through_kernel),
+                                  np.asarray(lines))
 
 
 def test_the_planted_faults_are_seen_only_after_the_hand_over():

@@ -4,8 +4,9 @@ full differential attention, gated memory units, cross attention over one
 shared cache, LayerNorm with bias, biased projections, a tied head) at a
 small size on the CPU, float32 parameters from a seed: the program
 (``models/ssm.py``, ``models/transformer.py``, ``models/generate.py``,
-``ops/cached_attention.py``) against the plain reference
-(``benchmarks/reference/phi4flash.py``), which shares no code with it.
+``ops/cached_attention.py``, ``ops/prefill_attention.py``) against the
+plain reference (``benchmarks/reference/phi4flash.py``), which shares no
+code with it.
 
 The toy (``tests/bench/data/configs/toy_phi4flash.json``) has 12 layers:
 four Mamba-1 (the last the memory's source), three windowed attentions of
@@ -255,6 +256,97 @@ def test_call_sizes_counts_the_layers_whose_scan_streams():
     assert "scan_streamed_layers" not in other
 
 
+# the cells whose prefill never attends a dense cache through the kernel
+# (head-major caches at one row; latent attention): configuration, the
+# module that makes its ModelConfig, a call of the cell's shapes
+OTHER_CELLS = {
+    "serve_chat_1chip": ("pythia-1.4b", "program", (1, 512, 64)),
+    "serve_ouro_1chip": ("ouro-2.6b", "program_ouro", (1, 1024, 64)),
+    "serve_longcat_1chip": ("longcat-flash-omni", "program_longcat",
+                            (64, 128, 128)),
+    "serve_axk1_1chip": ("a.x-k1", "program_axk1", (8, 8192, 64, 1024)),
+}
+
+
+def test_call_sizes_counts_the_layers_whose_prefill_attention_streams():
+    """``prefill_attention_streamed_layers``, ``_chunks`` and ``_bytes``
+    at the cell's shapes: the eight window layers and the full one, both
+    chunks, the bytes from ``prefill_attention.plan``; the keys stand
+    nowhere else: not at a cached step's one position a row, not at the
+    four cells whose caches are head-major or latent. The decode kernel's
+    own counters keep their meaning."""
+    import importlib
+
+    from faabric_tpu.ops import prefill_attention
+
+    cfg = program_phi4flash.model_config(values("published"))
+    sized = call_sizes(cfg, 64, 512, 256, 256)
+
+    def a_layer(slots, window):
+        return sum(prefill_attention.plan(
+            64, 40, 20, 256, slots, 64, jnp.bfloat16, True, start,
+            window)["streamed_bytes"] for start in (0, 256))
+
+    assert (sized["prefill_attention_streamed_layers"],
+            sized["prefill_attention_streamed_chunks"]) == (9, 2)
+    assert sized["prefill_attention_streamed_bytes"] \
+        == 8 * a_layer(512, 512) + a_layer(768, 0) == 6_794_772_480
+    assert sized["attention_streamed_layers"] == 16
+    assert "prefill_attention_streamed_layers" not in call_sizes(
+        cfg, 64, 1, 256)
+    for cell, (name, module, shape) in OTHER_CELLS.items():
+        with open(os.path.join(REPO, "benchmarks", "configs",
+                               name + ".json")) as f:
+            other = call_sizes(importlib.import_module(
+                f"benchmarks.{module}").model_config(json.load(f)), *shape)
+        assert not any(key.startswith("prefill_attention")
+                       for key in other), cell
+
+
+def test_generate_through_the_prefill_kernel_serves_the_lines_tokens(
+        monkeypatch):
+    """The toy with a window of 128 and a prompt of 256 in two chunks of
+    128, so that the kernel takes every attending layer of the
+    self-decoder in both chunks (three rings, the first empty, then half
+    written, and the shared cache): no array with a query and a key axis
+    is left in the prefill, and the tokens are those of the same call
+    through ``attend``."""
+    config = dict(values(), sliding_window=128, max_position_embeddings=512)
+    sizes = weights_phi4flash.sizes_of(config)
+    cfg = dataclasses.replace(program_phi4flash.model_config(config),
+                              remat=False)
+    params = weights_phi4flash.make_weights(SEED, sizes, jnp.float32)
+    toks = tokens(sizes, 2, 256)
+    sized = call_sizes(cfg, 2, 256, 4, 128)
+    assert (sized["prefill_attention_streamed_layers"],
+            sized["prefill_attention_streamed_chunks"]) == (4, 2)
+
+    def traced(cfg):
+        jaxpr = jax.make_jaxpr(lambda p: generate(
+            params, p, cfg, 4, prefill_chunk=128))(toks)
+        # a pair's scores (rows, groups, pairs, 2, 128 queries, keys):
+        # over a ring and the chunk, or over the shared cache
+        return _kernels(jaxpr).count("prefill_attention"), sum(
+            len(v.aval.shape) == 6
+            and v.aval.shape[-2:] in ((128, 256), (128, 384))
+            for e, _ in _walk_jaxpr(jaxpr.jaxpr)
+            if e.primitive.name != "pallas_call"
+            for v in e.outvars if hasattr(v.aval, "shape"))
+
+    assert traced(cfg) == (4 * 2, 0)
+    through_kernel = generate(params, toks, cfg, 4, prefill_chunk=128)
+    monkeypatch.setattr(transformer, "streams_prefill_attention",
+                        lambda *a, **k: None)
+    jax.clear_caches()
+    kernels, scores = traced(cfg)
+    assert kernels == 0 and scores > 0
+    lines = generate(params, toks, cfg, 4, prefill_chunk=128)
+    jax.clear_caches()
+    assert through_kernel.shape == (2, 4)
+    np.testing.assert_array_equal(np.asarray(through_kernel),
+                                  np.asarray(lines))
+
+
 def test_a_ring_takes_a_chunk_only_from_a_static_start(toy):
     """A chunk's place in the ring decides how it is cut in two: prefill's
     starts are static, and a traced one is refused, not guessed at."""
@@ -423,7 +515,10 @@ def test_the_granite_cell_builds_what_it_built():
         "attention_streamed_layers": 4, "cache_bytes": 335544320,
         "cache_slots": 640, "ffn_streamed_bytes": 4026531840,
         "ffn_streamed_layers": 40, "scan_chunks": 2, "ssm_layers": 36,
-        "state_bytes": 2476081152, "ut_passes": 129}
+        "state_bytes": 2476081152, "ut_passes": 129,
+        "prefill_attention_streamed_bytes": 1543503872,
+        "prefill_attention_streamed_chunks": 2,
+        "prefill_attention_streamed_layers": 4}
     assert cfg.stateless_from == 40
     assert [transformer.cache_layout(cfg, rows, 640) for rows in (64, 2)] \
         == ["dense", "head_major"]
