@@ -67,7 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from faabric_tpu.models import scopes, ssm
-from faabric_tpu.models.moe import COUNTERS
+from faabric_tpu.models.moe import COUNTERS, _row_tile, prefill_plan
 from faabric_tpu.models.transformer import (
     ATTENDING,
     ModelConfig,
@@ -146,8 +146,10 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
     the attention's kind keeps a position, every attention of every
     attention layer, every pass), ``ut_passes`` of the stack (prefill and
     each decode step pass it ``cfg.n_passes`` times); where the layers
-    have an expert layer also ``experts_held`` here and the
-    ``router_width``; where the configuration names its layers'
+    have an expert layer also ``experts_held`` here, the
+    ``router_width`` and ``prefill_expert_tile``, the rows of a tile of
+    the held experts' grouped product in the prompt's first chunk
+    (``moe.prefill_plan``); where the configuration names its layers'
     feed-forwards (``ffn_types``) also ``shared_experts``,
     ``dense_layers`` and ``expert_layers``, ``prefill_chunks``,
     ``score_blocks`` (the blocks of float32 scores that one layer's
@@ -255,8 +257,13 @@ def call_sizes(cfg: ModelConfig, batch: int, prompt_len: int,
                 prefilled.count(kind) * plan["streamed_bytes"]
                 for kind, plans in on_chip.items() for plan in plans))
     if shortcut or cfg.ffn_types:
-        sizes.update(experts_held=cfg.experts_held[1],
-                     router_width=cfg.routed_experts + cfg.zero_experts)
+        width = cfg.routed_experts + cfg.zero_experts
+        (_, length), *_ = _prefill_chunks(prompt_len, prefill_chunk)
+        sizes.update(experts_held=cfg.experts_held[1], router_width=width,
+                     prefill_expert_tile=prefill_plan(
+                         batch * length, cfg.experts_per_token, width,
+                         cfg.d_model)["tile"] if length > 1
+                     else _row_tile(batch))
     if cfg.ffn_types:
         chunks = _prefill_chunks(prompt_len, prefill_chunk)
         # a chunk the kernel takes sends no score through HBM
@@ -477,13 +484,15 @@ def _generate_impl(params, prompt, cfg: ModelConfig, n_tokens: int,
     counters = {}
     if counted_in_prefill is not None:
         counters = dict(zip(COUNTERS, _counted(cfg, cache)))
-        # over the decode steps and layers, the held experts that got a
-        # token (what a step has to read of the experts' weights) and
-        # the tiles it ran (what it read of them)
+        # over the decode steps and layers, and over prefill's chunks and
+        # layers, the held experts that got a token (what a call has to
+        # read of the experts' weights) and the tiles it ran (what it read
+        # of them)
         in_prefill = dict(zip(COUNTERS, counted_in_prefill))
         for name in ("experts_hit", "tiles"):
             counters[name + "_decode"] = (counters.pop(name)
                                           - in_prefill[name])
+            counters[name + "_prefill"] = in_prefill[name]
     return toks.T, counters  # (B, n_tokens)
 
 
@@ -540,7 +549,9 @@ def generate_with_counters(params, prompt, cfg: ModelConfig, n_tokens: int,
     ``experts_hit_decode`` is the held experts that got at least one
     token and ``tiles_decode`` the row tiles the grouped products ran
     (each reads one expert's matrices; an expert nobody picked has
-    none), both summed over the decode steps and layers."""
+    none), both summed over the decode steps and layers;
+    ``experts_hit_prefill`` and ``tiles_prefill`` the same over the
+    prompt's chunks and layers."""
     if mesh is not None and (cfg.attention, cfg.layer) != ("heads", "single"):
         raise ValueError(
             f"generate under a mesh implements attention='heads' and "

@@ -213,13 +213,19 @@ def _moe_layer(x: jax.Array, blk: dict, cfg: MoEConfig,
         return out.astype(x.dtype), aux.astype(jnp.float32)
 
 
-# A grouped product takes at most this many rows (picks) at once: a longer
-# call goes through the experts in token chunks. At a cached step it bounds
+# A cached step's grouped product takes at most this many rows (picks) at
+# once: a longer step goes through the experts in token chunks. It bounds
 # the static room for every pick and the gather back from it, at d_model
-# lanes; at prefill, where the tiles add into the tokens' rows, what grows
-# with the picks is the sort and the tiles' bookkeeping, beside the float32
-# accumulator of the chunk's tokens (78 MB at A.X-K1's 2,731 × 7,168).
+# lanes.
 _MAX_ROWS = 24576
+
+# A prefill call's held experts run over all its tokens at once, in token
+# pieces only where the tokens' float32 accumulator would pass this (224
+# MiB at A.X-K1's chunk of 8,192 tokens × 7,168 lanes, 192 MiB at
+# LongCat-Flash's 8,192 × 6,144): nothing else at prefill grows with the
+# tokens at d_model lanes, and the sort and the tiles' bookkeeping take a
+# few words a pick.
+_MAX_ACCUMULATOR_BYTES = 256 << 20
 
 # What expert_layer counts, in order: picks that fell on the experts held
 # here, on zero-compute experts, on experts held elsewhere, the held
@@ -259,27 +265,44 @@ def _row_tile(tokens: int) -> int:
     return min(128, max(16, tokens // 16 // 8 * 8))
 
 
+def prefill_plan(tokens: int, k: int, width: int, d: int) -> dict:
+    """How a prefill call of ``tokens`` runs its held experts, from shapes
+    alone: in ``pieces`` of its tokens (one, but where their float32
+    accumulator of ``d`` lanes passes ``_MAX_ACCUMULATOR_BYTES``), each
+    one grouped product in tiles of ``tile`` rows, the multiple of 128 at
+    or above the rows a held expert is expected to get in a piece (its
+    tokens × ``k`` picks over the router's ``width`` outputs, the
+    zero-compute ones counted). An expert's weights are then read about
+    once a piece, for enough rows that the tile waits on the MXU and not
+    on their stream (a tile of 128 rows did at A.X-K1's widths); an
+    expert that gets more rows runs more tiles, and none is dropped."""
+    row_bytes = -(-d // add_rows.LANE) * add_rows.LANE * 4
+    pieces = -(-tokens // max(1, _MAX_ACCUMULATOR_BYTES // row_bytes))
+    per_piece = -(-tokens // pieces)
+    expected = -(-per_piece * k // width)
+    return {"pieces": pieces, "tile": -(-expected // 128) * 128}
+
+
 def _held_experts(u: jax.Array, local: jax.Array, weights: jax.Array,
-                  experts: dict, dtype, accumulate: bool) -> tuple:
+                  experts: dict, dtype, tile: int, accumulate: bool) -> tuple:
     """The held experts' part for u (T, D): ``local`` (T, K) is a pick's
     index among the experts held, or their count where it fell elsewhere.
     A grouped product: the picks sorted by expert, every expert's rows
-    padded to whole tiles (none where it got no pick), and a loop over
-    those tiles, each through its expert's three matrices (sliced in
-    place, streamed once a tile): time follows the experts that got a
-    pick here, not the static bound or the count held, and no token is
-    dropped. The way back goes by the call's shape: where ``accumulate``
-    (more than one position a row: a prefill chunk), each tile adds its
-    weighted rows into a float32 accumulator of the T tokens at their
-    tokens' rows (``ops/add_rows.py``), so nothing grows with T · K at
-    d_model lanes; else (a cached step, whose T · K rows are few) each
-    tile writes them into a static room, gathered back to the picks'
-    order and summed over a token's K. Both add the same rows, rounded to
-    ``dtype``, in float32, in another order. Returns (the part (T, D),
-    tokens an expert (held,), the tiles run)."""
+    padded to whole tiles of ``tile`` rows (none where it got no pick),
+    and a loop over those tiles, each through its expert's three matrices
+    (sliced in place, streamed once a tile): time follows the experts
+    that got a pick here, not the static bound or the count held, and no
+    token is dropped. The way back goes by the call's shape: where
+    ``accumulate`` (more than one position a row: a prefill chunk), each
+    tile adds its weighted rows into a float32 accumulator of the T
+    tokens at their tokens' rows (``ops/add_rows.py``), so nothing grows
+    with T · K at d_model lanes; else (a cached step, whose T · K rows
+    are few) each tile writes them into a static room, gathered back to
+    the picks' order and summed over a token's K. Both add the same rows,
+    rounded to ``dtype``, in float32, in another order. Returns (the part
+    (T, D), tokens an expert (held,), the tiles run)."""
     t, k = local.shape
     held, d, _ = experts["w1"].shape
-    tile = _row_tile(t)
     flat = local.reshape(-1)
     order = jnp.argsort(flat, stable=True)  # the held picks first
     sizes = jnp.sum(flat[:, None] == jnp.arange(held)[None], axis=0,
@@ -370,11 +393,17 @@ def expert_layer(u: jax.Array, router: dict, experts: dict,
         zero_weight = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1)
     with jax.named_scope(scopes.EXPERTS):
         tokens = b * s
-        chunks = -(-tokens * k // _MAX_ROWS)
-        bounds = [tokens * i // chunks for i in range(chunks + 1)]
+        # a prefill chunk (more than one position a row) by its plan; a
+        # cached step in pieces of _MAX_ROWS picks, tiles by _row_tile
+        plan = prefill_plan(tokens, k, cfg.routed_experts + cfg.zero_experts,
+                            d) if s > 1 else None
+        pieces = plan["pieces"] if plan else -(-tokens * k // _MAX_ROWS)
+        bounds = [tokens * i // pieces for i in range(pieces + 1)]
         parts, sizes, tiles = zip(*(
             _held_experts(flat[lo:hi], local[lo:hi], weights[lo:hi],
-                          experts, u.dtype, accumulate=s > 1)
+                          experts, u.dtype,
+                          plan["tile"] if plan else _row_tile(hi - lo),
+                          accumulate=s > 1)
             for lo, hi in zip(bounds, bounds[1:])))
         m = jnp.concatenate(parts) \
             + zero_weight.astype(u.dtype)[:, None] * flat

@@ -178,9 +178,14 @@ def test_prefill_then_cached_decoding_matches_the_full_forward(rows, chunk):
     assert int(cache[1]["counters"][1]) == 0  # no zero-compute expert
 
 
+PREFILL_COUNTED = ("experts_hit_prefill", "tiles_prefill")
+
+
 def test_chunked_generate_serves_what_unchunked_does():
     """``generate()`` with a prompt of 40 in chunks of 16 and whole: the
-    same tokens, the reference's best, and the same counters."""
+    same tokens, the reference's best, and the same counters but
+    prefill's own, which count a chunk's experts and tiles: every held
+    expert read once a chunk a layer at most, its rows in one tile."""
     sz = sizes()
     cfg, params = config(sz), weights(sz)
     prompt = jnp.asarray(ids(2, 40, index=5))
@@ -188,15 +193,25 @@ def test_chunked_generate_serves_what_unchunked_does():
     chunked, counted_chunked = generate_with_counters(
         params, prompt, cfg, 8, prefill_chunk=16)
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(chunked))
-    assert {k: int(v) for k, v in counted.items()} \
-        == {k: int(v) for k, v in counted_chunked.items()}
+    counted = {k: int(v) for k, v in counted.items()}
+    counted_chunked = {k: int(v) for k, v in counted_chunked.items()}
+    in_prefill = {k: counted.pop(k) for k in PREFILL_COUNTED}
+    in_chunks = {k: counted_chunked.pop(k) for k in PREFILL_COUNTED}
+    assert counted == counted_chunked
+    # 4 held experts, 2 expert layers, 1 and 3 chunks; an expert gets at
+    # most 80 rows of a prefill tile of 128, so a tile an expert hit
+    assert 0 < in_prefill["experts_hit_prefill"] <= 4 * 2
+    assert in_prefill["experts_hit_prefill"] \
+        <= in_chunks["experts_hit_prefill"] <= 4 * 2 * 3
+    for prefill in (in_prefill, in_chunks):
+        assert prefill["tiles_prefill"] == prefill["experts_hit_prefill"]
     full = np.concatenate([np.asarray(prompt), np.asarray(whole)[:, :-1]],
                           axis=1)
     want = reference_logits(params, full, sz)[:, 39:]
     np.testing.assert_array_equal(np.asarray(whole), want.argmax(-1))
-    counted = {k: int(v) for k, v in counted.items()}
-    assert set(counted) == {"picks_held", "picks_zero", "picks_absent",
-                            "experts_hit_decode", "tiles_decode"}
+    assert set(counted) | set(in_prefill) == {
+        "picks_held", "picks_zero", "picks_absent", "experts_hit_decode",
+        "tiles_decode", "experts_hit_prefill", "tiles_prefill"}
     # two expert layers count; the dense layer has no router
     assert counted["picks_held"] + counted["picks_absent"] \
         == 2 * (40 + 8) * sz["top_k"] * 2
@@ -398,6 +413,8 @@ def test_call_sizes_at_the_cells_widths():
     assert got == {
         "cache_slots": 8320, "cache_bytes": 8064 * 8 * 8320,
         "ut_passes": 65, "experts_held": 12, "router_width": 192,
+        # a held expert's 8,192 × 8 / 192 = 341 rows a chunk in one tile
+        "prefill_expert_tile": 384,
         "shared_experts": 1, "dense_layers": 1, "expert_layers": 6,
         "prefill_chunks": 8,
         # every chunk of 1,024 queries over 1,024 … 8,192 keys keeps its
@@ -541,12 +558,13 @@ def test_who_does_not_implement_a_new_field_refuses_it_by_name(field):
     assert transformer.served_only(longcat) == []
 
 
-def _room_and_gather(u, local, weights, experts, dtype, accumulate):
+def _room_and_gather(u, local, weights, experts, dtype, tile, accumulate):
     """The way back of PRs 32 to 42, kept here as the reference whatever
     the call's shape: every tile's weighted rows written into a static
     room of (held + T · K / tile) tiles, gathered back to the picks'
-    order and summed over a token's K."""
-    del accumulate
+    order and summed over a token's K; its tiles ``_row_tile``'s, as
+    then."""
+    del tile, accumulate
     t, k = local.shape
     held, d, _ = experts["w1"].shape
     tile = moe._row_tile(t)
@@ -622,17 +640,18 @@ def _picks(case, tokens, rng):
 def test_prefill_adds_each_tiles_rows_into_their_tokens_rows(case,
                                                              monkeypatch):
     """A call of 40 positions a row (a prefill chunk) takes the way back
-    that adds each tile's rows into their tokens' rows: its output is the
-    room and gather's within one bfloat16 ulp (the same rounded rows
-    summed in float32 in another order) and its five counters are
-    equal."""
+    that adds each tile's rows into their tokens' rows, in the tiles of
+    its plan: its output is the room and gather's within one bfloat16 ulp
+    (the same rounded rows summed in float32 in another order), its
+    counters are equal but the tiles, and each ran ceil(rows / tile)
+    tiles an expert, by its own tile."""
     sz = sizes(layers=2)
     cfg = config(sz, dtype="bfloat16")
     blk = weights(sz)["blocks"][1]
     rng = np.random.default_rng(43)
-    u = jax.random.normal(jax.random.PRNGKey(43), (2, 40, 64), jnp.bfloat16)
-    picks = jnp.asarray(_picks(case, 80, rng), jnp.int32)
-    weights_ = jnp.asarray(rng.uniform(0.1, 1.0, (80, 4)), jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(43), (4, 40, 64), jnp.bfloat16)
+    picks = jnp.asarray(_picks(case, 160, rng), jnp.int32)
+    weights_ = jnp.asarray(rng.uniform(0.1, 1.0, (160, 4)), jnp.float32)
     monkeypatch.setattr(moe, "route", lambda *_: (picks, weights_))
 
     def layer():
@@ -643,14 +662,115 @@ def test_prefill_adds_each_tiles_rows_into_their_tokens_rows(case,
     got, counted = layer()
     monkeypatch.setattr(moe, "_held_experts", _room_and_gather)
     want, counted_want = layer()
+    tile = moe.prefill_plan(160, 4, cfg.routed_experts + cfg.zero_experts,
+                            64)["tile"]
+    assert tile == 128 and moe._row_tile(160) == 16
+    rows = [int((np.asarray(picks) == e).sum()) for e in range(4, 8)]
+    assert counted.pop("tiles") == sum(-(-n // tile) for n in rows)
+    assert counted_want.pop("tiles") == sum(-(-n // 16) for n in rows)
     assert counted == counted_want
-    held = int((np.asarray(picks) >= 4).sum() - (np.asarray(picks) >= 8).sum())
-    assert counted["picks_held"] == held
+    assert counted["picks_held"] == sum(rows)
     if case == "an_expert_with_several_tiles":
-        assert counted["tiles"] >= 5  # expert 6's 80 rows in tiles of 16
-    if not held:
+        # expert 6's 160 rows in two tiles of 128 (ten of 16)
+        assert rows[2] == 160 and -(-rows[2] // tile) == 2
+    if not sum(rows):
         assert not got.any() and not want.any()
         return
+    assert np.abs(want).max() > 0.05
+    ulp = np.exp2(np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - 7)
+    assert (np.abs(got - want) <= ulp).all()
+
+
+@pytest.mark.parametrize("tokens, k, width, d, plan", [
+    pytest.param(8192, 8, 192, 7168, {"pieces": 1, "tile": 384},
+                 id="serve_axk1_1chip"),
+    pytest.param(8192, 12, 768, 6144, {"pieces": 1, "tile": 128},
+                 id="serve_longcat_1chip"),
+    pytest.param(3072, 8, 192, 7168, {"pieces": 1, "tile": 128},
+                 id="expected_rows_of_128"),
+    pytest.param(3073, 8, 192, 7168, {"pieces": 1, "tile": 256},
+                 id="expected_rows_just_above_128"),
+    pytest.param(9362, 8, 192, 7168, {"pieces": 1, "tile": 512},
+                 id="the_most_tokens_one_piece_holds"),
+    pytest.param(65536, 8, 192, 7168, {"pieces": 8, "tile": 384},
+                 id="an_accumulator_past_the_bound"),
+])
+def test_the_prefill_tile_holds_an_experts_expected_rows(tokens, k, width,
+                                                         d, plan):
+    """A prefill call runs its tokens in one piece where their float32
+    accumulator fits the bound (224 MiB at A.X-K1's chunk), and an
+    expert's expected rows a piece (tokens × k / the router's width, its
+    zero-compute outputs counted) in one tile: the multiple of 128 at or
+    above them."""
+    assert moe.prefill_plan(tokens, k, width, d) == plan
+    most = -(-tokens // plan["pieces"])
+    assert most * -(-d // 128) * 128 * 4 <= moe._MAX_ACCUMULATOR_BYTES
+    expected = most * k / width
+    assert plan["tile"] - 128 < expected <= plan["tile"]
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_a_cached_step_keeps_its_tiles_of_16(rows, monkeypatch):
+    """One position a row (a cached step of the cells' 8 and 64 rows)
+    keeps ``_row_tile``'s 16 rows: an expert that every row picks runs
+    ceil(rows / 16) tiles."""
+    sz = sizes(layers=2)
+    cfg = config(sz, dtype="bfloat16")
+    blk = weights(sz)["blocks"][1]
+    rng = np.random.default_rng(rows)
+    picks = jnp.asarray(_picks("an_expert_with_several_tiles", rows, rng),
+                        jnp.int32)
+    weights_ = jnp.asarray(rng.uniform(0.1, 1.0, (rows, 4)), jnp.float32)
+    monkeypatch.setattr(moe, "route", lambda *_: (picks, weights_))
+    u = jax.random.normal(jax.random.PRNGKey(rows), (rows, 1, 64),
+                          jnp.bfloat16)
+    _, counters = moe.expert_layer(u, blk["router"], blk["experts"], cfg)
+    counted = dict(zip(moe.COUNTERS, np.asarray(counters).tolist()))
+    assert moe._row_tile(rows) == 16
+    held = [int((np.asarray(picks) == e).sum()) for e in range(4, 8)]
+    assert held[2] == rows
+    assert counted["tiles"] == sum(-(-n // 16) for n in held)
+
+
+@pytest.mark.parametrize("tile", [128, 16])
+def test_one_piece_in_the_plans_tiles_adds_what_pieces_of_small_tiles_did(
+        tile, monkeypatch):
+    """A prefill call of 320 tokens (4 rows of 80) whose picks crowd
+    experts 4 and 5 past a tile of 128: the plan's one piece in tiles of
+    128 against the way before it, the tokens in pieces of at most 512
+    picks (as ``_MAX_ROWS`` cut a chunk of the cell in three) and tiles
+    of ``tile`` rows (128 at the cell, ``_row_tile``'s 16 at this size).
+    The same output within one bfloat16 ulp (the same rows, each token's
+    picks added in the same order), the same picks and experts hit, and
+    fewer tiles."""
+    sz = sizes(layers=2)
+    cfg = config(sz, dtype="bfloat16")
+    blk = weights(sz)["blocks"][1]
+    rng = np.random.default_rng(44)
+    picks = _picks("uniform", 320, rng)
+    picks[:200] = np.stack([np.concatenate(
+        [[4, 5], rng.choice(np.delete(np.arange(16), [4, 5]), 2,
+                            replace=False)]) for _ in range(200)])
+    picks = jnp.asarray(picks, jnp.int32)
+    weights_ = jnp.asarray(rng.uniform(0.1, 1.0, (320, 4)), jnp.float32)
+    monkeypatch.setattr(moe, "route", lambda *_: (picks, weights_))
+    u = jax.random.normal(jax.random.PRNGKey(44), (4, 80, 64), jnp.bfloat16)
+
+    def layer():
+        m, counters = moe.expert_layer(u, blk["router"], blk["experts"], cfg)
+        return (np.asarray(m, np.float32),
+                dict(zip(moe.COUNTERS, np.asarray(counters).tolist())))
+
+    got, counted = layer()
+    assert moe.prefill_plan(320, 4, 16, 64) == {"pieces": 1, "tile": 128}
+    monkeypatch.setattr(moe, "prefill_plan", lambda tokens, k, width, d: {
+        "pieces": -(-tokens * k // 512), "tile": tile})
+    want, counted_want = layer()
+    rows = [int((np.asarray(picks) == e).sum()) for e in range(4, 8)]
+    assert rows[0] > 128 and rows[1] > 128  # two tiles each here
+    assert counted["tiles"] == sum(-(-n // 128) for n in rows)
+    assert counted.pop("tiles") < counted_want.pop("tiles")
+    assert counted == counted_want
     assert np.abs(want).max() > 0.05
     ulp = np.exp2(np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - 7)
     assert (np.abs(got - want) <= ulp).all()

@@ -281,12 +281,19 @@ def test_prefill_attention_compiles_for_v5e(call, one_chip, as_on_tpu):
     assert f",{queries},{slots + queries}]" not in compiled
 
 
-# (tokens, lanes of a row, tile): a sub-chunk of serve_axk1_1chip's
-# prefill (2,731 tokens of 7,168 lanes) and one of serve_longcat_1chip's
-# (2,048 of 6,144), in tiles of 128 rows; a row narrower than a lane tile
+# (tokens, lanes of a row, tile): a prefill chunk of serve_axk1_1chip
+# (8,192 tokens of 7,168 lanes) in its plan's tiles of 384 rows and one of
+# serve_longcat_1chip (8,192 of 6,144) in tiles of 128; the same chunks
+# in three and four pieces, as a bound on picks once cut them, in tiles
+# of 128; a tile of 512 at A.X-K1's lanes, past Mosaic's default VMEM
+# limit (the plan gives it to a call of 9,362 tokens, the most one piece
+# holds there); a row narrower than a lane tile
 ADD_ROWS_CALLS = {
+    "serve_axk1_1chip_chunk": (8192, 7168, 384),
+    "serve_longcat_1chip_chunk": (8192, 6144, 128),
     "serve_axk1_1chip_sub_chunk": (2731, 7168, 128),
     "serve_longcat_1chip_sub_chunk": (2048, 6144, 128),
+    "a_tile_of_512_rows": (9362, 7168, 512),
     "a_row_of_64_lanes": (80, 64, 16),
 }
 

@@ -404,9 +404,12 @@ def test_the_accepted_configurations_build_what_they_built(name):
     if name == "longcat-flash-omni":
         assert set(sized) == {"cache_slots", "cache_bytes", "ut_passes",
                               "experts_held", "router_width",
+                              "prefill_expert_tile",
                               "ffn_streamed_layers", "ffn_streamed_bytes",
                               "attention_streamed_layers",
                               "attention_streamed_bytes"}
+        # 256 tokens × 12 picks over 768 outputs: 4 rows, a tile of 128
+        assert sized["prefill_expert_tile"] == 128
         assert cache[0]["attn"][1]["latent"].shape == (1, 2, 256, 576)
         assert sized["cache_bytes"] == 8 * 2 * 256 * 576 * 2
     else:

@@ -258,8 +258,10 @@ def test_a_pick_of_an_absent_expert_adds_exactly_nothing():
                  id="the_first_and_the_last_held"),
     pytest.param((0, 16), [2, 7, 8, 13, 17, 22], (3, 7), {2, 7, 8, 13},
                  id="runs_of_unpicked_experts_between"),
-    pytest.param((4, 4), [6, 1, 2, 3], (2, 20), {6},
+    pytest.param((4, 4), [6, 1, 2, 3], (3, 100), {6},
                  id="an_expert_with_three_tiles"),
+    pytest.param((4, 4), [6, 1, 2, 3], (40, 1), {6},
+                 id="a_cached_step_with_three_tiles"),
     pytest.param((0, 16), [], (4, 16), set(range(16)),
                  id="the_routers_own_picks"),
     pytest.param((4, 4), [0, 3, 9, 15], (3, 7), set(),
@@ -270,7 +272,9 @@ def test_the_tiles_follow_the_picks(held, favoured, shape, hit):
     experts (each token's own four of them): the grouped product runs
     ceil(picks / tile) tiles for an expert and none for one nobody
     picked, wherever those lie among the experts held, and the layer is
-    the reference's masked loop over every expert held."""
+    the reference's masked loop over every expert held. The tile is the
+    prefill plan's where the call has more than one position a row (128
+    here), ``_row_tile``'s at a cached step (16)."""
     sz = sizes(layers=1, held=held)
     blk = weights(sz)["blocks"][0]
     router = blk["router"]
@@ -285,8 +289,11 @@ def test_the_tiles_follow_the_picks(held, favoured, shape, hit):
     first, count = held
     picked = np.asarray([(picks == first + e).sum() for e in range(count)])
     assert {first + e for e in np.flatnonzero(picked)} == hit
-    tile = moe._row_tile(shape[0] * shape[1])
-    assert tile == 16
+    width = sz["routed_experts"] + sz["zero_experts"]
+    tile = moe.prefill_plan(shape[0] * shape[1], sz["top_k"], width,
+                            64)["tile"] if shape[1] > 1 \
+        else moe._row_tile(shape[0])
+    assert tile == (128 if shape[1] > 1 else 16)
     assert counted["tiles"] == sum(-(-n // tile) for n in picked)
     assert counted["experts_hit"] == len(hit)
     assert counted["picks_held"] == picked.sum()
@@ -344,7 +351,13 @@ def test_generate_is_one_scan_and_its_counters_add_up():
     tokens, counters = generate_with_counters(params, prompt, cfg, 8)
     counters = {k: int(v) for k, v in counters.items()}
     assert set(counters) == {"picks_held", "picks_zero", "picks_absent",
-                             "experts_hit_decode", "tiles_decode"}
+                             "experts_hit_decode", "tiles_decode",
+                             "experts_hit_prefill", "tiles_prefill"}
+    # the prompt's 48 tokens give an expert at most 48 rows of a prefill
+    # tile of 128: one tile an expert hit, 4 held in each of 2 layers
+    assert 0 < counters["experts_hit_prefill"] <= 4 * 2
+    assert counters["tiles_prefill"] >= counters["experts_hit_prefill"]
+    assert counters["tiles_prefill"] == counters["experts_hit_prefill"]
     assert (counters["picks_held"] + counters["picks_zero"]
             + counters["picks_absent"]) == 3 * (16 + 8) * sz["top_k"] * 2
     assert 0 < counters["experts_hit_decode"] <= 8 * 2 * 4
@@ -383,12 +396,16 @@ def test_call_sizes_at_the_cells_widths():
     assert call_sizes(cfg, 64, 128, 128) == {
         "cache_slots": 256, "cache_bytes": 9216 * 64 * 256,
         "ut_passes": 129, "experts_held": 16, "router_width": 768,
+        # a held expert's 8,192 × 12 / 768 = 128 rows in one tile
+        "prefill_expert_tile": 128,
         # the two dense feed-forwards of each of the 4 double layers
         "ffn_streamed_layers": 8,
         "ffn_streamed_bytes": 8 * 3 * 6144 * 12288 * 2,
         # latent caches are attended as they lie, by no kernel
         "attention_streamed_layers": 0, "attention_streamed_bytes": 0}
     assert call_sizes(cfg, 1, 1, 127)["cache_bytes"] == 9216 * 128
+    # a prompt of one position is a cached step's call: _row_tile's
+    assert call_sizes(cfg, 1, 1, 127)["prefill_expert_tile"] == 16
     shapes = jax.eval_shape(lambda: init_kv_cache(cfg, 64, 256))
     assert len(shapes) == 4
     assert [c["latent"].shape for c in shapes[0]["attn"]] == [
